@@ -2,7 +2,7 @@
 
 With no paths, scans the repository tree the package sits in: the package
 itself plus ``tests/``, ``benchmarks/``, ``bench.py`` and
-``__graft_entry__.py`` (the env-hatch dead-flag check needs the whole tree —
+``chip_smoke.py`` (the env-hatch dead-flag check needs the whole tree —
 several hatches are read only by the harness).  Exit status: 0 when no
 violations remain after baseline filtering, 1 otherwise, 2 on usage errors.
 
@@ -38,7 +38,7 @@ from mpi4dl_tpu.analysis import (
 
 
 def default_paths(root: str) -> List[str]:
-    cand = ["mpi4dl_tpu", "tests", "benchmarks", "bench.py", "__graft_entry__.py"]
+    cand = ["mpi4dl_tpu", "tests", "benchmarks", "bench.py", "chip_smoke.py"]
     return [os.path.join(root, c) for c in cand if os.path.exists(os.path.join(root, c))]
 
 

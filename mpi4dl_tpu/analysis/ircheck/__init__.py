@@ -12,7 +12,7 @@ read-after-donate alias or a DMA/compute race becomes a finding on a CPU
 host instead of a hang on silicon (T3, arXiv:2401.16677; the MPMD
 program-graph direction, arXiv:2412.14374).
 
-Finding taxonomy (every kind has a violating fixture in
+Finding classification (every kind has a violating fixture in
 tests/test_ircheck.py; docs/analysis.md walks the semantics):
 
 jaxpr level (``check_jaxpr``):

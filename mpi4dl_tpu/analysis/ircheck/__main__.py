@@ -4,7 +4,7 @@
 
 Builds each contract engine family on the virtual CPU mesh, lowers and
 compiles it, and runs every IR-level check (see the package docstring for
-the finding taxonomy).  Exit status mirrors the analyzer: 0 = no findings
+the finding classification).  Exit status mirrors the analyzer: 0 = no findings
 after baseline filtering, 1 = findings, 2 = usage/environment errors.
 The CI job runs all 8 families with ``--json --out`` and uploads the
 findings as an artifact on failure.

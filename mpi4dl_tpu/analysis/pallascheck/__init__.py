@@ -12,7 +12,7 @@ WAR-hazard note, the hand-maintained VMEM caps — are now checked, and an
 inter-chip ``make_async_remote_copy`` kernel will be enrolled into the same
 gate by one registry row.
 
-Finding taxonomy (every kind has an injected-violation fixture in
+Finding classification (every kind has an injected-violation fixture in
 tests/test_pallascheck.py; keys are ``kernel:grid_point_class:kind`` with a
 grid-point class like ``lo-mid-hi`` — one coordinate class per grid dim —
 so baselines survive shape tweaks that keep the failure class):

@@ -4,7 +4,7 @@
 
 Traces every kernel case registered in ``mpi4dl_tpu.ops.kernel_registry``
 on the CPU host (no TPU compile), enumerates each kernel's full grid, and
-runs every check (see the package docstring for the finding taxonomy).
+runs every check (see the package docstring for the finding classification).
 Exit status mirrors the analyzer: 0 = no findings after baseline
 filtering, 1 = findings, 2 = usage/environment errors.  The CI job runs
 the full registry with ``--json --out`` + ``--sarif`` and uploads both as

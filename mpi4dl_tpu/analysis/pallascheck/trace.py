@@ -11,7 +11,7 @@ sub-jaxprs), and lifts the parts the checks consume into a stable
   inputs, outputs, scratch);
 - the kernel jaxpr itself, for the DMA/accumulator abstract interpreter.
 
-Written against jax 0.4.37's pallas internals (``GridMapping``/
+Written against jax 0.9.0's pallas internals (``GridMapping``/
 ``BlockMapping``); everything reached here is exercised by
 tests/test_pallascheck.py so a jax upgrade that moves a field fails loudly
 in the fixture lane, not silently in the gate.
@@ -152,11 +152,11 @@ def spec_of_eqn(eqn, case_name: str) -> KernelSpec:
             role = "in" if io < n_in else "out"
             label = f"in{io}" if io < n_in else f"out{io - n_in}"
             bm = block_mappings[io]
-            sd = getattr(bm, "array_shape_dtype", None)
-            arr_shape = tuple(int(d) for d in sd.shape) if sd is not None else None
+            arr_shape = tuple(int(d) for d in bm.array_aval.shape)
             imap = None if ms == ANY else bm.index_map_jaxpr
+            # Blocked dims carry their size; Squeezed dims are size 1.
             bs = tuple(
-                1 if d is None else int(d)
+                int(getattr(d, "block_size", 1))
                 for d in (bm.block_shape or shape)
             )
             shape = bs or shape

@@ -1,97 +1,44 @@
-"""Version tolerance for the jax APIs the parallel schedules lean on.
+"""The jax surface the package leans on, in one place.
 
-The package targets vma-aware jax (``jax.shard_map`` with varying-manual-axes
-tracking, ``lax.pcast``).  Older jax (0.4.x) still ships the experimental
-``shard_map`` with the ``check_rep`` flag and no pcast; this module papers
-over the difference so the package imports and the 8-device CPU test mesh
-runs on both:
+One supported jax: the vma-aware line (``jax.shard_map`` with
+varying-manual-axes tracking, ``lax.pcast``); ``contracts/lp.json`` records
+the version the goldens were made on and CI installs it.
 
-- :func:`shard_map` — ``jax.shard_map`` when present, else
-  ``jax.experimental.shard_map.shard_map`` with ``check_rep=False``.
-- :func:`pcast` — ``lax.pcast`` when present, else identity.
-- :func:`ensure_host_device_count` — the ``jax_num_cpu_devices`` config
-  option with the ``XLA_FLAGS`` fallback for older jax (shared by
-  tests/conftest.py and benchmarks/common.py).
-
-CAVEAT (legacy jax only): forward programs are identical, but the vma
-varying-marks (``pcast``) that the pipeline/GEMS schedules document as
-required for correct shard_map AD become no-ops, and the old
-``check_rep=False`` AD has known cotangent-scaling differences — gradient
-exactness of the scan-engine schedules is NOT guaranteed on jax 0.4.x
-(their exact-match tests fail there; single-device/DP/SP paths are fine).
-A one-line stderr note is emitted at import so training runs can't hit
-this silently.
-
-Import sites use ``from mpi4dl_tpu.compat import shard_map, pcast`` instead
-of reaching into jax directly.
+- :func:`shard_map`, :func:`pcast` — re-exports, so import sites read
+  ``from mpi4dl_tpu.compat import shard_map, pcast``.
+- :func:`ensure_host_device_count` — virtual CPU devices for the CPU mesh
+  (tests/conftest.py, benchmarks/common.py, the analysis CLIs).
+- :func:`ensure_compilation_cache` — where the persistent compile cache goes.
 """
 
 from __future__ import annotations
 
+import os
+from typing import Optional
+
+import jax
 from jax import lax
+from jax import shard_map  # noqa: F401 — re-export
 
-try:  # vma-aware shard_map (new jax)
-    from jax import shard_map as _shard_map
+pcast = lax.pcast
 
-    _LEGACY = False
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _LEGACY = True
-    import warnings as _warnings
-
-    _warnings.warn(
-        "mpi4dl_tpu.compat: legacy jax (<jax.shard_map) — vma varying-marks "
-        "are no-ops; pipeline/GEMS gradient exactness is not guaranteed on "
-        "this jax version (see mpi4dl_tpu/compat.py)",
-        stacklevel=2,
-    )
-
-# Public version guard: True on legacy jax (0.4.x line — no top-level
-# jax.shard_map, check_rep=False AD, pcast no-op).  The engine exactness
-# tests skipif on this (tests/*: the documented old-jax failures), so they
-# auto-unskip on any vma-aware jax.
-LEGACY_JAX = _LEGACY
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    # normalize the checker kwarg across the rename (check_rep -> check_vma)
-    if _LEGACY:
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        kwargs.setdefault("check_rep", False)
-    elif "check_rep" in kwargs and "check_vma" not in kwargs:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
-
-if hasattr(lax, "pcast"):
-    pcast = lax.pcast
-else:
-
-    def pcast(x, axes, to="varying"):
-        del axes, to  # no vma tracking on this jax — nothing to cast
-        return x
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def ensure_host_device_count(n: int) -> None:
-    """Request an ``n``-device CPU platform.  New jax: the
-    ``jax_num_cpu_devices`` config option (inert unless the CPU platform is
-    actually selected).  Older jax: the equivalent ``XLA_FLAGS`` host-device
-    flag, effective as long as no backend has initialized yet."""
-    import os
+    """Request an ``n``-device CPU platform (``jax_num_cpu_devices``; read
+    at backend initialisation, inert unless the CPU platform is selected)."""
+    jax.config.update("jax_num_cpu_devices", n)
 
-    import jax
 
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except Exception:  # noqa: BLE001 — option missing on this jax
-        if "--xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""
-        ):
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "")
-                + f" --xla_force_host_platform_device_count={n}"
-            ).strip()
+def ensure_compilation_cache() -> Optional[str]:
+    """Place jax's persistent compilation cache; call before the first
+    compile.  Where ``JAX_COMPILATION_CACHE_DIR`` is set jax already reads
+    it, so this does nothing and returns None.  Otherwise the cache goes to
+    the fixed ``<checkout>/.jax_cache`` (the path is part of the cache key,
+    so a directory that moves never hits) and that path is returned."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -79,15 +79,16 @@ def build_mesh(
 ) -> Mesh:
     """Build a named Mesh of shape (data, stage, sph, spw).
 
-    With fewer physical devices than ``spec.size`` this raises — tests use the
-    8-device CPU fixture; the driver validates multi-chip via
-    ``__graft_entry__.dryrun_multichip``.
+    With fewer devices than ``spec.size`` this raises — tests use the
+    8-device CPU fixture; ``chip_smoke.py --four-chips`` runs real meshes.
     """
     devices = list(devices if devices is not None else jax.devices())
     need = spec.size
     if len(devices) < need:
         raise ValueError(
-            f"mesh {spec} needs {need} devices, have {len(devices)}"
+            f"mesh {spec} needs {need} devices, have {len(devices)} "
+            "(virtual CPU devices are provisioned only under "
+            "JAX_PLATFORMS=cpu)"
         )
     arr = np.array(devices[:need]).reshape(spec.shape)
     return Mesh(arr, AXES)

@@ -23,6 +23,7 @@ from typing import Any, Callable, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from mpi4dl_tpu.cells import (
     Cell, CellModel, LayerCell, _unpack_one, checkpointed_apply,
@@ -104,7 +105,14 @@ class FactorizedReduce(Cell):
              self.conv2.apply(params["conv2"], x, ctx)],
             axis=-1,
         )
-        return self.bn.apply(params["bn"], y, ctx)
+        y = self.bn.apply(params["bn"], y, ctx)
+        # The barrier is a fusion boundary the chip needs: XLA:TPU (libtpu
+        # 0.0.34 under jax 0.9.0) miscompiles the bf16 backward of the cell
+        # this feeds when this output fuses into its consumers — at 1024²
+        # every gradient upstream of here came back NaN on the v5e, for any
+        # input and any cotangent, while op-by-op execution, fp32 compute
+        # and this barrier are all finite (PR 22, PERF.md).
+        return lax.optimization_barrier(y)
 
 
 def op_none(c: int, stride: int) -> Cell:

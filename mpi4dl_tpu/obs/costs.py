@@ -57,35 +57,43 @@ ICI_BYTES_PER_S = [
 DEFAULT_ICI_BYTES_PER_S = 1e10
 
 
+def _known_kind(device, table, what: str) -> float:
+    """First ``table`` row whose key is a substring of the device kind.  A
+    device that is not in the table is an error, not a default: a guessed
+    peak turns every utilization built on it into a guess."""
+    kind = (getattr(device, "device_kind", "") or "").lower()
+    for sub, value in table:
+        if sub in kind:
+            return value
+    raise ValueError(
+        f"no {what} known for device kind {kind!r} (platform "
+        f"{device.platform!r}); add it to mpi4dl_tpu/obs/costs.py with its "
+        "source"
+    )
+
+
 def ici_bytes_per_s(device) -> Tuple[float, str]:
     """(aggregate ICI bytes/s, source) for a jax device; source mirrors
-    :func:`peak_flops`: ``"table"``, ``"assumed-max"``, ``"nominal-cpu"``."""
-    kind = (getattr(device, "device_kind", "") or "").lower()
+    :func:`peak_flops`: ``"table"`` or ``"nominal-cpu"``.  Raises
+    ValueError for an accelerator kind the table does not know."""
     if device is None or device.platform == "cpu":
         return DEFAULT_ICI_BYTES_PER_S, "nominal-cpu"
-    for sub, bw in ICI_BYTES_PER_S:
-        if sub in kind:
-            return bw, "table"
-    return max(b for _, b in ICI_BYTES_PER_S), "assumed-max"
+    return _known_kind(device, ICI_BYTES_PER_S, "ICI bandwidth"), "table"
 
 
 def peak_flops(device, allow_cpu_nominal: bool = False
                ) -> Tuple[Optional[float], Optional[str]]:
     """(peak FLOP/s, source) for a jax device.
 
-    source: ``"table"`` (known kind), ``"assumed-max"`` (unknown accelerator
-    — over-estimate so an mfu>1 impossibility check stays sound, bench.py's
-    rule), ``"nominal-cpu"`` (only with ``allow_cpu_nominal``), or None.
+    source: ``"table"`` (known kind), ``"nominal-cpu"`` (only with
+    ``allow_cpu_nominal``), or None on the CPU.  Raises ValueError for an
+    accelerator kind the table does not know.
     """
-    kind = (getattr(device, "device_kind", "") or "").lower()
     if device.platform == "cpu":
         if allow_cpu_nominal:
             return CPU_NOMINAL_PEAK_FLOPS, "nominal-cpu"
         return None, None
-    for sub, peak in PEAK_BF16_FLOPS:
-        if sub in kind:
-            return peak, "table"
-    return max(p for _, p in PEAK_BF16_FLOPS), "assumed-max"
+    return _known_kind(device, PEAK_BF16_FLOPS, "bf16 peak FLOP/s"), "table"
 
 
 def compiled_cost(compiled) -> Dict[str, Optional[float]]:
@@ -126,7 +134,7 @@ def arithmetic_intensity(flops: Optional[float],
                          bytes_accessed: Optional[float]) -> Optional[float]:
     """FLOPs per HBM byte — the roofline abscissa; low values say the step
     is bandwidth-bound and more MFU needs fusion/layout work, not schedule
-    work (PERF_NOTES r5's 0.10-0.18 MFU diagnosis made quantitative)."""
+    work."""
     if not flops or not bytes_accessed:
         return None
     return flops / bytes_accessed

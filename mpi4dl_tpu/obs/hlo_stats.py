@@ -150,7 +150,7 @@ _WRAPPER_RE = re.compile(
 # semantic region they belong to).
 _FRAMING_COMPONENTS = re.compile(
     r"^(?:checkpoint|rematted_computation|remat|while|body|cond|"
-    r"branch_\d+(?:_fun)?|None)$"
+    r"branch_\d+(?:_fun)?|shard_map|closed_call|None)$"
 )
 
 _MLIR_TENSOR_RE = re.compile(
@@ -202,9 +202,12 @@ def _mlir_type_bytes(type_str: str) -> int:
 
 
 def _named_loc_path(loc_str: str) -> Optional[str]:
-    """The op-name path inside an MLIR location string, if any:
-    ``loc("jit(step)/.../ppermute"(callsite(...)))`` -> the quoted path."""
-    m = re.search(r'"((?:jit|shmap|pjit)[^"]*)"', loc_str)
+    """The op-name path of an MLIR name location, if the location is one:
+    ``loc("jit(step)/.../ppermute"(callsite(...)))`` -> the quoted path.
+    Inside a ``shard_map`` body jax restarts the name stack, so the path
+    there begins at the first ``obs.scope`` (``"loss_reduce/psum"``), not at
+    ``jit(``.  A file location (``loc("x.py":3:1)``) is not a name."""
+    m = re.match(r'loc\("([^"]*)"\(', loc_str)
     return m.group(1) if m else None
 
 

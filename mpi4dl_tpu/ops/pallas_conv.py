@@ -117,13 +117,17 @@ def _kernel_stats(x_any, w_any, o_ref, s_ref, sq_ref, xwin, wbuf, acc, sem,
     i = pl.program_id(0)
     j = pl.program_id(1)
     h0, h1, w0, w1 = win
-    ri = jax.lax.broadcasted_iota(jnp.int32, (th, tw), 0) + i * th
-    ci = jax.lax.broadcasted_iota(jnp.int32, (th, tw), 1) + j * tw
+    # The window mask is built on the accumulator's own [th*tw, tco] layout,
+    # one column that broadcasts along the lanes: Mosaic refuses the
+    # [th, tw] -> [th, tw, 1] shape cast a 2-D mask would need.
+    p = jax.lax.broadcasted_iota(jnp.int32, (th * tw, 1), 0)
+    ri = p // tw + i * th
+    ci = p % tw + j * tw
     valid = (ri >= h0) & (ri < h1) & (ci >= w0) & (ci < w1)
-    yf = o_ref[:].astype(jnp.float32)
-    yv = jnp.where(valid[:, :, None], yf, 0.0)
-    s_ref[0, 0, :] = jnp.sum(yv, axis=(0, 1))
-    sq_ref[0, 0, :] = jnp.sum(yv * yv, axis=(0, 1))
+    yf = acc[:].astype(o_ref.dtype).astype(jnp.float32)
+    yv = jnp.where(valid, yf, 0.0)
+    s_ref[0, 0] = jnp.sum(yv, axis=0, keepdims=True)
+    sq_ref[0, 0] = jnp.sum(yv * yv, axis=0, keepdims=True)
 
 
 # Per-core VMEM pool the kernel budgets against (~16 MiB on current TPUs;
@@ -357,9 +361,13 @@ def halo_conv2d(
         )
         y = jax.vmap(call, in_axes=(0, None))(x_p, w_pd)
         return y[:, :h, :wid, :cout]
-    stat_shape = (grid[0], grid[1], cout_p)
+    # One [1, tco] row per program.  The unit dim before the lanes is the
+    # ARRAY's own extent there: Mosaic requires a block's last two dims to
+    # be (8, 128)-divisible or equal to the array's, and a (1, 1, tco) block
+    # of a [gi, gj, Cout] array is neither (refused when compiled for v5e).
+    stat_shape = (grid[0], grid[1], 1, cout_p)
     stat_spec = pl.BlockSpec(
-        (1, 1, tco), lambda i, j, c: (i, j, c), memory_space=pltpu.VMEM
+        (1, 1, 1, tco), lambda i, j, c: (i, j, 0, c), memory_space=pltpu.VMEM
     )
     call = pl.pallas_call(
         functools.partial(
@@ -380,8 +388,8 @@ def halo_conv2d(
     y, s, ss = jax.vmap(call, in_axes=(0, None))(x_p, w_pd)
     return (
         y[:, :h, :wid, :cout],
-        jnp.sum(s, axis=(0, 1, 2))[:cout],
-        jnp.sum(ss, axis=(0, 1, 2))[:cout],
+        jnp.sum(s, axis=(0, 1, 2, 3))[:cout],
+        jnp.sum(ss, axis=(0, 1, 2, 3))[:cout],
     )
 
 

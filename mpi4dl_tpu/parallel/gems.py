@@ -43,7 +43,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from mpi4dl_tpu.layer_ctx import ApplyCtx
 from mpi4dl_tpu.obs.scopes import scope
 from mpi4dl_tpu.parallel.partition import StagePartition
-from mpi4dl_tpu.parallel.pipeline import PipelineState, grad_pmean, metric_psum
+from mpi4dl_tpu.parallel.pipeline import PipelineState, grad_pmean
 from mpi4dl_tpu.quant.policy import QuantPolicy
 from mpi4dl_tpu.parallel.stage_common import (
     gems_dual_scan,
@@ -144,8 +144,8 @@ def make_gems_train_step(
                     )
             denom = 2 * times * Pn
             with scope("loss_reduce"):
-                loss = metric_psum(loss_acc, (AXIS_STAGE,)) / denom
-                acc = metric_psum(acc_acc, (AXIS_STAGE,)) / denom
+                loss = lax.psum(loss_acc, (AXIS_STAGE,)) / denom
+                acc = lax.psum(acc_acc, (AXIS_STAGE,)) / denom
                 if grad_axes:
                     loss = lax.pmean(loss, grad_axes)
                     acc = lax.pmean(acc, grad_axes)

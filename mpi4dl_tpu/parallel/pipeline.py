@@ -64,32 +64,6 @@ from mpi4dl_tpu.train import Optimizer
 from mpi4dl_tpu.mesh import AXIS_DATA, AXIS_STAGE
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def metric_psum(x, axes):  # analysis: ok(unscoped-collective) — callers own the loss_reduce scope
-    """``lax.psum`` for the scalar loss/metric accumulators, with a wire-free
-    transpose.  jax's psum is its own transpose, so differentiating
-    ``psum(loss_acc, axes)`` re-reduces the cotangent over the wire — but the
-    cotangent of a scalar loss is replicated (value_and_grad seeds 1.0), so
-    that backward all-reduce only multiplies by the axis size.  The custom
-    rule does the multiply statically (``psum(1, axes)`` constant-folds);
-    bit-identical gradients, one collective fewer per step (ircheck:
-    wasted-wire).  Only sound where the cotangent is axis-invariant — i.e.
-    reductions feeding a scalar objective, not arbitrary psums."""
-    return lax.psum(x, axes)
-
-
-def _metric_psum_fwd(x, axes):
-    return lax.psum(x, axes), None  # analysis: ok(unscoped-collective) — callers own the loss_reduce scope
-
-
-def _metric_psum_bwd(axes, _, ct):
-    # psum of a trace-time constant constant-folds: no wire, no scope owner.
-    return (ct * lax.psum(1, axes),)  # analysis: ok(unscoped-collective)
-
-
-metric_psum.defvjp(_metric_psum_fwd, _metric_psum_bwd)
-
-
 def grad_pmean(x, axes, quant: Optional[QuantPolicy]):  # analysis: ok(unscoped-collective) — callers own the grad_reduce/stats_reduce scopes
     """The engines' gradient/BN-stats ``pmean``, EQuARX-style-quantized
     when the policy's ``grad`` class is on (quantized all_to_all → exact
@@ -202,8 +176,8 @@ def make_pipeline_train_step(
             # Only the last stage accumulated; psum broadcasts to all stages
             # (and sums over data-parallel groups' mean below).
             with scope("loss_reduce"):
-                loss = metric_psum(loss_acc, (AXIS_STAGE,)) / Pn
-                acc = metric_psum(acc_acc, (AXIS_STAGE,)) / Pn
+                loss = lax.psum(loss_acc, (AXIS_STAGE,)) / Pn
+                acc = lax.psum(acc_acc, (AXIS_STAGE,)) / Pn
                 if grad_axes:
                     loss = lax.pmean(loss, grad_axes)
                     acc = lax.pmean(acc, grad_axes)

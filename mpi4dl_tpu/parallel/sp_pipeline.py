@@ -72,7 +72,7 @@ from mpi4dl_tpu.parallel.partition import (
     pad_to,
     stat_leaf_info,
 )
-from mpi4dl_tpu.parallel.pipeline import grad_pmean, metric_psum
+from mpi4dl_tpu.parallel.pipeline import grad_pmean
 from mpi4dl_tpu.parallel.spatial import (
     apply_junction,
     apply_spatial_region,
@@ -379,13 +379,18 @@ def _make_sp_step(
                     branches, tail_flat, x_parts, y_parts, vary_axes
                 )
             with scope("loss_reduce"):
-                loss = metric_psum(loss_acc, (AXIS_STAGE,)) / denom
-                acc = metric_psum(acc_acc, (AXIS_STAGE,)) / denom
-                # Under 'gather' every tile device saw the full batch, so
-                # loss/acc are already tile-invariant and the pmean would be
-                # an identity over the wire (ircheck: wasted-wire); only the
-                # batch_split junction leaves per-tile batch shards to merge.
-                if tile_axes and spp.junction == "batch_split":
+                loss = lax.psum(loss_acc, (AXIS_STAGE,)) / denom
+                acc = lax.psum(acc_acc, (AXIS_STAGE,)) / denom
+                # batch_split leaves per-tile batch shards to merge.  Under
+                # 'gather' every tile device saw the full batch and loss/acc
+                # ARE equal on every tile — but ``lax.all_gather`` is typed
+                # varying→varying, so typed shard_map cannot know it, and a
+                # collective has to say so: this pmean (two scalars; an
+                # identity on the value), as apply_spatial_model does for the
+                # SP family.  ``all_gather_invariant`` at the junction would
+                # say it without wire; it is not public API on jax 0.9.0 and
+                # needs the gradient bookkeeping below re-derived (ROADMAP D1).
+                if tile_axes:
                     loss = lax.pmean(loss, tile_axes)
                     acc = lax.pmean(acc, tile_axes)
                 if grad_axes:
@@ -428,13 +433,13 @@ def _make_sp_step(
             )
         if with_stats_tail:
             # Tail stats vary over the tile axes under junction='batch_split'
-            # (distinct batch shards) and over data; identical over tiles
-            # under 'gather', where the pmean would move the whole stats
-            # vector over the wire to reproduce it (ircheck: wasted-wire) —
-            # skip it there.
+            # (distinct batch shards) and over data.  Under 'gather' they are
+            # identical over tiles but typed varying (see loss_reduce above):
+            # the pmean is what makes new_tail provably tile-invariant for
+            # out_specs, at the cost of one stats vector on the wire.
             stt = tail_stats
             with scope("stats_reduce"):
-                if tile_axes and spp.junction == "batch_split":
+                if tile_axes:
                     stt = grad_pmean(stt, tile_axes, quant)
                 if grad_axes:
                     stt = grad_pmean(stt, grad_axes, quant)

@@ -352,6 +352,13 @@ def scatter_part_row(G, g, slot, mask):
     return lax.dynamic_update_index_in_dim(G, new, slot, 0)
 
 
+def _vary_like(ct, primal):
+    """``ct`` with the varying-axes type of the primal output it answers:
+    typed shard_map checks a VJP's cotangents against its outputs' types."""
+    missing = sorted(jax.typeof(primal).vma - jax.typeof(ct).vma)
+    return pcast(ct, tuple(missing), to="varying") if missing else ct
+
+
 def _make_fb_branches(
     branches: List[Callable],
     *,
@@ -407,7 +414,8 @@ def _make_fb_branches(
                 # micro-batch — its stage input is the live ``buf``, not a
                 # ring entry (statically selected: no where-materialised
                 # extra activation buffer).
-                (seed,) = ce_vjp(jnp.asarray(seed_scale, jnp.float32))
+                (seed,) = ce_vjp(
+                    _vary_like(jnp.asarray(seed_scale, jnp.float32), l))
                 cot_y = jnp.where(valid_out, seed, 0.0).astype(compute_dtype)
                 a_in = buf
             # Sequence the backward after the forward: without the barrier
@@ -426,10 +434,11 @@ def _make_fb_branches(
             # linearization residuals (a plain jax.vjp would materialize
             # every transpose operand during the forward sweep and hold it
             # across the whole stage body).
-            _, vjp = jax.vjp(jax.checkpoint(fwd), flat_params, a_in)
-            gp, ga = vjp(
-                (cot_y, jnp.zeros((stat_n,), jnp.float32))
-            )
+            (y_r, st_r), vjp = jax.vjp(jax.checkpoint(fwd), flat_params, a_in)
+            gp, ga = vjp((
+                _vary_like(cot_y, y_r),
+                _vary_like(jnp.zeros((stat_n,), jnp.float32), st_r),
+            ))
             return y, st, l, a, ga, gp
 
         return fn

@@ -27,7 +27,7 @@ a multi-day pathology run.  This package turns the existing pieces
   typed per-scenario verdicts; ``--supervisor`` drills the supervisor's
   whole control plane.
 - :mod:`~mpi4dl_tpu.resilience.supervisor` — the elastic supervisor
-  (ISSUE 15): legs as subprocesses, typed failure taxonomy, per-class
+  (ISSUE 15): legs as subprocesses, typed failure classification, per-class
   retry/backoff, poison-batch quarantine, degrade-and-continue.
 - :mod:`~mpi4dl_tpu.resilience.planner` — the degradation ladder + the
   compile-only feasibility probe the supervisor re-plans with; ISSUE 18

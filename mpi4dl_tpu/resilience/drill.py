@@ -47,7 +47,7 @@ the typed classification, the policy (degrade vs retry vs quarantine), the
 feasibility-probed config delta, the elastic resume, and the final loss
 against a control run at the supervisor's final geometry
 (:func:`supervisor_scenarios` / :func:`run_supervisor_scenario`).
-Additional failure kinds there: ``misclassified`` (wrong taxonomy class),
+Additional failure kinds there: ``misclassified`` (wrong classification class),
 ``wrong_policy`` (unexpected policy, unprobed degrade, or a geometry
 change where none was allowed), ``false_positive`` (incidents on a clean
 run).

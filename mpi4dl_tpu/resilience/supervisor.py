@@ -1,4 +1,4 @@
-"""Elastic supervisor: typed failure taxonomy + per-class recovery (ISSUE 15
+"""Elastic supervisor: typed failure classification + per-class recovery (ISSUE 15
 tentpole).
 
 PR 13 made checkpoints elastic across geometries and PR 12 made geometry
@@ -6,7 +6,7 @@ PR 13 made checkpoints elastic across geometries and PR 12 made geometry
 when something goes wrong.  The trainer becomes a restartable *leg* under a
 process-level supervisor: the leg runs as a subprocess (fresh XLA backend
 per attempt — also the only sound way to retry a compile-OOM), and every
-leg exit is classified into a **typed failure taxonomy** from three
+leg exit is classified into a **typed failure classification** from three
 evidence sources — a structured crash-marker file the leg writes on the way
 down (:func:`write_crash_marker`, wired through
 :func:`mpi4dl_tpu.resilience.loop.run_supervised`), the leg's RunLog tail,
@@ -185,7 +185,7 @@ def classify_failure(
     stderr_tail: str = "",
     flight: Optional[Mapping[str, Any]] = None,
 ) -> "Classification":
-    """Map one leg exit onto the typed taxonomy.
+    """Map one leg exit onto the typed classification.
 
     Evidence precedence: an explicit ``failure_class`` in the marker (the
     watchdog's ``hang``, the mesh faults) wins; then the marker's error

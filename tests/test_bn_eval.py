@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import skip_old_jax  # the shared old-jax version guard
-
 
 from mpi4dl_tpu.cells import CellModel, LayerCell
 from mpi4dl_tpu.layer_ctx import spatial_ctx_for
@@ -153,7 +151,6 @@ def test_spatial_stats_match_single_device(devices8):
     )
 
 
-@skip_old_jax
 def test_fine_remat_matches_plain_on_amoebanet():
     """remat="fine" (per-op checkpoints inside AmoebaCells, ctx.remat_ops)
     must reproduce the plain step's updates — incl. BN running stats crossing

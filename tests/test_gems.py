@@ -7,8 +7,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import skip_old_jax  # the shared old-jax version guard
-
 
 from mpi4dl_tpu.mesh import MeshSpec, build_mesh
 from mpi4dl_tpu.models.resnet import get_resnet_v2
@@ -18,7 +16,6 @@ from mpi4dl_tpu.parallel.pipeline import init_pipeline_state
 from mpi4dl_tpu.train import Optimizer, TrainState, make_train_step
 
 
-@skip_old_jax
 @pytest.mark.parametrize("times,parts", [(1, 1), (1, 2), (2, 1)])
 def test_gems_matches_single_device(devices8, times, parts):
     S = 4

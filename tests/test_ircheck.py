@@ -1,6 +1,6 @@
 """Tests for the IR-level shard-flow verifier (ISSUE 16 tentpole).
 
-Every finding kind in ``analysis/ircheck``'s taxonomy has a violating
+Every finding kind in ``analysis/ircheck``'s classification has a violating
 fixture here — hand-built jaxprs traced through ``compat.shard_map`` on the
 8-CPU virtual mesh for the replication-flow / collective-matching kinds,
 hand-written scheduled-HLO modules for the donation / async / Pallas-alias
@@ -55,7 +55,7 @@ def _smap(body, mesh, in_specs, out_specs):
     from mpi4dl_tpu.compat import shard_map
 
     return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)
 
 
 def _kinds(findings):

@@ -5,8 +5,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import skip_old_jax  # the shared old-jax version guard
-
 
 from mpi4dl_tpu.cells import split_even
 from mpi4dl_tpu.layer_ctx import ApplyCtx
@@ -70,11 +68,6 @@ def test_softmax_in_model_flag():
     np.testing.assert_allclose(float(jnp.sum(y)), 1.0, rtol=1e-5)
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "enable_x64"),
-    reason="known old-jax failure: jax.enable_x64 (top-level) missing on "
-           "the legacy 0.4.x line; auto-unskips when the API exists",
-)
 def test_lane_pad_function_preserving(monkeypatch):
     """MPI4DL_LANE_PAD=1 pads bottleneck mid-channels to 128 lanes with
     zero weights — losses, grads, and running stats must match the unpadded
@@ -147,7 +140,6 @@ def test_lane_pad_function_preserving(monkeypatch):
     )
 
 
-@skip_old_jax
 def test_amoebanet_fine_remat_packed_states_exact(monkeypatch):
     """remat='fine' (per-op checkpoints with lane-packed DAG states) must
     be bit-level equivalent to the no-remat path: packing is a reshape and
@@ -178,3 +170,17 @@ def test_amoebanet_fine_remat_packed_states_exact(monkeypatch):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7
         )
+
+
+def test_factorized_reduce_keeps_its_fusion_barrier():
+    """XLA:TPU miscompiled the bf16 backward across FactorizedReduce's output
+    (NaN gradients on the v5e, PR 22); the CPU cannot reproduce that, so this
+    pins the barrier that fixed it.  chip_smoke.py is the test that runs it."""
+    from mpi4dl_tpu.models.amoebanet import FactorizedReduce
+
+    fr = FactorizedReduce(8, 16)
+    params, out_shape = fr.init(jax.random.key(0), (1, 16, 16, 8))
+    x = jnp.ones((1, 16, 16, 8), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda p, h: fr.apply(p, h, CTX))(params, x)
+    assert "optimization_barrier" in str(jaxpr)
+    assert out_shape == (1, 8, 8, 16)

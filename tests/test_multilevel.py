@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import skip_old_jax  # the shared old-jax version guard
-
 
 from mpi4dl_tpu.cells import CellModel, LayerCell
 from mpi4dl_tpu.layer_ctx import SpatialCtx, spatial_levels_for
@@ -233,7 +231,6 @@ def test_sp_pipeline_statless_stage_branch(devices8):
     assert np.isfinite(float(m["loss"]))
 
 
-@skip_old_jax
 def test_multilevel_sp_pipeline_exact(devices8):
     """SP x PP with a two-level spatial region (stage=2 x sph=2 x spw=2):
     matches single-device micro-batched SGD exactly on a BN-free model."""

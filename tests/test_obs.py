@@ -265,6 +265,23 @@ def test_peak_flops_sources():
     assert src == "nominal-cpu" and peak > 0
 
 
+class _FakeDevice:
+    def __init__(self, kind, platform="tpu"):
+        self.device_kind, self.platform = kind, platform
+
+
+@pytest.mark.parametrize("fn", ["peak_flops", "ici_bytes_per_s"])
+def test_device_tables_know_the_v5e_and_refuse_a_stranger(fn):
+    """A device that is not in the table is an error, not a default."""
+    from mpi4dl_tpu.obs import costs
+
+    value, src = getattr(costs, fn)(_FakeDevice("TPU v5 lite"))
+    assert src == "table"
+    assert value == {"peak_flops": 197e12, "ici_bytes_per_s": 2.0e11}[fn]
+    with pytest.raises(ValueError, match="device kind 'tpu v9x'"):
+        getattr(costs, fn)(_FakeDevice("TPU v9x"))
+
+
 def test_collective_stats_from_compiled(devices8):
     from mpi4dl_tpu.compat import shard_map
     from jax.sharding import PartitionSpec as P

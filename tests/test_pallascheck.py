@@ -220,7 +220,7 @@ def test_vmem_frac_gate_tightens():
 # DMA/semaphore discipline fixtures (c)
 # ---------------------------------------------------------------------------
 
-_ANY = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+_ANY = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
 
 
 def _dma_fixture(kernel, n_sems=1, grid=(1,)):
@@ -658,7 +658,7 @@ def test_analysis_dispatch():
 
 def test_finding_kind_registry_is_exact():
     """Every documented kind is producible and no check emits an
-    undocumented kind: the fixture lane covers the taxonomy 1:1."""
+    undocumented kind: the fixture lane covers the classification 1:1."""
     assert set(FINDING_KINDS) == {
         "oob-block", "overlapping-output", "untiled-output",
         "misaligned-block", "vmem-overbudget", "unmatched-dma",

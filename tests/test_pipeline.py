@@ -6,8 +6,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import skip_old_jax  # the shared old-jax version guard
-
 
 from mpi4dl_tpu.cells import split_even
 from mpi4dl_tpu.mesh import MeshSpec, build_mesh
@@ -36,7 +34,6 @@ def _setup(model, batch, parts, split_size, devices, balance=None, data=1):
     return params, part, opt, step, state
 
 
-@skip_old_jax
 @pytest.mark.parametrize("parts,split_size", [(1, 2), (2, 4), (4, 2)])
 def test_pipeline_matches_single_device(devices8, parts, split_size):
     model = get_resnet_v2((4, 32, 32, 3), depth=11, num_classes=10)

@@ -6,8 +6,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import skip_old_jax  # the shared old-jax version guard
-
 
 from mpi4dl_tpu.mesh import MeshSpec, build_mesh
 from mpi4dl_tpu.models.seqblock import SeqBlock, make_seq_cp_train_step
@@ -44,7 +42,6 @@ def test_seqblock_forward_sharded_matches_replicated(devices8, causal):
     )
 
 
-@skip_old_jax
 def test_seq_cp_train_step_matches_single_device(devices8):
     n = 4
     mesh = build_mesh(MeshSpec(spw=n), jax.devices()[:n])

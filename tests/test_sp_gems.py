@@ -6,8 +6,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import skip_old_jax  # the shared old-jax version guard
-
 
 from mpi4dl_tpu.cells import CellModel, LayerCell
 from mpi4dl_tpu.layer_ctx import SpatialCtx
@@ -35,7 +33,6 @@ def _bn_free_model(mb):
     return m
 
 
-@skip_old_jax
 @pytest.mark.parametrize("times,parts", [(1, 1), (2, 1), (1, 2)])
 def test_sp_gems_matches_single_device(devices8, times, parts):
     """2-stage tail x 2-tile SP region; BN-free model so the GEMS schedule

@@ -14,8 +14,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import skip_old_jax  # the shared old-jax version guard
-
 
 from mpi4dl_tpu.layer_ctx import SpatialCtx
 from mpi4dl_tpu.mesh import MeshSpec, build_mesh
@@ -39,7 +37,6 @@ def _mk(model, params, mesh, sp, split_size, parts, mb, junction, data=1):
     return spp, opt, step, state
 
 
-@skip_old_jax
 def test_sp_pipeline_matches_single_device(devices8):
     """stage=2 x spw=2 (vertical 2-tile SP region, 2-stage tail pipeline)."""
     model = get_resnet_v2((2, 32, 32, 3), depth=11, num_classes=10)
@@ -94,7 +91,6 @@ def test_sp_pipeline_batch_split_junction(devices8):
     assert losses[-1] < losses[0], losses
 
 
-@skip_old_jax
 def test_sp_pipeline_batch_split_exact_bn_free(devices8):
     """ADVICE r1: pin the gradient-combine rule for the batch_split junction
     too.  On a BN-free model the junction's batch re-sharding is numerically

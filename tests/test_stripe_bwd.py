@@ -37,8 +37,6 @@ from mpi4dl_tpu.mesh import AXIS_SPH, AXIS_SPW, MeshSpec, build_mesh
 from mpi4dl_tpu.ops import stripe_bwd as sb
 from mpi4dl_tpu.ops.d2 import accumulated_halo, apply_layers_premargin
 
-from conftest import skip_old_jax  # noqa: F401  (used by engine tests)
-
 
 def _bn_conv_stack(key=0, cin=4, cmid=8):
     layers = [BatchNorm(cin), ReLU(), Conv2d(cin, cmid, 3, bias=False),
@@ -361,7 +359,6 @@ def test_lp_engine_stripe_count_invariance(monkeypatch, schedule, devices8):
     assert l2[-1] < l2[0], f"striped {schedule} engine did not descend: {l2}"
 
 
-@skip_old_jax
 @pytest.mark.slow
 def test_sp_pipeline_stripe_gpipe_matches_1f1b(monkeypatch, devices8):
     """SP x PP with striping on: gpipe == 1f1b at the PR-5 exactness level

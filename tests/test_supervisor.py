@@ -1,5 +1,5 @@
 """Elastic supervisor (mpi4dl_tpu/resilience/supervisor.py + planner.py,
-ISSUE 15): the typed failure taxonomy, the crash-marker plumbing through
+ISSUE 15): the typed failure classification, the crash-marker plumbing through
 the supervised loop, backoff arithmetic, the degradation ladder with its
 feasibility probe, the supervisor state machine (fake legs), the drill
 judge, and — slow lane — the end-to-end oom-degrade drill on the virtual
@@ -70,7 +70,7 @@ def test_synthetic_oom_message_carries_the_status_code():
 
 
 # ---------------------------------------------------------------------------
-# Taxonomy classification — every class, plus the unknown fallback
+# Classification classification — every class, plus the unknown fallback
 # ---------------------------------------------------------------------------
 
 
