@@ -146,164 +146,192 @@ def build_train(cfg: ParallelConfig, family: str, mesh):
 
     ``eval_params_fn(state) -> params_list`` reassembles full parameters for
     the eval step / checkpointing regardless of the family's state layout.
+
+    Recorded as the ``setup/build_train`` span with ``setup/build_model``,
+    ``setup/init_params``, ``setup/make_step`` and ``setup/place_state``
+    inside it (obs/spans.py); jax's trace, lower and compile-or-load events
+    that fall in it are its ``jax/*`` children.
     """
-    import jax
+    from mpi4dl_tpu.obs.spans import recorder
 
-    from mpi4dl_tpu.models import build_model
-    from mpi4dl_tpu.train import Optimizer, TrainState
+    # One frame, as before the spans: jax's lowering slows with the depth of
+    # the Python stack it is called from (PERF.md, PR 26), and model.init
+    # lowers sixty programs from in here.
+    rec = recorder()
+    with rec.span("setup/build_train"):
+        import jax
 
-    from mpi4dl_tpu.quant import QuantPolicy
+        from mpi4dl_tpu.models import build_model
+        from mpi4dl_tpu.train import Optimizer, TrainState
 
-    if cfg.stripe_bwd:
-        # The stripe-wise backward is dispatched at trace time off the
-        # MPI4DL_STRIPE_BWD hatch (like the other layer-dispatch hatches);
-        # the config flag sets it for this process before any step builds.
-        # Deliberately NOT cleared when cfg.stripe_bwd is false: tracing
-        # happens after build_train returns, and the env-var hatch is a
-        # documented interface of its own (HATCHES) — an in-process
-        # striped-vs-plain A/B must manage the variable itself (as the
-        # tests do via monkeypatch).
-        os.environ["MPI4DL_STRIPE_BWD"] = "1"
-    model = build_model(cfg)
-    params, shapes = model.init(jax.random.key(cfg.seed))
-    opt = Optimizer(cfg.optimizer, lr=cfg.lr, momentum=cfg.momentum)
-    dp = cfg.data_parallel
-    dtype = cfg.compute_dtype
-    pdtype = cfg.param_dtype
-    # Quantized-collective policy (None = off = bit-identical engines);
-    # the MPI4DL_QUANT_COLLECTIVES hatch overrides the --quant flag.
-    quant = QuantPolicy.resolve(cfg.quant_collectives)
-    if quant is not None:
-        print(f"note: quantized collectives on: {quant.spec()}",
-              file=sys.stderr)
-    if cfg.precision == "bf_16_all":
-        # bf_16_all: parameters stored bf16 as well (reference parser.py
-        # precision vocabulary); fp32 update arithmetic lives in Optimizer.
-        params = jax.tree.map(lambda p: p.astype(pdtype), params)
-    from_probs = cfg.softmax_in_model
+        from mpi4dl_tpu.quant import QuantPolicy
 
-    if cfg.schedule != "gpipe" and cfg.split_size <= 1:
-        print(
-            f"note: --schedule {cfg.schedule} needs a pipeline "
-            "(--split-size >= 2); single-chip path ignores it",
-            file=sys.stderr,
-        )
+        if cfg.stripe_bwd:
+            # The stripe-wise backward is dispatched at trace time off the
+            # MPI4DL_STRIPE_BWD hatch (like the other layer-dispatch hatches);
+            # the config flag sets it for this process before any step builds.
+            # Deliberately NOT cleared when cfg.stripe_bwd is false: tracing
+            # happens after build_train returns, and the env-var hatch is a
+            # documented interface of its own (HATCHES) — an in-process
+            # striped-vs-plain A/B must manage the variable itself (as the
+            # tests do via monkeypatch).
+            os.environ["MPI4DL_STRIPE_BWD"] = "1"
+        with rec.span("setup/build_model"):
+            model = build_model(cfg)
+        with rec.span("setup/init_params"):
+            params, shapes = model.init(jax.random.key(cfg.seed))
+        opt = Optimizer(cfg.optimizer, lr=cfg.lr, momentum=cfg.momentum)
+        dp = cfg.data_parallel
+        dtype = cfg.compute_dtype
+        pdtype = cfg.param_dtype
+        # Quantized-collective policy (None = off = bit-identical engines);
+        # the MPI4DL_QUANT_COLLECTIVES hatch overrides the --quant flag.
+        quant = QuantPolicy.resolve(cfg.quant_collectives)
+        if quant is not None:
+            print(f"note: quantized collectives on: {quant.spec()}",
+                  file=sys.stderr)
+        if cfg.precision == "bf_16_all":
+            # bf_16_all: parameters stored bf16 as well (reference parser.py
+            # precision vocabulary); fp32 update arithmetic lives in Optimizer.
+            params = jax.tree.map(lambda p: p.astype(pdtype), params)
+        from_probs = cfg.softmax_in_model
 
-    if family == "lp":
-        if cfg.split_size <= 1:
-            from mpi4dl_tpu.train import make_train_step
-
-            step = make_train_step(
-                model, opt, mesh if dp > 1 else None, parts=cfg.parts,
-                compute_dtype=dtype, from_probs=from_probs, remat=cfg.remat,
-                donate=True,
+        if cfg.schedule != "gpipe" and cfg.split_size <= 1:
+            print(
+                f"note: --schedule {cfg.schedule} needs a pipeline "
+                "(--split-size >= 2); single-chip path ignores it",
+                file=sys.stderr,
             )
-            state = TrainState.create(params, opt)
+
+        if family == "lp":
+            if cfg.split_size <= 1:
+                from mpi4dl_tpu.train import make_train_step
+
+                with rec.span("setup/make_step"):
+                    step = make_train_step(
+                        model, opt, mesh if dp > 1 else None, parts=cfg.parts,
+                        compute_dtype=dtype, from_probs=from_probs,
+                        remat=cfg.remat, donate=True,
+                    )
+                with rec.span("setup/place_state"):
+                    state = TrainState.create(params, opt)
+                return step, state, (lambda s: s.params), cfg.batch_size * dp
+            from mpi4dl_tpu.parallel.partition import StagePartition
+            from mpi4dl_tpu.parallel.pipeline import (
+                init_pipeline_state,
+                make_pipeline_train_step,
+            )
+
+            mb = cfg.batch_size // cfg.parts
+            part = StagePartition.build(
+                model, params, cfg.split_size,
+                (mb, cfg.image_size, cfg.image_size, 3),
+                balance=cfg.balance, compute_dtype=dtype, param_dtype=pdtype,
+            )
+            with rec.span("setup/make_step"):
+                step = make_pipeline_train_step(
+                    part, opt, mesh, cfg.parts, compute_dtype=dtype,
+                    remat=cfg.remat, from_probs=from_probs,
+                    with_data_axis=dp > 1, donate=True, schedule=cfg.schedule,
+                    quant=quant,
+                )
+            with rec.span("setup/place_state"):
+                state = init_pipeline_state(part, params, opt, mesh)
+            return (
+                step, state,
+                (lambda s: part.unpack_params(jax.device_get(s.param_buf))),
+                cfg.batch_size * dp,
+            )
+
+        if family == "gems":
+            from mpi4dl_tpu.parallel.gems import make_gems_train_step
+            from mpi4dl_tpu.parallel.partition import StagePartition
+            from mpi4dl_tpu.parallel.pipeline import init_pipeline_state
+
+            groups = 2 * cfg.times * cfg.parts
+            assert cfg.batch_size % groups == 0, (
+                f"GEMS needs batch_size divisible by 2*times*parts={groups}"
+            )
+            mb = cfg.batch_size // groups
+            part = StagePartition.build(
+                model, params, cfg.split_size,
+                (mb, cfg.image_size, cfg.image_size, 3),
+                balance=cfg.balance, compute_dtype=dtype, param_dtype=pdtype,
+            )
+            with rec.span("setup/make_step"):
+                step = make_gems_train_step(
+                    part, opt, mesh, cfg.parts, times=cfg.times,
+                    compute_dtype=dtype, remat=cfg.remat, from_probs=from_probs,
+                    with_data_axis=dp > 1, donate=True, schedule=cfg.schedule,
+                    quant=quant,
+                )
+            with rec.span("setup/place_state"):
+                state = init_pipeline_state(part, params, opt, mesh)
+            return (
+                step, state,
+                (lambda s: part.unpack_params(jax.device_get(s.param_buf))),
+                cfg.batch_size * dp,
+            )
+
+        # Spatial families
+        levels = _spatial_levels(cfg, len(model.cells), shapes=shapes)
+        sp = levels[0][1]
+        model.spatial_until = levels[-1][0]
+        junction = "batch_split" if cfg.local_dp_lp > 1 else "gather"
+        local_dp = cfg.local_dp_lp if cfg.local_dp_lp > 1 else None
+
+        if family == "sp" and cfg.split_size <= 1:
+            from mpi4dl_tpu.train import make_spatial_train_step
+
+            with rec.span("setup/make_step"):
+                step = make_spatial_train_step(
+                    model, opt, mesh, sp, parts=cfg.parts, with_data_axis=dp > 1,
+                    compute_dtype=dtype, from_probs=from_probs,
+                    spatial_until=model.spatial_until, junction=junction,
+                    levels=levels, local_dp=local_dp, donate=True, quant=quant,
+                )
+            with rec.span("setup/place_state"):
+                state = TrainState.create(params, opt)
             return step, state, (lambda s: s.params), cfg.batch_size * dp
-        from mpi4dl_tpu.parallel.partition import StagePartition
-        from mpi4dl_tpu.parallel.pipeline import (
-            init_pipeline_state,
-            make_pipeline_train_step,
+
+        from mpi4dl_tpu.parallel.sp_pipeline import (
+            SPPipeline,
+            init_sp_pipeline_state,
+            make_sp_gems_train_step,
+            make_sp_pipeline_train_step,
         )
 
-        mb = cfg.batch_size // cfg.parts
-        part = StagePartition.build(
-            model, params, cfg.split_size,
-            (mb, cfg.image_size, cfg.image_size, 3),
-            balance=cfg.balance, compute_dtype=dtype, param_dtype=pdtype,
+        groups = (2 * cfg.times * cfg.parts) if family == "gems_sp" else cfg.parts
+        assert cfg.batch_size % groups == 0, (cfg.batch_size, groups)
+        micro = cfg.batch_size // groups
+        spp = SPPipeline.build(
+            model, params, max(cfg.split_size, 2), sp, microbatch=micro,
+            junction=junction, balance=cfg.balance, compute_dtype=dtype,
+            levels=levels, local_dp=local_dp, param_dtype=pdtype,
         )
-        step = make_pipeline_train_step(
-            part, opt, mesh, cfg.parts, compute_dtype=dtype, remat=cfg.remat,
-            from_probs=from_probs, with_data_axis=dp > 1, donate=True,
-            schedule=cfg.schedule, quant=quant,
-        )
-        state = init_pipeline_state(part, params, opt, mesh)
+        with rec.span("setup/make_step"):
+            if family == "gems_sp":
+                step = make_sp_gems_train_step(
+                    spp, opt, mesh, cfg.parts, times=cfg.times,
+                    compute_dtype=dtype, remat=cfg.remat, from_probs=from_probs,
+                    with_data_axis=dp > 1, donate=True, schedule=cfg.schedule,
+                    quant=quant,
+                )
+            else:
+                step = make_sp_pipeline_train_step(
+                    spp, opt, mesh, cfg.parts, compute_dtype=dtype,
+                    remat=cfg.remat, from_probs=from_probs,
+                    with_data_axis=dp > 1, donate=True, schedule=cfg.schedule,
+                    quant=quant,
+                )
+        with rec.span("setup/place_state"):
+            state = init_sp_pipeline_state(spp, params, opt, mesh)
         return (
             step, state,
-            (lambda s: part.unpack_params(jax.device_get(s.param_buf))),
+            (lambda s: spp.unpack_all(
+                jax.device_get(s.sp_buf), jax.device_get(s.tail_buf))),
             cfg.batch_size * dp,
         )
-
-    if family == "gems":
-        from mpi4dl_tpu.parallel.gems import make_gems_train_step
-        from mpi4dl_tpu.parallel.partition import StagePartition
-        from mpi4dl_tpu.parallel.pipeline import init_pipeline_state
-
-        groups = 2 * cfg.times * cfg.parts
-        assert cfg.batch_size % groups == 0, (
-            f"GEMS needs batch_size divisible by 2*times*parts={groups}"
-        )
-        mb = cfg.batch_size // groups
-        part = StagePartition.build(
-            model, params, cfg.split_size,
-            (mb, cfg.image_size, cfg.image_size, 3),
-            balance=cfg.balance, compute_dtype=dtype, param_dtype=pdtype,
-        )
-        step = make_gems_train_step(
-            part, opt, mesh, cfg.parts, times=cfg.times, compute_dtype=dtype,
-            remat=cfg.remat, from_probs=from_probs, with_data_axis=dp > 1,
-            donate=True, schedule=cfg.schedule, quant=quant,
-        )
-        state = init_pipeline_state(part, params, opt, mesh)
-        return (
-            step, state,
-            (lambda s: part.unpack_params(jax.device_get(s.param_buf))),
-            cfg.batch_size * dp,
-        )
-
-    # Spatial families
-    levels = _spatial_levels(cfg, len(model.cells), shapes=shapes)
-    sp = levels[0][1]
-    model.spatial_until = levels[-1][0]
-    junction = "batch_split" if cfg.local_dp_lp > 1 else "gather"
-    local_dp = cfg.local_dp_lp if cfg.local_dp_lp > 1 else None
-
-    if family == "sp" and cfg.split_size <= 1:
-        from mpi4dl_tpu.train import make_spatial_train_step
-
-        step = make_spatial_train_step(
-            model, opt, mesh, sp, parts=cfg.parts, with_data_axis=dp > 1,
-            compute_dtype=dtype, from_probs=from_probs,
-            spatial_until=model.spatial_until, junction=junction,
-            levels=levels, local_dp=local_dp, donate=True, quant=quant,
-        )
-        state = TrainState.create(params, opt)
-        return step, state, (lambda s: s.params), cfg.batch_size * dp
-
-    from mpi4dl_tpu.parallel.sp_pipeline import (
-        SPPipeline,
-        init_sp_pipeline_state,
-        make_sp_gems_train_step,
-        make_sp_pipeline_train_step,
-    )
-
-    groups = (2 * cfg.times * cfg.parts) if family == "gems_sp" else cfg.parts
-    assert cfg.batch_size % groups == 0, (cfg.batch_size, groups)
-    micro = cfg.batch_size // groups
-    spp = SPPipeline.build(
-        model, params, max(cfg.split_size, 2), sp, microbatch=micro,
-        junction=junction, balance=cfg.balance, compute_dtype=dtype,
-        levels=levels, local_dp=local_dp, param_dtype=pdtype,
-    )
-    if family == "gems_sp":
-        step = make_sp_gems_train_step(
-            spp, opt, mesh, cfg.parts, times=cfg.times, compute_dtype=dtype,
-            remat=cfg.remat, from_probs=from_probs, with_data_axis=dp > 1,
-            donate=True, schedule=cfg.schedule, quant=quant,
-        )
-    else:
-        step = make_sp_pipeline_train_step(
-            spp, opt, mesh, cfg.parts, compute_dtype=dtype, remat=cfg.remat,
-            from_probs=from_probs, with_data_axis=dp > 1, donate=True,
-            schedule=cfg.schedule, quant=quant,
-        )
-    state = init_sp_pipeline_state(spp, params, opt, mesh)
-    return (
-        step, state,
-        (lambda s: spp.unpack_all(
-            jax.device_get(s.sp_buf), jax.device_get(s.tail_buf))),
-        cfg.batch_size * dp,
-    )
 
 
 def _ensure_devices(need: int) -> None:

@@ -90,8 +90,9 @@ HATCHES: Dict[str, Hatch] = {
               "step level — PERF_NOTES r4)."),
         Hatch("MPI4DL_NO_SCOPES", "0",
               "1 = disable obs trace scopes (jax.named_scope semantic names "
-              "in traces/HLO) and host step annotations — pristine A/B "
-              "compiles."),
+              "in traces/HLO), host step annotations and the span recorder "
+              "(obs/spans.py; the crash marker's phase words stay) — "
+              "pristine A/B compiles."),
         Hatch("MPI4DL_QUANT_COLLECTIVES", "<unset>",
               "Quantized-collective policy override (wins over --quant when "
               "set): `off`, one mode for every class (`int8`|`fp8`|`int4`), "

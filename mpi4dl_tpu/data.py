@@ -258,20 +258,30 @@ def prefetch_batches(
 
     ``stall_hook(gstep)`` (fault injection) returns seconds to sleep before
     producing that batch — the watchdog's test stimulus.
+
+    Spans (obs/spans.py): every batch made is a ``make_batch`` span with its
+    ``gstep`` on the thread that made it, and the consumer's open span (the
+    loop's ``batch_wait``) learns as ``ready`` whether the batch handed over
+    was already queued.
     """
+    from mpi4dl_tpu.obs.spans import recorder
+
+    rec = recorder()
     idx_of = index_of if index_of is not None else (lambda g: g)
 
     def fetch(g: int) -> Tuple[np.ndarray, np.ndarray]:
-        if stall_hook is not None:
-            delay = stall_hook(g)
-            if delay:
-                time.sleep(delay)
-        return fetch_batch_with_retry(
-            dataset, idx_of(g), batch_size, retries=retries, backoff=backoff
-        )
+        with rec.span("make_batch", gstep=g):
+            if stall_hook is not None:
+                delay = stall_hook(g)
+                if delay:
+                    time.sleep(delay)
+            return fetch_batch_with_retry(
+                dataset, idx_of(g), batch_size, retries=retries,
+                backoff=backoff)
 
     if num_workers <= 0:
         for g in range(start, stop):
+            rec.annotate_open(ready=False)  # made on demand
             yield g, fetch(g)
         return
 
@@ -301,11 +311,13 @@ def prefetch_batches(
     t.start()
     try:
         while True:
+            ready = not q.empty()
             item = q.get()
             if item is None:
                 return
             if isinstance(item, BaseException):
                 raise item
+            rec.annotate_open(ready=ready)
             yield item
     finally:
         stop_evt.set()

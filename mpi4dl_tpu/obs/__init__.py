@@ -8,6 +8,10 @@ the reference's CUDA-event phase timing + MPI message accounting, SURVEY
   threads semantic names (``cell03``, ``halo_exchange_w``, ``stage1``)
   through the hot paths so XProf traces and compiled HLO carry phase
   attribution.  Disable with ``MPI4DL_NO_SCOPES=1``.
+- **Host spans** (:mod:`~mpi4dl_tpu.obs.spans`): one in-memory recorder of
+  where the host's time goes — set-up, the supervised loop, the loader and
+  jax's own trace/lower/compile events — each span also a profiler
+  annotation.  ``MPI4DL_NO_SCOPES=1`` turns it off with the scopes.
 - **Run telemetry** (:mod:`~mpi4dl_tpu.obs.runlog`): :class:`RunLog` JSONL
   sink — run metadata (config, mesh, device, jax version, active hatches)
   plus per-step records (wall ms, images/sec, loss/acc, memory watermark,

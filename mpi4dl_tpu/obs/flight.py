@@ -28,6 +28,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from mpi4dl_tpu.obs.spans import recorder
 from mpi4dl_tpu.obs.runlog import (
     _jsonable,
     device_memory_watermarks,
@@ -164,6 +165,8 @@ class FlightRecorder:
             }
         snap["device_memory"] = device_memory_watermarks()
         snap["host_rss_peak_bytes"] = host_rss_peak_bytes()
+        # the span recorder's set-up spans and builds in the loop (obs/spans.py)
+        snap["spans"] = recorder().summary()
         return snap
 
     # -- dumping -----------------------------------------------------------

@@ -82,6 +82,15 @@ def render_run(path: str) -> str:
             f"p10 {_pct(ms, 0.10):.2f}  p90 {_pct(ms, 0.90):.2f}  "
             f"min {ms[0]:.2f}"
         )
+        # the loop's own spans of each step (obs/spans.py), beside its time
+        spans: Dict[str, List[float]] = {}
+        for r in measured:
+            for name, v in (r.get("spans_ms") or {}).items():
+                spans.setdefault(name, []).append(float(v))
+        if spans:
+            lines.append("step spans ms (median): " + "  ".join(
+                f"{name} {_pct(sorted(v), 0.5):.2f}"
+                for name, v in spans.items()))
         ips = [float(r["images_per_sec"]) for r in measured]
         last_loss = measured[-1].get("loss")
         lines.append(
@@ -91,6 +100,23 @@ def render_run(path: str) -> str:
     else:
         med = None
         lines.append(f"steps: 0 measured, {warmup} warmup dropped")
+
+    # -- the span recorder's closing record (obs/spans.py) ------------------
+    closing = _first(records, "spans")
+    if closing is not None:
+        parts = [f"{name.split('/', 1)[1]} {ms / 1e3:.2f}"
+                 for name, ms in (closing.get("setup_ms") or {}).items()]
+        for name, k in (closing.get("jax") or {}).items():
+            top = ", ".join(f"{t['program']} {t['ms'] / 1e3:.2f}"
+                            for t in k["top"])
+            parts.append(f"{name} {k['ms'] / 1e3:.2f} ({k['count']}: {top})")
+        if parts:
+            lines.append("set-up spans s: " + "  ".join(parts))
+        built = [f"{b['program']}@{b['gstep']} {b['ms'] / 1e3:.2f}s"
+                 + (" from the cache" if b.get("cache_hit") else "")
+                 for b in closing.get("built_in_loop") or []]
+        if built:  # the same program at two steps is a retrace
+            lines.append("programs built in the loop: " + ", ".join(built[:8]))
 
     # -- resilience events (docs/resilience.md) ----------------------------
     events = [r for r in records

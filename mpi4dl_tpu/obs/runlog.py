@@ -2,7 +2,7 @@
 
 One run = one ``.jsonl`` file; one line = one record, every record carrying
 ``kind`` (meta | cost | step | summary | hbm | timeline | overlap |
-mem_probe | junction_sweep | xprof_ops | readiness | anomaly | recovery |
+mem_probe | junction_sweep | xprof_ops | readiness | spans | anomaly | recovery |
 preempt | checkpoint | restore | quarantine | drill | drill_summary |
 supervisor | supervisor_summary | fleet | fleet_summary | <custom> — field
 reference in docs/observability.md), ``t`` (unix
@@ -220,7 +220,14 @@ class RunLog:
         )
 
     def close(self) -> None:
+        """Close the file; a log whose step records carry ``spans_ms`` (the
+        supervised loop's) first gets the span recorder's set-up spans and
+        the programs built in the loop (one ``spans`` record)."""
         if not self._fh.closed:
+            if (self.last_by_kind.get("step") or {}).get("spans_ms"):
+                from mpi4dl_tpu.obs.spans import recorder
+
+                self.write("spans", **recorder().summary())
             self._fh.close()
 
     def __enter__(self) -> "RunLog":

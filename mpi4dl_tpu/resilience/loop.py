@@ -40,10 +40,10 @@ watchdog gains the compile-grace budget for the first step and, under
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 import os
+import threading
 from typing import Any, Callable, Dict, Optional
 
 from mpi4dl_tpu.checkpoint import CheckpointManager, arrays_to_state, state_to_arrays
@@ -63,9 +63,6 @@ from mpi4dl_tpu.resilience.watchdog import (
     watchdog_escalation_from_env,
 )
 from mpi4dl_tpu.resilience.writer import AsyncCheckpointWriter
-from mpi4dl_tpu.utils import Timer
-
-_NULL_CTX = contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -117,22 +114,41 @@ def run_supervised(
     snapshot refreshed at the checkpoint cadence (costs a full extra copy
     of the training state in host RAM; fine for tests/small models, not
     for pathology-scale stage buffers).
+
+    The call is one ``run`` span of the process's span recorder
+    (obs/spans.py), every step a ``step`` span with ``batch_wait``,
+    ``step_call``, ``loss_wait``, ``guard``, ``record`` and ``save`` inside
+    it; each is also a profiler annotation (``step`` a
+    ``StepTraceAnnotation``), so a trace taken round the call shows them
+    whether or not ``profile`` says so: ``profile`` is only recorded on the
+    ``run`` span, for whoever reads the recorder to tell a traced run from a
+    measured one.  The step line's ``time_ms`` is the step call plus the
+    loss fetch, as ever.
     """
     emit = print_fn if print_fn is not None else (lambda line: None)
     faults = faults if faults is not None else FaultInjector(None)
-    timer = Timer()
     total = steps_per_epoch * num_epochs
     gstep = start_step
     metrics_out: Dict[str, float] = {}
     anomalies = 0
     preempted = False
     steps_run = 0
-    # Supervisor plumbing (ISSUE 15): where to leave structured last words,
-    # which steps are quarantined, which phase the loop is in (the crash
-    # marker's phase field — "compile" is the process's first step).
+    # Supervisor plumbing (ISSUE 15): where to leave structured last words
+    # and which steps are quarantined.
     marker_path = crash_marker_path()
     quarantine = quarantine_steps_from_env()
-    phase = "init"
+
+    # The span recorder knows where the loop is: the crash marker's and the
+    # flight dump's phase word is that of the span this thread opened last
+    # ("compile" is the process's first step).  The watchdog asks from its
+    # own thread.
+    from mpi4dl_tpu.obs.spans import phase_word, recorder
+
+    rec = recorder()
+    loop_thread = threading.get_ident()
+
+    def _phase() -> str:
+        return phase_word(rec.at(loop_thread), first_step=steps_run == 0)
 
     # Flight recorder (ISSUE 17): the always-on in-memory forensic ring
     # every leg runs by default.  Dumps land next to the crash marker when
@@ -165,19 +181,19 @@ def run_supervised(
     )
 
     def _save(st: Any, step_id: int) -> Optional[str]:
-        nonlocal phase
         if ckpt is None:
             return None
-        phase = "save"
-        if writer:
-            path = writer.save(st, step_id)
-        else:
-            path = ckpt.save(st, step_id)
-            _ckpt_record(ckpt.last_save_stats)
-        if faults.spec is not None and faults.spec.kind in CKPT_FAULT_KINDS:
-            if writer is not None:
-                writer.flush()  # the fault corrupts a file, not a queue entry
-            faults.after_save(step_id, path)
+        with rec.span("save"):
+            if writer:
+                path = writer.save(st, step_id)
+            else:
+                path = ckpt.save(st, step_id)
+                _ckpt_record(ckpt.last_save_stats)
+            if (faults.spec is not None
+                    and faults.spec.kind in CKPT_FAULT_KINDS):
+                if writer is not None:
+                    writer.flush()  # the fault corrupts a file, not a queue entry
+                faults.after_save(step_id, path)
         return path
 
     # Rollback target: newest on-disk checkpoint, else (opt-in) an
@@ -201,8 +217,6 @@ def run_supervised(
             _save(st, step_id)
         elif snapshot is not None:
             snapshot = (state_to_arrays(st, step_id), step_id)
-
-    from mpi4dl_tpu.obs import step_annotation  # deferred: pulls in jax
 
     def _wd_context():
         """Stall-dump context: the last record of any kind PLUS the last
@@ -230,7 +244,7 @@ def run_supervised(
             # `phase` says WHERE the leg is wedged (fetch = data stall,
             # step = collective, save = checkpoint gather) — the evidence
             # the supervisor uses to split the hang classes.
-            flight.dump("watchdog_escalation", phase=phase, gstep=gstep)
+            flight.dump("watchdog_escalation", phase=_phase(), gstep=gstep)
         if marker_path:
             write_crash_marker(
                 marker_path, phase="step", gstep=gstep,
@@ -268,7 +282,7 @@ def run_supervised(
         if flight is not None:
             flight.note("preempt", gstep=step_id, signum=preempt.signum,
                         saved=saved)
-            flight.dump("preemption", phase=phase, gstep=step_id)
+            flight.dump("preemption", phase=_phase(), gstep=step_id)
         emit(
             f"preemption signal {preempt.signum} — "
             + (f"checkpoint saved at step {step_id}"
@@ -279,7 +293,8 @@ def run_supervised(
         )
 
     try:
-        with preempt, watchdog:
+        with rec.span("run", steps=total - start_step,
+                      profile=bool(profile)) as run_span, preempt, watchdog:
             while gstep < total and not preempted:
                 # One contiguous segment of the batch stream; a rollback
                 # closes it and reopens past the poison batch.
@@ -291,145 +306,165 @@ def run_supervised(
                 )
                 rollback_to = None
                 try:
-                    while True:
-                        # Arm BEFORE the fetch: a stalled producer is
-                        # exactly the hang the watchdog exists for.  The
-                        # process's first step pays the XLA compile, so it
-                        # gets the compile-grace budget instead of the step
-                        # budget (ISSUE 15 satellite).
-                        watchdog.arm(f"step {gstep}",
-                                     compile=steps_run == 0)
-                        phase = "fetch"
-                        try:
-                            g, (x, y) = next(segment)
-                        except StopIteration:
+                    while gstep < total:
+                        with rec.span("step", gstep=gstep) as step_span:
+                            # Arm BEFORE the fetch: a stalled producer is
+                            # exactly the hang the watchdog exists for.  The
+                            # process's first step pays the XLA compile, so it
+                            # gets the compile-grace budget instead of the step
+                            # budget (ISSUE 15 satellite).
+                            watchdog.arm(f"step {gstep}",
+                                         compile=steps_run == 0)
+                            with rec.span("batch_wait"):
+                                item = next(segment, None)
+                            if item is None:  # the stream ended early
+                                watchdog.disarm()
+                                break
+                            g, (x, y) = item
+                            # A signal that landed during the fetch must not pay
+                            # for a whole extra step before being honored — the
+                            # grace window may not cover it.  `gstep` steps are
+                            # complete; the just-fetched batch is simply dropped.
+                            if preempt.requested:
+                                watchdog.disarm()
+                                _preempt_exit(state, gstep)
+                                preempted = True
+                                break
+                            epoch, i = divmod(g, steps_per_epoch)
+                            if g in quarantine:
+                                # Supervisor poison-batch exclusion: a step the
+                                # anomaly guard already fail-fasted on is
+                                # skipped outright — same advance-past
+                                # semantics as a rollback skip.
+                                watchdog.disarm()
+                                emit(f"step {g} quarantined "
+                                     "(MPI4DL_QUARANTINE_STEPS); skipping")
+                                if runlog is not None:
+                                    runlog.write("quarantine", gstep=g,
+                                                 epoch=epoch, step=i)
+                                if flight is not None:
+                                    flight.note("quarantine", gstep=g,
+                                                epoch=epoch, step=i)
+                                gstep = g + 1
+                                if gstep % steps_per_epoch == 0:
+                                    _boundary_save(state, gstep)
+                                continue
+                            # The injected faults stand in for the step's
+                            # own (an OOM in its compile, a wedged
+                            # collective), so they fall inside its span.
+                            with rec.span("step_call"):
+                                faults.before_step(g)
+                                x = faults.poison_batch(g, x)
+                                t_call = rec.clock()
+                                out = step_fn(state, x, y)
+                            with rec.span("loss_wait"):
+                                # Rebinding `state` releases the donated
+                                # one's arrays (a millisecond or two at a
+                                # thousand leaves) while the device works:
+                                # part of the wait, not of the call.
+                                state, metrics = out
+                                loss = float(metrics["loss"])  # blocks on device
+                            ms = (rec.clock() - t_call) / 1e6
                             watchdog.disarm()
-                            break
-                        # A signal that landed during the fetch must not pay
-                        # for a whole extra step before being honored — the
-                        # grace window may not cover it.  `gstep` steps are
-                        # complete; the just-fetched batch is simply dropped.
-                        if preempt.requested:
-                            watchdog.disarm()
-                            _preempt_exit(state, gstep)
-                            preempted = True
-                            break
-                        epoch, i = divmod(g, steps_per_epoch)
-                        if g in quarantine:
-                            # Supervisor poison-batch exclusion: a step the
-                            # anomaly guard already fail-fasted on is
-                            # skipped outright — same advance-past
-                            # semantics as a rollback skip.
-                            watchdog.disarm()
-                            emit(f"step {g} quarantined "
-                                 "(MPI4DL_QUARANTINE_STEPS); skipping")
-                            if runlog is not None:
-                                runlog.write("quarantine", gstep=g,
-                                             epoch=epoch, step=i)
-                            if flight is not None:
-                                flight.note("quarantine", gstep=g,
-                                            epoch=epoch, step=i)
+                            loss = faults.poison_loss(g, loss)
+
+                            with rec.span("guard"):
+                                reason = (
+                                    guard.check(loss, metrics)
+                                    if guard is not None else None
+                                )
+                            if reason is not None:
+                                anomalies += 1
+                                if runlog is not None:
+                                    runlog.write(
+                                        "anomaly", gstep=g, epoch=epoch, step=i,
+                                        loss=loss, reason=reason,
+                                    )
+                                if flight is not None:
+                                    flight.note("anomaly", gstep=g, epoch=epoch,
+                                                step=i, loss=loss, reason=reason,
+                                                guard=guard.snapshot()
+                                                if guard is not None else None)
+                                    flight.dump("anomaly", phase="step", gstep=g)
+                                emit(f"anomaly at step {g}: {reason}")
+                                if ckpt is None and snapshot is None:
+                                    # detection-only: no rollback target exists
+                                    # (and silently continuing would train on a
+                                    # possibly-poisoned state)
+                                    raise AnomalyError(
+                                        f"anomaly at step {g} ({reason}) with no "
+                                        "rollback target — pass a checkpoint "
+                                        "directory (or snapshot_rollback=True) "
+                                        "to recover instead of failing fast"
+                                    )
+                                guard.note_rollback()  # raises when exhausted
+                                if ckpt is not None:
+                                    if writer is not None:
+                                        writer.flush()
+                                    # require=True: with every on-disk file
+                                    # invalid, handing back the live (possibly
+                                    # NaN-poisoned) template as a "recovery"
+                                    # would keep training on corrupt weights —
+                                    # fail loudly instead.
+                                    state, good = ckpt.restore_latest(
+                                        state, require=True
+                                    )
+                                else:
+                                    arrays, good = snapshot
+                                    state = arrays_to_state(arrays, state)
+                                if runlog is not None:
+                                    runlog.write(
+                                        "recovery", resumed_from=good,
+                                        skipped_step=g, next_step=g + 1,
+                                    )
+                                emit(
+                                    f"rolled back to step {good}; skipping "
+                                    f"poison batch {g}"
+                                )
+                                rollback_to = g + 1
+                                break
+
+                            measured = (meter.add(ms) if meter is not None
+                                        else True)
+                            acc = float(metrics.get("accuracy", math.nan))
+                            metrics_out = {"loss": loss, "accuracy": acc}
+                            with rec.span("record"):
+                                # the step's spans that have closed by now
+                                spans_ms = (
+                                    {k: round(v, 3)
+                                     for k, v in step_span.kids_ms.items()}
+                                    if step_span is not None else None
+                                )
+                                ips = global_batch / (ms / 1e3)
+                                emit(
+                                    f"epoch {epoch} step {i} time_ms {ms:.1f} "
+                                    f"images_per_sec {ips:.3f} "
+                                    f"loss {loss:.4f} acc {acc:.4f}"
+                                )
+                                if runlog is not None:
+                                    runlog.write_step(
+                                        epoch=epoch, step=i, ms=ms,
+                                        images_per_sec=ips, loss=loss,
+                                        accuracy=acc, step_fn=step_fn,
+                                        measured=measured, gstep=g,
+                                        spans_ms=spans_ms,
+                                    )
+                                if flight is not None:
+                                    flight.note_step(
+                                        gstep=g, phase=_phase(),
+                                        step_fn=step_fn, epoch=epoch, step=i,
+                                        ms=round(ms, 3), loss=loss,
+                                        spans_ms=spans_ms,
+                                    )
                             gstep = g + 1
+                            steps_run += 1
+
+                            if preempt.requested:
+                                _preempt_exit(state, gstep)
+                                preempted = True
+                                break
                             if gstep % steps_per_epoch == 0:
                                 _boundary_save(state, gstep)
-                            continue
-                        phase = "compile" if steps_run == 0 else "step"
-                        faults.before_step(g)
-                        x = faults.poison_batch(g, x)
-                        timer.start()
-                        with step_annotation(g) if profile else _NULL_CTX:
-                            state, metrics = step_fn(state, x, y)
-                            loss = float(metrics["loss"])  # blocks on device
-                        ms = timer.stop()
-                        watchdog.disarm()
-                        phase = "loop"
-                        loss = faults.poison_loss(g, loss)
-
-                        reason = (
-                            guard.check(loss, metrics)
-                            if guard is not None else None
-                        )
-                        if reason is not None:
-                            anomalies += 1
-                            if runlog is not None:
-                                runlog.write(
-                                    "anomaly", gstep=g, epoch=epoch, step=i,
-                                    loss=loss, reason=reason,
-                                )
-                            if flight is not None:
-                                flight.note("anomaly", gstep=g, epoch=epoch,
-                                            step=i, loss=loss, reason=reason,
-                                            guard=guard.snapshot()
-                                            if guard is not None else None)
-                                flight.dump("anomaly", phase="step", gstep=g)
-                            emit(f"anomaly at step {g}: {reason}")
-                            if ckpt is None and snapshot is None:
-                                # detection-only: no rollback target exists
-                                # (and silently continuing would train on a
-                                # possibly-poisoned state)
-                                raise AnomalyError(
-                                    f"anomaly at step {g} ({reason}) with no "
-                                    "rollback target — pass a checkpoint "
-                                    "directory (or snapshot_rollback=True) "
-                                    "to recover instead of failing fast"
-                                )
-                            guard.note_rollback()  # raises when exhausted
-                            if ckpt is not None:
-                                if writer is not None:
-                                    writer.flush()
-                                # require=True: with every on-disk file
-                                # invalid, handing back the live (possibly
-                                # NaN-poisoned) template as a "recovery"
-                                # would keep training on corrupt weights —
-                                # fail loudly instead.
-                                state, good = ckpt.restore_latest(
-                                    state, require=True
-                                )
-                            else:
-                                arrays, good = snapshot
-                                state = arrays_to_state(arrays, state)
-                            if runlog is not None:
-                                runlog.write(
-                                    "recovery", resumed_from=good,
-                                    skipped_step=g, next_step=g + 1,
-                                )
-                            emit(
-                                f"rolled back to step {good}; skipping "
-                                f"poison batch {g}"
-                            )
-                            rollback_to = g + 1
-                            break
-
-                        measured = meter.add(ms) if meter is not None else True
-                        acc = float(metrics.get("accuracy", math.nan))
-                        metrics_out = {"loss": loss, "accuracy": acc}
-                        emit(
-                            f"epoch {epoch} step {i} time_ms {ms:.1f} "
-                            f"images_per_sec {global_batch / (ms / 1e3):.3f} "
-                            f"loss {loss:.4f} acc {acc:.4f}"
-                        )
-                        if runlog is not None:
-                            runlog.write_step(
-                                epoch=epoch, step=i, ms=ms,
-                                images_per_sec=global_batch / (ms / 1e3),
-                                loss=loss, accuracy=acc, step_fn=step_fn,
-                                measured=measured, gstep=g,
-                            )
-                        if flight is not None:
-                            flight.note_step(
-                                gstep=g, phase=phase, step_fn=step_fn,
-                                epoch=epoch, step=i, ms=round(ms, 3),
-                                loss=loss,
-                            )
-                        gstep = g + 1
-                        steps_run += 1
-
-                        if preempt.requested:
-                            _preempt_exit(state, gstep)
-                            preempted = True
-                            break
-                        if gstep % steps_per_epoch == 0:
-                            _boundary_save(state, gstep)
                 finally:
                     segment.close()
                 if rollback_to is not None:
@@ -442,22 +477,25 @@ def run_supervised(
                     # whole run just to re-skip the same poison batch.
                     if gstep % steps_per_epoch == 0:
                         _boundary_save(state, gstep)
+            if run_span is not None:
+                run_span.set(steps=steps_run)  # planned until here
     except BaseException as e:
         # The leg's structured last words (ISSUE 15): phase + step + error,
         # written BEFORE the exception propagates so the supervisor can
         # classify this death even if the interpreter never unwinds
         # further.  write_crash_marker itself never raises.
+        died_in = _phase()
         if flight is not None:
             flight.note("crash", error_type=type(e).__name__,
-                        error=str(e)[:500], phase=phase, gstep=gstep)
-            flight.dump("crash", phase=phase, gstep=gstep)
+                        error=str(e)[:500], phase=died_in, gstep=gstep)
+            flight.dump("crash", phase=died_in, gstep=gstep)
         if marker_path:
             extra = {}
             spec = getattr(e, "spec", None)
             if isinstance(spec, str) and spec:
                 extra["shrunk_spec"] = spec  # MeshShrunk carries it
             write_crash_marker(
-                marker_path, phase=phase, gstep=gstep,
+                marker_path, phase=died_in, gstep=gstep,
                 steps_run=steps_run, error=e, **extra,
             )
         raise

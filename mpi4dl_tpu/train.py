@@ -33,6 +33,7 @@ from mpi4dl_tpu.compat import pcast
 from mpi4dl_tpu.cells import CellModel
 from mpi4dl_tpu.layer_ctx import ApplyCtx, SpatialCtx
 from mpi4dl_tpu.mesh import AXIS_DATA
+from mpi4dl_tpu.obs.scopes import scope
 
 
 def cross_entropy(logits_or_probs: jax.Array, labels: jax.Array,
@@ -179,7 +180,9 @@ def make_loss_fn(model: CellModel, ctx: ApplyCtx, from_probs: bool = False,
         if isinstance(logits, tuple):
             logits = logits[0]
         stats = stat_updates_from_sink(c.bn_sink, params_list) if with_stats else None
-        return cross_entropy(logits, labels, from_probs), (logits, stats)
+        with scope("loss"):
+            loss = cross_entropy(logits, labels, from_probs)
+        return loss, (logits, stats)
 
     return loss_fn
 
@@ -284,7 +287,9 @@ def make_train_step(
             grads = jax.tree.map(lambda g: g / parts, grads)
             stats = jax.tree.map(lambda s: s / parts, stats)
             loss, acc = loss / parts, acc / parts
-        params, opt_state = optimizer.update(state.params, grads, state.opt_state)
+        with scope("optimizer_update"):
+            params, opt_state = optimizer.update(
+                state.params, grads, state.opt_state)
         params = merge_stat_updates(params, stats)
         return (
             TrainState(params, opt_state, state.step + 1),

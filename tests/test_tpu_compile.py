@@ -6,12 +6,19 @@ installed in this container can, for a chip that is described and not
 attached.  The shapes are the ones ``chip_smoke.py`` runs on the chip: the
 D2-step tile (512², 208 channels) and a 4096-token, 128-wide head.
 
+The one-chip train step is compiled the same way, tiny, to see that the
+program's scope names (``cellNN``, ``loss``, ``optimizer_update``) reach the
+``op_name`` metadata of the instructions the chip would run: a device trace
+names an op by its HLO instruction and nothing else, so that metadata is the
+only road from a trace event back to the model.
+
 All in ONE file and the topology in a fixture, never at import: only one
 process may load libtpu, and under xdist every worker imports every file.
 Nothing runs here, so these say nothing about results or times.
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -93,3 +100,46 @@ def test_block_flash_fwd_bwd_compiles_for_v5e(one_chip, no_persistent_cache):
         return out, vjp(jnp.ones_like(out))
 
     _assert_mosaic(jax.jit(fwd_bwd).lower(qkv, qkv, qkv).compile())
+
+
+def test_one_chip_step_names_its_scopes_in_op_name_metadata(
+        one_chip, no_persistent_cache):
+    """The smallest model with every scope of the one-chip step (a ResNet v2
+    of depth 11, 32 x 32, batch 2, per-cell remat as the entry point defaults
+    it), shapes placed on the described chip, nothing put on a device."""
+    from mpi4dl_tpu.models.resnet import get_resnet_v2
+    from mpi4dl_tpu.obs.scopes import scopes_enabled
+    from mpi4dl_tpu.train import Optimizer, TrainState, make_train_step
+
+    assert scopes_enabled()
+    model = get_resnet_v2((2, 32, 32, 3), depth=11, num_classes=10)
+    opt = Optimizer("sgd", lr=0.001)
+    step = make_train_step(model, opt, None, compute_dtype=jnp.bfloat16,
+                           remat=True, donate=True)
+    state = jax.eval_shape(lambda: TrainState.create(
+        model.init(jax.random.key(0))[0], opt))
+    state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), state)
+    x = jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32, sharding=one_chip)
+    y = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+    text = step.lower(state, x, y).compile().as_text()
+
+    names = re.findall(r'op_name="([^"]+)"', text)
+    want = [f"cell{i:02d}" for i in range(len(model.cells))] + [
+        "loss", "optimizer_update"]
+    for scope in want:
+        assert any(re.search(rf"[/(]{scope}[/)]", n) for n in names), scope
+    # forward, recompute and backward of a cell are told apart by jax's own
+    # name stack round the scope
+    cell = [n for n in names if "cell01" in n]
+    assert any(n.startswith("jit(step)/jvp(cell01)") for n in cell)
+    assert any("transpose(jvp(cell01))" in n for n in cell)
+    assert any("rematted_computation" in n for n in cell)
+    # most instructions that do work carry one of the program's scopes (the
+    # rest are the compiler's own: converts, copies between memory spaces)
+    work = [l for l in text.split("\n")
+            if re.match(r"^\s+(ROOT )?%[\w.\-]+ = ", l) and not re.search(
+                r" (parameter|constant|get-tuple-element|tuple|bitcast)\(", l)]
+    scoped = [l for l in work if re.search(
+        r'op_name="[^"]*[/(](cell\d+|loss|optimizer_update)[/)]', l)]
+    assert len(scoped) > 0.5 * len(work), (len(scoped), len(work))
