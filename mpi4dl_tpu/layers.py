@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from mpi4dl_tpu.layer_ctx import ApplyCtx, SpatialCtx
+from mpi4dl_tpu.obs.spans import recorder
 from mpi4dl_tpu.ops.halo import HaloSpec, halo_exchange_2d, halo_exchange_with_mask
 
 # Escape hatches, read at DISPATCH time (trace), not import — so a script
@@ -39,7 +40,8 @@ from mpi4dl_tpu.ops.halo import HaloSpec, halo_exchange_2d, halo_exchange_with_m
 #  MPI4DL_NO_PHASE_DX=1  — strided convs keep XLA's lhs-dilation backward
 #                          instead of ops/conv_phase.py.
 #  MPI4DL_NO_HSTRIPE=1   — tiny-channel huge-spatial convs keep the plain
-#                          XLA conv instead of ops/hstripe_conv.py.
+#                          XLA conv instead of ops/wfold_conv.py (or, where
+#                          the fold is not exact, ops/hstripe_conv.py).
 # Both wins are scheduling/layout properties of XLA's TPU lowering, not of
 # the math — hence the hatches.
 def _phase_dx_enabled() -> bool:
@@ -55,6 +57,13 @@ def _hstripe_enabled() -> bool:
 
 
 _HSTRIPE_MIN_PIXELS = 1 << 20
+# The W-fold takes narrow convs below this size.  From 2048² up a narrow
+# block runs H-stripe by H-stripe on flat [N, H, W·C] buffers
+# (hstripe_conv._RUN_MIN_PIXELS), and the one conv left beside it (ResNet's
+# 16→64 shortcut) folded to a full-size lane-dense tensor costs the
+# ResNet-110 v2 2048² step 3.5 GiB (19.13 against 15.60 GiB compiled for a
+# v5e under remat='sqrt', PERF.md PR 27): there it keeps the striped path.
+_WFOLD_MAX_PIXELS = 1 << 22
 # Pools at or below this input size take the phase-view strided reduction
 # (fast path); larger ones keep strided slices (see _window_reduce).
 # 256 MB covers the 1024² headline (109 MB pools); a 512 MB setting that
@@ -180,15 +189,20 @@ class Conv2d(Layer):
 
     @staticmethod
     def _hstripe_shape(kh, kw, sh, sw, groups, x) -> bool:
-        """Shape-based H-stripe dispatch for XLA-hostile convs: stride-1
-        small-kernel convs on TINY-channel HUGE-spatial inputs, where XLA's
-        TPU lowering materializes an im2col-style patch tensor (measured
-        ~3 GB per 3x3 conv at C=16, 2048² — the ResNet-110 high-resolution
-        OOM driver, PERF_NOTES r3/r4).  ops/hstripe_conv.py bounds the
-        temp by scanning H stripes.  (The Pallas kernel cannot take these
+        """The shape gate for XLA-hostile convs: stride-1 convs on
+        NARROW-channel HUGE-spatial inputs, where XLA's TPU lowering puts
+        the <= 64 channels in the 128 lanes (2-8x the tensor in memory and
+        traffic) and materializes an im2col-style patch tensor (measured
+        ~3 GB per 3x3 conv at C=16, 2048²).  A conv that passes takes the
+        W-fold (ops/wfold_conv.py: lane-dense operands, no loop) wherever
+        that is exact, which is SAME padding on a W the fold divides, and
+        the image is under _WFOLD_MAX_PIXELS; what is left for the H
+        stripes (ops/hstripe_conv.py) is a tile whose W margin came from a
+        halo exchange (VALID on W), an indivisible W, the stem's Cin 3 and
+        single convs at 2048² and up.  (The Pallas kernel cannot take these
         shapes: Mosaic refuses sub-128 lane DMA extents and a 128-lane
         channel pad multiplies the input 8–42x in HBM — measured OOM.)
-        MPI4DL_NO_HSTRIPE=1 opts out."""
+        MPI4DL_NO_HSTRIPE=1 opts out of both: the plain XLA conv."""
         if not _hstripe_enabled():
             return False
         n, h, w, c = x.shape
@@ -200,15 +214,12 @@ class Conv2d(Layer):
         )
 
     @staticmethod
-    def _pallas_apply(bias, x, kernel, pads):
+    def _pallas_apply(x, kernel, pads):
         from mpi4dl_tpu.ops.pallas_conv import halo_conv2d_t
 
         if any(p != (0, 0) for p in pads):
             x = jnp.pad(x, pads)
-        y = halo_conv2d_t(x, kernel)
-        if bias is not None:
-            y = y + bias.astype(y.dtype)
-        return y
+        return halo_conv2d_t(x, kernel)
 
     def apply(self, params, x, ctx: ApplyCtx):
         kh, kw, sh, sw, ph, pw = self._geometry()
@@ -257,45 +268,55 @@ class Conv2d(Layer):
             use_pallas = (
                 sp is not None and sp.axis_h is None and sp.axis_w is None
             )
-        # hstripe is checked BEFORE the Pallas opt-in: tiny-channel
-        # huge-spatial convs (ResNet C<=16 at 2048²-class) are the regime
-        # where the kernel's 128-lane channel pad multiplies the input
-        # 8-42x in HBM (measured OOM) — a pallas_conv=True A/B run must
-        # not route them away from the striped path built for them.
-        if self._hstripe_shape(kh, kw, sh, sw, self.feature_group_count, x):
-            from mpi4dl_tpu.ops.hstripe_conv import hstripe_conv2d
+        # The narrow-channel huge-spatial gate is checked BEFORE the Pallas
+        # opt-in: ResNet's C<=64 convs at 1024²-class are the regime where
+        # the kernel's 128-lane channel pad multiplies the input 8-42x in
+        # HBM (measured OOM) — a pallas_conv=True A/B run must not route
+        # them away from the paths built for them.
+        groups = self.feature_group_count
+        if self._hstripe_shape(kh, kw, sh, sw, groups, x):
+            from mpi4dl_tpu.ops.wfold_conv import wfold_conv2d, wfold_factor
 
-            y = hstripe_conv2d(x, kernel, padding[0], padding[1])
-            if bias is not None:
-                y = y + bias.astype(y.dtype)
-            return y
-        if use_pallas and self._pallas_dispatchable(
-            sp, kh, kw, sh, sw, self.feature_group_count, kernel
+            p = wfold_factor(
+                x.shape[2], kw, kernel.shape[2], kernel.shape[3], padding[1]
+            )
+            if p and x.shape[1] * x.shape[2] < _WFOLD_MAX_PIXELS:
+                path = "wfold"
+                y = wfold_conv2d(x, kernel, padding[0], p)
+            else:
+                from mpi4dl_tpu.ops.hstripe_conv import hstripe_conv2d
+
+                path = "hstripe"
+                y = hstripe_conv2d(x, kernel, padding[0], padding[1])
+        elif use_pallas and self._pallas_dispatchable(
+            sp, kh, kw, sh, sw, groups, kernel
         ):
             # The kernel wants the margin present on BOTH dims — pad any dim
             # whose margin wasn't realized by halo exchange (all of them in
             # the unsharded case: SAME = pad + margin-consuming VALID).
-            return self._pallas_apply(
-                bias, x, kernel,
-                [(0, 0), padding[0], padding[1], (0, 0)],
+            path = "pallas"
+            y = self._pallas_apply(
+                x, kernel, [(0, 0), padding[0], padding[1], (0, 0)]
             )
-        if ((sh, sw) != (1, 1) and self.feature_group_count == 1
-                and _phase_dx_enabled()):
+        elif (sh, sw) != (1, 1) and groups == 1 and _phase_dx_enabled():
             # Strided convs take the phase-decomposed-backward form: same
             # forward conv, but dx avoids XLA's lhs-dilation machinery
             # (ops/conv_phase.py; measured step-level win, PERF_NOTES r4).
             from mpi4dl_tpu.ops.conv_phase import conv2d_strided_t
 
+            path = "phase"
             y = conv2d_strided_t(x, kernel, (sh, sw), padding)
         else:
+            path = "xla"
             y = lax.conv_general_dilated(
                 x,
                 kernel,
                 window_strides=(sh, sw),
                 padding=padding,
                 dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                feature_group_count=self.feature_group_count,
+                feature_group_count=groups,
             )
+        recorder().note_conv(self, path)
         if bias is not None:
             y = y + bias.astype(y.dtype)
         return y

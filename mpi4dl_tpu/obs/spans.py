@@ -5,7 +5,8 @@ A span is a name, a start and an end in ``time.perf_counter_ns``, its own id,
 the id of the span that was open on its thread when it opened (its parent),
 the thread, and ``gstep``: the identifier that the spans of one optimizer step
 share.  Closed spans go to a bounded ring; what has to outlive the ring (how
-often jax built each program) is counted beside it.
+often jax built each program, which path each convolution was handed to XLA
+by) is counted beside it.
 
 Every span is also a ``jax.profiler.TraceAnnotation`` (``step`` a
 ``StepTraceAnnotation`` with its ``step_num``): a flag test while no trace
@@ -71,6 +72,11 @@ JAX_EVENTS = {
     "/jax/core/compile/backend_compile_duration": "jax/compile_or_load",
 }
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+# How ``layers.Conv2d.apply`` handed a convolution to XLA: W-folded
+# (ops/wfold_conv.py), H-striped (ops/hstripe_conv.py), the Pallas kernel, the
+# phase-decomposed strided form (ops/conv_phase.py), or as it stands.
+CONV_PATHS = ("wfold", "hstripe", "pallas", "phase", "xla")
 
 # At least 4,000 steps of the loop's spans (nine a step with the loader's).
 DEFAULT_CAPACITY = 65_536
@@ -144,6 +150,9 @@ class Recorder:
         self._closed: deque = deque(maxlen=max(1, int(capacity)))
         # (kind, program) -> events: outlives the ring
         self._programs: Dict[Tuple[str, str], int] = {}
+        # (id of the layer, path) -> the layer, held so that its id stays its
+        # own: a site counts once however often jax traces it
+        self._conv_sites: Dict[Tuple[int, str], Any] = {}
         # thread id -> stack of open spans
         self._open: Dict[int, List[Span]] = {}
         # thread id -> name of the span it opened last, open or closed, kept
@@ -252,6 +261,21 @@ class Recorder:
             return {program: n for (k, program), n
                     in sorted(self._programs.items()) if k == kind}
 
+    def note_conv(self, layer: Any, path: str) -> None:
+        """``layers.Conv2d.apply``, at trace time: ``layer`` went down ``path``
+        (one of :data:`CONV_PATHS`)."""
+        if self.enabled:
+            with self._lock:
+                self._conv_sites[(id(layer), path)] = layer
+
+    def conv_paths(self) -> Dict[str, int]:
+        """``conv_paths{path}``: the distinct convolution layers traced so far
+        in the process, by the path their dispatch chose."""
+        with self._lock:
+            paths = [path for _, path in self._conv_sites]
+        return {path: paths.count(path) for path in CONV_PATHS
+                if path in paths}
+
     # -- jax's own events --------------------------------------------------
 
     def listen_to_jax(self) -> None:
@@ -308,10 +332,10 @@ class Recorder:
 
     def summary(self) -> Dict[str, Any]:
         """The set-up spans, for ``RunLog.close`` and ``flight.dump`` (``obs
-        report`` prints all three): the ``setup/*`` spans one by one, jax's
+        report`` prints them all): the ``setup/*`` spans one by one, jax's
         events summed by kind with the longest three by program, and the
         programs built or loaded inside a step with its ``gstep`` (a program
-        that appears twice was retraced)."""
+        that appears twice was retraced); and ``conv_paths``."""
         spans = sorted((s for s in list(self._closed)
                         if s.name.startswith(SETUP_PREFIXES)),
                        key=lambda s: s.start_ns)
@@ -336,6 +360,7 @@ class Recorder:
                          if s.name.startswith("setup/")},
             "jax": jax_kinds,
             "built_in_loop": in_loop,
+            "conv_paths": self.conv_paths(),
         }
 
 

@@ -10,12 +10,22 @@ spatial.py`).  The Pallas margin-consuming kernel cannot take these shapes
 either: Mosaic refuses sub-128 lane DMA extents, and padding C=3..16 up to
 128 lanes multiplies the whole input in HBM (8–42x, measured OOM).
 
+Since PR 27 ``layers.Conv2d.apply`` hands these convs to the W-fold first
+(ops/wfold_conv.py: the channels made lane-dense by a reshape, no loop;
+the 72 striped loops of the ResNet-110 v2 1024² step were 44 % of it).  The
+stripes keep what the fold cannot take exactly: a spatial tile whose W
+margin came from a halo exchange (VALID on W), a W the fold does not
+divide, the stem's Cin 3 (one stripe at 1024²); and single convs from 2048²
+up (``layers._WFOLD_MAX_PIXELS``), beside the block-level form below.
+
 So: run the conv as a ``lax.map`` (serial scan) over H stripes.  Each
 stripe is a VALID conv on ``[N, sh + kh - 1, W', C]`` — the patch temp
 shrinks by the stripe count and is freed before the next stripe runs.  The
 backward (scan transpose) accumulates stripe input-grads with contiguous
 ``dynamic_update_slice``s — no scatter.  FLOPs are identical; only peak
-memory changes.
+memory changes.  The block-level form below (``hstripe_layer_run``, 2048²
+and up) calls ``Conv2d.apply`` on each stripe, which picks its path by the
+same rule on the stripe's own shape.
 """
 
 from __future__ import annotations

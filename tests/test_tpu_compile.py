@@ -143,3 +143,40 @@ def test_one_chip_step_names_its_scopes_in_op_name_metadata(
     scoped = [l for l in work if re.search(
         r'op_name="[^"]*[/(](cell\d+|loss|optimizer_update)[/)]', l)]
     assert len(scoped) > 0.5 * len(work), (len(scoped), len(work))
+
+
+def test_narrow_resblock_at_1024_folds_lane_dense_for_v5e(
+        one_chip, no_persistent_cache):
+    """A block of ResNet-110 v2's 16-channel stage at its real size
+    (1 x 1024 x 1024 x 64 in bf16; 3x3 64→16, 3x3 16→16, 1x1 16→64), forward
+    and backward under ``jax.checkpoint``: every convolution W-folded
+    (ops/wfold_conv.py), so no loop, no tensor in the narrow ``T(2,128)``
+    tiling on a convolution or anywhere else, and temporaries under a bound
+    taken from this compile (763,265,536 B on jax 0.9.0; the striped block
+    before the fold: 4 loops, 32 such tensors, 1,077,160,960 B) with 20 %
+    room."""
+    from mpi4dl_tpu.layer_ctx import ApplyCtx
+    from mpi4dl_tpu.models.resnet import ResBlockV2
+
+    blk = ResBlockV2(in_f=64, f1=16, f2=64, stride=1, first_block=False,
+                     pre_activation=True)
+    shape = (1, 1024, 1024, 64)
+    params = jax.eval_shape(lambda: blk.init(jax.random.key(0), shape)[0])
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(p, x):
+        y = jax.checkpoint(
+            lambda p, x: blk.apply(p, x, ApplyCtx(train=True)))(p, x)
+        return jnp.sum(y.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile()
+    text = compiled.as_text()
+    assert not re.search(r" while\(", text)
+    folded = re.findall(r"= bf16\[1024,8,1[67],(?:128|512)\]\S* convolution\(",
+                        text)
+    assert len(folded) >= 5    # forward, recomputed and dx, on [N,H,W/8,8·C]
+    narrow = [l for l in text.split("\n") if "T(2,128)" in l]
+    assert not narrow, narrow[:2]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2 * 763_265_536

@@ -1,0 +1,288 @@
+"""W-folded convolution (ops/wfold_conv.py) and its place in Conv2d.apply.
+
+The fold is gated to narrow-channel convolutions at a million pixels and
+more, which no shape of the suite reaches, so these tests call it directly
+or force ``layers._HSTRIPE_MIN_PIXELS`` down, and pin values, gradients,
+the dispatch's fall-throughs and the recorder's ``conv_paths`` count."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from mpi4dl_tpu import layers as L
+from mpi4dl_tpu.layer_ctx import ApplyCtx, SpatialCtx
+from mpi4dl_tpu.obs import spans
+from mpi4dl_tpu.ops import hstripe_conv as hc
+from mpi4dl_tpu.ops import wfold_conv as wf
+
+# perfbench/configs/resnet110_v2.json, tolerances.cell: what a cell in bf16
+# may differ from the float32 reference's by, as a relative L2 norm
+CELL_TOLERANCE = 0.015
+
+
+def _ref(x, w, ph, pw, stride=1, groups=1):
+    return lax.conv_general_dilated(
+        x, w, (stride, stride), (ph, pw),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=groups,
+    )
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.fixture
+def rec():
+    """A recorder of this test's own, so that ``conv_paths`` counts what the
+    test traced and nothing else."""
+    spans._reset_recorder()
+    yield spans.recorder()
+    spans._reset_recorder()
+
+
+@pytest.fixture
+def gate_down(monkeypatch):
+    """Suite-sized shapes pass the million-pixel gate, and a stripe's patch
+    budget is small enough that the striped path would loop."""
+    monkeypatch.setattr(L, "_HSTRIPE_MIN_PIXELS", 1)
+    monkeypatch.setattr(hc, "_PATCH_BUDGET", 4000)
+
+
+@pytest.mark.parametrize("h_pad", ["same", "none"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, CELL_TOLERANCE)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("cin,cout,k", [(16, 16, 3), (64, 16, 3),
+                                        (16, 64, 1), (32, 32, 3)])
+def test_wfold_matches_lax(cin, cout, k, dtype, tol, h_pad):
+    """Forward, dx and dw against lax.conv_general_dilated, with H padded
+    SAME and not at all (a pre-margined run)."""
+    n, h, wid = 2, 12, 32
+    pw = ((k - 1) // 2,) * 2
+    ph = pw if h_pad == "same" else (0, 0)
+    p = wf.wfold_factor(wid, k, cin, cout, pw)
+    assert p == 128 // min(cin, cout) and p > 1
+    k1, k2, k3 = jax.random.split(jax.random.key(0), 3)
+    x = jax.random.normal(k1, (n, h, wid, cin), dtype)
+    w = (jax.random.normal(k2, (k, k, cin, cout)) / (k * cin ** 0.5)
+         ).astype(dtype)
+
+    y = wf.wfold_conv2d(x, w, ph, p)
+    y_ref = _ref(x, w, ph, pw)
+    assert y.shape == y_ref.shape and y.dtype == dtype
+    assert _rel(y, y_ref) <= tol
+
+    t = jax.random.normal(k3, y.shape, dtype)
+
+    def loss(conv):
+        return lambda x, w: jnp.sum((conv(x, w) * t).astype(jnp.float32))
+
+    gx, gw = jax.grad(loss(lambda x, w: wf.wfold_conv2d(x, w, ph, p)),
+                      (0, 1))(x, w)
+    gx_r, gw_r = jax.grad(loss(lambda x, w: _ref(x, w, ph, pw)), (0, 1))(x, w)
+    assert gx.dtype == dtype and gw.dtype == dtype
+    assert _rel(gx, gx_r) <= tol
+    assert _rel(gw, gw_r) <= tol
+
+
+def test_folded_kernel_is_the_true_one_on_a_block_band():
+    """Every element of the folded kernel is a tap of the true one or an
+    exact zero, and each tap appears p times (once for each pixel of a
+    folded column) but for those that leave the row's two neighbours."""
+    p, k, cin, cout = 4, 3, 2, 3
+    w = jax.random.normal(jax.random.key(1), (k, k, cin, cout))
+    wfd = np.asarray(wf.fold_kernel(w, p)).reshape(k, 3, p, cin, p, cout)
+    w = np.asarray(w)
+    for j in range(3):
+        for a in range(p):
+            for b in range(p):
+                x = a + p * (j - 1) - b + 1
+                want = w[:, x] if 0 <= x < k else np.zeros_like(w[:, 0])
+                np.testing.assert_array_equal(wfd[:, j, a, :, b, :], want)
+
+
+@pytest.mark.parametrize(
+    "wid,kw,cin,cout,pad_w,p",
+    [
+        (1024, 3, 16, 16, (1, 1), 8),
+        (1024, 3, 64, 16, (1, 1), 8),
+        (1024, 1, 16, 64, (0, 0), 8),
+        (512, 3, 64, 64, (1, 1), 2),
+        (1024, 3, 3, 16, (1, 1), 0),      # 42 does not divide the row
+        (1024, 3, 128, 128, (1, 1), 0),   # lane-dense as it stands
+        (1024, 3, 16, 16, (0, 0), 0),     # the margin came from an exchange
+        (1024, 3, 16, 16, (1, 2), 0),     # not symmetric
+        (1024, 2, 16, 16, (1, 1), 0),     # even kernel
+        (1020, 3, 16, 16, (1, 1), 0),     # 8 does not divide the row
+        (1024, 7, 64, 64, (3, 3), 0),     # reaches beyond one folded pixel
+    ],
+)
+def test_wfold_factor(wid, kw, cin, cout, pad_w, p):
+    assert wf.wfold_factor(wid, kw, cin, cout, pad_w) == p
+
+
+@pytest.mark.parametrize(
+    "why,conv,shape,path",
+    [
+        ("Cin 3: W % 42", L.Conv2d(3, 16, 3), (1, 16, 32, 3), "hstripe"),
+        ("W % p", L.Conv2d(16, 16, 3), (1, 16, 20, 16), "hstripe"),
+        ("VALID W", L.Conv2d(16, 16, 3, padding=(1, 0)), (1, 16, 32, 16),
+         "hstripe"),
+        ("stride 2", L.Conv2d(16, 16, 3, stride=2), (1, 16, 32, 16), "phase"),
+        ("groups", L.Conv2d(16, 16, 3, feature_group_count=2),
+         (1, 16, 32, 16), "xla"),
+    ],
+    ids=lambda v: v.replace(" ", "_") if isinstance(v, str) else None,
+)
+def test_fall_throughs_take_todays_path(gate_down, rec, why, conv, shape,
+                                        path):
+    """Where the fold is not exact the dispatch goes where it went before,
+    decided from shapes and padding alone, and gives that path's result."""
+    params, _ = conv.init(jax.random.key(2), shape)
+    x = jax.random.normal(jax.random.key(3), shape)
+    y = conv.apply(params, x, ApplyCtx(train=True))
+    assert rec.conv_paths() == {path: 1}, why
+
+    kh, kw, sh, sw, ph, pw = conv._geometry()
+    y_ref = _ref(x, params["kernel"], (ph, ph), (pw, pw), stride=sh,
+                 groups=conv.feature_group_count) + params["bias"]
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-5)
+
+
+def _lowered(conv, params, x):
+    return jax.jit(
+        lambda p, x: conv.apply(p, x, ApplyCtx(train=True))
+    ).lower(params, x).as_text()
+
+
+def test_conv2d_takes_the_fold_before_the_stripes(gate_down, rec,
+                                                  monkeypatch):
+    """At a qualifying shape Conv2d.apply lowers to one folded convolution
+    and no loop; the striped function at the same shape does loop; and
+    MPI4DL_NO_HSTRIPE=1 means the plain convolution: neither."""
+    conv = L.Conv2d(16, 16, 3)
+    shape = (1, 16, 32, 16)
+    params, _ = conv.init(jax.random.key(4), shape)
+    x = jax.random.normal(jax.random.key(5), shape)
+
+    text = _lowered(conv, params, x)
+    assert "stablehlo.while" not in text
+    assert "tensor<3x3x128x128xf32>" in text          # the folded kernel
+    assert rec.conv_paths() == {"wfold": 1}
+    y = conv.apply(params, x, ApplyCtx(train=True))
+
+    striped = jax.jit(
+        lambda x, w: hc.hstripe_conv2d(x, w, (1, 1), (1, 1))
+    ).lower(x, params["kernel"]).as_text()
+    assert "stablehlo.while" in striped
+
+    monkeypatch.setenv("MPI4DL_NO_HSTRIPE", "1")
+    plain = _lowered(conv, params, x)
+    assert "stablehlo.while" not in plain
+    assert "x128x128x" not in plain
+    assert rec.conv_paths() == {"wfold": 1, "xla": 1}
+    y_plain = conv.apply(params, x, ApplyCtx(train=True))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_plain), atol=1e-5)
+
+
+def test_fold_under_an_h_sharded_context_with_the_margin_pre_exchanged(
+        gate_down, rec):
+    """The context ``hstripe_layer_run`` builds (and an SP tile sharded on
+    H alone): H margin already in the activation, so no H padding; W SAME.
+    Each tile takes the fold and together they are the unsharded result."""
+    conv = L.Conv2d(16, 16, 3)
+    shape = (1, 16, 32, 16)
+    params, _ = conv.init(jax.random.key(6), shape)
+    x = jax.random.normal(jax.random.key(7), shape)
+    whole = conv.apply(params, x, ApplyCtx(train=True))
+
+    tiles, rows = 2, shape[1] // 2
+    sp = SpatialCtx(axis_h="sph", grid_h=tiles, bn_cross_tile=False,
+                    stat_local=True, halo_pre_exchanged=True, pre_margin_h=1)
+    ctx = ApplyCtx(train=True, spatial=sp)
+    xp = jnp.pad(x, ((0, 0), (1, 1), (0, 0), (0, 0)))
+    parts = [conv.apply(params, xp[:, i * rows:(i + 1) * rows + 2], ctx)
+             for i in range(tiles)]
+    assert rec.conv_paths() == {"wfold": 1}
+    np.testing.assert_allclose(
+        np.asarray(jnp.concatenate(parts, axis=1)), np.asarray(whole),
+        atol=1e-5)
+
+    # a tile whose W margin came from an exchange is VALID on W: the stripes
+    sp_w = SpatialCtx(axis_w="spw", grid_w=2, bn_cross_tile=False,
+                      stat_local=True, halo_pre_exchanged=True,
+                      pre_margin_w=1)
+    conv.apply(params, jnp.pad(x, ((0, 0), (0, 0), (1, 1), (0, 0))),
+               ApplyCtx(train=True, spatial=sp_w))
+    assert rec.conv_paths() == {"wfold": 1, "hstripe": 1}
+
+
+def _trace_model(model, dtype=jnp.float32):
+    """Trace init and apply on shapes alone: nothing is allocated."""
+    params = jax.eval_shape(lambda: model.init(jax.random.key(0))[0])
+    jax.eval_shape(
+        lambda p, x: model.apply(p, x, ApplyCtx(train=True)),
+        params, jax.ShapeDtypeStruct(model.in_shape, dtype))
+
+
+def test_conv_paths_of_a_resnet_v2(monkeypatch, rec):
+    """Depth 11 (one block a stage) at 64² with the gate at 64²: the
+    16-channel stage's two 3×3 and two 1×1 convolutions fold, the stem (Cin
+    3) keeps the stripes, the strided ones take the phase form, the rest are
+    XLA's; a site counts once however often it is traced."""
+    from mpi4dl_tpu.models.resnet import get_resnet_v2
+
+    monkeypatch.setattr(L, "_HSTRIPE_MIN_PIXELS", 64 * 64)
+    model = get_resnet_v2((1, 64, 64, 3), depth=11, num_classes=10)
+    _trace_model(model)
+    want = {"wfold": 4, "hstripe": 1, "phase": 4, "xla": 4}
+    assert rec.conv_paths() == want
+    _trace_model(model)
+    assert rec.conv_paths() == want
+    assert rec.summary()["conv_paths"] == want
+
+
+def test_conv_paths_of_the_resnet_cell(rec):
+    """``resnet110_v2.1024.bs1`` as it is: 13 3×3 16→16, 11 3×3 64→16 and 13
+    1×1 16→64 fold; the stem alone is left to the stripes."""
+    from mpi4dl_tpu.models.resnet import get_resnet_v2
+
+    _trace_model(get_resnet_v2((1, 1024, 1024, 3), depth=110,
+                               num_classes=1000), jnp.bfloat16)
+    assert rec.conv_paths() == {"wfold": 37, "hstripe": 1, "phase": 4,
+                                "xla": 70}
+
+
+def test_conv_paths_of_resnet_at_2048(rec):
+    """At 2048² the 16-channel stage runs block by block in H stripes
+    (``hstripe_layer_run``: each stripe is under the gate, so XLA's own),
+    the shortcut conv left beside it is at ``_WFOLD_MAX_PIXELS`` and keeps
+    the striped path with the stem, and the 64-channel stage at 1024² folds
+    by 2: 12 3×3 64→64 and 12 1×1 64→128."""
+    from mpi4dl_tpu.models.resnet import get_resnet_v2
+
+    _trace_model(get_resnet_v2((1, 2048, 2048, 3), depth=110,
+                               num_classes=1000), jnp.bfloat16)
+    assert rec.conv_paths() == {"wfold": 24, "hstripe": 2, "phase": 4,
+                                "xla": 82}
+
+
+def test_conv_paths_of_the_amoebanet_cell(rec):
+    """``amoebanet_d.2048.bs1`` as it is: the stem is strided and every
+    other convolution at a million pixels is wider than 64 channels, so the
+    traffic bypasses the fold and the stripes."""
+    from mpi4dl_tpu.models.amoebanet import amoebanetd
+
+    _trace_model(amoebanetd((1, 2048, 2048, 3), num_classes=1000,
+                            num_layers=18, num_filters=416), jnp.bfloat16)
+    assert rec.conv_paths() == {"phase": 13, "xla": 266}
+
+
+def test_conv_paths_are_not_counted_with_the_recorder_off(monkeypatch):
+    rec = spans.Recorder(enabled=False)
+    rec.note_conv(object(), "xla")
+    assert rec.conv_paths() == {}
