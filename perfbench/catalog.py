@@ -4,10 +4,23 @@
 metrics; each name is a file:
 
     configs/<config>.json         sizes, the entry point's flags, the reference
-    traffic/<traffic>.json        image size, batch, loader and layout flags
+    traffic/<traffic>.json        size, batch, loader and layout flags
     layer_metrics/<metric>.json   the reader kind and its parameters
     readers/<kind>.py             ``read(record, **params)``
     references/<module>.py        ``cells(params, sizes, tally)``
+
+Optional, each read where it is there and passed over where it is not:
+
+    ``size`` in a traffic file: one sample's size (an image's side, a
+        sequence's length), the key under which ``model_flops_per_img`` is read
+    ``model_flops_per_img`` in a configuration: ``{"<size>": FLOPs a sample}``,
+        held against the reference's count in every run
+    ``batch_spec(sizes, traffic)`` in a reference: the ``ShapeDtypeStruct``s of
+        ``(x, y)``; absent, one square float32 RGB image and one class a sample
+
+A key of a configuration's ``sizes`` that the parsed flags carry too
+(``num_layers``) is held equal to the flag by the tests; any other is the
+reference's alone.
 
 A later PR adds a cell, a configuration, a metric or a reader kind by adding
 files and entries; no file that is here needs an edit for it.
@@ -62,13 +75,35 @@ class Cell:
         return [*self.config["argv"], *self.traffic["argv"],
                 "--seed", str(int(seed) % (2**31 - 1))]
 
-    def reference_cells(self) -> Callable:
-        """``cells(params, sizes, tally)`` of the configuration's plain
-        reference: one float32 function per cell of the program's model."""
+    @property
+    def size(self) -> Optional[int]:
+        """The size of one sample as the traffic states it, or None."""
+        return self.traffic.get("size")
+
+    def reference(self):
+        """The configuration's plain reference, as a module."""
         path = os.path.join(self.bench_dir, "references",
                             self.config["reference"] + ".py")
         return _load_module(
-            path, "perfbench_reference_" + self.config["reference"]).cells
+            path, "perfbench_reference_" + self.config["reference"])
+
+    def reference_cells(self) -> Callable:
+        """``cells(params, sizes, tally)`` of the configuration's plain
+        reference: one float32 function per cell of the program's model."""
+        return self.reference().cells
+
+    def batch_spec(self):
+        """The ``ShapeDtypeStruct``s of one batch ``(x, y)``: the reference's
+        own ``batch_spec(sizes, traffic)``, else an image and its class."""
+        from perfbench.references import plain
+
+        spec = getattr(self.reference(), "batch_spec", plain.image_batch_spec)
+        return spec(self.config["sizes"], self.traffic)
+
+    def stored_model_flops(self) -> Optional[int]:
+        """``model_flops_per_img`` of the configuration at the traffic's
+        size, or None where either file does not state it."""
+        return self.config.get("model_flops_per_img", {}).get(str(self.size))
 
 
 class Catalog:

@@ -26,7 +26,8 @@ from typing import Any, Dict, List, Optional
 
 from perfbench import trace as trace_mod
 from perfbench.catalog import Catalog, Cell
-from perfbench.references.plain import Tally, cross_entropy, model_flops
+from perfbench.references.plain import (
+    Tally, cast_floating, cross_entropy, model_flops)
 
 WARM_STEPS = 3  # the compiling step and two more
 MIN_WINDOW_STEPS = 10
@@ -216,9 +217,11 @@ def reference_check(cell: Cell, cfg, params, x, y) -> Dict[str, Any]:
         with jax.default_matmul_precision("highest"):
             ref_cells = cell.reference_cells()(p, cell.config["sizes"], tally)
             assert len(ref_cells) == len(model.cells)
-            act, errors = x.astype(jnp.float32), []
+            # Only the first cell can meet a leaf that is not floating
+            # (token ids): both sides get it as the loader made it.
+            act, errors = cast_floating(x, jnp.float32), []
             for i, (ref_cell, cell_i) in enumerate(zip(ref_cells, model.cells)):
-                given = jax.tree.map(lambda a: a.astype(cfg.compute_dtype), act)
+                given = cast_floating(act, cfg.compute_dtype)
                 got = first(cell_i.apply(p[i], given, ApplyCtx(train=True)))
                 act = ref_cell(act)
                 want = first(act)
@@ -233,17 +236,20 @@ def reference_check(cell: Cell, cfg, params, x, y) -> Dict[str, Any]:
         "cell_rel_err": errors,
         "cell_rel_err_max": max(errors),
         "forward_macs_per_img": tally.macs // x.shape[0],
+        "forward_macs_per_img_by_kind": {
+            k: v // x.shape[0] for k, v in tally.by_kind.items()},
         "model_flops_per_img": model_flops(tally.macs) // x.shape[0],
     }
 
 
-def check_stored_flops(cell: Cell, image_size: int, counted: int) -> None:
-    """The configuration's file states the model's FLOPs an image for the
-    sizes its cells use; a count that has drifted from it is an error."""
-    stored = cell.config.get("model_flops_per_img", {}).get(str(image_size))
+def check_stored_flops(cell: Cell, counted: int) -> None:
+    """The configuration's file states the model's FLOPs a sample for the
+    sizes its cells' traffic uses; a count that has drifted from it is an
+    error."""
+    stored = cell.stored_model_flops()
     if stored is not None and stored != counted:
         raise ValueError(
-            f"{cell.config_name}: model_flops_per_img at {image_size} is "
+            f"{cell.config_name}: model_flops_per_img at {cell.size} is "
             f"{stored} in the file, {counted} by the reference's count")
 
 
@@ -262,21 +268,38 @@ def all_finite(tree) -> bool:
     return bool(check(tree))
 
 
-def verdict(*, losses: List[float], anomalies: int, state_finite: bool,
-            compiles_in_window: int, first_loss: float, reference_loss: float,
-            loss_tolerance: float, cell_rel_err_max: float,
-            cell_tolerance: float) -> Dict[str, bool]:
-    """The conditions of ``correct``; all must hold."""
-    loss_rel = abs(first_loss - reference_loss) / abs(reference_loss)
-    return {
-        "losses_finite": all(math.isfinite(v) for v in losses),
-        "guard_silent": anomalies == 0,
-        "loss_moves": len(set(losses)) > 1,
-        "state_finite": bool(state_finite),
-        "no_compile_in_window": compiles_in_window == 0,
-        "first_loss_matches_reference": loss_rel <= loss_tolerance,
-        "cells_match_reference": cell_rel_err_max <= cell_tolerance,
+def compared(*, losses: List[float], anomalies: int, state_finite: bool,
+             compiles_in_window: int, first_loss: float, reference_loss: float,
+             loss_tolerance: float, cell_rel_err_max: float,
+             cell_tolerance: float) -> Dict[str, Dict[str, Any]]:
+    """Every number ``correct`` compares, beside its limit, under the name
+    of the condition it decides: the one table that ``verdict`` judges and
+    that every run prints.  A condition holds where its value is at or under
+    its limit; a value that is not a number is ``None`` (valid JSON) and
+    holds nowhere."""
+    table = {
+        # how many losses are not finite; how often the guard spoke
+        "losses_finite": (sum(not math.isfinite(v) for v in losses), 0),
+        "guard_silent": (anomalies, 0),
+        # 1 where every step's loss is the same number, and where the state
+        # has a leaf that is not finite
+        "loss_moves": (int(len(set(losses)) <= 1), 0),
+        "state_finite": (int(not state_finite), 0),
+        "no_compile_in_window": (compiles_in_window, 0),
+        "first_loss_matches_reference": (
+            abs(first_loss - reference_loss) / abs(reference_loss),
+            loss_tolerance),
+        "cells_match_reference": (cell_rel_err_max, cell_tolerance),
     }
+    return {name: {"value": value if math.isfinite(value) else None,
+                   "limit": limit}
+            for name, (value, limit) in table.items()}
+
+
+def verdict(table: Dict[str, Dict[str, Any]]) -> Dict[str, bool]:
+    """The conditions of ``correct``, from ``compared``; all must hold."""
+    return {name: c["value"] is not None and c["value"] <= c["limit"]
+            for name, c in table.items()}
 
 
 def trace_options():
@@ -329,7 +352,7 @@ def run_cell(catalog: Catalog, cell: Cell, *, seed: int, seconds: float,
         ref = reference_check(cell, cfg, eval_params_fn(state), x0, y0)
         gc.collect()  # the check program leaves the device before the step
         ref_s = time.perf_counter() - t_ref
-        check_stored_flops(cell, cfg.image_size, ref["model_flops_per_img"])
+        check_stored_flops(cell, ref["model_flops_per_img"])
 
         stamps = Stamps(step, echo=say)
         anomalies = 0
@@ -404,7 +427,7 @@ def run_cell(catalog: Catalog, cell: Cell, *, seed: int, seconds: float,
                 shutil.rmtree(trace_dir, ignore_errors=True)
 
     tolerances = cell.config["tolerances"]
-    checks = verdict(
+    table = compared(
         losses=losses, anomalies=anomalies, state_finite=state_finite,
         compiles_in_window=int(watch.get("window", "count")),
         first_loss=losses[0], reference_loss=ref["reference_loss"],
@@ -412,6 +435,7 @@ def run_cell(catalog: Catalog, cell: Cell, *, seed: int, seconds: float,
         cell_rel_err_max=ref["cell_rel_err_max"],
         cell_tolerance=tolerances["cell"]["value"],
     )
+    checks = verdict(table)
     used = list(devices)
     runtime_peak = max(
         (int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
@@ -438,7 +462,8 @@ def run_cell(catalog: Catalog, cell: Cell, *, seed: int, seconds: float,
         },
         "trace": trace_info,
         "run": {"img_per_s": img_per_s, "chips": cell.chips},
-        "model": {"flops_per_img": ref["model_flops_per_img"]},
+        "model": {"flops_per_img": ref["model_flops_per_img"],
+                  "forward_macs_per_img": ref["forward_macs_per_img_by_kind"]},
         "peaks": {"bf16_flops": (catalog.peak(used[0].device_kind, "bf16_flops")
                                  if used[0].platform != "cpu" else None)},
     }
@@ -467,6 +492,9 @@ def run_cell(catalog: Catalog, cell: Cell, *, seed: int, seconds: float,
             result["metrics"][m["name"]] = {"value": end_to_end[m["name"]],
                                             "unit": m["unit"]}
 
+    # Every number `correct` compared, beside its limit: the last key of the
+    # result's line and the last lines on standard error.
+    result["compared"] = table
     details = {
         "cell": cell.name, "seed": seed, "seconds": seconds, "argv": argv,
         "result": result, "checks": checks, "reference": ref,
@@ -481,13 +509,13 @@ def run_cell(catalog: Catalog, cell: Cell, *, seed: int, seconds: float,
     }
     with open(details_path, "w", encoding="utf-8") as f:
         json.dump(details, f, indent=1)
-    loss_rel = abs(losses[0] - ref["reference_loss"]) / abs(ref["reference_loss"])
     say(f"perfbench: {cell.name} seed {seed}: {untraced} steps in "
         f"{wall_s:.3f} s after {setup_s:.1f} s of set-up; period median "
         f"{statistics.median(spans['period']):.3f} ms; "
         f"{record['counters']['compiles_in_window']} compiles in the window; "
         f"first loss {losses[0]:.6f} against the reference's "
-        f"{ref['reference_loss']:.6f} (rel {loss_rel:.2e}, tolerance "
+        f"{ref['reference_loss']:.6f} (rel "
+        f"{table['first_loss_matches_reference']['value']!r}, tolerance "
         f"{tolerances['loss']['value']}); cells off by at most "
         f"{ref['cell_rel_err_max']:.2e} in relative L2, each fed the "
         f"reference's input (tolerance {tolerances['cell']['value']}); "
@@ -496,6 +524,9 @@ def run_cell(catalog: Catalog, cell: Cell, *, seed: int, seconds: float,
         f"{runtime_peak / 2**30:.3f} GiB; checks "
         f"{json.dumps(checks)}; details in "
         f"{os.path.relpath(details_path, catalog.root)}")
+    for name, c in table.items():
+        print(f"perfbench: compared {name} {c['value']!r} limit {c['limit']!r}",
+              file=sys.stderr)
     return result
 
 

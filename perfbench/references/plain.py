@@ -4,13 +4,18 @@ Straightforward ``jax.numpy``: no kernels, no remat, no layout tricks, no
 code of the program under test.  Every convolution and dense layer also
 records its multiply-accumulates in a :class:`Tally`, so the walk that
 computes the reference is the walk that counts the model's FLOPs: one
-description of the architecture serves both.
+description of the architecture serves both.  A reference with other kinds
+of product (attention scores, experts) counts them under names of its own:
+``tally.add("attn_scores", macs)``.
 
 The caller runs these under ``jax.default_matmul_precision("highest")``: on
 a TPU a float32 matmul otherwise runs in bf16 passes.
 """
 
 from __future__ import annotations
+
+import math
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -20,15 +25,27 @@ BN_EPS = 1e-5  # torch.nn.BatchNorm2d's default, which the reference models use
 
 
 class Tally:
-    """Multiply-accumulates of the forward pass, counted from shapes."""
+    """Multiply-accumulates of the forward pass, counted from shapes, by the
+    kind of product: ``conv`` and ``dense`` here, any other name a reference
+    gives.  ``macs`` is the sum over all kinds."""
 
     def __init__(self) -> None:
-        self.conv_macs = 0
-        self.dense_macs = 0
+        self.by_kind: Dict[str, int] = {}
+
+    def add(self, kind: str, macs: int) -> None:
+        self.by_kind[kind] = self.by_kind.get(kind, 0) + int(macs)
 
     @property
     def macs(self) -> int:
-        return self.conv_macs + self.dense_macs
+        return sum(self.by_kind.values())
+
+    @property
+    def conv_macs(self) -> int:
+        return self.by_kind.get("conv", 0)
+
+    @property
+    def dense_macs(self) -> int:
+        return self.by_kind.get("dense", 0)
 
 
 def model_flops(forward_macs: int) -> int:
@@ -58,16 +75,17 @@ def conv(x, p, stride=1, padding=0, tally: Tally | None = None):
     if tally is not None:
         n, oh, ow, oc = y.shape
         kh, kw, ic, _ = k.shape
-        tally.conv_macs += n * oh * ow * oc * kh * kw * ic
+        tally.add("conv", n * oh * ow * oc * kh * kw * ic)
     if "bias" in p:
         y = y + p["bias"].astype(jnp.float32)
     return y
 
 
 def dense(x, p, tally: Tally | None = None):
+    """``x @ kernel + bias`` over the last axis of ``x``."""
     k = p["kernel"].astype(jnp.float32)
     if tally is not None:
-        tally.dense_macs += x.shape[0] * k.shape[0] * k.shape[1]
+        tally.add("dense", math.prod(x.shape[:-1]) * k.shape[0] * k.shape[1])
     return jnp.dot(x, k, precision=lax.Precision.HIGHEST) + p["bias"].astype(
         jnp.float32)
 
@@ -104,15 +122,33 @@ def avg_pool(x, k, stride, padding=0, count_include_pad=True):
     return s / _windows(ones, k, stride, padding, 0.0, lax.add)
 
 
+def cast_floating(tree, dtype):
+    """Every floating leaf (an image, an activation) in ``dtype``; any other
+    (token ids) as it came: cast to bf16, ids are exact only below 256."""
+    return jax.tree.map(
+        lambda a: (a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating)
+                   else a), tree)
+
+
+def image_batch_spec(sizes, traffic):
+    """``(x, y)`` of a batch where the reference states none of its own: one
+    square float32 RGB image and one class a sample."""
+    n, size = traffic["batch_size"], traffic["size"]
+    return (jax.ShapeDtypeStruct((n, size, size, 3), jnp.float32),
+            jax.ShapeDtypeStruct((n,), jnp.int32))
+
+
 def forward(cells, x):
     """The logits: every cell in turn, in float32."""
-    act = x.astype(jnp.float32)
+    act = cast_floating(x, jnp.float32)
     for cell in cells:
         act = cell(act)
     return act
 
 
 def cross_entropy(logits, labels):
-    """Mean softmax cross-entropy with integer labels."""
+    """Mean softmax cross-entropy with integer labels of the logits' leading
+    shape: ``[B, V]`` with ``[B]``, or ``[B, S, V]`` with ``[B, S]`` and the
+    mean over every position."""
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=-1))
+    return -jnp.mean(jnp.take_along_axis(logp, labels[..., None], axis=-1))

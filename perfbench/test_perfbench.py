@@ -28,6 +28,34 @@ BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 
 
+VOCAB, HIDDEN = 1000, 32
+
+# The plain reference of the token stub: embedding, one dense layer, a head.
+TINY_TOKENS_REFERENCE = '''
+import jax
+import jax.numpy as jnp
+
+from perfbench.references import plain
+
+
+def embed(table, ids):
+    return table.astype(jnp.float32)[ids]
+
+
+def batch_spec(sizes, traffic):
+    shape = (traffic["batch_size"], traffic["size"])
+    return (jax.ShapeDtypeStruct(shape, jnp.int32),
+            jax.ShapeDtypeStruct(shape, jnp.int32))
+
+
+def cells(params, sizes, tally=None):
+    assert params[0]["table"].shape == (sizes["vocab"], sizes["hidden"])
+    return [lambda ids: embed(params[0]["table"], ids),
+            lambda x: plain.relu(plain.dense(x, params[1], tally)),
+            lambda x: plain.dense(x, params[2], tally)]
+'''
+
+
 def _dump(path, obj):
     with open(path, "w", encoding="utf-8") as f:
         json.dump(obj, f)
@@ -35,9 +63,12 @@ def _dump(path, obj):
 
 @pytest.fixture(scope="module")
 def tiny(tmp_path_factory):
-    """A copy of the benchmark's files with a throw-away configuration, a
-    throw-away traffic mix, two cells, a metric and a reader kind ADDED as
-    new files and entries: nothing that is there is edited."""
+    """A copy of the benchmark's files with throw-away configurations,
+    traffic mixes, cells, a reference, a metric and a reader kind ADDED as
+    new files and entries: nothing that is there is edited.  Among them a
+    token model (``tiny_tokens``: a reference with its own ``batch_spec``, a
+    configuration and a traffic file, and no more) and a spatial cell over
+    four devices."""
     root = tmp_path_factory.mktemp("checkout")
     shutil.copytree(os.path.join(ROOT, "perfbench"), root / "perfbench",
                     ignore=shutil.ignore_patterns("out", "__pycache__"))
@@ -62,6 +93,27 @@ def tiny(tmp_path_factory):
         "argv": ["--image-size", "32", "--batch-size", "2", "--split-size",
                  "1", "--num-workers", "1"],
     })
+    _dump(p / "traffic" / "32.sp2x2.bs1.json", {
+        "family": "sp", "size": 32, "batch_size": 1,
+        "argv": ["--image-size", "32", "--batch-size", "1", "--split-size",
+                 "1", "--num-spatial-parts", "4", "--slice-method", "square",
+                 "--num-workers", "1"],
+    })
+    # The program has no token flags yet: the sequence length travels as
+    # --image-size and the vocabulary as --num-classes, to the stubs below.
+    _dump(p / "configs" / "tiny_tokens.json", {
+        "model": "tokens", "reference": "tiny_tokens",
+        "sizes": {"vocab": VOCAB, "hidden": HIDDEN},
+        "argv": ["--num-classes", str(VOCAB), "--precision", "bf_16"],
+        "model_flops_per_img": {"16": 6 * 16 * (HIDDEN * HIDDEN + HIDDEN * VOCAB)},
+        "tolerances": {"loss": {"value": 1e-2}, "cell": {"value": 0.05}},
+    })
+    _dump(p / "traffic" / "seq16.bs2.json", {
+        "family": "lp", "size": 16, "batch_size": 2,
+        "argv": ["--image-size", "16", "--batch-size", "2", "--split-size",
+                 "1", "--num-workers", "1"],
+    })
+    (p / "references" / "tiny_tokens.py").write_text(TINY_TOKENS_REFERENCE)
     _dump(p / "layer_metrics" / "loss_wait_p10_ms.json", {
         "unit": "ms", "layer": "device", "moves": "img_per_s",
         "reader": "span_decile", "params": {"span": "loss_wait", "decile": 1},
@@ -78,6 +130,14 @@ def tiny(tmp_path_factory):
         bench["workloads"].append({
             "name": f"{name}.32.bs2", "config": name, "traffic": "32.bs2",
             "chips": 1, "why": "test"})
+    bench["configs"].append({
+        "name": "tiny_tokens", "source": "test", "reduced": [], "why": "test",
+        "file": "perfbench/configs/tiny_tokens.json"})
+    bench["workloads"] += [
+        {"name": "tiny_tokens.seq16.bs2", "config": "tiny_tokens",
+         "traffic": "seq16.bs2", "chips": 1, "why": "test"},
+        {"name": "tiny_resnet.32.sp2x2.bs1", "config": "tiny_resnet",
+         "traffic": "32.sp2x2.bs1", "chips": 4, "why": "test"}]
     bench["per_layer"].append({
         "name": "loss_wait_p10_ms", "unit": "ms", "better": "lower",
         "source": "host_clock", "layer": "device", "moves": "img_per_s",
@@ -148,27 +208,179 @@ def test_seed_is_reduced_below_2_to_the_31():
 # --- the run ------------------------------------------------------------------
 
 
+SEED = 2147483659
+
+
+def _run(tiny, cell, chips=1, say=lambda text: None):
+    """One short run of ``cell``: the result and the details file."""
+    out_dir = os.path.join(tiny.bench_dir, "out")
+    result = harness.run_cell(
+        tiny, cell, seed=SEED, seconds=0.2, trace=False,
+        t0=time.perf_counter(), devices=jax.devices()[:chips],
+        out_dir=out_dir, say=say)
+    with open(os.path.join(
+            out_dir, f"{cell.name}.seed{SEED}.trace0.json")) as f:
+        return result, json.load(f)
+
+
 def test_cell_runs_through_build_train_and_run_supervised(tiny):
     """argv -> build_train -> run_supervised on a tiny model: the four
     end-to-end metrics, a correct run, and the details file."""
     cell = tiny.cell("tiny_resnet.32.bs2")
     lines = []
-    result = harness.run_cell(
-        tiny, cell, seed=2147483659, seconds=0.2, trace=False,
-        t0=time.perf_counter(), devices=jax.devices()[:1],
-        out_dir=os.path.join(tiny.bench_dir, "out"), say=lines.append)
+    result, details = _run(tiny, cell, say=lines.append)
     assert result["correct"] and result["failed"] == 0
     assert result["attempted"] >= harness.MIN_WINDOW_STEPS
+    assert list(result)[-1] == "compared" and all(
+        0 <= c["value"] <= c["limit"] for c in result["compared"].values())
+    assert list(result["compared"]) == list(details["checks"])
+    assert result["compared"]["cells_match_reference"] == {
+        "value": details["reference"]["cell_rel_err_max"], "limit": 0.05}
     assert set(result["metrics"]) == {"img_per_s", "step_ms_p90", "hbm_gib",
                                       "setup_s"}
     assert all(m["value"] > 0 for m in result["metrics"].values())
     assert sum(l.startswith("epoch ") for l in lines) == (
         harness.WARM_STEPS + result["attempted"])
-    details = json.load(open(os.path.join(
-        tiny.bench_dir, "out", f"{cell.name}.seed2147483659.trace0.json")))
     assert details["compiles"].get("window") is None
     assert details["memory_analysis"]["temp"] > 0
     assert len(details["spans_ms"]["period"]) == result["attempted"] - 1
+
+
+# --- a token model and a spatial cell, added as files only -------------------
+
+
+class _TokenDataset:
+    """int32 ids below the vocabulary and a label for every position."""
+
+    def __init__(self, cfg):
+        self.seq, self.vocab, self.seed = cfg.image_size, cfg.num_classes, cfg.seed
+
+    def __len__(self):
+        return 320
+
+    def batch(self, idx, batch_size):
+        rng = np.random.default_rng(self.seed + idx)
+        shape = (batch_size, self.seq)
+        return (rng.integers(0, self.vocab, shape, dtype=np.int32),
+                rng.integers(0, self.vocab, shape, dtype=np.int32))
+
+
+def _token_model(cfg):
+    """The stub ``CellModel``: embedding, a dense layer, a head, in bf16."""
+    from mpi4dl_tpu.cells import CellModel, FnCell
+
+    seq, vocab = cfg.image_size, cfg.num_classes
+
+    def table_init(key, in_shape):
+        return ({"table": jax.random.normal(key, (vocab, HIDDEN))},
+                (*in_shape, HIDDEN))
+
+    def dense_init(n_out):
+        def init(key, in_shape):
+            k = jax.random.normal(key, (in_shape[-1], n_out)) / in_shape[-1] ** 0.5
+            return ({"kernel": k, "bias": jnp.full((n_out,), 0.1)},
+                    (*in_shape[:-1], n_out))
+        return init
+
+    def dense(p, x):
+        return x @ p["kernel"].astype(x.dtype) + p["bias"].astype(x.dtype)
+
+    return CellModel([
+        FnCell(table_init, lambda p, ids, ctx: p["table"].astype(jnp.bfloat16)[ids]),
+        FnCell(dense_init(HIDDEN), lambda p, x, ctx: jnp.maximum(dense(p, x), 0)),
+        FnCell(dense_init(vocab), lambda p, x, ctx: dense(p, x)),
+    ], (cfg.batch_size, seq), vocab, name="tiny_tokens")
+
+
+def _token_loss(logits, labels, from_probs=False):
+    """The stub's own loss, written apart from the reference's: the mean over
+    every position of logsumexp less the label's logit."""
+    z = logits.astype(jnp.float32)
+    picked = jnp.sum(z * jax.nn.one_hot(labels, z.shape[-1]), axis=-1)
+    return jnp.mean(jax.nn.logsumexp(z, axis=-1) - picked)
+
+
+@pytest.fixture
+def token_program(monkeypatch):
+    """What the program lacks for a token model, stubbed and not edited (the
+    edits are the ``model_config`` PR's): a model, a dataset, and the two
+    places where ``train.py``'s one-chip step takes its batch for an image:
+    the cast of ``x`` to the compute dtype (a no-op once that dtype is the
+    ids' own) and ``labels[:, None]``."""
+    import mpi4dl_tpu.data
+    import mpi4dl_tpu.models
+    from mpi4dl_tpu import train
+
+    make_train_step = train.make_train_step
+    monkeypatch.setattr(mpi4dl_tpu.models, "build_model", _token_model)
+    monkeypatch.setattr(mpi4dl_tpu.data, "make_dataset", _TokenDataset)
+    monkeypatch.setattr(train, "make_train_step", lambda *a, **kw: (
+        make_train_step(*a, **{**kw, "compute_dtype": jnp.int32})))
+    monkeypatch.setattr(train, "cross_entropy", _token_loss)
+
+
+def test_token_cell_runs_through_the_whole_of_run_cell(tiny, token_program):
+    """Ids of 256 and over reach both first cells as the loader made them
+    (cast to bf16 the program's embedding would look up other rows), the
+    loss is the mean over positions, and the stored FLOPs are looked up
+    under the traffic's size."""
+    cell = tiny.cell("tiny_tokens.seq16.bs2")
+    result, details = _run(tiny, cell)
+    assert result["correct"], details["checks"]
+    assert result["attempted"] >= harness.MIN_WINDOW_STEPS
+    ref = details["reference"]
+    assert len(ref["cell_rel_err"]) == 3 and ref["cell_rel_err"][0] < 0.01
+    assert ref["model_flops_per_img"] == cell.stored_model_flops() > 0
+    assert ref["forward_macs_per_img_by_kind"] == {
+        "dense": 16 * (HIDDEN * HIDDEN + HIDDEN * VOCAB)}
+    assert abs(ref["reference_loss"] - math.log(VOCAB)) < 1.0
+
+
+def test_permuted_embedding_rows_fail_the_cell_check(tiny, token_program,
+                                                     monkeypatch):
+    from mpi4dl_tpu.config import config_from_args, get_parser
+
+    cell = tiny.cell("tiny_tokens.seq16.bs2")
+    cfg = config_from_args(get_parser().parse_args(cell.argv(7)))
+    params, _ = _token_model(cfg).init(jax.random.key(0))
+    x, y = _TokenDataset(cfg).batch(0, 2)
+    assert x.max() >= 256
+    tol = cell.config["tolerances"]["cell"]["value"]
+    good = harness.reference_check(cell, cfg, params, x, y)
+    assert good["cell_rel_err_max"] < tol
+    monkeypatch.setattr(cell.reference(), "embed",
+                        lambda table, ids: table.astype(jnp.float32)[::-1][ids])
+    bad = harness.reference_check(cell, cfg, params, x, y)
+    assert bad["cell_rel_err"][0] > 1.0 and bad["cell_rel_err_max"] > tol
+
+
+def test_spatial_cell_over_four_devices_runs_through_run_cell(tiny):
+    """The ``sp`` family on a 2x2 mesh of host devices: the mesh from
+    ``MeshSpec.from_config``, the reference check on the replicated
+    parameters, ``memory_analysis()`` a device.  The shard_map step retraces
+    on its second call (PERF.md, PR 22); with three warm steps that falls
+    in set-up, so the window builds nothing, and the recorder has counted
+    the step program twice."""
+    from mpi4dl_tpu.obs.spans import recorder
+
+    assert len(jax.devices()) >= 4, "perfbench/conftest.py asks for four"
+    cell = tiny.cell("tiny_resnet.32.sp2x2.bs1")
+    assert cell.chips == 4 and cell.family == "sp"
+    spec = json.load(open(os.path.join(
+        tiny.bench_dir, "layer_metrics", "step_program_builds.json")))["params"]
+
+    def step_program_builds():  # the recorder counts over the whole process
+        return sum(n for program, n in recorder().programs(spec["kind"]).items()
+                   if re.search(spec["pattern"], program))
+
+    before = step_program_builds()
+    result, details = _run(tiny, cell, chips=4)
+    assert result["correct"], details["checks"]
+    assert details["checks"]["no_compile_in_window"]
+    assert result["device"]["count"] == 4
+    assert result["device"]["memory_peak_bytes"] >= (
+        details["memory_analysis"]["total"]) > 0
+    assert step_program_builds() - before == 2
 
 
 def test_window_sizing():
@@ -219,7 +431,7 @@ GOOD = dict(losses=[6.91, 6.90, 6.92], anomalies=0, state_finite=True,
     ({"cell_rel_err_max": 0.3}, "cells_match_reference"),
 ])
 def test_each_condition_of_correct_fails_when_it_should(change, fails):
-    checks = harness.verdict(**{**GOOD, **change})
+    checks = harness.verdict(harness.compared(**{**GOOD, **change}))
     assert [k for k, ok in checks.items() if not ok] == ([fails] if fails else [])
 
 
@@ -295,6 +507,53 @@ def test_reference_agrees_with_the_program_in_float32(tiny, kind, tol):
     assert float(jnp.max(jnp.abs(ref - got))) < tol * float(jnp.max(jnp.abs(ref)))
 
 
+@pytest.mark.parametrize("shape", [(4,), (2, 3)])
+def test_cross_entropy_takes_labels_of_the_logits_leading_shape(shape):
+    """``[B, V]`` with ``[B]`` is the number it always was, to the bit;
+    ``[B, S, V]`` with ``[B, S]`` is the mean over every position."""
+    rng = np.random.default_rng(0)
+    logits = jnp.asarray(rng.standard_normal((*shape, 7), np.float32))
+    labels = jnp.asarray(rng.integers(0, 7, shape, dtype=np.int32))
+    flat, flat_labels = logits.reshape(-1, 7), labels.reshape(-1)
+    logp = jax.nn.log_softmax(flat, axis=-1)
+    was = -jnp.mean(jnp.take_along_axis(logp, flat_labels[:, None], axis=-1))
+    got = float(plain.cross_entropy(logits, labels))
+    assert got == float(was) if len(shape) == 1 else got == pytest.approx(
+        float(was), rel=1e-6)  # the mean over two axes sums in another order
+    by_hand = -np.mean([np.log(np.exp(r[k]) / np.exp(r).sum())
+                        for r, k in zip(np.asarray(flat, np.float64),
+                                        np.asarray(flat_labels))])
+    assert float(was) == pytest.approx(by_hand, rel=1e-6)
+
+
+def test_tally_counts_under_any_name():
+    tally = plain.Tally()
+    assert (tally.macs, tally.conv_macs, tally.dense_macs) == (0, 0, 0)
+    x = jax.ShapeDtypeStruct((2, 5, 8), jnp.float32)
+    p = {"kernel": jax.ShapeDtypeStruct((8, 3), jnp.float32),
+         "bias": jax.ShapeDtypeStruct((3,), jnp.float32)}
+    jax.eval_shape(lambda x, p: plain.dense(x, p, tally), x, p)
+    tally.add("attn_scores", 40)
+    tally.add("attn_scores", 2)
+    tally.add("conv", 7)
+    assert tally.by_kind == {"dense": 2 * 5 * 8 * 3, "attn_scores": 42, "conv": 7}
+    assert (tally.conv_macs, tally.dense_macs) == (7, 240)
+    assert tally.macs == 240 + 42 + 7
+
+
+def test_an_integer_leaf_keeps_its_dtype_and_a_floating_one_is_cast():
+    ids = np.array([[0, 255, 256, 999]], np.int32)
+    assert plain.cast_floating(ids, jnp.bfloat16).dtype == np.int32
+    assert plain.cast_floating(
+        jnp.ones((1, 2), jnp.bfloat16), jnp.float32).dtype == jnp.float32
+    both = plain.cast_floating(
+        {"ids": ids, "image": np.ones((1, 2), np.float32)}, jnp.bfloat16)
+    assert (both["ids"].dtype, both["image"].dtype) == (np.int32, jnp.bfloat16)
+    spec = plain.image_batch_spec({}, {"batch_size": 2, "size": 32})
+    assert [(s.shape, s.dtype) for s in spec] == [
+        ((2, 32, 32, 3), jnp.float32), ((2,), jnp.int32)]
+
+
 def test_model_flops_against_a_hand_count():
     """ResNet-(9+2) v2 at 32x32, batch 1, 10 classes, by hand: per output
     position, kernel area x channels in x channels out."""
@@ -320,25 +579,38 @@ def test_model_flops_against_a_hand_count():
     assert plain.model_flops(tally.macs) == 6 * (conv_macs + dense_macs)
 
 
-@pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
-def test_stored_model_flops_are_what_the_reference_counts(workload):
-    """``model_flops_per_img`` in the configuration's file, at the cell's
-    image size, against the count from shapes at that size (nothing runs)."""
+@pytest.mark.parametrize("workload", [
+    *(w["name"] for w in BENCH["workloads"]), "tiny_tokens.seq16.bs2"])
+def test_stored_model_flops_are_what_the_reference_counts(workload, request):
+    """``model_flops_per_img`` in the configuration's file, under the
+    traffic's size, against the count from shapes at that size (nothing
+    runs); the batch is the one the reference states for itself, and a key
+    of ``sizes`` that the parsed flags carry too is the flag's value.  The
+    token cell of the tiny catalog goes through the same lines as the cells
+    of ``BENCHMARK.json``."""
+    import mpi4dl_tpu.models as models
     from mpi4dl_tpu.config import config_from_args, get_parser
-    from mpi4dl_tpu.models import build_model
 
-    cell = Catalog().cell(workload)
+    if workload.startswith("tiny_"):
+        request.getfixturevalue("token_program")  # the program's model: a stub
+        catalog = request.getfixturevalue("tiny")
+    else:
+        catalog = Catalog()
+    cell = catalog.cell(workload)
     cfg = config_from_args(get_parser().parse_args(cell.argv(0)))
-    shapes = jax.eval_shape(lambda k: build_model(cfg).init(k)[0],
+    shapes = jax.eval_shape(lambda k: models.build_model(cfg).init(k)[0],
                             jax.random.key(0))
     tally = plain.Tally()
-    x = jax.ShapeDtypeStruct(
-        (cfg.batch_size, cfg.image_size, cfg.image_size, 3), jnp.float32)
+    x, _ = cell.batch_spec()
     jax.eval_shape(lambda p, x: plain.forward(cell.reference_cells()(
         p, cell.config["sizes"], tally), x), shapes, x)
-    assert cell.config["model_flops_per_img"][str(cfg.image_size)] == (
-        plain.model_flops(tally.macs) // cfg.batch_size)
-    assert cell.config["sizes"]["num_layers"] == cfg.num_layers
+    assert cell.stored_model_flops() == (
+        plain.model_flops(tally.macs) // x.shape[0]) > 0
+    sizes = cell.config["sizes"]
+    held = {key for key in sizes if hasattr(cfg, key)}
+    assert all(sizes[key] == getattr(cfg, key) for key in held), held
+    if not workload.startswith("tiny_"):  # the image configurations' own
+        assert "num_layers" in held
 
 
 # --- the trace ----------------------------------------------------------------
@@ -390,6 +662,29 @@ def test_busy_time_comes_from_modules_not_from_ops():
     assert cat.read_layer_metric("device_step_ms", record) == pytest.approx(900e-6)
     assert cat.read_layer_metric("device_idle_pct", record) == pytest.approx(10.0)
     assert cat.read_layer_metric("device_idle_pct", {"trace": None, "spans": {}}) is None
+
+
+def test_chips_of_a_mesh_are_averaged_and_the_first_is_broken_down():
+    """Four chips run the step program side by side: busy time and the
+    window are the chips' mean, the periods those every chip has, and the
+    step's device time and the op sums the first chip's."""
+    planes = [(f"/device:TPU:{chip}", [
+        ("XLA Modules", [("jit_step(9)", k * 1000 + chip, 800 + 10 * chip)
+                         for k in range(3 + (chip == 3))]),
+        ("XLA Ops", [("%fusion.1 = bf16[8]{0} fusion(bf16[8] %p)",
+                      k * 1000 + chip, 100 * (chip + 1)) for k in range(3)])])
+        for chip in (2, 0, 3, 1)]
+    out = trace.reduce_planes(trace.read_planes(_profile(planes)),
+                              harness.STEP_PROGRAM)
+    assert [c["chip"] for c in out["chips"]] == [0, 1, 2, 3]
+    assert out["periods"] == 2
+    assert out["busy_s"] == pytest.approx(
+        (2 * 800 + 2 * 810 + 2 * 820 + 3 * 830) / 4 * 1e-9)
+    assert out["window_s"] == pytest.approx((2000 + 2000 + 2000 + 3000) / 4 * 1e-9)
+    assert out["op_seconds"] == pytest.approx({"fusion:bf16[8]": 200e-9})
+    record = {"trace": out, "spans": {"period": [1000e-6] * 5}}
+    assert Catalog().read_layer_metric("device_step_ms", record) == (
+        pytest.approx(800e-6))
 
 
 def test_mfu_reader():
