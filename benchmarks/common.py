@@ -141,6 +141,30 @@ def _spatial_levels(cfg: ParallelConfig, n_cells: int, shapes=None):
     return levels
 
 
+def _check_token_family(cfg: ParallelConfig, family: str, dtype) -> None:
+    """What a token model trains through: the one-chip step (with data
+    parallelism) and the GPipe pipeline over its cells.  The spatial families
+    shard an image's H and W and GEMS pairs mirrored image batches; a
+    sequence axis as a sharded axis is not built yet (ROADMAP R6)."""
+    import jax.numpy as jnp
+
+    if family != "lp" or cfg.schedule != "gpipe":
+        raise ValueError(
+            f"--model {cfg.model} is a token model: it trains through the lp "
+            f"family (one chip, or GPipe over cells with --split-size), not "
+            f"through {family!r}"
+            + (f" with --schedule {cfg.schedule}" if family == "lp" else "")
+            + "; the sequence axis is not a sharded axis yet (ROADMAP R6)")
+    if cfg.split_size > 1 and 2 ** (jnp.finfo(dtype).nmant + 1) < cfg.vocab_size:
+        # the stage buffers are flat vectors in the compute dtype, and the
+        # ids enter stage 0 through one
+        raise ValueError(
+            f"--split-size {cfg.split_size} carries the ids to stage 0 in the "
+            f"compute dtype, and {jnp.dtype(dtype).name} holds only "
+            f"{2 ** (jnp.finfo(dtype).nmant + 1)} of --vocab-size "
+            f"{cfg.vocab_size} exactly: use --precision fp_32")
+
+
 def build_train(cfg: ParallelConfig, family: str, mesh):
     """Return (step, state, eval_params_fn, global_batch).
 
@@ -196,6 +220,9 @@ def build_train(cfg: ParallelConfig, family: str, mesh):
             params = jax.tree.map(lambda p: p.astype(pdtype), params)
         from_probs = cfg.softmax_in_model
 
+        if cfg.is_token_model:
+            _check_token_family(cfg, family, dtype)
+
         if cfg.schedule != "gpipe" and cfg.split_size <= 1:
             print(
                 f"note: --schedule {cfg.schedule} needs a pipeline "
@@ -225,7 +252,7 @@ def build_train(cfg: ParallelConfig, family: str, mesh):
             mb = cfg.batch_size // cfg.parts
             part = StagePartition.build(
                 model, params, cfg.split_size,
-                (mb, cfg.image_size, cfg.image_size, 3),
+                (mb, *cfg.sample_shape),
                 balance=cfg.balance, compute_dtype=dtype, param_dtype=pdtype,
             )
             with rec.span("setup/make_step"):
@@ -255,7 +282,7 @@ def build_train(cfg: ParallelConfig, family: str, mesh):
             mb = cfg.batch_size // groups
             part = StagePartition.build(
                 model, params, cfg.split_size,
-                (mb, cfg.image_size, cfg.image_size, 3),
+                (mb, *cfg.sample_shape),
                 balance=cfg.balance, compute_dtype=dtype, param_dtype=pdtype,
             )
             with rec.span("setup/make_step"):

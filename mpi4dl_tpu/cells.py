@@ -108,6 +108,11 @@ class CellModel:
     num_classes: int
     spatial_until: int = 0
     name: str = "model"
+    # ``step_metrics(params, tokens) -> {name: scalar}``: what a step counted
+    # besides its loss, from statistics the layers left in the parameters (a
+    # routed model's expert load); the one-chip step returns it as
+    # ``metrics["counted"]`` and the loop writes it on the ``step`` span.
+    step_metrics: Optional[Callable[[Any, int], dict]] = None
 
     def init(self, key) -> Tuple[List[Any], List[ShapeLike]]:
         """Init all cells; returns (params_list, shape_list) where

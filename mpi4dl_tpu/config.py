@@ -218,7 +218,7 @@ def hatches_markdown(include_internal: bool = False) -> str:
 @dataclasses.dataclass
 class ParallelConfig:
     # --- model / problem (reference parser.py) ---
-    model: str = "resnet"  # resnet | amoebanet
+    model: str = "resnet"  # resnet | amoebanet | lfm2_moe
     batch_size: int = 32
     parts: int = 1  # micro-batches per step (GPipe "parts")
     split_size: int = 1  # number of pipeline stages (LP splits)
@@ -235,6 +235,14 @@ class ParallelConfig:
     num_layers: int = 18  # amoebanet cell count knob
     num_filters: int = 416
     num_classes: int = 10
+    # --- token models (lfm2_moe): the cut and the job; the published sizes
+    # are models/lfm2.py's own.  A sample is a sequence of seq_len ids below
+    # vocab_size; of the published experts this process holds experts_held,
+    # from expert_first (one chip's share under expert parallelism).
+    seq_len: int = 128
+    vocab_size: int = 65536
+    experts_held: int = 64
+    expert_first: int = 0
     balance: Optional[Tuple[int, ...]] = None  # per-stage cell counts
     halo_d2: bool = False  # fused-halo "design 2"
     # Margin-consuming layers per fused halo block in D2 (reference
@@ -285,6 +293,21 @@ class ParallelConfig:
     @property
     def spatial_part_size(self) -> int:
         return self.num_spatial_parts[0]
+
+    @property
+    def is_token_model(self) -> bool:
+        """Whether a sample is a sequence of ids: the model's own entry in
+        ``models.MODELS`` says what it takes in."""
+        from mpi4dl_tpu.models import input_kind
+
+        return input_kind(self.model) == "tokens"
+
+    @property
+    def sample_shape(self) -> Tuple[int, ...]:
+        """One sample of the input, without the batch axis."""
+        if self.is_token_model:
+            return (self.seq_len,)
+        return (self.image_size, self.image_size, 3)
 
     @property
     def compute_dtype(self):
@@ -380,6 +403,15 @@ def get_parser() -> argparse.ArgumentParser:
     p.add_argument("--num-layers", type=int, default=18)
     p.add_argument("--num-filters", type=int, default=416)
     p.add_argument("--num-classes", type=int, default=10)
+    p.add_argument("--seq-len", type=int, default=128,
+                   help="token models: ids a sequence")
+    p.add_argument("--vocab-size", type=int, default=65536,
+                   help="token models: rows of the vocabulary held here")
+    p.add_argument("--experts-held", type=int, default=64,
+                   help="token models: routed experts this process holds "
+                        "(64 of 64 is the uncut layer)")
+    p.add_argument("--expert-first", type=int, default=0,
+                   help="token models: the first expert held")
     p.add_argument("--balance", type=str, default=None)
     # the reference spells it --halo-D2 (parser.py); accept both
     p.add_argument("--halo-d2", "--halo-D2", dest="halo_d2", action="store_true")
@@ -466,6 +498,10 @@ def config_from_args(args: argparse.Namespace) -> ParallelConfig:
         num_layers=args.num_layers,
         num_filters=args.num_filters,
         num_classes=args.num_classes,
+        seq_len=args.seq_len,
+        vocab_size=args.vocab_size,
+        experts_held=args.experts_held,
+        expert_first=args.expert_first,
         balance=_int_tuple(args.balance),
         halo_d2=args.halo_d2,
         fused_layers=args.fused_layers,

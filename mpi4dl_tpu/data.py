@@ -1,6 +1,7 @@
 """Data pipelines: the reference's three APP modes
 (benchmark_amoebanet_sp.py:264-306): 1 = image folder, 2 = CIFAR-10-like,
-3 = synthetic.  All yield NHWC float32 batches + int labels.
+3 = synthetic.  All yield NHWC float32 batches + int labels; a token model's
+synthetic data (:class:`SyntheticTokens`) is int32 ids with a label a position.
 
 Synthetic mode is deterministic per-index (like the reference's
 torch.randn dataset with a fixed seed) and generation happens on host in
@@ -43,6 +44,27 @@ class SyntheticDataset:
         )
         y = rng.integers(0, self.num_classes, size=(batch_size,), dtype=np.int32)
         return x, y
+
+
+@dataclasses.dataclass
+class SyntheticTokens:
+    """Token sequences for a language model, fixed by seed: ``seq_len + 1``
+    ids uniform over the vocabulary held; the input is all but the last and
+    the label of each position the id after it.  Both int32."""
+
+    seq_len: int
+    vocab_size: int
+    length: int = 320
+    seed: int = 0
+
+    def __len__(self) -> int:
+        return self.length
+
+    def batch(self, idx: int, batch_size: int) -> Tuple[np.ndarray, np.ndarray]:
+        rng = np.random.default_rng(self.seed + idx)
+        ids = rng.integers(0, self.vocab_size,
+                           size=(batch_size, self.seq_len + 1), dtype=np.int32)
+        return ids[:, :-1], ids[:, 1:]
 
 
 @dataclasses.dataclass
@@ -198,7 +220,11 @@ class ImageFolderDataset:
 
 def make_dataset(cfg):
     """APP-mode dispatch (reference benchmark scripts, e.g.
-    benchmark_amoebanet_sp.py:264-306)."""
+    benchmark_amoebanet_sp.py:264-306); a token model has synthetic ids only."""
+    if cfg.is_token_model:
+        if cfg.app != 3:
+            raise ValueError(f"--model {cfg.model} has synthetic data only (--app 3)")
+        return SyntheticTokens(cfg.seq_len, cfg.vocab_size, seed=cfg.seed)
     if cfg.app == 1:
         return ImageFolderDataset(cfg.datapath, cfg.image_size, cfg.num_classes, cfg.seed)
     if cfg.app == 2:

@@ -105,7 +105,9 @@ class ApplyCtx:
                    into the post-optimizer params — the JAX-functional form of
                    torch BatchNorm2d's in-place running-buffer update
                    (reference models use plain nn.BatchNorm2d,
-                   resnet_spatial.py:149-163).
+                   resnet_spatial.py:149-163).  Any layer's running
+                   statistic travels the same way: the routed expert layer's
+                   ``load`` (ops/moe.py) does.
     """
 
     train: bool = True

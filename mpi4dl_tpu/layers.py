@@ -523,8 +523,11 @@ class Softmax(Layer):
 
 @dataclasses.dataclass(frozen=True)
 class Dense(Layer):
+    """``x @ kernel (+ bias)`` over the last axis: ``[B, F]`` or ``[B, S, F]``."""
+
     in_features: int
     out_features: int
+    use_bias: bool = True
 
     def init(self, key, in_shape):
         assert in_shape[-1] == self.in_features, (in_shape, self.in_features)
@@ -532,13 +535,60 @@ class Dense(Layer):
         k1, k2 = jax.random.split(key)
         params = {
             "kernel": _uniform(k1, (self.in_features, self.out_features), bound),
-            "bias": _uniform(k2, (self.out_features,), bound),
         }
+        if self.use_bias:
+            params["bias"] = _uniform(k2, (self.out_features,), bound)
         return params, (*in_shape[:-1], self.out_features)
 
     def apply(self, params, x, ctx):
         y = x @ params["kernel"].astype(x.dtype)
-        return y + params["bias"].astype(y.dtype)
+        if self.use_bias:
+            y = y + params["bias"].astype(y.dtype)
+        return y
+
+
+@dataclasses.dataclass(frozen=True)
+class RMSNorm(Layer):
+    """``x / rms(x) * scale`` over the last axis, computed in float32 and
+    handed on in the activation's dtype; ``scale`` is learned, from one."""
+
+    features: int
+    eps: float = 1e-5
+
+    def init(self, key, in_shape):
+        assert in_shape[-1] == self.features, (in_shape, self.features)
+        return {"scale": jnp.ones((self.features,), jnp.float32)}, in_shape
+
+    def apply(self, params, x, ctx):
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        y = xf * lax.rsqrt(ms + self.eps) * params["scale"].astype(jnp.float32)
+        return y.astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class CausalConv1d(Layer):
+    """Depthwise causal convolution along the sequence axis of ``[B, S, C]``:
+    ``y[t] = sum_j kernel[j] * x[t - (K-1) + j]``, zeros left of the
+    sequence, no bias.  The spatial layers' one-dimensional case, written as
+    K shifted multiply-adds: a depthwise kernel of three taps has no matrix
+    product in it.  (``ops/ring.ghost_conv1d`` is dense and centred.)"""
+
+    features: int
+    kernel_size: int = 3
+
+    def init(self, key, in_shape):
+        assert in_shape[-1] == self.features, (in_shape, self.features)
+        bound = 1.0 / math.sqrt(self.kernel_size)
+        return {"kernel": _uniform(
+            key, (self.kernel_size, self.features), bound)}, in_shape
+
+    def apply(self, params, x, ctx):
+        k = self.kernel_size
+        w = params["kernel"].astype(x.dtype)
+        padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+        s = x.shape[1]
+        return sum(padded[:, j:j + s] * w[j] for j in range(k))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,11 +1,63 @@
 from mpi4dl_tpu.models.resnet import get_resnet_v1, get_resnet_v2, get_resnet
 from mpi4dl_tpu.models.amoebanet import amoebanetd
+from mpi4dl_tpu.models.lfm2 import lfm2_moe
 from mpi4dl_tpu.models.seqblock import SeqBlock, make_seq_cp_train_step
 
 __all__ = [
-    "get_resnet_v1", "get_resnet_v2", "get_resnet", "amoebanetd",
-    "SeqBlock", "make_seq_cp_train_step",
+    "get_resnet_v1", "get_resnet_v2", "get_resnet", "amoebanetd", "lfm2_moe",
+    "SeqBlock", "make_seq_cp_train_step", "MODELS", "input_kind", "build_model",
 ]
+
+
+def _resnet(cfg, in_shape):
+    from mpi4dl_tpu.utils import get_depth
+
+    return get_resnet(
+        in_shape,
+        depth=get_depth(2, cfg.num_layers),
+        num_classes=cfg.num_classes,
+        version=2,
+        softmax_in_model=cfg.softmax_in_model,
+    )
+
+
+def _amoebanet(cfg, in_shape):
+    return amoebanetd(
+        in_shape,
+        num_classes=cfg.num_classes,
+        num_layers=cfg.num_layers,
+        num_filters=cfg.num_filters,
+    )
+
+
+def _lfm2_moe(cfg, in_shape):
+    return lfm2_moe(
+        in_shape,
+        num_layers=cfg.num_layers,
+        vocab_size=cfg.vocab_size,
+        experts_held=cfg.experts_held,
+        expert_first=cfg.expert_first,
+        compute_dtype=cfg.compute_dtype,
+    )
+
+
+# ``--model`` -> (what a sample is, the builder).  A sample is an ``image``
+# (``[H, W, 3]`` floats, one class) or ``tokens`` (``[S]`` int32 ids, the next
+# id at every position): the loader (``data.make_dataset``), the input's shape
+# (``ParallelConfig.sample_shape``) and the engines that refuse a sequence
+# (``benchmarks/common``) read it here, so a new model is entered here alone.
+MODELS = {
+    "resnet": ("image", _resnet),
+    "amoebanet": ("image", _amoebanet),
+    "lfm2_moe": ("tokens", _lfm2_moe),
+}
+
+
+def input_kind(model: str) -> str:
+    """``image`` or ``tokens``: what a sample of ``--model`` is."""
+    if model not in MODELS:
+        raise ValueError(f"unknown model {model!r}")
+    return MODELS[model][0]
 
 
 def build_model(cfg):
@@ -15,23 +67,9 @@ def build_model(cfg):
     For resnet, ``cfg.num_layers`` is the block-count n of the v2 depth
     formula 9n+2 (reference hardcodes n=12 → ResNet-110-v2 per benchmark,
     benchmark_resnet_sp.py:161-163; pass --num-layers 12 for parity).  For
-    amoebanet it is the NAS cell count as in the reference parser."""
-    from mpi4dl_tpu.utils import get_depth
-
-    in_shape = (cfg.batch_size // cfg.parts, cfg.image_size, cfg.image_size, 3)
-    if cfg.model == "resnet":
-        return get_resnet(
-            in_shape,
-            depth=get_depth(2, cfg.num_layers),
-            num_classes=cfg.num_classes,
-            version=2,
-            softmax_in_model=cfg.softmax_in_model,
-        )
-    elif cfg.model == "amoebanet":
-        return amoebanetd(
-            in_shape,
-            num_classes=cfg.num_classes,
-            num_layers=cfg.num_layers,
-            num_filters=cfg.num_filters,
-        )
-    raise ValueError(f"unknown model {cfg.model!r}")
+    amoebanet it is the NAS cell count as in the reference parser.  For
+    lfm2_moe (a token model: ``[B, S]`` ids in) it is the layers as run, and
+    the vocabulary rows and the experts held come from their own flags."""
+    input_kind(cfg.model)  # an unknown model is refused before its shape is asked
+    in_shape = (cfg.batch_size // cfg.parts, *cfg.sample_shape)
+    return MODELS[cfg.model][1](cfg, in_shape)

@@ -1,0 +1,333 @@
+"""LFM2-24B-A2B (LiquidAI, Hugging Face ``lfm2_moe``) as a token ``CellModel``.
+
+Forty layers, each ``h += op(RMSNorm(h))`` then ``h += ffn(RMSNorm(h))``.
+``op`` is a gated short convolution (30 layers) or grouped-query attention
+with a per-head RMSNorm and a rotary embedding on q and k (layers 2, 6, ...,
+38); ``ffn`` is a SwiGLU in the first two layers and a routed expert layer
+(64 experts, four a token, sigmoid scores) in the other 38.  Every projection
+is without bias.  After the last layer one more RMSNorm, then the head.
+
+:data:`PUBLISHED` is the model's ``config.json``, key for key.  The flags
+state only the cut and the job (config.py): ``--num-layers`` (layers as run),
+``--vocab-size`` (rows of the vocabulary held), ``--experts-held`` and
+``--expert-first`` (this chip's experts under expert parallelism, ops/moe.py),
+``--seq-len``.  Forty layers, 64 experts and 65,536 rows are the uncut model.
+A cut in depth keeps the leading dense layers once: ``n`` layers are the
+published layers ``num_dense_layers - 1`` to ``num_dense_layers + n - 2``,
+each with its own published ``layer_type`` (nine layers: the dense layer 1,
+then two whole periods of ``full_attention, conv, conv, conv``, layers 2-9).
+
+One cell a layer, an embedding cell before and a cell of final norm and head
+after; each cell owns its parameters (embedding and head are not tied), as
+``split_even`` and the benchmark's cell-by-cell check need.  Activations
+between cells are ``[B, S, hidden]`` in the compute dtype; norms, the
+router's scores, the softmax and the loss are computed in float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict, Mapping, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from mpi4dl_tpu.cells import Cell, CellModel, FnCell
+from mpi4dl_tpu.layer_ctx import ApplyCtx
+from mpi4dl_tpu.layers import CausalConv1d, Dense, Layer, RMSNorm
+from mpi4dl_tpu.obs.spans import recorder
+from mpi4dl_tpu.ops.moe import RoutedExperts
+
+_PERIOD = ("conv", "conv", "full_attention", "conv")
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig:
+    """``https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json``,
+    the keys that say something about the model's shape, under their names."""
+
+    conv_L_cache: int = 3
+    conv_bias: bool = False
+    hidden_size: int = 2048
+    intermediate_size: int = 11776
+    layer_types: Tuple[str, ...] = _PERIOD * 10
+    max_position_embeddings: int = 128000
+    model_type: str = "lfm2_moe"
+    moe_intermediate_size: int = 1536
+    norm_eps: float = 1e-05
+    norm_topk_prob: bool = True
+    num_attention_heads: int = 32
+    num_dense_layers: int = 2
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    num_hidden_layers: int = 40
+    num_key_value_heads: int = 8
+    rope_parameters: Mapping[str, Any] = dataclasses.field(
+        default_factory=lambda: {"rope_theta": 1000000, "rope_type": "default"})
+    routed_scaling_factor: float = 1
+    use_expert_bias: bool = True
+    vocab_size: int = 65536
+
+    @property
+    def head_dim(self) -> int:
+        # not a key of the config: hidden_size / num_attention_heads
+        return self.hidden_size // self.num_attention_heads
+
+
+PUBLISHED = Lfm2MoeConfig()
+EMBED_STD = 0.02  # the family's initializer_range; kernels: U(+-1/sqrt(fan_in))
+
+
+def layers_run(config: Lfm2MoeConfig, num_layers: int) -> Tuple[int, ...]:
+    """The published layers that a model of ``num_layers`` layers runs: all of
+    them, or, cut, a run that starts at the last leading dense layer."""
+    if not 1 <= num_layers <= config.num_hidden_layers:
+        raise ValueError(f"--num-layers {num_layers}: the model has "
+                         f"{config.num_hidden_layers}")
+    first = (0 if num_layers == config.num_hidden_layers
+             else max(config.num_dense_layers - 1, 0))
+    if first + num_layers > config.num_hidden_layers:
+        raise ValueError(f"--num-layers {num_layers}: a cut model starts at "
+                         f"layer {first} of {config.num_hidden_layers}")
+    return tuple(range(first, first + num_layers))
+
+
+@dataclasses.dataclass(frozen=True)
+class ShortConv(Layer):
+    """The gated short convolution: ``B, C, x = split3(W_in h)``;
+    ``y = W_out (C * conv(B * x))``, the convolution depthwise and causal."""
+
+    features: int
+    kernel_size: int
+
+    def _parts(self):
+        d = self.features
+        return (Dense(d, 3 * d, use_bias=False),
+                CausalConv1d(d, self.kernel_size), Dense(d, d, use_bias=False))
+
+    def init(self, key, in_shape):
+        names = ("in_proj", "conv", "out_proj")
+        keys = jax.random.split(key, 3)
+        return {n: layer.init(k, in_shape)[0]
+                for n, k, layer in zip(names, keys, self._parts())}, in_shape
+
+    def apply(self, params, x, ctx):
+        in_proj, conv, out_proj = self._parts()
+        b, c, u = jnp.split(in_proj.apply(params["in_proj"], x, ctx), 3, axis=-1)
+        y = c * conv.apply(params["conv"], b * u, ctx)
+        return out_proj.apply(params["out_proj"], y, ctx)
+
+
+def rotary(x, theta: float):
+    """The default rotary embedding on ``[B, S, H, hd]``, positions 0..S-1:
+    the two halves of a head rotated by ``pos / theta^(2i/hd)``, in float32."""
+    s, hd = x.shape[1], x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class Attention(Layer):
+    """Causal grouped-query attention: RMSNorm over each head of q and of k,
+    the rotary embedding on both, each key-value head serving
+    ``heads // kv_heads`` query heads, scale ``head_dim ** -0.5``.
+
+    The attention itself is ``ops.ring.ring_attention`` on one shard: the
+    Pallas block kernel on a TPU backend, the einsum form elsewhere.  At
+    8,192 tokens the einsum form's scores are 8.6 GB a sequence in float32,
+    so it is not the chip's.  The kernel takes one sequence at a time
+    (``lax.map``): its padded operands and its backward tiles are then one
+    sequence's."""
+
+    features: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rope_theta: float
+    eps: float
+
+    def _parts(self) -> Dict[str, Layer]:
+        d, hd = self.features, self.head_dim
+        return {
+            "q_proj": Dense(d, self.heads * hd, use_bias=False),
+            "k_proj": Dense(d, self.kv_heads * hd, use_bias=False),
+            "v_proj": Dense(d, self.kv_heads * hd, use_bias=False),
+            "out_proj": Dense(self.heads * hd, d, use_bias=False),
+            "q_norm": RMSNorm(hd, self.eps),
+            "k_norm": RMSNorm(hd, self.eps),
+        }
+
+    def init(self, key, in_shape):
+        parts = self._parts()
+        keys = jax.random.split(key, len(parts))
+        shapes = {"out_proj": (*in_shape[:-1], self.heads * self.head_dim),
+                  "q_norm": (self.head_dim,), "k_norm": (self.head_dim,)}
+        return {n: layer.init(k, shapes.get(n, in_shape))[0]
+                for k, (n, layer) in zip(keys, parts.items())}, in_shape
+
+    def apply(self, params, x, ctx):
+        from mpi4dl_tpu.ops.ring import _resolve_flash, ring_attention
+
+        parts = self._parts()
+        b, s, _ = x.shape
+
+        def heads(name, n):
+            y = parts[name + "_proj"].apply(params[name + "_proj"], x, ctx)
+            return y.reshape(b, s, n, self.head_dim)
+
+        q = parts["q_norm"].apply(params["q_norm"], heads("q", self.heads), ctx)
+        k = parts["k_norm"].apply(params["k_norm"], heads("k", self.kv_heads), ctx)
+        q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+        v = heads("v", self.kv_heads)
+        rep = self.heads // self.kv_heads
+        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+        recorder().note_site(
+            "attention", self,
+            "block_flash" if _resolve_flash(None) else "einsum")
+
+        def attend(qkv):
+            return ring_attention(*(t[None] for t in qkv), None, 1, causal=True,
+                                  scale=self.head_dim ** -0.5)[0]
+
+        o = lax.map(attend, (q, k, v))
+        return parts["out_proj"].apply(
+            params["out_proj"], o.reshape(b, s, self.heads * self.head_dim), ctx)
+
+
+@dataclasses.dataclass(frozen=True)
+class SwiGLU(Layer):
+    """``W2 (silu(W1 h) * W3 h)``."""
+
+    features: int
+    ffn: int
+
+    def _parts(self):
+        return {"w1": Dense(self.features, self.ffn, use_bias=False),
+                "w3": Dense(self.features, self.ffn, use_bias=False),
+                "w2": Dense(self.ffn, self.features, use_bias=False)}
+
+    def init(self, key, in_shape):
+        parts = self._parts()
+        keys = jax.random.split(key, 3)
+        hidden = (*in_shape[:-1], self.ffn)
+        return {n: layer.init(k, hidden if n == "w2" else in_shape)[0]
+                for k, (n, layer) in zip(keys, parts.items())}, in_shape
+
+    def apply(self, params, x, ctx):
+        parts = self._parts()
+        up = lambda n: parts[n].apply(params[n], x, ctx)
+        return parts["w2"].apply(
+            params["w2"], jax.nn.silu(up("w1")) * up("w3"), ctx)
+
+
+@dataclasses.dataclass
+class BlockCell(Cell):
+    """One layer: ``h += op(norm(h))``, then ``h += ffn(norm(h))``."""
+
+    op: Layer
+    ffn: Layer
+    norm: RMSNorm
+    name: str = "layer"
+
+    def init(self, key, in_shape):
+        k_op, k_ffn = jax.random.split(key)
+        scale = lambda: self.norm.init(None, in_shape)[0]
+        return {"op_norm": scale(), "op": self.op.init(k_op, in_shape)[0],
+                "ffn_norm": scale(), "ffn": self.ffn.init(k_ffn, in_shape)[0],
+                }, in_shape
+
+    def apply(self, params, x, ctx):
+        x = x + self.op.apply(
+            params["op"], self.norm.apply(params["op_norm"], x, ctx), ctx)
+        return x + self.ffn.apply(
+            params["ffn"], self.norm.apply(params["ffn_norm"], x, ctx), ctx)
+
+
+def _block(config: Lfm2MoeConfig, layer: int, experts_held: int,
+           expert_first: int) -> BlockCell:
+    d = config.hidden_size
+    kind = config.layer_types[layer]
+    if kind == "conv":
+        assert not config.conv_bias
+        op: Layer = ShortConv(d, config.conv_L_cache)
+    elif kind == "full_attention":
+        op = Attention(d, config.num_attention_heads, config.num_key_value_heads,
+                       config.head_dim, float(config.rope_parameters["rope_theta"]),
+                       config.norm_eps)
+    else:
+        raise ValueError(f"layer {layer}: unknown layer_type {kind!r}")
+    if layer < config.num_dense_layers:
+        ffn: Layer = SwiGLU(d, config.intermediate_size)
+    else:
+        assert config.norm_topk_prob and config.use_expert_bias
+        ffn = RoutedExperts(
+            d, config.moe_intermediate_size, config.num_experts,
+            config.num_experts_per_tok, experts_held, expert_first,
+            float(config.routed_scaling_factor))
+    return BlockCell(op, ffn, RMSNorm(d, config.norm_eps),
+                     name=f"layer{layer:02d}_{kind}")
+
+
+def lfm2_moe(in_shape: Tuple[int, int], *, num_layers: int, vocab_size: int,
+             experts_held: int, expert_first: int = 0,
+             compute_dtype=jnp.float32,
+             config: Lfm2MoeConfig = PUBLISHED) -> CellModel:
+    """The model on ``in_shape = (batch, seq_len)`` int32 ids below
+    ``vocab_size``: embedding, ``num_layers`` layers, final norm and head;
+    the logits are ``[batch, seq_len, vocab_size]`` in float32."""
+    d = config.hidden_size
+    if not 1 <= vocab_size <= config.vocab_size:
+        raise ValueError(f"--vocab-size {vocab_size} of {config.vocab_size}")
+
+    def embed_init(key, shape):
+        table = jax.random.normal(key, (vocab_size, d), jnp.float32) * EMBED_STD
+        return {"table": table}, (*shape, d)
+
+    def embed(p, ids, ctx):
+        # a pipeline stage's input arrives in the compute dtype
+        return jnp.take(p["table"].astype(compute_dtype),
+                        ids.astype(jnp.int32), axis=0)
+
+    norm, head = RMSNorm(d, config.norm_eps), Dense(d, vocab_size, use_bias=False)
+
+    def head_init(key, shape):
+        return {"norm": norm.init(None, shape)[0],
+                "head": head.init(key, shape)[0]}, (*shape[:-1], vocab_size)
+
+    def head_apply(p, x, ctx):
+        x = norm.apply(p["norm"], x, ctx)
+        return jnp.dot(x, p["head"]["kernel"].astype(x.dtype),
+                       preferred_element_type=jnp.float32)
+
+    blocks = [_block(config, layer, experts_held, expert_first)
+              for layer in layers_run(config, num_layers)]
+    routed = [i + 1 for i, b in enumerate(blocks)
+              if isinstance(b.ffn, RoutedExperts)]
+
+    def step_metrics(params, tokens: int) -> Dict[str, jax.Array]:
+        """What a step routed, from the expert layers' ``load`` statistic:
+        the rows computed here (over all expert layers), the assignments
+        made (tokens x experts a token x expert layers), and the largest
+        held expert's load over the mean of its layer, the worst layer's."""
+        if not routed:
+            return {}
+        load = jnp.stack([params[i]["ffn"]["load"] for i in routed])
+        per_layer = tokens * config.num_experts_per_tok
+        return {
+            "expert_rows": jnp.round(jnp.sum(load) * per_layer),
+            "expert_assignments": jnp.float32(per_layer * len(routed)),
+            "expert_load_max_over_mean": jnp.max(
+                jnp.max(load, axis=1) / jnp.maximum(jnp.mean(load, axis=1), 1e-30)),
+        }
+
+    return CellModel(
+        [FnCell(embed_init, embed, "embed"), *blocks,
+         FnCell(head_init, head_apply, "norm_head")],
+        tuple(in_shape), vocab_size, name="lfm2_moe",
+        step_metrics=step_metrics)

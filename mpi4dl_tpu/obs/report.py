@@ -117,10 +117,11 @@ def render_run(path: str) -> str:
                  for b in closing.get("built_in_loop") or []]
         if built:  # the same program at two steps is a retrace
             lines.append("programs built in the loop: " + ", ".join(built[:8]))
-        paths = closing.get("conv_paths") or {}
-        if paths:
-            lines.append("conv paths (sites): " + "  ".join(
-                f"{path} {n}" for path, n in paths.items()))
+        for kind in ("conv", "attention", "expert"):
+            paths = closing.get(f"{kind}_paths") or {}
+            if paths:
+                lines.append(f"{kind} paths (sites): " + "  ".join(
+                    f"{path} {n}" for path, n in paths.items()))
 
     # -- resilience events (docs/resilience.md) ----------------------------
     events = [r for r in records

@@ -77,6 +77,15 @@ CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 # (ops/wfold_conv.py), H-striped (ops/hstripe_conv.py), the Pallas kernel, the
 # phase-decomposed strided form (ops/conv_phase.py), or as it stands.
 CONV_PATHS = ("wfold", "hstripe", "pallas", "phase", "xla")
+# Which kernel a site of another kind was traced with: attention
+# (models/lfm2.Attention: the Pallas block kernel of ops/pallas_attention.py
+# or the einsum form) and the routed experts' grouped product (ops/moe.py:
+# ``lax.ragged_dot``, the one path).
+SITE_PATHS = {
+    "conv": CONV_PATHS,
+    "attention": ("block_flash", "einsum"),
+    "experts": ("ragged_dot",),
+}
 
 # At least 4,000 steps of the loop's spans (nine a step with the loader's).
 DEFAULT_CAPACITY = 65_536
@@ -150,9 +159,9 @@ class Recorder:
         self._closed: deque = deque(maxlen=max(1, int(capacity)))
         # (kind, program) -> events: outlives the ring
         self._programs: Dict[Tuple[str, str], int] = {}
-        # (id of the layer, path) -> the layer, held so that its id stays its
-        # own: a site counts once however often jax traces it
-        self._conv_sites: Dict[Tuple[int, str], Any] = {}
+        # (kind, id of the layer, path) -> the layer, held so that its id
+        # stays its own: a site counts once however often jax traces it
+        self._sites: Dict[Tuple[str, int, str], Any] = {}
         # thread id -> stack of open spans
         self._open: Dict[int, List[Span]] = {}
         # thread id -> name of the span it opened last, open or closed, kept
@@ -261,20 +270,30 @@ class Recorder:
             return {program: n for (k, program), n
                     in sorted(self._programs.items()) if k == kind}
 
+    def note_site(self, kind: str, layer: Any, path: str) -> None:
+        """At trace time: the site ``layer`` of ``kind`` (a key of
+        :data:`SITE_PATHS`) went down ``path``."""
+        if self.enabled:
+            with self._lock:
+                self._sites[(kind, id(layer), path)] = layer
+
+    def site_paths(self, kind: str) -> Dict[str, int]:
+        """``<kind>_paths{path}``: the distinct sites of ``kind`` traced so
+        far in the process, by the path their dispatch chose."""
+        with self._lock:
+            paths = [path for k, _, path in self._sites if k == kind]
+        return {path: paths.count(path) for path in SITE_PATHS[kind]
+                if path in paths}
+
     def note_conv(self, layer: Any, path: str) -> None:
         """``layers.Conv2d.apply``, at trace time: ``layer`` went down ``path``
         (one of :data:`CONV_PATHS`)."""
-        if self.enabled:
-            with self._lock:
-                self._conv_sites[(id(layer), path)] = layer
+        self.note_site("conv", layer, path)
 
     def conv_paths(self) -> Dict[str, int]:
         """``conv_paths{path}``: the distinct convolution layers traced so far
         in the process, by the path their dispatch chose."""
-        with self._lock:
-            paths = [path for _, path in self._conv_sites]
-        return {path: paths.count(path) for path in CONV_PATHS
-                if path in paths}
+        return self.site_paths("conv")
 
     # -- jax's own events --------------------------------------------------
 
@@ -335,7 +354,8 @@ class Recorder:
         report`` prints them all): the ``setup/*`` spans one by one, jax's
         events summed by kind with the longest three by program, and the
         programs built or loaded inside a step with its ``gstep`` (a program
-        that appears twice was retraced); and ``conv_paths``."""
+        that appears twice was retraced); ``conv_paths``; and, where the
+        model has such sites, ``attention_paths`` and ``expert_paths``."""
         spans = sorted((s for s in list(self._closed)
                         if s.name.startswith(SETUP_PREFIXES)),
                        key=lambda s: s.start_ns)
@@ -355,13 +375,20 @@ class Recorder:
                     "ms": round(s.ms, 3), "cache_hit": s.attrs.get("cache_hit")}
                    for s in spans if s.name == "jax/compile_or_load"
                    and s.gstep is not None]
-        return {
+        out = {
             "setup_ms": {s.name: round(s.ms, 3) for s in spans
                          if s.name.startswith("setup/")},
             "jax": jax_kinds,
             "built_in_loop": in_loop,
             "conv_paths": self.conv_paths(),
         }
+        # only where the model has such sites
+        for key, kind in (("attention_paths", "attention"),
+                          ("expert_paths", "experts")):
+            paths = self.site_paths(kind)
+            if paths:
+                out[key] = paths
+        return out
 
 
 def _annotation(span: Span):

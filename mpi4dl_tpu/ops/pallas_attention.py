@@ -74,12 +74,19 @@ def _out_structs(operands, shapes_dtypes):
 
 
 def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-            acc, m_scr, l_scr, *, tq, tk, nk, causal, t_k_real):
+            acc, m_scr, l_scr, *, tq, tk, nk, causal, t_k_real, scale):
     """One (bh, q-tile, k-tile) step.  Scratch (acc, m, l) persists across
     the innermost k dimension; outputs are written at the last k tile.
     ``t_k_real``: un-padded key count (static) — key slots past it are
-    masked out so Tk padding contributes exactly nothing."""
+    masked out so Tk padding contributes exactly nothing.
+
+    Both products take their operands as they come (bf16 operands run on
+    the MXU as bf16; float32 ones as float32) and accumulate in float32;
+    the scale is applied to the float32 scores.  Under ``causal`` a tile
+    whose every key lies after its last query is skipped: it would add
+    exactly nothing (the guard below), so only the causal half is computed."""
     ki = pl.program_id(2)
+    qi = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _():
@@ -87,38 +94,49 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    q = q_ref[0].astype(jnp.float32)            # [TQ, D] (pre-scaled)
-    k = k_ref[0].astype(jnp.float32)            # [TK, D]
-    s = jax.lax.dot_general(                    # [TQ, TK]
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    col = ki * tk + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-    if t_k_real % tk:
-        s = jnp.where(col < t_k_real, s, _NEG_INF)
-    if causal:
-        qi = pl.program_id(1)
-        q_pos = offs_ref[0] + qi * tq + lax.broadcasted_iota(
-            jnp.int32, (tq, tk), 0
-        )
-        s = jnp.where(q_pos >= offs_ref[1] + col, s, _NEG_INF)
+    def fold():
+        # DEFAULT, said outright: a caller's "highest" default (the
+        # benchmark's check traces under one) asks Mosaic for a float32
+        # product of bf16 operands, which it refuses.
+        s = jax.lax.dot_general(                    # [TQ, TK]
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            precision=lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32,
+        ) * scale
+        col = ki * tk + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+        if t_k_real % tk:
+            s = jnp.where(col < t_k_real, s, _NEG_INF)
+        if causal:
+            q_pos = offs_ref[0] + qi * tq + lax.broadcasted_iota(
+                jnp.int32, (tq, tk), 0
+            )
+            s = jnp.where(q_pos >= offs_ref[1] + col, s, _NEG_INF)
 
-    m_prev = m_scr[:, 0]                        # [TQ]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    c = jnp.exp(m_prev - m_new)
-    # Guard fully-masked rows: there m_new == _NEG_INF and the naive
-    # exp(s - m_new) = exp(0) = 1 would count every masked key (the classic
-    # flash pitfall — causal ring hops from later devices mask whole rows).
-    p = jnp.where(
-        s > _NEG_INF * 0.5, jnp.exp(s - m_new[:, None]), 0.0
-    )                                           # [TQ, TK]
-    l_new = l_scr[:, 0] * c + jnp.sum(p, axis=-1)
-    acc[:] = acc[:] * c[:, None] + jax.lax.dot_general(
-        p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+        m_prev = m_scr[:, 0]                        # [TQ]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        c = jnp.exp(m_prev - m_new)
+        # Guard fully-masked rows: there m_new == _NEG_INF and the naive
+        # exp(s - m_new) = exp(0) = 1 would count every masked key (the
+        # classic flash pitfall — causal ring hops from later devices mask
+        # whole rows).
+        p = jnp.where(
+            s > _NEG_INF * 0.5, jnp.exp(s - m_new[:, None]), 0.0
+        )                                           # [TQ, TK]
+        l_new = l_scr[:, 0] * c + jnp.sum(p, axis=-1)
+        v = v_ref[0]
+        acc[:] = acc[:] * c[:, None] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            precision=lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+
+    if causal:
+        # the tile's last query is at or after its first key
+        pl.when(offs_ref[0] + (qi + 1) * tq - 1 >= offs_ref[1] + ki * tk)(fold)
+    else:
+        fold()
 
     @pl.when(ki == nk - 1)
     def _():
@@ -152,8 +170,7 @@ def _block_flash_fwd_impl(q, k, v, q_off, k_off, *, causal, scale,
     tq_p = _round_up(t_q, tq)
     tk_p = _round_up(t_k, tk)
     d_p = _round_up(d, _LANES)
-    qp = jnp.pad(q.astype(jnp.float32) * scale,
-                 ((0, 0), (0, tq_p - t_q), (0, d_p - d)))
+    qp = jnp.pad(q, ((0, 0), (0, tq_p - t_q), (0, d_p - d)))
     kp = jnp.pad(k, ((0, 0), (0, tk_p - t_k), (0, d_p - d)))
     vp = jnp.pad(v, ((0, 0), (0, tk_p - t_k), (0, d_p - d)))
     # Padded key slots (a q·0 = 0 score would pollute m/l) are masked inside
@@ -164,7 +181,7 @@ def _block_flash_fwd_impl(q, k, v, q_off, k_off, *, causal, scale,
     grid = (bh, nq, nk)
     kern = pl.pallas_call(
         functools.partial(_kernel, tq=tq, tk=tk, nk=nk, causal=causal,
-                          t_k_real=t_k),
+                          t_k_real=t_k, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -193,6 +210,7 @@ def _block_flash_fwd_impl(q, k, v, q_off, k_off, *, causal, scale,
             ],
         ),
         interpret=interpret,
+        name="block_flash_fwd",
     )
     o, m, l = kern(offs, qp, kp, vp)
     d_out = q.shape[-1]
@@ -240,9 +258,22 @@ def _block_flash_fwd(q, k, v, q_off, k_off, causal, scale, tq, tk, interpret):
     return (o, m, l), (q, k, v, q_off, k_off, o, m, l)
 
 
+# Tiles, measured on a v5e at 32 heads of 64 over 8,192 tokens in bf16
+# (PERF.md, PR 29): the forward kernel alone takes 15.9 ms at (256, 512),
+# 13.0 at (512, 512), 8.7 at (512, 1024) and 7.3 at (1024, 1024); the
+# backward's einsum tiles are fastest at 1,024 queries by 512 keys (21 ms;
+# 33 at 1,024 keys, 43 at 2,048 queries), whatever the forward's were.
+LOCAL_TILES = (1024, 1024)  # query, key rows of a tile, flash_attention_local
+_BWD_TQ, _BWD_TK = 1024, 512  # query, key rows of a backward tile
+
+
 def _block_flash_bwd(causal, scale, tq, tk, interpret, res, cts):
-    """Blockwise backward: a scan over Tk tiles of einsum blocks — never
-    materializes the [Tq, Tk_total] score matrix.
+    """Blockwise backward: a scan over Tk tiles, and inside it a scan over
+    Tq tiles, of einsum blocks — never more than a [BH, TQ, TK] score tile
+    at a time, whatever the sequence length.  Under ``causal`` a tile whose
+    every key lies after its last query is skipped (``lax.cond``): it adds
+    exactly nothing.  The products take q, k, v as they come (and dô in
+    their dtype) and accumulate in float32.
 
     With ô = P·V, l = rowsum(P), P = exp(s - m) (m treated as a constant
     plateau — its cotangent is zero almost everywhere):
@@ -251,62 +282,101 @@ def _block_flash_bwd(causal, scale, tq, tk, interpret, res, cts):
     """
     q, k, v, q_off, k_off, o, m, l = res
     do, dm, dl = cts  # dm is zero a.e.; fold dl into dP
-    del o, dm
+    del o, dm, l
     bh, t_q, d = q.shape
     t_k = k.shape[1]
-    qf = q.astype(jnp.float32) * scale
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    do = do.astype(jnp.float32)
-    dl = dl.astype(jnp.float32)
-    nk = max(1, (t_k + tk - 1) // tk)
+    f32 = jnp.float32
+    del tq, tk  # the forward kernel's; the backward's tiles are its own
+    nk = max(1, (t_k + _BWD_TK - 1) // _BWD_TK)
     tk_c = _round_up(t_k, nk) // nk if t_k else t_k
-    # pad Tk to an even tile split for the scan
-    tk_pad = nk * tk_c - t_k
-    kf_p = jnp.pad(kf, ((0, 0), (0, tk_pad), (0, 0)))
-    vf_p = jnp.pad(vf, ((0, 0), (0, tk_pad), (0, 0)))
-    k_ids = jnp.arange(nk * tk_c, dtype=jnp.int32)
-    q_pos = q_off + jnp.arange(t_q, dtype=jnp.int32)
+    nq = max(1, (t_q + _BWD_TQ - 1) // _BWD_TQ)
+    tq_c = _round_up(t_q, nq) // nq if t_q else t_q
+    k_pad, q_pad = nk * tk_c - t_k, nq * tq_c - t_q
 
-    def tile(carry, inp):
-        dq_acc, = carry
+    def tiles(x, n, pad):
+        """[BH, T, ...] as [n, BH, T/n, ...], zero rows appended."""
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape(bh, n, x.shape[1] // n, *x.shape[2:])
+        return jnp.moveaxis(x, 1, 0)
+
+    # Padded queries have dô = dl = 0, so they add nothing to dk and dv, and
+    # their dq rows are cut off; padded keys are masked by their index.
+    kts, vts = tiles(k, nk, k_pad), tiles(v, nk, k_pad)
+    k_ids = jnp.arange(nk * tk_c, dtype=jnp.int32).reshape(nk, tk_c)
+    q_tiles = (tiles(q, nq, q_pad), tiles(do.astype(q.dtype), nq, q_pad),
+               tiles(m.astype(f32), nq, q_pad), tiles(dl.astype(f32), nq, q_pad),
+               (q_off + jnp.arange(nq * tq_c, dtype=jnp.int32)).reshape(nq, tq_c))
+
+    # Under shard_map the accumulators become device-varying inside the
+    # scans; their initial values must be marked varying up front.
+    def vary(t):
+        try:
+            vma = frozenset()
+            for a in (q, k, v, do):
+                vma = vma | frozenset(jax.typeof(a).vma)
+            return pcast(t, tuple(vma), to="varying") if vma else t
+        except (AttributeError, TypeError):
+            return t
+
+    def k_tile(dq_acc, inp):
         kt, vt, ids = inp  # [BH, tk_c, D], [BH, tk_c, D], [tk_c]
-        s = jnp.einsum("bqd,bkd->bqk", qf, kt)
-        mask = (ids < t_k)[None, :]
-        if causal:
-            mask = mask & (q_pos[:, None] >= (k_off + ids)[None, :])
-        s = jnp.where(mask[None], s, _NEG_INF)
-        p = jnp.where(s > _NEG_INF * 0.5, jnp.exp(s - m[..., None]), 0.0)
-        dp = jnp.einsum("bqd,bkd->bqk", do, vt) + dl[..., None]
-        ds = p * dp
-        dq_acc = dq_acc + jnp.einsum("bqk,bkd->bqd", ds, kt)
-        dkt = jnp.einsum("bqk,bqd->bkd", ds, qf)
-        dvt = jnp.einsum("bqk,bqd->bkd", p, do)
-        return (dq_acc,), (dkt, dvt)
 
-    kts = kf_p.reshape(bh, nk, tk_c, -1).transpose(1, 0, 2, 3)
-    vts = vf_p.reshape(bh, nk, tk_c, -1).transpose(1, 0, 2, 3)
-    idts = k_ids.reshape(nk, tk_c)
-    dq0 = jnp.zeros((bh, t_q, d), jnp.float32)
-    # Under shard_map the accumulator becomes device-varying inside the
-    # scan; its initial value must be marked varying up front.
-    try:
-        vma = frozenset()
-        for a in (q, k, v, do):
-            vma = vma | frozenset(jax.typeof(a).vma)
-        if vma:
-            dq0 = pcast(dq0, tuple(vma), to="varying")
-    except (AttributeError, TypeError):
-        pass
-    (dq,), (dks, dvs) = lax.scan(tile, (dq0,), (kts, vts, idts))
-    dk = dks.transpose(1, 0, 2, 3).reshape(bh, nk * tk_c, -1)[:, :t_k]
-    dv = dvs.transpose(1, 0, 2, 3).reshape(bh, nk * tk_c, -1)[:, :t_k]
+        def q_tile(carry, qin):
+            dk_t, dv_t, dq_acc = carry
+            i, qt, dot, mt, dlt, q_pos = qin
+
+            def fold(dk_t, dv_t):
+                s = jnp.einsum("bqd,bkd->bqk", qt, kt,
+                               preferred_element_type=f32) * scale
+                mask = jnp.broadcast_to((ids < t_k)[None, :], s.shape[1:])
+                if causal:
+                    mask = mask & (q_pos[:, None] >= (k_off + ids)[None, :])
+                s = jnp.where(mask[None], s, _NEG_INF)
+                p = jnp.where(s > _NEG_INF * 0.5, jnp.exp(s - mt[..., None]), 0.0)
+                dp = jnp.einsum("bqd,bkd->bqk", dot, vt,
+                                preferred_element_type=f32) + dlt[..., None]
+                ds = (p * dp).astype(qt.dtype)
+                dq_t = jnp.einsum("bqk,bkd->bqd", ds, kt,
+                                  preferred_element_type=f32)
+                dk_t = dk_t + jnp.einsum("bqk,bqd->bkd", ds, qt,
+                                         preferred_element_type=f32)
+                dv_t = dv_t + jnp.einsum("bqk,bqd->bkd", p.astype(dot.dtype),
+                                         dot, preferred_element_type=f32)
+                return dk_t, dv_t, dq_t
+
+            if causal:
+                # the tile's last query is at or after its first key
+                dk_t, dv_t, dq_t = lax.cond(
+                    q_pos[-1] >= k_off + ids[0], fold,
+                    lambda dk_t, dv_t: (dk_t, dv_t,
+                                        vary(jnp.zeros((bh, tq_c, d), f32))),
+                    dk_t, dv_t)
+            else:
+                dk_t, dv_t, dq_t = fold(dk_t, dv_t)
+            # dq's tile is added where it lies: the accumulator is a loop
+            # carry, updated in place, and never passes over as a whole
+            dq_acc = lax.dynamic_update_index_in_dim(
+                dq_acc, lax.dynamic_index_in_dim(dq_acc, i, 0, False) + dq_t,
+                i, 0)
+            return (dk_t, dv_t, dq_acc), None
+
+        zero = vary(jnp.zeros((bh, tk_c, d), f32))
+        (dk_t, dv_t, dq_acc), _ = lax.scan(
+            q_tile, (zero, zero, dq_acc),
+            (jnp.arange(nq, dtype=jnp.int32), *q_tiles))
+        return dq_acc, (dk_t, dv_t)
+
+    dq0 = vary(jnp.zeros((nq, bh, tq_c, d), f32))
+    dq, (dks, dvs) = lax.scan(k_tile, dq0, (kts, vts, k_ids))
+    dq = jnp.moveaxis(dq, 0, 1).reshape(bh, nq * tq_c, d)
+    untile = lambda x: jnp.moveaxis(x, 0, 1).reshape(bh, nk * tk_c, d)[:, :t_k]
     # Integer (position-offset) primals take float0 cotangents.
     import numpy as np
 
     f0 = np.zeros((), jax.dtypes.float0)
     return (
-        (dq * scale).astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+        (dq[:, :t_q] * scale).astype(q.dtype),
+        (untile(dks) * scale).astype(k.dtype), untile(dvs).astype(v.dtype),
         f0, f0,
     )
 
@@ -340,7 +410,7 @@ def flash_attention_local(q, k, v, causal=False, scale=None,
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
     zero = jnp.zeros((), jnp.int32)
     o, m, l = block_flash(
-        fold(q), fold(k), fold(v), zero, zero, causal, sc, 256, 512,
+        fold(q), fold(k), fold(v), zero, zero, causal, sc, *LOCAL_TILES,
         interpret,
     )
     out = o / jnp.maximum(l, 1e-30)[..., None]

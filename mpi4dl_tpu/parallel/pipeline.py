@@ -107,7 +107,9 @@ def make_pipeline_train_step(
 ):
     """Build `(PipelineState, x, labels) -> (PipelineState, metrics)`.
 
-    x: [B, H, W, C] global batch (B = parts * microbatch); labels: [B].
+    x: [B, H, W, C] global batch (B = parts * microbatch); labels: [B].  (A
+    token model: x [B, S] ids, which ride the stage buffer in the compute
+    dtype, and labels [B, S].)
 
     ``schedule``: ``"gpipe"`` (default — all-forward-then-all-backward as
     jax.grad of the tick scan, the exactness oracle) or ``"1f1b"`` (the
@@ -156,7 +158,7 @@ def make_pipeline_train_step(
         opt_local = squeeze_opt_rows(opt_state)
         mb = x.shape[0] // Pn
         x_parts = x.reshape(Pn, mb, *x.shape[1:]).astype(compute_dtype)
-        y_parts = labels.reshape(Pn, mb)
+        y_parts = labels.reshape(Pn, mb, *labels.shape[1:])
 
         def loss_and_metrics(flat_params):
             if schedule == "1f1b":

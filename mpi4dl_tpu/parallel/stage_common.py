@@ -166,7 +166,6 @@ def gpipe_scan(
     is_last = s_idx == S - 1
     in_pack0 = part.act_packs[0]
     logits_n = part.out_pack.total
-    nclass = part.out_pack.shapes[0][-1]
     amax = part.act_max
     stat_n = branches_stat_n(branches, part)
 
@@ -187,7 +186,7 @@ def gpipe_scan(
         # Last stage: loss for part p = t - (S-1) when in range.
         p_out = t - (S - 1)
         valid = (p_out >= 0) & (p_out < Pn) & is_last
-        logits = lax_slice(y, 0, logits_n).reshape(mb, nclass)
+        logits = lax_slice(y, 0, logits_n).reshape(part.out_pack.shapes[0])
         lbl = lax.dynamic_index_in_dim(
             y_parts, jnp.clip(p_out, 0, Pn - 1), keepdims=False
         )

@@ -293,8 +293,8 @@ def run_supervised(
         )
 
     try:
-        with rec.span("run", steps=total - start_step,
-                      profile=bool(profile)) as run_span, preempt, watchdog:
+        with rec.span("run", steps=total - start_step, profile=bool(profile),
+                      global_batch=global_batch) as run_span, preempt, watchdog:
             while gstep < total and not preempted:
                 # One contiguous segment of the batch stream; a rollback
                 # closes it and reopens past the poison batch.
@@ -364,6 +364,15 @@ def run_supervised(
                                 # part of the wait, not of the call.
                                 state, metrics = out
                                 loss = float(metrics["loss"])  # blocks on device
+                                # What the step counted besides (a routed
+                                # model's expert rows and load), under the
+                                # one key ``counted``: on the host with the
+                                # loss, no second wait; attributes of the
+                                # step span.
+                                counted = metrics.get("counted")
+                                if counted and step_span is not None:
+                                    step_span.set(**{k: float(v) for k, v
+                                                     in counted.items()})
                             ms = (rec.clock() - t_call) / 1e6
                             watchdog.disarm()
                             loss = faults.poison_loss(g, loss)

@@ -38,14 +38,25 @@ from mpi4dl_tpu.obs.scopes import scope
 
 def cross_entropy(logits_or_probs: jax.Array, labels: jax.Array,
                   from_probs: bool = False) -> jax.Array:
-    """Mean softmax cross-entropy with integer labels."""
+    """Mean softmax cross-entropy with integer labels of the logits' leading
+    shape: ``[B, V]`` with ``[B]``, or ``[B, S, V]`` with ``[B, S]`` and the
+    mean over every position."""
     x = logits_or_probs.astype(jnp.float32)
     if from_probs:
         logp = jnp.log(jnp.clip(x, 1e-20, 1.0))
     else:
         logp = jax.nn.log_softmax(x, axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32), axis=-1)
+    nll = -jnp.take_along_axis(logp, labels[..., None].astype(jnp.int32), axis=-1)
     return jnp.mean(nll)
+
+
+def cast_input(x, dtype):
+    """The batch's input in the compute dtype, where it is floating; token
+    ids stay as the loader made them (cast to bf16 they are exact only below
+    256)."""
+    return jax.tree.map(
+        lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a,
+        x)
 
 
 def accuracy(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -252,7 +263,7 @@ def make_train_step(
 
     def grads_for(params, x, labels):
         (loss, (logits, stats)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, x.astype(compute_dtype), labels
+            params, cast_input(x, compute_dtype), labels
         )
         return loss, logits, stats, grads
 
@@ -262,7 +273,8 @@ def make_train_step(
             acc = accuracy(logits, labels)
         else:
             mb_x = x.reshape(parts, x.shape[0] // parts, *x.shape[1:])
-            mb_y = labels.reshape(parts, labels.shape[0] // parts)
+            mb_y = labels.reshape(parts, labels.shape[0] // parts,
+                                  *labels.shape[1:])
             zero = jax.tree.map(jnp.zeros_like, state.params)
             # Abstract probe for the (static) stat-update structure.
             stats_struct = jax.eval_shape(
@@ -291,10 +303,10 @@ def make_train_step(
             params, opt_state = optimizer.update(
                 state.params, grads, state.opt_state)
         params = merge_stat_updates(params, stats)
-        return (
-            TrainState(params, opt_state, state.step + 1),
-            {"loss": loss, "accuracy": acc},
-        )
+        metrics = {"loss": loss, "accuracy": acc}
+        if model.step_metrics is not None:
+            metrics["counted"] = model.step_metrics(params, x.size)
+        return TrainState(params, opt_state, state.step + 1), metrics
 
     if scan_steps > 1 and mesh is not None:
         raise ValueError("scan_steps>1 is single-device only")
@@ -418,7 +430,7 @@ def make_spatial_train_step(
         def grads_for(p, xx, yy):
             (loss, (logits, yy_used, stats)), grads = jax.value_and_grad(
                 global_loss_fn, has_aux=True
-            )(p, xx.astype(compute_dtype), yy)
+            )(p, cast_input(xx, compute_dtype), yy)
             return loss, accuracy(logits, yy_used), stats, grads
 
         if parts == 1:
@@ -505,7 +517,7 @@ def make_eval_step(
     ctx = ApplyCtx(train=False)
 
     def estep(params_list, x, labels):
-        logits = model.apply(params_list, x.astype(compute_dtype), ctx)
+        logits = model.apply(params_list, cast_input(x, compute_dtype), ctx)
         if isinstance(logits, tuple):
             logits = logits[0]
         return {
@@ -553,7 +565,7 @@ def make_spatial_eval_step(
 
     def sharded_eval(params_list, x, labels):
         logits = apply_spatial_model(
-            model, params_list, x.astype(compute_dtype), ctx,
+            model, params_list, cast_input(x, compute_dtype), ctx,
             spatial_until=spatial_until, junction=junction,
             levels=levels, local_dp=local_dp,
         )

@@ -180,3 +180,52 @@ def test_narrow_resblock_at_1024_folds_lane_dense_for_v5e(
     narrow = [l for l in text.split("\n") if "T(2,128)" in l]
     assert not narrow, narrow[:2]
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2 * 763_265_536
+
+
+def test_lfm2_kernels_compile_for_v5e_under_a_highest_default(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The token model's two kernels at its published widths, bf16, forward
+    and backward, traced as the benchmark's check traces the program's cells:
+    under ``jax.default_matmul_precision("highest")``, where a bf16 product
+    left to the default is asked for in float32 and Mosaic refuses it ("Bad
+    lhs type", PR 29's first chip run).  The grouped expert product is
+    ``lax.ragged_dot`` over one round's rows (32,768 tokens, 8 of 64
+    experts), which XLA:TPU compiles to instructions named ``ragged-dot-*``:
+    the names by which the benchmark's ``expert_ffn_ms`` picks them from the
+    trace.  Attention is one sequence of 8,192 tokens, 32 heads of 64."""
+    from mpi4dl_tpu.ops import moe
+
+    def struct(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def experts(x, router, weights):
+        def loss(x, router, weights):
+            y, _ = moe.routed_experts(x, router, weights, first=0, held=8,
+                                      total=64, top_k=4)
+            return jnp.sum(y.astype(jnp.float32))
+
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(loss, (0, 1, 2))(x, router, weights)
+
+    f32 = jnp.float32
+    compiled = jax.jit(experts).lower(
+        struct((32768, 2048)),
+        {"kernel": struct((2048, 64), f32), "bias": struct((64,), f32)},
+        {"w1": struct((8, 2048, 1536), f32), "w3": struct((8, 2048, 1536), f32),
+         "w2": struct((8, 1536, 2048), f32)}).compile()
+    _assert_mosaic(compiled)
+    products = re.findall(
+        r"%ragged-dot-\w+(?:\.\d+)? = bf16\[(20480,1536|20480,2048|8,2048,1536|"
+        r"8,1536,2048)\]", compiled.as_text())
+    # W1, W3, W2 forward, by the rows and by the weights backward, in the
+    # first round and in the overflow rounds' branch
+    assert len(products) >= 9 and len(set(products)) == 4, products
+
+    qkv = struct((32, 8192, 64))
+
+    def attention(q, k, v):
+        with jax.default_matmul_precision("highest"):
+            out, vjp = jax.vjp(flash_attention, q, k, v)
+        return out, vjp(jnp.ones_like(out))
+
+    _assert_mosaic(jax.jit(attention).lower(qkv, qkv, qkv).compile())
