@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from mpi4dl_tpu.layer_ctx import ApplyCtx, EVAL_CTX
-from mpi4dl_tpu.layers import Layer
+from mpi4dl_tpu.layers import Layer, stream_fold
 from mpi4dl_tpu.obs.scopes import scope
 
 Act = Union[jax.Array, Tuple[jax.Array, ...]]
@@ -219,13 +219,20 @@ class CellModel:
 # W*C a multiple of 128 takes the W-fold form [N,H,W*C/128,128]; otherwise
 # (margined SP tiles) H*W*C a multiple of 128 takes the full-flatten form
 # [N,H*W*C/128,128]; packs nothing else — zero graph change.
+#
+# Inside the W-fold's gate the W-fold form has the lanes of the narrow
+# stage's folded runs (layers.stream_fold: [N,H,W/8,512] for ResNet-110 v2's
+# 64-channel boundaries at 1024², as today for its 16-channel one): a block
+# that runs folded then unpacks and packs by no relayout at all.  Measured on
+# the chip against 128 lanes there (PERF.md, PR 30): 13 ms of a 427 ms step
+# for 0.78 GiB.
 # ---------------------------------------------------------------------------
 
 _PACK_MIN_ELEMS = 1 << 24  # 16.7M elements = 32 MB bf16 per saved boundary
 
 
 def _pack_meta(shape):
-    """(w, c) for the W-fold form [N,H,W*C/128,128], or (h, w, c) for the
+    """(w, c) for the W-fold form [N,H,W*C/lanes,lanes], or (h, w, c) for the
     full-flatten form [N,H*W*C/128,128] (margined SP tiles, whose halo
     rows/cols break the per-row divisibility), or None (no packing)."""
     import os
@@ -242,13 +249,21 @@ def _pack_meta(shape):
     return None
 
 
+def _pack_lanes(shape) -> int:
+    """Lanes of the W-fold form of ``shape``: 128, or the p·C of the narrow
+    stage's folded runs where ``shape`` is theirs."""
+    lanes = stream_fold(shape) * shape[3]
+    return lanes if lanes and lanes % 128 == 0 else 128
+
+
 def _pack_one(x):
     m = _pack_meta(getattr(x, "shape", ()))
     if m is None:
         return x, None
     n, h, w, c = x.shape
     if len(m) == 2:
-        return x.reshape(n, h, (w * c) // 128, 128), m
+        lanes = _pack_lanes(x.shape)
+        return x.reshape(n, h, (w * c) // lanes, lanes), m
     return x.reshape(n, (h * w * c) // 128, 128), m
 
 

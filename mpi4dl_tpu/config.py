@@ -55,7 +55,8 @@ HATCHES: Dict[str, Hatch] = {
               "the phase-decomposed dx path."),
         Hatch("MPI4DL_NO_HSTRIPE", "0",
               "1 = tiny-channel huge-spatial convs keep the plain XLA conv "
-              "instead of the W-fold or H-striped patching."),
+              "instead of the W-fold or H-striped patching, and no run of "
+              "layers between them is carried folded."),
         Hatch("MPI4DL_HSTRIPE_RUN", "auto",
               "Block-level H-striping control: 0 = off, 1 = on (silences the "
               "train-mode BN stats warning), auto = on with warning."),

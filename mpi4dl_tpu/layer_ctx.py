@@ -124,6 +124,11 @@ class ApplyCtx:
     # op at a time — set by make_train_step(remat="fine"); the
     # max-trainable-resolution configuration (PERF_NOTES.md).
     remat_ops: bool = False
+    # Internal: set by ``layers.apply_run`` for the layers INSIDE a folded
+    # run: the activation is carried as ``[N, H, W/fold, fold·C]`` from one
+    # W-folded convolution to the next (ops/wfold_conv.py), and BatchNorm,
+    # the convolutions and their bias work on that form.  0: ``[N, H, W, C]``.
+    fold: int = 0
 
     def with_spatial(self, spatial: Optional[SpatialCtx]) -> "ApplyCtx":
         return dataclasses.replace(self, spatial=spatial)

@@ -41,7 +41,8 @@ from mpi4dl_tpu.ops.halo import HaloSpec, halo_exchange_2d, halo_exchange_with_m
 #                          instead of ops/conv_phase.py.
 #  MPI4DL_NO_HSTRIPE=1   — tiny-channel huge-spatial convs keep the plain
 #                          XLA conv instead of ops/wfold_conv.py (or, where
-#                          the fold is not exact, ops/hstripe_conv.py).
+#                          the fold is not exact, ops/hstripe_conv.py);
+#                          no run of layers is carried folded either.
 # Both wins are scheduling/layout properties of XLA's TPU lowering, not of
 # the math — hence the hatches.
 def _phase_dx_enabled() -> bool:
@@ -73,6 +74,13 @@ _PHASE_POOL_MAX_BYTES = 256 * 1024 * 1024
 
 Params = Any
 Shape = Tuple[int, ...]
+
+
+def _narrow_huge(shape) -> bool:
+    """A narrow-channel huge-spatial ``[N, H, W, C]``: what the W-fold and
+    the H stripes exist for (``Conv2d._hstripe_shape``)."""
+    n, h, w, c = shape
+    return _hstripe_enabled() and c <= 64 and h * w >= _HSTRIPE_MIN_PIXELS
 
 
 def _pair(v) -> Tuple[int, int]:
@@ -188,7 +196,7 @@ class Conv2d(Layer):
         )
 
     @staticmethod
-    def _hstripe_shape(kh, kw, sh, sw, groups, x) -> bool:
+    def _hstripe_shape(kh, kw, sh, sw, groups, shape) -> bool:
         """The shape gate for XLA-hostile convs: stride-1 convs on
         NARROW-channel HUGE-spatial inputs, where XLA's TPU lowering puts
         the <= 64 channels in the 128 lanes (2-8x the tensor in memory and
@@ -203,15 +211,45 @@ class Conv2d(Layer):
         shapes: Mosaic refuses sub-128 lane DMA extents and a 128-lane
         channel pad multiplies the input 8–42x in HBM — measured OOM.)
         MPI4DL_NO_HSTRIPE=1 opts out of both: the plain XLA conv."""
-        if not _hstripe_enabled():
-            return False
-        n, h, w, c = x.shape
         # 1x1 convs are pure matmuls, but at huge spatial XLA still splits
         # them with ~2x-padded GB-scale temps — striping bounds those too.
-        return (
-            (sh, sw) == (1, 1) and groups == 1
-            and c <= 64 and h * w >= _HSTRIPE_MIN_PIXELS
+        return (sh, sw) == (1, 1) and groups == 1 and _narrow_huge(shape)
+
+    @staticmethod
+    def _sharded(sp) -> Tuple[bool, bool]:
+        """Whether H and W are sharded over more than one tile: a sharded
+        dim takes its margin from neighbour tiles and runs VALID."""
+        if sp is None or not sp.active:
+            return False, False
+        return (bool(sp.axis_h) and sp.grid_h > 1,
+                bool(sp.axis_w) and sp.grid_w > 1)
+
+    def _wfold(self, shape, pad_w) -> int:
+        """For a convolution that passed ``_hstripe_shape`` on an input of
+        ``shape``: the fold it takes (ops/wfold_conv.py), or 0 where it is
+        left to the H stripes."""
+        n, h, w, c = shape
+        if h * w >= _WFOLD_MAX_PIXELS:
+            return 0
+        from mpi4dl_tpu.ops.wfold_conv import wfold_factor
+
+        return wfold_factor(
+            w, _pair(self.kernel_size)[1],
+            self.lane_pad_in or self.in_channels,
+            self.lane_pad_out or self.out_channels, pad_w,
         )
+
+    def fold_for(self, shape, ctx: ApplyCtx) -> int:
+        """The fold ``apply`` gives this convolution on an unfolded input of
+        ``shape`` under ``ctx``, or 0 where it takes any other path: what a
+        folded run (:func:`run_fold`) asks of each of its convolutions."""
+        kh, kw, sh, sw, ph, pw = self._geometry()
+        if not self._hstripe_shape(
+            kh, kw, sh, sw, self.feature_group_count, shape
+        ):
+            return 0
+        exchanged = self._sharded(ctx.spatial)[1] and pw
+        return self._wfold(shape, (0, 0) if exchanged else (pw, pw))
 
     @staticmethod
     def _pallas_apply(x, kernel, pads):
@@ -233,8 +271,7 @@ class Conv2d(Layer):
                 bias = jnp.pad(bias, (0, po))
         sp = ctx.spatial
         if sp is not None and sp.active:
-            sharded_h = bool(sp.axis_h) and sp.grid_h > 1
-            sharded_w = bool(sp.axis_w) and sp.grid_w > 1
+            sharded_h, sharded_w = self._sharded(sp)
             halo_h = HaloSpec.symmetric(ph if sharded_h else 0)
             halo_w = HaloSpec.symmetric(pw if sharded_w else 0)
             # Per-conv ("D1") halo exchange of the receptive-field overlap —
@@ -274,13 +311,21 @@ class Conv2d(Layer):
         # HBM (measured OOM) — a pallas_conv=True A/B run must not route
         # them away from the paths built for them.
         groups = self.feature_group_count
-        if self._hstripe_shape(kh, kw, sh, sw, groups, x):
-            from mpi4dl_tpu.ops.wfold_conv import wfold_conv2d, wfold_factor
+        if ctx.fold:
+            # Inside a folded run, whose gate (run_fold) held this
+            # convolution to the fold below: x is [N, H, W/p, p·Cin] and
+            # stays folded on the way out.
+            from mpi4dl_tpu.ops.wfold_conv import wfold_conv_folded
 
-            p = wfold_factor(
-                x.shape[2], kw, kernel.shape[2], kernel.shape[3], padding[1]
-            )
-            if p and x.shape[1] * x.shape[2] < _WFOLD_MAX_PIXELS:
+            path = "wfold"
+            y = wfold_conv_folded(x, kernel, padding[0], ctx.fold)
+            if bias is not None:
+                bias = jnp.tile(bias, ctx.fold)
+        elif self._hstripe_shape(kh, kw, sh, sw, groups, x.shape):
+            p = self._wfold(x.shape, padding[1])
+            if p:
+                from mpi4dl_tpu.ops.wfold_conv import wfold_conv2d
+
                 path = "wfold"
                 y = wfold_conv2d(x, kernel, padding[0], p)
             else:
@@ -375,13 +420,22 @@ class BatchNorm(Layer):
         # Memory discipline on the TRAIN path (the 2048px→beyond lever,
         # PERF_NOTES.md; eval below trades it back for fp32 precision):
         # never materialize an fp32 copy of the activation.  Statistics come from
-        # ONE fused sum/sumsq pair with fp32 ACCUMULATION over the original
-        # dtype (XLA fuses the upcast/square into the reductions), and
-        # normalization is folded to y = x·a + b with per-channel fp32
+        # ONE sum/sumsq pair with fp32 ACCUMULATION over the original dtype,
+        # and normalization is folded to y = x·a + b with per-channel fp32
         # (a, b) precomputed — a single fma in the compute dtype, so both
         # the forward temp and the backward cotangents stay bf16 under
-        # bf16 compute.
+        # bf16 compute.  XLA fuses the upcast and the square into the
+        # reductions where the activation's layout is the reduction's own;
+        # behind a W-folded convolution it is not, and the chip wrote x and
+        # x² out in float32 (64.6 ms of the ResNet-110 v2 1024² step, PERF.md
+        # PR 27).  Inside a folded run (ctx.fold = p, apply_run below) the
+        # activation arrives as [N, H, W/p, p·C] and stays so: the sums run
+        # over (N, H, W/p) to a [p·C] vector each and then over the p folded
+        # pixels, the same sums in another order, and (a, b) are tiled p
+        # times.
         orig_dtype = x.dtype
+        fold = ctx.fold  # train mode only: run_fold
+        recorder().note_site("norm", self, "folded" if fold else "plain")
         pad = (self.lane_pad - self.num_features) if self.lane_pad else 0
         scale = jnp.pad(params["scale"], (0, pad)) if pad else params["scale"]
         bias = jnp.pad(params["bias"], (0, pad)) if pad else params["bias"]
@@ -397,6 +451,7 @@ class BatchNorm(Layer):
                 # statistics come from the true tile region only, so fused-run
                 # BN matches the unfused (and single-device) statistics
                 # exactly.  Normalisation still covers the full extended tile.
+                # (No run is folded over such a margin: run_fold.)
                 mh = sp.pre_margin_h if (sp.axis_h and sp.grid_h > 1) else 0
                 mw = sp.pre_margin_w if (sp.axis_w and sp.grid_w > 1) else 0
                 stat_x = x[:, mh : x.shape[1] - mh, mw : x.shape[2] - mw, :]
@@ -404,12 +459,16 @@ class BatchNorm(Layer):
             # under x64 inputs (keeps f64 runs genuinely f64 end-to-end).
             acc_dt = jnp.promote_types(jnp.float32, x.dtype)
             cnt = jnp.asarray(
-                math.prod([stat_x.shape[a] for a in axes]), acc_dt
+                math.prod([stat_x.shape[a] for a in axes]) * (fold or 1),
+                acc_dt,
             )
             s = jnp.sum(stat_x, axis=axes, dtype=acc_dt)
             ss = jnp.sum(
                 jnp.square(stat_x.astype(acc_dt)), axis=axes
             )
+            if fold:
+                s = s.reshape(fold, -1).sum(axis=0)
+                ss = ss.reshape(fold, -1).sum(axis=0)
             if sp is not None and sp.active and sp.bn_cross_tile:
                 # Cross-tile statistics: psum local (sum, sumsq).  The count
                 # is a trace-time constant (SPMD tiles share a shape), so its
@@ -443,6 +502,8 @@ class BatchNorm(Layer):
         inv = lax.rsqrt(var + self.eps) * scale
         a = inv.astype(orig_dtype)
         b = (bias - mean * inv).astype(orig_dtype)
+        if fold:
+            a, b = jnp.tile(a, fold), jnp.tile(b, fold)
         return x * a + b
 
     def normalize_with_stats(self, params, x, mean, var, cnt, ctx: ApplyCtx):
@@ -507,6 +568,80 @@ class Identity(Layer):
 
     def apply(self, params, x, ctx):
         return x
+
+
+# ---------------------------------------------------------------------------
+# A folded run: layers between W-folded convolutions, on the folded form
+# ---------------------------------------------------------------------------
+
+
+# The narrowest convolutions the models here have (ResNet's first stage: 16
+# channels, with 64-channel tensors between its bottleneck blocks).
+_NARROW_CHANNELS = 16
+
+
+def stream_fold(shape: Shape) -> int:
+    """The fold in which a narrow stage hands an activation of ``shape``
+    from one block to the next, or 0 where ``shape`` is outside the W-fold's
+    gate: that of a convolution between it and ``_NARROW_CHANNELS`` channels
+    (8 for ResNet-110 v2's 16- and 64-channel tensors at 1024²), which is the
+    fold of the stage's runs.  The packed cell boundary takes this form
+    (cells._pack_lanes), and so does a BatchNorm that follows the stage
+    without a folded convolution of its own (``run_fold`` with ``p``)."""
+    if len(shape) != 4 or not _narrow_huge(shape):
+        return 0
+    n, h, w, c = shape
+    if h * w >= _WFOLD_MAX_PIXELS:  # as Conv2d._wfold
+        return 0
+    from mpi4dl_tpu.ops.wfold_conv import wfold_factor
+
+    return wfold_factor(w, 1, c, _NARROW_CHANNELS, (0, 0))
+
+
+def run_fold(layers: Sequence[Layer], shape: Shape, ctx: ApplyCtx,
+             p: int = 0) -> int:
+    """The fold p that carries ``layers`` from an input of ``shape`` as
+    ``[N, H, W/p, p·C]`` throughout, or 0: the run is folded only where it is
+    BatchNorm, ReLU and convolutions, in train mode, with no pre-exchanged
+    margin in the activation (BatchNorm slices that off its statistics), and
+    every convolution takes the W-fold (``Conv2d.fold_for``: the dispatch's
+    own gate) with one and the same p.  The fold is the run's, not a
+    layer's: a BatchNorm(64) between convolutions folded by 8 folds by 8.
+    Given ``p`` (the fold of the stream the run continues, ``stream_fold``)
+    the run follows that one or none, and may be without a convolution.
+    Decided from shapes and the context alone; where it is 0 every layer
+    takes the path it takes alone."""
+    sp = ctx.spatial
+    if len(shape) != 4 or ctx.fold or not ctx.train or (
+        sp is not None and sp.halo_pre_exchanged
+        and (sp.pre_margin_h or sp.pre_margin_w)
+    ):
+        return 0
+    n, h, w, c = shape
+    for layer in layers:
+        if isinstance(layer, Conv2d):
+            q = layer.fold_for((n, h, w, c), ctx)
+            if not q or (p and q != p):
+                return 0
+            kh, _, _, _, ph, _ = layer._geometry()
+            p, h = q, h + 2 * ph - kh + 1
+            c = layer.lane_pad_out or layer.out_channels
+        elif isinstance(layer, BatchNorm):
+            if layer.lane_pad:
+                return 0
+        elif not isinstance(layer, (ReLU, Identity)):
+            return 0
+    return p
+
+
+def apply_run(layers: Sequence[Layer], params, x, ctx: ApplyCtx, fold: int = 0):
+    """``layers`` in order; with ``fold`` (from :func:`run_fold`) on an ``x``
+    that is already folded, handing the folded result on."""
+    if fold:
+        ctx = dataclasses.replace(ctx, fold=fold)
+    for p, layer in zip(params, layers):
+        x = layer.apply(p, x, ctx)
+    return x
 
 
 @dataclasses.dataclass(frozen=True)

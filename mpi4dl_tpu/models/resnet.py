@@ -34,7 +34,11 @@ from mpi4dl_tpu.layers import (
     Pool2d,
     ReLU,
     Softmax,
+    apply_run,
+    run_fold,
+    stream_fold,
 )
+from mpi4dl_tpu.ops.wfold_conv import fold, unfold
 
 
 def _resnet_layer(
@@ -231,6 +235,12 @@ class ResBlockV2(Cell):
 
             if hstripe_run_eligible(branch_layers, x.shape, ctx):
                 y = hstripe_layer_run(branch_layers, branch_params, x, ctx)
+        if y is None and not ctx.remat_ops:
+            out = self._folded_block(branch_layers, branch_params, params,
+                                     x, ctx)
+            if out is not None:
+                return out
+            y = self._branch_behind_the_stream(params, x, ctx)
         if y is None:
             y = _apply_branch(
                 (self.r1, self.r2, self.r3),
@@ -239,6 +249,41 @@ class ResBlockV2(Cell):
         if self.r4 is not None:
             x = self.r4.apply(params["r4"], x, ctx)
         return x + y
+
+    def _folded_block(self, layers, flat, params, x, ctx: ApplyCtx):
+        """The block as one folded run, or None.  In the narrow stage at 2²⁰
+        pixels and more every convolution of the branch is W-folded by one p
+        (``layers.run_fold``), so the block stays on ``[N, H, W/p, p·C]``
+        from its input to its residual add, the shortcut convolution with it
+        where it takes the same fold."""
+        p = run_fold(layers, x.shape, ctx)
+        if not p:
+            return None
+        xf = fold(x, p)
+        yf = apply_run(layers, flat, xf, ctx, p)
+        if self.r4 is not None:
+            if run_fold(self.r4.layers, x.shape, ctx) != p:
+                return self.r4.apply(params["r4"], x, ctx) + unfold(yf, p)
+            xf = apply_run(self.r4.layers, params["r4"], xf, ctx, p)
+        return unfold(xf + yf, p)
+
+    def _branch_behind_the_stream(self, params, x, ctx: ApplyCtx):
+        """The branch of the block that follows the narrow stage, or None:
+        its strided convolution does not fold, but the BatchNorm and ReLU
+        before it take the stream as the stage's runs left it
+        (``layers.stream_fold``), not re-tiled for the reduction."""
+        lead = len(self.r1.layers) - 1
+        if not lead or self.stride == 1:
+            return None
+        p = run_fold(self.r1.layers[:lead], x.shape, ctx, stream_fold(x.shape))
+        if not p:
+            return None
+        h = unfold(apply_run(self.r1.layers[:lead], params["r1"][:lead],
+                             fold(x, p), ctx, p), p)
+        return _apply_branch(
+            (LayerCell(self.r1.layers[lead:]), self.r2, self.r3),
+            (params["r1"][lead:], params["r2"], params["r3"]), h, ctx,
+        )
 
 
 def _head(

@@ -80,9 +80,12 @@ CONV_PATHS = ("wfold", "hstripe", "pallas", "phase", "xla")
 # Which kernel a site of another kind was traced with: attention
 # (models/lfm2.Attention: the Pallas block kernel of ops/pallas_attention.py
 # or the einsum form) and the routed experts' grouped product (ops/moe.py:
-# ``lax.ragged_dot``, the one path).
+# ``lax.ragged_dot``, the one path); and on which form of the activation a
+# BatchNorm took its sums and applied its affine: ``[N, H, W/p, p·C]`` inside
+# a folded run (``layers.run_fold``), or ``[N, H, W, C]``.
 SITE_PATHS = {
     "conv": CONV_PATHS,
+    "norm": ("folded", "plain"),
     "attention": ("block_flash", "einsum"),
     "experts": ("ragged_dot",),
 }
@@ -354,8 +357,9 @@ class Recorder:
         report`` prints them all): the ``setup/*`` spans one by one, jax's
         events summed by kind with the longest three by program, and the
         programs built or loaded inside a step with its ``gstep`` (a program
-        that appears twice was retraced); ``conv_paths``; and, where the
-        model has such sites, ``attention_paths`` and ``expert_paths``."""
+        that appears twice was retraced); ``conv_paths`` and ``norm_paths``;
+        and, where the model has such sites, ``attention_paths`` and
+        ``expert_paths``."""
         spans = sorted((s for s in list(self._closed)
                         if s.name.startswith(SETUP_PREFIXES)),
                        key=lambda s: s.start_ns)
@@ -381,6 +385,7 @@ class Recorder:
             "jax": jax_kinds,
             "built_in_loop": in_loop,
             "conv_paths": self.conv_paths(),
+            "norm_paths": self.site_paths("norm"),
         }
         # only where the model has such sites
         for key, kind in (("attention_paths", "attention"),

@@ -22,6 +22,16 @@ The folded kernel is built inside the step from the true one, so parameters,
 optimiser state and checkpoints know nothing of it, and the weight gradient
 comes from autodiff as the sum of the p diagonal blocks, accumulated in
 float32.  H padding is the caller's and passes through unchanged.
+
+Two callers.  A convolution that stands alone takes ``wfold_conv2d``:
+``fold``, the folded convolution, ``unfold``.  A run of layers between
+convolutions that all fold by one p (``layers.run_fold``: ResNet v2's narrow
+stage, BatchNorm, ReLU and the residual add with them) folds its input once,
+calls ``wfold_conv_folded`` (folded in, folded out) and unfolds its result:
+behind a folded convolution XLA keeps the activation in the convolution's
+tiling, and what follows on ``[N, H, W, C]`` pays a relayout for it (BatchNorm
+wrote x and x² out in float32, 64.6 ms of a 483.6 ms ResNet-110 v2 step at
+1024², PERF.md PR 27 and PR 30).
 """
 
 from __future__ import annotations
@@ -70,15 +80,38 @@ def fold_kernel(w: jax.Array, p: int) -> jax.Array:
     return wf.reshape(kh, sel.shape[0], p * cin, p * cout).astype(w.dtype)
 
 
+def fold(x: jax.Array, p: int) -> jax.Array:
+    """``[N, H, W, C]`` → ``[N, H, W/p, p·C]``: the same row-major bytes."""
+    n, h, wid, c = x.shape
+    return x.reshape(n, h, wid // p, p * c)
+
+
+def unfold(xf: jax.Array, p: int) -> jax.Array:
+    """``[N, H, W/p, p·C]`` → ``[N, H, W, C]``."""
+    n, h, wq, pc = xf.shape
+    return xf.reshape(n, h, wq * p, pc // p)
+
+
+def _conv_folded(xf: jax.Array, wf: jax.Array, pad_h) -> jax.Array:
+    reach = wf.shape[1] // 2  # folded columns the kernel reaches to each side
+    return lax.conv_general_dilated(
+        xf, wf, (1, 1), (tuple(pad_h), (reach, reach)),
+        dimension_numbers=_DIMNUMS,
+    )
+
+
+def wfold_conv_folded(xf: jax.Array, w: jax.Array, pad_h, p: int) -> jax.Array:
+    """The folded convolution proper, folded in and folded out, for a run
+    of layers that stays on the folded form (``layers.run_fold``): xf
+    ``[N, H, W/p, p·Cin]``; w the true ``[kh, kw, Cin, Cout]`` →
+    ``[N, H + Σpad_h − kh + 1, W/p, p·Cout]``."""
+    return _conv_folded(xf, fold_kernel(w, p), pad_h)
+
+
 def wfold_conv2d(x: jax.Array, w: jax.Array, pad_h, p: int) -> jax.Array:
     """Stride-1 convolution, SAME on W, ``pad_h`` on H, folded by ``p``
-    (from :func:`wfold_factor`).  x: [N, H, W, Cin]; w: [kh, kw, Cin, Cout]
-    → [N, H + Σpad_h − kh + 1, W, Cout]."""
-    n, h, wid, cin = x.shape
-    wf = fold_kernel(w, p)
-    reach = wf.shape[1] // 2  # folded columns the kernel reaches to each side
-    y = lax.conv_general_dilated(
-        x.reshape(n, h, wid // p, p * cin), wf, (1, 1),
-        (tuple(pad_h), (reach, reach)), dimension_numbers=_DIMNUMS,
-    )
-    return y.reshape(n, y.shape[1], wid, w.shape[3])
+    (from :func:`wfold_factor`), for a convolution that stands alone: the
+    reshapes round the folded convolution.  x: [N, H, W, Cin];
+    w: [kh, kw, Cin, Cout] → [N, H + Σpad_h − kh + 1, W, Cout]."""
+    wf = fold_kernel(w, p)  # before the reshape, as the step was lowered before
+    return unfold(_conv_folded(fold(x, p), wf, pad_h), p)

@@ -270,11 +270,12 @@ def test_every_step_of_a_tiny_model_is_covered_by_named_spans(
         steps[2].kids_ms["step_call"], abs=1e-3)
     (summary,) = [r for r in records if r["kind"] == "spans"]
     assert set(summary) == {"kind", "schema", "t", "setup_ms", "jax",
-                            "built_in_loop", "conv_paths"}
+                            "built_in_loop", "conv_paths", "norm_paths"}
     # every convolution of the tiny model, by the path its dispatch chose
     # (32² is far under the narrow-channel gate: nothing folds or stripes)
     assert summary["conv_paths"] == rec.conv_paths()
     assert set(summary["conv_paths"]) == {"phase", "xla"}
+    assert set(summary["norm_paths"]) == {"plain"}
     assert summary["setup_ms"]["setup/build_train"] > 0
     assert summary["jax"]["jax/trace"]["top"][0]["program"] == "step"
     assert [(b["program"], b["gstep"]) for b in summary["built_in_loop"]

@@ -154,7 +154,11 @@ def test_narrow_resblock_at_1024_folds_lane_dense_for_v5e(
     tiling on a convolution or anywhere else, and temporaries under a bound
     taken from this compile (763,265,536 B on jax 0.9.0; the striped block
     before the fold: 4 loops, 32 such tensors, 1,077,160,960 B) with 20 %
-    room."""
+    room.  The block is one folded run (``layers.run_fold``): BatchNorm takes
+    its sums on ``[N, H, W/8, 8·C]``, so the step writes no float32 tensor
+    the size of an activation in the convolutions' tiling (with each layer
+    folded alone the compiled block had four ``copy f32[1024,8,17,128]``:
+    x and x² of two BatchNorms, re-tiled for the reduction)."""
     from mpi4dl_tpu.layer_ctx import ApplyCtx
     from mpi4dl_tpu.models.resnet import ResBlockV2
 
@@ -180,6 +184,9 @@ def test_narrow_resblock_at_1024_folds_lane_dense_for_v5e(
     narrow = [l for l in text.split("\n") if "T(2,128)" in l]
     assert not narrow, narrow[:2]
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2 * 763_265_536
+    entry = text[text.index("ENTRY "):]
+    f32_activations = re.findall(r"= f32\[1024,8,1[67],\d+\]\S* \S+\(", entry)
+    assert not f32_activations, f32_activations[:4]
 
 
 def test_lfm2_kernels_compile_for_v5e_under_a_highest_default(

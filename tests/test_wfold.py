@@ -241,9 +241,16 @@ def test_conv_paths_of_a_resnet_v2(monkeypatch, rec):
     _trace_model(model)
     want = {"wfold": 4, "hstripe": 1, "phase": 4, "xla": 4}
     assert rec.conv_paths() == want
+    # the stage's one block is a folded run (layers.run_fold): its two
+    # BatchNorms work on [N, H, W/8, 8·C], and so does the one that opens
+    # the next stage, before its strided convolution; the stem's, the six
+    # others of the two other blocks and the head's do not
+    norms = {"folded": 3, "plain": 7}
+    assert rec.site_paths("norm") == norms
     _trace_model(model)
     assert rec.conv_paths() == want
     assert rec.summary()["conv_paths"] == want
+    assert rec.summary()["norm_paths"] == norms
 
 
 def test_conv_paths_of_the_resnet_cell(rec):
@@ -255,6 +262,11 @@ def test_conv_paths_of_the_resnet_cell(rec):
                                num_classes=1000), jnp.bfloat16)
     assert rec.conv_paths() == {"wfold": 37, "hstripe": 1, "phase": 4,
                                 "xla": 70}
+    # stage 0 is twelve folded runs: block 0 has two BatchNorms, blocks 1–11
+    # three each, and the BatchNorm(64) at 1024² that opens stage 1 takes the
+    # stream as they left it; the stem's, the other 71 of stages 1 and 2 and
+    # the head's are plain
+    assert rec.site_paths("norm") == {"folded": 36, "plain": 73}
 
 
 def test_conv_paths_of_resnet_at_2048(rec):
@@ -269,6 +281,9 @@ def test_conv_paths_of_resnet_at_2048(rec):
                                num_classes=1000), jnp.bfloat16)
     assert rec.conv_paths() == {"wfold": 24, "hstripe": 2, "phase": 4,
                                 "xla": 82}
+    # no run is folded: stage 0 is over the fold's 2²² pixels, and a block
+    # of stage 1 opens with a 128-channel convolution that does not fold
+    assert rec.site_paths("norm") == {"plain": 109}
 
 
 def test_conv_paths_of_the_amoebanet_cell(rec):
@@ -280,6 +295,7 @@ def test_conv_paths_of_the_amoebanet_cell(rec):
     _trace_model(amoebanetd((1, 2048, 2048, 3), num_classes=1000,
                             num_layers=18, num_filters=416), jnp.bfloat16)
     assert rec.conv_paths() == {"phase": 13, "xla": 266}
+    assert set(rec.site_paths("norm")) == {"plain"}
 
 
 def test_conv_paths_are_not_counted_with_the_recorder_off(monkeypatch):
