@@ -78,11 +78,6 @@ def _build_step(image_size: int, num_layers: int, num_filters: int,
     step = make_train_step(
         model, opt, compute_dtype=jnp.bfloat16, remat=remat, donate=True,
         scan_steps=scan,
-        # A/B escape hatch (same pattern as MPI4DL_SQRT_GROUPS): route
-        # [ReLU, Conv2d, BatchNorm] windows through the fused Pallas
-        # relu→conv→BN-stats kernel (single-device dispatch, ops/d2.py
-        # maybe_run_fused_unsharded).
-        pallas_conv=os.environ.get("MPI4DL_PALLAS_CONV") == "1",
     )
     state = TrainState.create(params, opt)
     return step, state

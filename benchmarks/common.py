@@ -38,9 +38,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
-from mpi4dl_tpu.config import (
-    ParallelConfig, config_from_args, get_parser, resolve_pallas_conv,
-)
+from mpi4dl_tpu.config import ParallelConfig, config_from_args, get_parser
 from mpi4dl_tpu.utils import StepMeter
 
 
@@ -113,7 +111,6 @@ def _spatial_levels(cfg: ParallelConfig, n_cells: int, shapes=None):
         # --fused-layers caps margin-consuming layers per fused exchange
         # (reference resnet_spatial_d2.py get_balance); <=0 → maximal fusion.
         d2_max_fused=cfg.fused_layers if cfg.fused_layers > 0 else None,
-        use_pallas_conv=resolve_pallas_conv(cfg.pallas_conv),
     )
     levels = []
     for i in range(k):

@@ -5,8 +5,8 @@ One process, no child, no CPU path.  With no arguments it needs one TPU chip:
 1. the trainer at the full width of AmoebaNet-D(18, 416) at 1024² bs 1 bf16,
    through the normal entry point ``benchmarks.common.run`` (1 compiling
    step + 3 more);
-2. each Pallas kernel once, compiled (never interpreted), at a real width,
-   against its plain reference.
+2. the Pallas attention kernel, compiled (never interpreted), at a real
+   width, against its plain reference.
 
 ``--four-chips`` needs four chips and runs only what exists across chips,
 each leg beside the one-device run it is compared with: leg A, the 2x2
@@ -57,10 +57,9 @@ LOSS_RTOL_LATER_STEPS = 5e-2  # after up to three updates
 # Kernel vs plain reference at highest matmul precision, relative L2.
 KERNEL_RTOL = 2e-2
 
-# The kernels' shapes: the D2-step tile (benchmark_d2_step.py --tile 512
-# --channels 208) and a 4096-token, 128-wide head.  tests/test_tpu_compile.py
+# The kernel's shapes: a 4096-token, 128-wide head.  tests/test_tpu_compile.py
 # compiles the same shapes for a described v5e.
-KERNEL_SHAPES = dict(tile=512, channels=208, seq=4096, head_dim=128, heads=8)
+KERNEL_SHAPES = dict(seq=4096, head_dim=128, heads=8)
 
 _STEP_LINE = re.compile(
     r"^epoch \d+ step \d+ time_ms ([0-9.]+) images_per_sec \S+ loss (\S+) acc"
@@ -284,41 +283,16 @@ def flash_attention(q, k, v):
     return o / l[..., None]
 
 
-def kernels(*, tile: int, channels: int, seq: int, head_dim: int,
-            heads: int) -> None:
-    """Each Pallas kernel once through Mosaic (``interpret=False`` — this
-    phase has no CPU form) at the D2-step shapes, against its plain XLA
+def kernels(*, seq: int, head_dim: int, heads: int) -> None:
+    """The Pallas attention kernel through Mosaic (``interpret=False`` — this
+    phase has no CPU form), forward and backward, against its plain XLA
     reference at highest matmul precision."""
     import jax
     import jax.numpy as jnp
 
     from mpi4dl_tpu.ops.pallas_attention import _reference_mlo
-    from mpi4dl_tpu.ops.pallas_conv import _lax_valid_conv, halo_conv2d
 
-    kx, kw_, kq, kk, kv, kc = jax.random.split(jax.random.key(0), 6)
-    x = jax.random.normal(kx, (1, tile + 2, tile + 2, channels), jnp.bfloat16)
-    w = (jax.random.normal(kw_, (3, 3, channels, channels), jnp.float32)
-         / math.sqrt(9 * channels)).astype(jnp.bfloat16)
-
-    @jax.jit
-    def conv_ref(x, w):
-        with jax.default_matmul_precision("highest"):
-            return _lax_valid_conv(x.astype(jnp.float32), w.astype(jnp.float32))
-
-    t0 = time.perf_counter()
-    y = jax.block_until_ready(halo_conv2d(x, w, interpret=False))
-    print(f"[smoke] halo_conv2d compiled+ran in {time.perf_counter() - t0:.1f}s")
-    _kernel_check("halo_conv2d", y, conv_ref(x, w))
-
-    win = (0, tile, 0, tile)
-    y, s, ss = jax.block_until_ready(halo_conv2d(
-        x, w, interpret=False, fuse_relu=True, stat_window=win))
-    # Statistics are of the CAST output, as the unfused BatchNorm reads it.
-    y_ref = conv_ref(jax.nn.relu(x), w)
-    y_cast = y_ref.astype(jnp.bfloat16).astype(jnp.float32)
-    _kernel_check("fused_relu_conv_bn", (y, s, ss),
-                  (y_ref, y_cast.sum((0, 1, 2)), (y_cast ** 2).sum((0, 1, 2))))
-
+    kq, kk, kv, kc = jax.random.split(jax.random.key(0), 4)
     q = jax.random.normal(kq, (heads, seq, head_dim), jnp.bfloat16)
     k = jax.random.normal(kk, (heads, seq, head_dim), jnp.bfloat16)
     v = jax.random.normal(kv, (heads, seq, head_dim), jnp.bfloat16)
@@ -380,7 +354,7 @@ def main(argv=None) -> int:
              steps=PIPELINE_STEPS, **PIPELINE_MODEL))]
         if args.four_chips else
         [("one-chip trainer", lambda: one_chip_trainer(**FULL_MODEL)),
-         ("pallas kernels", lambda: kernels(**KERNEL_SHAPES))]
+         ("pallas kernel", lambda: kernels(**KERNEL_SHAPES))]
     )
     failed = []
     for name, phase in phases:

@@ -49,7 +49,7 @@ compiled scheduled HLO level (``check_hlo``):
   the in-flight async value or writes in place into the DMA source buffer;
 - ``pallas-alias`` — a custom call whose ``output_to_operand_aliasing``
   is out of range, doubly aliased, or shape-mismatched (the argument-alias
-  contract ``pallas_conv.py``/``pallas_attention.py`` kernels must honor).
+  contract the ``pallas_attention.py`` kernels must honor).
 
 Entry points: :func:`check_jaxpr`, :func:`check_hlo`,
 :func:`check_family` (builds a contract engine family and runs both), and

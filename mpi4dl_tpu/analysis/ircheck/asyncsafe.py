@@ -22,8 +22,8 @@ The schedule is execution order, so the async contract is structural:
   promises must be well-formed: operand index in range, no operand buffer
   promised to two outputs, aliased operand shape equal to the output
   (sub)shape.  This is the argument-alias contract a Pallas kernel asserts
-  with ``input_output_aliasing`` (``pallas_conv.py``/``pallas_attention.py``)
-  — asserted manually, so nothing else checks it before silicon.
+  with ``input_output_aliasing`` (``pallas_attention.py``) — asserted
+  manually, so nothing else checks it before silicon.
 """
 
 from __future__ import annotations

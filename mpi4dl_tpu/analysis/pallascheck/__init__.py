@@ -7,8 +7,9 @@ kernel registered in :mod:`mpi4dl_tpu.ops.kernel_registry` on a CPU host
 abstract-interprets the kernel jaxpr per grid point in the TPU's sequential
 row-major order (last grid dimension innermost, scratch persisting across
 steps).  It is the safety rail ROADMAP item 2's halo-RDMA conv is built
-against: the invariants that were comments — the ``ops/pallas_conv.py``
-WAR-hazard note, the hand-maintained VMEM caps — are now checked, and an
+against: the invariants that were comments — a DMA write into a scratch
+buffer that is still being read (a WAR hazard Mosaic does not fence), the
+hand-maintained VMEM caps — are now checked, and an
 inter-chip ``make_async_remote_copy`` kernel will be enrolled into the same
 gate by one registry row.
 
@@ -41,8 +42,9 @@ DMA/semaphore discipline (interp.py):
   some ``pl.when``/branch path (or still in flight at kernel end), a wait
   with no start, or a second start racing an in-flight copy;
 - ``dma-race`` — a read of a DMA destination before its wait, or a write
-  to a DMA source/destination while the copy is in flight (the
-  ``pallas_conv.py`` WAR hazard, now an invariant);
+  to a DMA source/destination while the copy is in flight (Mosaic does
+  not fence a DMA write against in-flight reads of the same buffer: a
+  scratch slot is written by one DMA a visit and waited before its read);
 - ``nonbijective-device-map`` — a remote copy whose resolved ``device_id``
   map repeats a target (or leaves the declared ring) across the grid, or
   any remote copy in a kernel whose registry case declares no topology;
@@ -77,7 +79,7 @@ FINDING_KINDS = (
     "uninit-accumulator",
 )
 
-#: per-core VMEM pool certified against (matches ops/pallas_conv._VMEM_BYTES)
+#: per-core VMEM pool certified against: a TPU v5e core's 16 MiB
 VMEM_BYTES = 16 * 1024 * 1024
 
 

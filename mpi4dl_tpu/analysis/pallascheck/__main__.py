@@ -34,7 +34,7 @@ def main(argv=None) -> int:
     )
     ap.add_argument("--kernels", metavar="NAMES", default=None,
                     help="comma-separated subset of registry cases; a bare "
-                         "kernel name (e.g. halo_conv2d) selects every "
+                         "kernel name (e.g. block_flash) selects every "
                          "variant of it "
                          f"(default: {','.join(c.name for c in REGISTRY)})")
     ap.add_argument("--json", action="store_true",

@@ -18,7 +18,7 @@ Tracked state:
   persists) and per-output-visit-run written sets (grid.output_runs);
 - in-flight DMAs keyed by semaphore ref, carrying src/dst refs: a read of
   a dst before its wait or a write to a src/dst while in flight is a
-  ``dma-race`` (the ops/pallas_conv.py:48 WAR hazard as an invariant);
+  ``dma-race`` (the WAR hazard Mosaic does not fence, as an invariant);
 - resolved ``device_id`` values of remote copies, checked bijective
   against the registry case's declared ring topology.
 """
@@ -210,7 +210,7 @@ def _check_write(ctx: _Ctx, state: _State, pos: int) -> None:
                 "dma-race",
                 f"{ctx.name(pos)} is written while it is the SOURCE of an "
                 f"in-flight DMA (semaphore {ctx.name(sem)}) — the "
-                "write-after-read hazard ops/pallas_conv.py documents; "
+                "write-after-read hazard, which Mosaic does not fence; "
                 "wait before reusing the buffer",
             )
         if dma.dst == pos:

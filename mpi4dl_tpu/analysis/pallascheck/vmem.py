@@ -4,10 +4,10 @@ Re-derives the per-grid-point VMEM total from the traced specs — VMEM
 scratch allocations at full size plus every blocked VMEM operand DOUBLE
 (the Pallas pipeline keeps two buffers per blocked operand so the next
 block's DMA overlaps compute) — and certifies it against
-``--require-vmem-frac`` x the 16 MiB per-core pool.  This is the
-derived-not-declared counterpart of ``ops/pallas_conv._vmem_total_bytes``:
-the kernel's own budget model is an a-priori formula, this one is read back
-from what was actually traced, so the two cannot drift apart silently.
+``--require-vmem-frac`` x the 16 MiB per-core pool.  The total is derived,
+not declared: a kernel's own budget model is an a-priori formula, this one
+is read back from what was actually traced, so the two cannot drift apart
+silently.
 """
 
 from __future__ import annotations

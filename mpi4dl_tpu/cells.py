@@ -58,7 +58,7 @@ class LayerCell(Cell):
         return params, shape
 
     def apply(self, params, x, ctx):
-        from mpi4dl_tpu.ops.d2 import maybe_run_d2, maybe_run_fused_unsharded
+        from mpi4dl_tpu.ops.d2 import maybe_run_d2
         from mpi4dl_tpu.ops.stripe_bwd import maybe_stripe_run
 
         y = maybe_run_d2(self.layers, params, x, ctx)
@@ -68,9 +68,6 @@ class LayerCell(Cell):
         # forward and backward — one H-stripe at a time under pad-once
         # margins (ops/stripe_bwd.py; the flagship's O(parts) buy-back).
         y = maybe_stripe_run(self.layers, params, x, ctx)
-        if y is not None:
-            return y
-        y = maybe_run_fused_unsharded(self.layers, params, x, ctx)
         if y is not None:
             return y
         for p, layer in zip(params, self.layers):

@@ -137,7 +137,7 @@ LAYOUT_FIELDS = frozenset({
     "parts", "split_size", "schedule", "num_spatial_parts", "spatial_size",
     "slice_method", "spatial_until", "quant_collectives", "stripe_bwd",
     "halo_d2", "fused_layers", "local_dp_lp", "balance",
-    "times", "remat", "pallas_conv", "enable_gems", "enable_master_comm_opt",
+    "times", "remat", "enable_gems", "enable_master_comm_opt",
 })
 
 

@@ -85,10 +85,6 @@ HATCHES: Dict[str, Hatch] = {
         Hatch("MPI4DL_LANE_PAD", "0",
               "1 = pad AmoebaNet bottleneck mid-channels to 128 lanes "
               "(vector-lane utilization A/B)."),
-        Hatch("MPI4DL_PALLAS_CONV", "0",
-              "1 = route eligible spatial convs through the Pallas "
-              "implicit-GEMM kernel in bench.py A/Bs (off: XLA wins at the "
-              "step level — PERF_NOTES r4)."),
         Hatch("MPI4DL_NO_SCOPES", "0",
               "1 = disable obs trace scopes (jax.named_scope semantic names "
               "in traces/HLO), host step annotations and the span recorder "
@@ -266,12 +262,6 @@ class ParallelConfig:
     momentum: float = 0.0
     optimizer: str = "sgd"
     remat: bool = True  # jax.checkpoint each stage application
-    # Route eligible SP convs through the Pallas kernel.  None = auto = OFF:
-    # the op-level wins (1.2-2.3x at D2 shapes on v5e) did NOT survive the
-    # step-level A/B — XLA's conv+BN+ReLU fusion beats the kernel in whole
-    # programs (PERF_NOTES r4, benchmark_d2_step.py).  --pallas-conv is the
-    # explicit opt-in; resolved by resolve_pallas_conv().
-    pallas_conv: Optional[bool] = None
     # Quantized-collective policy spec ("off" | "int8" | "fp8" | "int4" |
     # per-class "junction=int4,grad=int8[,block=N]"); resolved by
     # mpi4dl_tpu.quant.QuantPolicy.resolve (the MPI4DL_QUANT_COLLECTIVES
@@ -361,29 +351,11 @@ class ParallelConfig:
 
 
 def is_tpu_backend() -> bool:
-    """True on the TPU backend — the shared auto-enable predicate for
-    Pallas (Mosaic) kernels: the conv dispatch here and ring attention's
-    flash path (ops/ring.py)."""
+    """True on the TPU backend — the auto-enable predicate for the Pallas
+    (Mosaic) kernel of ring attention's flash path (ops/ring.py)."""
     import jax
 
     return jax.default_backend() == "tpu"
-
-
-def resolve_pallas_conv(setting: Optional[bool]) -> bool:
-    """Resolve the tri-state ``pallas_conv`` config: ``None`` = auto = OFF.
-
-    The kernel wins 1.1-2.3x at the OP level at D2 shapes, but the r4
-    STEP-level A/B (benchmark_d2_step.py: full relu-conv-bn fused runs,
-    forward+backward+update, real chip) measured 0.62-1.06x — XLA's
-    conv+BN+ReLU fusion and layout propagation across the whole program
-    beat the kernel's op-level margin at every representative shape except
-    a statistical tie (PERF_NOTES r4; exactly the failure mode the r3
-    single-device SAME-conv measurement warned about).  ``--pallas-conv``
-    remains the explicit opt-in; CPU keeps XLA conv (interpret mode is for
-    tests)."""
-    if setting is not None:
-        return setting
-    return False
 
 
 def get_parser() -> argparse.ArgumentParser:
@@ -439,14 +411,6 @@ def get_parser() -> argparse.ArgumentParser:
     p.add_argument("--enable-gems", action="store_true")
     p.add_argument("--lr", type=float, default=0.001)
     p.add_argument("--no-remat", action="store_true")
-    p.add_argument("--pallas-conv", action="store_const", const=True,
-                   dest="pallas_conv", default=None,
-                   help="force the Pallas margin-consuming conv kernel for "
-                        "eligible spatial convs (default: auto — on for TPU "
-                        "backends; see PERF_NOTES.md)")
-    p.add_argument("--no-pallas-conv", action="store_const", const=False,
-                   dest="pallas_conv",
-                   help="keep all convs on XLA even on TPU")
     p.add_argument("--quant", dest="quant_collectives", type=str,
                    default="off", metavar="SPEC",
                    help="quantized-collective policy: off (default, "
@@ -519,7 +483,6 @@ def config_from_args(args: argparse.Namespace) -> ParallelConfig:
         enable_gems=args.enable_gems,
         lr=args.lr,
         remat=not args.no_remat,
-        pallas_conv=args.pallas_conv,
         quant_collectives=getattr(args, "quant_collectives", "off"),
         stripe_bwd=getattr(args, "stripe_bwd", False),
         spatial_until=_spatial_until_arg(getattr(args, "spatial_until", None)),

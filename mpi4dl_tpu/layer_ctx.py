@@ -71,11 +71,6 @@ class SpatialCtx:
     # --fused-layers knob (resnet_spatial_d2.py get_balance); None = fuse
     # maximal runs (better: fewer exchanges).
     d2_max_fused: Optional[int] = None
-    # Route eligible margin-consuming convs (stride 1, no feature groups)
-    # through the Pallas implicit-GEMM kernel (ops/pallas_conv.py) instead of
-    # lax.conv.  Off by default — adoption is gated on the hardware
-    # measurement (PERF_NOTES.md); everything else falls back to XLA.
-    use_pallas_conv: bool = False
     # The axes of this ctx are a SINGLE-DEVICE fiction (the H-striped
     # layer-run executor, ops/hstripe_conv.hstripe_layer_run): no mesh axis
     # exists, so BN statistic deposits must stay local — no pmean over the

@@ -179,23 +179,6 @@ class Conv2d(Layer):
         return params, (n, oh, ow, self.lane_pad_out or self.out_channels)
 
     @staticmethod
-    def _pallas_dispatchable(sp, kh, kw, sh, sw, groups, kernel) -> bool:
-        """Route this conv through the Pallas margin-consuming kernel?
-        Stride 1, no groups, not 1x1 (a pure matmul XLA already handles),
-        and the kernel's VMEM scratch within its caps in both directions —
-        the weight slab AND the th=1 input window (pallas_conv_eligible)."""
-        if not (sp is not None and sp.use_pallas_conv):
-            return False
-        if (sh, sw) != (1, 1) or (kh, kw) == (1, 1) or groups != 1:
-            return False
-        from mpi4dl_tpu.ops.pallas_conv import pallas_conv_eligible
-
-        return pallas_conv_eligible(
-            kernel.shape[2], kernel.shape[3], kernel.shape[0],
-            kernel.shape[1], itemsize=kernel.dtype.itemsize,
-        )
-
-    @staticmethod
     def _hstripe_shape(kh, kw, sh, sw, groups, shape) -> bool:
         """The shape gate for XLA-hostile convs: stride-1 convs on
         NARROW-channel HUGE-spatial inputs, where XLA's TPU lowering puts
@@ -207,9 +190,7 @@ class Conv2d(Layer):
         the image is under _WFOLD_MAX_PIXELS; what is left for the H
         stripes (ops/hstripe_conv.py) is a tile whose W margin came from a
         halo exchange (VALID on W), an indivisible W, the stem's Cin 3 and
-        single convs at 2048² and up.  (The Pallas kernel cannot take these
-        shapes: Mosaic refuses sub-128 lane DMA extents and a 128-lane
-        channel pad multiplies the input 8–42x in HBM — measured OOM.)
+        single convs at 2048² and up.
         MPI4DL_NO_HSTRIPE=1 opts out of both: the plain XLA conv."""
         # 1x1 convs are pure matmuls, but at huge spatial XLA still splits
         # them with ~2x-padded GB-scale temps — striping bounds those too.
@@ -251,14 +232,6 @@ class Conv2d(Layer):
         exchanged = self._sharded(ctx.spatial)[1] and pw
         return self._wfold(shape, (0, 0) if exchanged else (pw, pw))
 
-    @staticmethod
-    def _pallas_apply(x, kernel, pads):
-        from mpi4dl_tpu.ops.pallas_conv import halo_conv2d_t
-
-        if any(p != (0, 0) for p in pads):
-            x = jnp.pad(x, pads)
-        return halo_conv2d_t(x, kernel)
-
     def apply(self, params, x, ctx: ApplyCtx):
         kh, kw, sh, sw, ph, pw = self._geometry()
         kernel = params["kernel"].astype(x.dtype)
@@ -289,27 +262,8 @@ class Conv2d(Layer):
                 (0, 0) if halo_h.lo else (ph, ph),
                 (0, 0) if halo_w.lo else (pw, pw),
             )
-            # Sharded runs MAY use the Pallas margin-consuming kernel — but
-            # only on explicit opt-in (sp.use_pallas_conv, checked by the
-            # dispatch gate): the r4 step-level A/B measured XLA's fused
-            # VALID conv equal-or-faster at every D2-representative shape
-            # despite the kernel's op-level wins (PERF_NOTES r4).
-            use_pallas = True
         else:
             padding = ((ph, ph), (pw, pw))
-            # Unsharded dispatch only for an AXIS-FREE knob carrier (the
-            # explicit make_train_step(pallas_conv=True) route) — NOT for
-            # degenerate multi-level SP levels (grid 1, rep>1: inactive but
-            # axis-bearing), whose full-image SAME convs measured 35% slower
-            # on this path (PERF_NOTES.md).
-            use_pallas = (
-                sp is not None and sp.axis_h is None and sp.axis_w is None
-            )
-        # The narrow-channel huge-spatial gate is checked BEFORE the Pallas
-        # opt-in: ResNet's C<=64 convs at 1024²-class are the regime where
-        # the kernel's 128-lane channel pad multiplies the input 8-42x in
-        # HBM (measured OOM) — a pallas_conv=True A/B run must not route
-        # them away from the paths built for them.
         groups = self.feature_group_count
         if ctx.fold:
             # Inside a folded run, whose gate (run_fold) held this
@@ -333,16 +287,6 @@ class Conv2d(Layer):
 
                 path = "hstripe"
                 y = hstripe_conv2d(x, kernel, padding[0], padding[1])
-        elif use_pallas and self._pallas_dispatchable(
-            sp, kh, kw, sh, sw, groups, kernel
-        ):
-            # The kernel wants the margin present on BOTH dims — pad any dim
-            # whose margin wasn't realized by halo exchange (all of them in
-            # the unsharded case: SAME = pad + margin-consuming VALID).
-            path = "pallas"
-            y = self._pallas_apply(
-                x, kernel, [(0, 0), padding[0], padding[1], (0, 0)]
-            )
         elif (sh, sw) != (1, 1) and groups == 1 and _phase_dx_enabled():
             # Strided convs take the phase-decomposed-backward form: same
             # forward conv, but dx avoids XLA's lhs-dilation machinery
@@ -508,13 +452,12 @@ class BatchNorm(Layer):
 
     def normalize_with_stats(self, params, x, mean, var, cnt, ctx: ApplyCtx):
         """Train-mode normalization with externally computed batch
-        statistics — the fused Pallas relu-conv-bn epilogue path
-        (ops/pallas_conv.fused_relu_conv_bn_t computes (sum, sumsq) in the
-        conv kernel; the caller turns them into mean/var, cross-tile
-        psum'd when required).  Running-stat deposit and the folded
-        compute-dtype fma are identical to apply()'s train path.
-        ``lane_pad`` is unsupported here (the fused dispatch gates it)."""
-        assert not self.lane_pad, "fused-stats path does not support lane_pad"
+        statistics: the exact-stats striped run (ops/hstripe_conv.py
+        ``_FixedStatsBN``) hands every stripe the same global (mean, var).
+        Running-stat deposit and the folded compute-dtype fma are identical
+        to apply()'s train path.  ``lane_pad`` is unsupported here (the
+        caller gates it)."""
+        assert not self.lane_pad, "fixed-stats path does not support lane_pad"
         if ctx.bn_sink is not None:
             self._deposit_running(params, mean, var, cnt, ctx)
         inv = lax.rsqrt(var + self.eps) * params["scale"]

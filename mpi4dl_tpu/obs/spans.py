@@ -74,9 +74,9 @@ JAX_EVENTS = {
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 # How ``layers.Conv2d.apply`` handed a convolution to XLA: W-folded
-# (ops/wfold_conv.py), H-striped (ops/hstripe_conv.py), the Pallas kernel, the
-# phase-decomposed strided form (ops/conv_phase.py), or as it stands.
-CONV_PATHS = ("wfold", "hstripe", "pallas", "phase", "xla")
+# (ops/wfold_conv.py), H-striped (ops/hstripe_conv.py), the phase-decomposed
+# strided form (ops/conv_phase.py), or as it stands.
+CONV_PATHS = ("wfold", "hstripe", "phase", "xla")
 # Which kernel a site of another kind was traced with: attention
 # (models/lfm2.Attention: the Pallas block kernel of ops/pallas_attention.py
 # or the einsum form) and the routed experts' grouped product (ops/moe.py:

@@ -123,7 +123,7 @@ def _bwd(strides, padding, res, ct):
         x = xr
     dx = _phase_dx(ct, w, strides, padding, x.shape, x.dtype)
     # dw: XLA's backprop-filter (linear_transpose avoids a throwaway primal
-    # forward on eager backward calls — same pattern as ops/pallas_conv).
+    # forward on eager backward calls).
     w_t_fn = jax.linear_transpose(
         lambda w_: lax.conv_general_dilated(
             x, w_, strides, padding, dimension_numbers=_DIMNUMS

@@ -91,115 +91,10 @@ def can_fuse(layers: Sequence, sp) -> bool:
     return (sharded_h and hh > 0) or (sharded_w and hw > 0)
 
 
-def _fusable_triple(layers, i, x_dtype, train: bool,
-                    x_shape=None) -> bool:
-    """[ReLU, Conv2d, BatchNorm] starting at i, eligible for the fused
-    Pallas relu→conv→BN-stats kernel: stride-1 non-1x1 ungrouped unbiased
-    conv, no lane padding, train mode (eval normalizes with running stats —
-    no stats to fuse), VMEM caps OK in both conv directions.  Tiny-channel
-    huge-spatial inputs are excluded (``x_shape`` given): the kernel's
-    128-lane pad multiplies such inputs 8-42x in HBM — that regime belongs
-    to ops/hstripe_conv.py (see Conv2d.apply's dispatch order)."""
-    if i + 2 >= len(layers) or not train:
-        return False
-    if (x_shape is not None and len(x_shape) == 4
-            and x_shape[-1] <= 64 and x_shape[1] * x_shape[2] >= (1 << 20)):
-        return False
-    r, cv, bn = layers[i], layers[i + 1], layers[i + 2]
-    if not (type(r) is ReLU and type(cv) is Conv2d and type(bn) is BatchNorm):
-        return False
-    kh, kw, sh, sw, _, _ = cv._geometry()
-    if (sh, sw) != (1, 1) or (kh, kw) == (1, 1) or cv.feature_group_count != 1:
-        return False
-    if cv.bias or cv.lane_pad_in or cv.lane_pad_out or bn.lane_pad:
-        return False
-    if bn.num_features != cv.out_channels:
-        return False
-    from mpi4dl_tpu.ops.pallas_conv import pallas_conv_eligible
-
-    return pallas_conv_eligible(
-        cv.in_channels, cv.out_channels, kh, kw, itemsize=x_dtype.itemsize
-    )
-
-
-def _apply_fused_triple(cv: Conv2d, bn: BatchNorm, p_conv, p_bn, x, ctx,
-                        sub, mh, mw, sharded_h, sharded_w):
-    """One fused relu→conv→bn through the Pallas epilogue kernel.  Margins:
-    relu consumes none; the conv consumes (ph, pw) on sharded dims (padding
-    the unsharded dims explicitly — SAME semantics there); BN consumes none
-    and its statistics exclude the remaining margin, exactly as the unfused
-    BatchNorm.apply slices stat_x."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    from mpi4dl_tpu.ops.pallas_conv import fused_relu_conv_bn_t
-
-    kh, kw, _, _, ph, pw = cv._geometry()
-    w = p_conv["kernel"].astype(x.dtype)
-    pad_h = (0, 0) if sharded_h else (ph, ph)
-    pad_w = (0, 0) if sharded_w else (pw, pw)
-    if pad_h != (0, 0) or pad_w != (0, 0):
-        x = jnp.pad(x, ((0, 0), pad_h, pad_w, (0, 0)))
-    h_out = x.shape[1] - (kh - 1)
-    w_out = x.shape[2] - (kw - 1)
-    mh2 = (mh - ph) if sharded_h else mh
-    mw2 = (mw - pw) if sharded_w else mw
-    win = (mh2, h_out - mh2, mw2, w_out - mw2)
-    y, s, ss = fused_relu_conv_bn_t(x, w, win)
-    cnt = jnp.asarray(
-        y.shape[0] * (win[1] - win[0]) * (win[3] - win[2]), jnp.float32
-    )
-    if sub.active and sub.bn_cross_tile:
-        ax_names = tuple(a for a in (sub.axis_h, sub.axis_w) if a)
-        with scope("bn_cross_tile"):
-            # Count is a trace-time constant: static multiply, not a wire
-            # psum (psum(1, axes) folds to the axis-size product).
-            cnt = cnt * lax.psum(1, ax_names)
-            s = lax.psum(s, ax_names)
-            ss = lax.psum(ss, ax_names)
-    mean = s / cnt
-    var = jnp.maximum(ss / cnt - mean * mean, 0.0)
-    y = bn.normalize_with_stats(
-        p_bn, y, mean, var, cnt, ctx.with_spatial(sub)
-    )
-    return y, mh2, mw2
-
-
-def maybe_run_fused_unsharded(layers: Sequence, params_seq, x,
-                              ctx: ApplyCtx):
-    """Single-device fused relu→conv→bn dispatch for a plain layer cell.
-
-    The unsharded case is the degenerate premargin run (no margins, SAME =
-    explicit pad + margin-consuming VALID), so [ReLU, Conv2d, BatchNorm]
-    windows can take the same fused Pallas kernel the D2 path uses —
-    gated on the axis-free ``use_pallas_conv`` knob carrier
-    (make_train_step(pallas_conv=True)); returns None (zero graph change)
-    unless at least one fusable window exists and every layer in the cell
-    is premargin-capable."""
-    sp = ctx.spatial
-    if (sp is None or not sp.use_pallas_conv or sp.active
-            or sp.axis_h is not None or sp.axis_w is not None):
-        return None
-    if any(layer_d2_geometry(l) is None for l in layers):
-        return None
-    if not any(
-        _fusable_triple(layers, i, x.dtype, ctx.train, x.shape)
-        for i in range(len(layers))
-    ):
-        return None
-    y, _, _ = apply_layers_premargin(layers, params_seq, x, ctx, 0, 0)
-    return y
-
-
 def apply_layers_premargin(layers: Sequence, params_seq, x, ctx: ApplyCtx,
                            mh: int, mw: int):
     """Apply `layers` to an activation already carrying margin (mh, mw) on the
     sharded dims, consuming it layer by layer.  Returns (y, mh_out, mw_out).
-
-    When ``sp.use_pallas_conv`` is on, [ReLU, Conv2d, BatchNorm] windows
-    take the fused Pallas relu→conv→BN-stats kernel (one VMEM pass for the
-    pre-activation, statistics off the accumulator cast) — the step-level
-    contender against XLA's conv+BN+ReLU fusion (VERDICT r4 task 5).
 
     Trace-time checks (ADVICE r1): each stride must divide both the remaining
     margin and the true local extent, otherwise tiles would silently de-phase
@@ -207,26 +102,7 @@ def apply_layers_premargin(layers: Sequence, params_seq, x, ctx: ApplyCtx,
     sp = ctx.spatial
     sharded_h = bool(sp.axis_h) and sp.grid_h > 1
     sharded_w = bool(sp.axis_w) and sp.grid_w > 1
-    idx = 0
-    while idx < len(layers):
-        if sp.use_pallas_conv and _fusable_triple(layers, idx, x.dtype,
-                                                  ctx.train, x.shape):
-            cv, bn = layers[idx + 1], layers[idx + 2]
-            ph, pw, *_ = layer_d2_geometry(cv)
-            # Stride is 1 by the gate, so the misalignment checks below are
-            # trivially satisfied for this window.
-            sub = dataclasses.replace(
-                sp, halo_pre_exchanged=True,
-                pre_margin_h=(mh - ph) if sharded_h else mh,
-                pre_margin_w=(mw - pw) if sharded_w else mw,
-            )
-            x, mh, mw = _apply_fused_triple(
-                cv, bn, params_seq[idx + 1], params_seq[idx + 2], x, ctx,
-                sub, mh, mw, sharded_h, sharded_w,
-            )
-            idx += 3
-            continue
-        layer, p = layers[idx], params_seq[idx]
+    for layer, p in zip(layers, params_seq):
         ph, pw, sh, sw, *_ = layer_d2_geometry(layer)
         sub = dataclasses.replace(
             sp, halo_pre_exchanged=True, pre_margin_h=mh, pre_margin_w=mw
@@ -250,7 +126,6 @@ def apply_layers_premargin(layers: Sequence, params_seq, x, ctx: ApplyCtx,
             mh = (mh - ph) // sh
         if sharded_w:
             mw = (mw - pw) // sw
-        idx += 1
     return x, mh, mw
 
 

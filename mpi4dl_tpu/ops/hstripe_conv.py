@@ -6,9 +6,8 @@ materializes an im2col-style patch tensor of ~kh·kw·H·W·C elements
 ResNet-110-v2 2048² bs1 did not fit a 16 GB chip, PERF_NOTES r3; the
 reference sidesteps it only because cuDNN has native strided kernels and
 its SP mode splits H/W across 5 GPUs, `/root/reference/src/torchgems/
-spatial.py`).  The Pallas margin-consuming kernel cannot take these shapes
-either: Mosaic refuses sub-128 lane DMA extents, and padding C=3..16 up to
-128 lanes multiplies the whole input in HBM (8–42x, measured OOM).
+spatial.py`).  Padding C=3..16 up to 128 lanes is no way out: it multiplies
+the whole input in HBM (8–42x, measured OOM).
 
 Since PR 27 ``layers.Conv2d.apply`` hands these convs to the W-fold first
 (ops/wfold_conv.py: the channels made lane-dense by a reshape, no loop;

@@ -31,7 +31,6 @@ from typing import Callable, Optional, Sequence, Tuple
 # verified must be imported here (statically parsed, never executed by the
 # analyzer).
 from mpi4dl_tpu.ops.pallas_attention import block_flash
-from mpi4dl_tpu.ops.pallas_conv import halo_conv2d
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,28 +48,6 @@ class KernelCase:
     name: str
     build: Callable[[], Tuple[Callable, tuple]]
     ring_size: Optional[int] = None
-
-
-def _conv_case(dtype: str, fused: bool):
-    def build():
-        import jax.numpy as jnp
-
-        dt = jnp.dtype(dtype)
-        # Grid (th-tiles, 2, 3): every grid dim has an edge and the Cout
-        # dim an interior point; cout=300 exercises the lane-pad tail.
-        x = jnp.zeros((1, 130, 258, 8), dt)
-        w = jnp.zeros((3, 3, 8, 300), dt)
-        if fused:
-            # Margin-excluding stat window, as the D2 dispatch passes it.
-            fn = lambda x, w: halo_conv2d(  # noqa: E731
-                x, w, fuse_relu=True, stat_window=(1, 127, 2, 254)
-            )
-        else:
-            fn = halo_conv2d
-        return fn, (x, w)
-
-    variant = "fused_stats:" if fused else ""
-    return KernelCase(name=f"halo_conv2d:{variant}{dtype}", build=build)
 
 
 def _flash_case(dtype: str, causal: bool):
@@ -97,10 +74,6 @@ def _flash_case(dtype: str, causal: bool):
 # engines dispatch (quant/kernels.py itself is pure jnp — no pallas_call,
 # which rule 12 verifies stays true).
 REGISTRY: Tuple[KernelCase, ...] = (
-    _conv_case("float32", fused=False),
-    _conv_case("bfloat16", fused=False),
-    _conv_case("float32", fused=True),
-    _conv_case("bfloat16", fused=True),
     _flash_case("float32", causal=False),
     _flash_case("bfloat16", causal=True),
 )

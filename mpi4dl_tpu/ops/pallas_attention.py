@@ -61,7 +61,7 @@ def _round_up(n: int, m: int) -> int:
 def _out_structs(operands, shapes_dtypes):
     """ShapeDtypeStructs carrying the operands' union vma — under shard_map
     with vma checking, pallas_call must declare how outputs vary across mesh
-    axes (same pattern as ops/pallas_conv.py)."""
+    axes."""
     try:
         vma = frozenset()
         for op in operands:

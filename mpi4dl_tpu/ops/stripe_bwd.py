@@ -320,7 +320,6 @@ def stripe_layer_run(layers, params_seq, x, ctx, acc=None, plan=None):
         bn_cross_tile=False,
         stat_local=True,
         d2_mode=False,
-        use_pallas_conv=False,
     )
     # data_axis/bn_stat_axes feed ONLY the running-stat deposit pmean
     # (BatchNorm._deposit_running; normalization statistics never read

@@ -208,7 +208,6 @@ def make_train_step(
     remat: bool = False,
     bn_stats: bool = True,
     donate: bool = False,
-    pallas_conv: bool = False,
     scan_steps: int = 1,
 ):
     """Single-device or DP (batch sharded over 'data') training step.
@@ -236,15 +235,6 @@ def make_train_step(
     averaged over microbatches, which the momentum rule makes equivalent to
     averaging the per-microbatch updated values).
     """
-    if pallas_conv and mesh is not None:
-        raise ValueError(
-            "pallas_conv=True is a single-device dispatch (pallas_call has "
-            "no GSPMD partitioning rule under a pjit mesh); for sharded "
-            "runs set use_pallas_conv on the SpatialCtx inside shard_map"
-        )
-    sp_knobs = (
-        SpatialCtx(use_pallas_conv=True) if pallas_conv else None
-    )
     import os as _os
 
     # MPI4DL_REMAT_OPS=1 combines per-op checkpoints with ANY outer remat
@@ -254,7 +244,6 @@ def make_train_step(
         train=True,
         remat_ops=(remat == "fine"
                    or _os.environ.get("MPI4DL_REMAT_OPS") == "1"),
-        spatial=sp_knobs,
     )
     model_remat = "sqrt" if remat == "sqrt" else bool(remat)
     loss_fn = make_loss_fn(

@@ -41,3 +41,14 @@ def devices8():
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def rec():
+    """A span recorder of the test's own, so that ``conv_paths`` and the
+    other site counts hold what the test traced and nothing else."""
+    from mpi4dl_tpu.obs import spans
+
+    spans._reset_recorder()
+    yield spans.recorder()
+    spans._reset_recorder()

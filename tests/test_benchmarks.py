@@ -69,23 +69,40 @@ def test_run_lp_bf16_all(devices8):
     })))
 
 
-def test_pallas_conv_flag_tristate():
-    """--pallas-conv / --no-pallas-conv / absent parse to True/False/None,
-    and auto resolves OFF on every backend (XLA's fusion wins at the step
-    level — resolve_pallas_conv's docstring); the flag is the opt-in."""
-    from mpi4dl_tpu.config import (
-        config_from_args, get_parser, resolve_pallas_conv,
-    )
+_PERFBENCH = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "perfbench")
 
-    p = get_parser()
-    assert config_from_args(p.parse_args([])).pallas_conv is None
-    assert config_from_args(p.parse_args(["--pallas-conv"])).pallas_conv is True
-    assert config_from_args(
-        p.parse_args(["--no-pallas-conv"])
-    ).pallas_conv is False
-    assert resolve_pallas_conv(True) is True
-    assert resolve_pallas_conv(False) is False
-    assert resolve_pallas_conv(None) is False
+
+@pytest.mark.parametrize("rel", sorted(
+    os.path.join(d, f) for d in ("configs", "traffic")
+    for f in os.listdir(os.path.join(_PERFBENCH, d)) if f.endswith(".json")))
+def test_every_flag_a_benchmark_cell_passes_still_parses(rel):
+    """The `argv` of each configuration and traffic file of the benchmark
+    (read, never edited here) goes through the entry point's parser and
+    `config_from_args` as `perfbench.harness.build` sends it.  Fails when a
+    flag a cell passes is retired or renamed: argparse exits on it."""
+    import json
+
+    from mpi4dl_tpu.config import ParallelConfig, config_from_args, get_parser
+
+    with open(os.path.join(_PERFBENCH, rel)) as f:
+        argv = json.load(f)["argv"]
+    assert any(a.startswith("--") for a in argv)
+    cfg = config_from_args(get_parser().parse_args(argv))
+    assert isinstance(cfg, ParallelConfig)
+
+
+@pytest.mark.parametrize("flag", ["--pallas-conv", "--no-pallas-conv"])
+def test_retired_convolution_flags_are_refused(flag, capsys):
+    """The option that picked a convolution's path is gone with the path: a
+    command line that still carries it stops at the parser, not silently on
+    another path.  Fails if the parser takes the flag again (or abbreviates
+    it to a live one)."""
+    from mpi4dl_tpu.config import get_parser
+
+    with pytest.raises(SystemExit):
+        get_parser().parse_args([flag])
+    assert "unrecognized arguments" in capsys.readouterr().err
 
 
 @pytest.mark.parametrize("platforms,provisioned", [

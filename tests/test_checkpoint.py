@@ -350,6 +350,50 @@ def test_quant_policy_change_is_reshape_not_drift(tmp_path):
     assert r.last_restore.saved_layout["quant_resolved"] == "junction=int8"
 
 
+def test_checkpoint_from_before_a_layout_field_was_retired_restores(
+        tmp_path, monkeypatch):
+    """A v2 checkpoint written before PR 31: ``ParallelConfig`` then had a
+    ``pallas_conv`` field (None unless a flag set it) and counted it as
+    layout, so the manifest's ``layout_desc`` carries ``"pallas_conv": null``
+    and its layout fingerprint covers the key.  Today's run has no such field
+    and ``LAYOUT_FIELDS`` no such name: the model's identity is the same, so
+    the checkpoint restores, flagged as a restore across layouts (the two
+    layout fingerprints differ by the key alone), and the manifest still says
+    what was saved.  Fails if a retired layout field is made part of a run's
+    identity, or an unknown key in a saved layout description is refused."""
+    import dataclasses
+
+    from mpi4dl_tpu import checkpoint as ckpt
+    from mpi4dl_tpu.config import ParallelConfig
+    from mpi4dl_tpu.mesh import MeshSpec
+
+    assert "pallas_conv" not in ckpt.LAYOUT_FIELDS
+    cfg = ParallelConfig(model="resnet", batch_size=2, image_size=32)
+    assert not hasattr(cfg, "pallas_conv")
+    spec = MeshSpec()
+    # as the parent wrote it: its dataclass had the field, its LAYOUT_FIELDS
+    # the name
+    with monkeypatch.context() as then:
+        then.setattr(ckpt, "LAYOUT_FIELDS",
+                     ckpt.LAYOUT_FIELDS | {"pallas_conv"})
+        i_then, l_then, d_then = ckpt.split_config_fingerprint(
+            dict(dataclasses.asdict(cfg), pallas_conv=None), spec)
+    assert d_then["pallas_conv"] is None
+    CheckpointManager(str(tmp_path), identity=i_then, layout=l_then,
+                      layout_desc=d_then).save({"w": jnp.arange(6.0)}, 3)
+    with open(tmp_path / "ckpt_3" / ckpt.SHARD_MANIFEST) as f:
+        assert '"pallas_conv": null' in f.read()
+
+    i_now, l_now, d_now = ckpt.split_config_fingerprint(cfg, spec)
+    assert i_now == i_then and l_now != l_then and "pallas_conv" not in d_now
+    r = CheckpointManager(str(tmp_path), identity=i_now, layout=l_now,
+                          layout_desc=d_now)
+    state, sid = r.restore_latest({"w": jnp.zeros((6,))}, require=True)
+    assert sid == 3 and r.last_restore.elastic
+    assert r.last_restore.saved_layout["pallas_conv"] is None
+    np.testing.assert_array_equal(np.asarray(state["w"]), np.arange(6.0))
+
+
 def test_cheap_validation_reads_no_array_bytes(tmp_path, monkeypatch):
     """Walking past a torn checkpoint is manifest-first: the rejected
     candidates cost a manifest read + stat pass, never a shard read; a

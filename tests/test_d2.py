@@ -367,58 +367,100 @@ def test_amoeba_cell_d2_remat_ops_matches_plain(devices8):
     np.testing.assert_array_equal(np.asarray(fine[1]), np.asarray(plain[1]))
 
 
-def test_d2_fused_pallas_triple_sharded_matches_unfused(devices8):
-    """The fused relu-conv-bn Pallas path under a REAL shard_map D2 run
-    (vertical 4-tile): values and grads must match the unfused path,
-    including the cross-tile psum of the kernel's BN statistics and the
-    three-output pallas_call's vma declaration (untested anywhere else)."""
-    cell = LayerCell(
-        [ReLU(), Conv2d(8, 8, 3, bias=False), BatchNorm(8),
-         ReLU(), Conv2d(8, 8, 3, bias=False), BatchNorm(8)]
-    )
+def _one_by_one(layers, params, x, margin, eps=1e-5):
+    """[ReLU, Conv2d, BatchNorm]* by hand on one tile that carries `margin`
+    rows and columns of context on every side: relu, a VALID convolution
+    that takes its padding from the margin, batch statistics over the rows
+    and columns that are the tile's own."""
+    for layer, p in zip(layers, params):
+        if isinstance(layer, ReLU):
+            x = jax.nn.relu(x)
+        elif isinstance(layer, Conv2d):
+            x = lax.conv_general_dilated(
+                x, p["kernel"], (1, 1), "VALID",
+                dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            margin -= layer._geometry()[4]
+        else:
+            own = x[:, margin:x.shape[1] - margin,
+                    margin:x.shape[2] - margin, :]
+            mean = jnp.mean(own, (0, 1, 2))
+            var = jnp.mean(own * own, (0, 1, 2)) - mean * mean
+            x = (x - mean) * lax.rsqrt(var + eps) * p["scale"] + p["bias"]
+    assert margin == 0
+    return x
+
+
+@pytest.mark.parametrize("cross_tile", [True, False],
+                         ids=["cross_tile_stats", "per_tile_stats"])
+def test_relu_conv_bn_windows_in_d2_run_match_layers_one_by_one(
+        devices8, cross_tile):
+    """Two [ReLU, Conv2d, BatchNorm] windows as ONE fused D2 run on 2x2
+    tiles, train mode: output and gradients equal the six layers applied by
+    hand to the image padded once by the run's halo, with the batch
+    statistics taken over all tiles (cross-tile: the whole image's) or over
+    each tile's own pixels (per-tile: tile by tile).  The first BatchNorm
+    works while one row of margin is still unconsumed.  Fails if
+    `apply_layers_premargin` steps over a layer of a window (the fused
+    branch that sat at the head of its loop advanced three at a time), stops
+    lowering the margin behind a convolution (the BatchNorm then counts its
+    neighbours' rows), or sums the statistics over the wrong tiles."""
+    layers = [ReLU(), Conv2d(8, 8, 3, bias=False), BatchNorm(8),
+              ReLU(), Conv2d(8, 8, 3, bias=False), BatchNorm(8)]
+    cell = LayerCell(layers)
     params, _ = cell.init(jax.random.key(0), (2, 16, 16, 8))
-    x = jax.random.normal(jax.random.key(1), (2, 16, 16, 8))
-    mesh = build_mesh(MeshSpec(data=1, stage=1, sph=1, spw=4), jax.devices()[:4])
+    params[2]["scale"] = params[2]["scale"] * 1.5
+    params[5]["bias"] = params[5]["bias"] + 0.25
+    x = jax.random.normal(jax.random.key(1), (2, 16, 16, 8)) + 0.3
+    mesh = build_mesh(MeshSpec(data=1, stage=1, sph=2, spw=2), jax.devices()[:4])
+    sp = SpatialCtx(axis_h="sph", axis_w="spw", grid_h=2, grid_w=2,
+                    d2_mode=True, bn_cross_tile=cross_tile)
+    assert can_fuse(layers, sp) and accumulated_halo(layers) == (2, 2)
+    ctx = ApplyCtx(train=True, spatial=sp)
+    spec = P(None, "sph", "spw", None)
 
-    from mpi4dl_tpu.ops import d2 as d2mod
+    def sharded(ps, x):
+        return shard_map(lambda ps, t: cell.apply(ps, t, ctx), mesh=mesh,
+                         in_specs=(P(), spec), out_specs=spec)(ps, x)
 
-    hits = []
-    orig = d2mod._fusable_triple
+    def by_hand(ps, x):
+        xp = jnp.pad(x, ((0, 0), (2, 2), (2, 2), (0, 0)))
+        if cross_tile:
+            return _one_by_one(layers, ps, xp, 2)
+        return jnp.concatenate([jnp.concatenate(
+            [_one_by_one(layers, ps,
+                         xp[:, 8 * i:8 * i + 12, 8 * j:8 * j + 12, :], 2)
+             for j in range(2)], axis=2) for i in range(2)], axis=1)
 
-    def probe(layers, i, dt, train, x_shape=None):
-        r = orig(layers, i, dt, train, x_shape)
-        if r:
-            hits.append(i)
-        return r
+    def loss(f):
+        return lambda ps, x: jnp.mean(jnp.square(f(ps, x)))
 
-    def run(use_pallas):
-        sp = SpatialCtx(axis_w="spw", grid_w=4, d2_mode=True,
-                        use_pallas_conv=use_pallas)
-        ctx = ApplyCtx(train=True, spatial=sp)
-        assert can_fuse(cell.layers, sp)
-
-        def loss_fn(ps, x_tile):
-            y = cell.apply(ps, x_tile, ctx)
-            return jnp.mean(jnp.square(y))
-
-        def fwd(ps, x_tile):
-            loss, grads = jax.value_and_grad(loss_fn)(ps, x_tile)
-            return lax.pmean(loss, "spw"), grads
-
-        spec = P(None, None, "spw", None)
-        return jax.jit(shard_map(
-            fwd, mesh=mesh,
-            in_specs=(P(), spec), out_specs=(P(), P()),
-        ))(params, x)
-
-    l0, g0 = run(False)
-    d2mod._fusable_triple = probe
-    try:
-        l1, g1 = run(True)
-    finally:
-        d2mod._fusable_triple = orig
-    assert hits, "fused dispatch never engaged under shard_map"
-    np.testing.assert_allclose(float(l0), float(l1), rtol=1e-5)
-    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(sharded)(params, x)),
+        np.asarray(by_hand(params, x)), rtol=1e-4, atol=1e-5)
+    got = jax.jit(jax.grad(loss(sharded), argnums=(0, 1)))(params, x)
+    want = jax.grad(loss(by_hand), argnums=(0, 1))(params, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("dim", ["H", "W"])
+def test_premargin_run_refuses_a_stride_that_dephases_the_tile(dim):
+    """A stride-2 convolution inside a pre-exchanged run on a tile whose own
+    extent on the sharded dim is odd: tile k's outputs would fall between
+    the global convolution's, so `apply_layers_premargin` raises at trace
+    time and names the dim.  Fails if the check on that dim is dropped or
+    reads the other dim's margin or extent."""
+    from mpi4dl_tpu.ops.d2 import apply_layers_premargin
+
+    conv = Conv2d(3, 4, 3, stride=2)
+    params, _ = conv.init(jax.random.key(0), (1, 8, 8, 3))
+    h = dim == "H"
+    sp = SpatialCtx(axis_h="sph" if h else None, axis_w=None if h else "spw",
+                    grid_h=2 if h else 1, grid_w=1 if h else 2, d2_mode=True)
+    # margin 1 a side on the sharded dim: own extent 7 there, 8 on the other
+    x = jnp.zeros((1, 9, 8, 3) if h else (1, 8, 9, 3))
+    with pytest.raises(ValueError, match=f"stride misalignment on {dim}"):
+        apply_layers_premargin(
+            [conv], [params], x, ApplyCtx(train=True, spatial=sp),
+            1 if h else 0, 0 if h else 1)

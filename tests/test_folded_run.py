@@ -33,13 +33,6 @@ DTYPES = pytest.mark.parametrize(
 
 
 @pytest.fixture
-def rec():
-    spans._reset_recorder()
-    yield spans.recorder()
-    spans._reset_recorder()
-
-
-@pytest.fixture
 def gate_down(monkeypatch):
     monkeypatch.setattr(L, "_HSTRIPE_MIN_PIXELS", 1)
 

@@ -184,3 +184,44 @@ def test_factorized_reduce_keeps_its_fusion_barrier():
     jaxpr = jax.make_jaxpr(lambda p, h: fr.apply(p, h, CTX))(params, x)
     assert "optimization_barrier" in str(jaxpr)
     assert out_shape == (1, 8, 8, 16)
+
+
+@pytest.mark.parametrize("name,sizes", [
+    ("resnet", dict(image_size=32, num_layers=1, num_classes=10)),
+    ("amoebanet", dict(image_size=64, num_layers=3, num_filters=32,
+                       num_classes=10)),
+    ("lfm2_moe", dict(num_layers=2, seq_len=64, vocab_size=512,
+                      experts_held=8)),
+])
+def test_every_convolution_of_a_model_takes_one_of_the_four_paths(
+        monkeypatch, rec, name, sizes):
+    """Each model of ``models.MODELS`` at its smallest size, every
+    ``MPI4DL_*`` switch unset, traced on shapes: every ``Conv2d`` site the
+    trace applied is counted by ``conv_paths`` under ``wfold``, ``hstripe``,
+    ``phase`` or ``xla``, the recorder's whole vocabulary, so no site went
+    down a path that has no name (the count drops what it cannot name).
+    Fails if a fifth arm is put back into ``Conv2d.apply``, or a model is
+    entered that hands a convolution over some other way."""
+    import os
+
+    from mpi4dl_tpu import layers as L
+    from mpi4dl_tpu.config import ParallelConfig
+    from mpi4dl_tpu.models import MODELS, build_model
+    from mpi4dl_tpu.obs import spans
+
+    assert set(MODELS) == {"resnet", "amoebanet", "lfm2_moe"}
+    assert spans.CONV_PATHS == ("wfold", "hstripe", "phase", "xla")
+    for key in [k for k in os.environ if k.startswith("MPI4DL_")]:
+        monkeypatch.delenv(key)
+    applied = set()
+    apply = L.Conv2d.apply
+    monkeypatch.setattr(
+        L.Conv2d, "apply",
+        lambda self, *a: applied.add(id(self)) or apply(self, *a))
+    model = build_model(ParallelConfig(model=name, batch_size=2, **sizes))
+    params = jax.eval_shape(lambda: model.init(jax.random.key(0))[0])
+    dtype = jnp.int32 if MODELS[name][0] == "tokens" else jnp.float32
+    jax.eval_shape(lambda p, x: model.apply(p, x, CTX), params,
+                   jax.ShapeDtypeStruct(model.in_shape, dtype))
+    assert sum(rec.conv_paths().values()) == len(applied)
+    assert bool(applied) == (MODELS[name][0] == "image")

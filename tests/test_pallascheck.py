@@ -275,8 +275,8 @@ def test_dma_race_read_destination_before_wait():
 
 
 def test_dma_race_write_source_in_flight():
-    """The ops/pallas_conv.py WAR hazard as a checked invariant: storing
-    into the source of an in-flight copy."""
+    """The WAR hazard Mosaic does not fence, as a checked invariant:
+    storing into the source of an in-flight copy."""
     def k(x_ref, o_ref, a, b, sem):
         a[...] = x_ref[...]
         cp = pltpu.make_async_copy(a, b, sem)
@@ -506,11 +506,11 @@ def registry_contract():
 
 
 def test_registry_is_clean(registry_contract):
-    """The acceptance bar: every registered kernel case (raw fp32 + bf16
-    quant paths, fused and causal variants) verifies clean."""
+    """The acceptance bar: every registered kernel case (the raw fp32 path
+    and the causal bf16 one) verifies clean."""
     kernels = registry_contract["kernels"]
     assert set(kernels) == {c.name for c in REGISTRY}
-    assert len(kernels) >= 6
+    assert len(kernels) >= 2
     for name, entry in kernels.items():
         assert entry["findings"] == {}, (name, entry["findings"])
 
@@ -524,13 +524,15 @@ def test_registry_fits_ci_vmem_gate(registry_contract):
 
 
 def test_conv_contract_shape(registry_contract):
-    """The conv rows pin what the kernel actually stages: ANY-space inputs
-    hand-DMA'd (so 2 starts/step), a VMEM out block, 3 scratch + 2 sems."""
-    entry = registry_contract["kernels"]["halo_conv2d:float32"]
-    assert entry["dma_starts"] == 2
+    """The attention row pins what the kernel actually stages: q, k, v as
+    blocked VMEM operands the pipeline copies (no DMA of its own), three
+    VMEM out blocks (o, m, l), three scratch accumulators."""
+    entry = registry_contract["kernels"]["block_flash:float32"]
+    assert entry["dma_starts"] == 0
     assert len(entry["grid"]) == 3
     names = set(entry["blocks"])
-    assert {"out0", "scratch0", "scratch1", "scratch2"} <= names
+    assert {"in0", "in1", "in2", "out0", "out1", "out2",
+            "scratch0", "scratch1", "scratch2"} == names
 
 
 def test_pallas_contract_roundtrip(registry_contract):
@@ -564,15 +566,15 @@ def test_pallas_contract_diff_localizes(registry_contract):
     from mpi4dl_tpu.analysis.contracts.diff import diff_pallas_contract
 
     mutated = json.loads(json.dumps(registry_contract))
-    name = "halo_conv2d:float32"
+    name = "block_flash:float32"
     mutated["kernels"][name]["vmem_bytes"] += 1
     mutated["kernels"][name]["findings"]["dma-race"] = 1
-    del mutated["kernels"]["block_flash:float32"]
+    del mutated["kernels"]["block_flash:causal:bfloat16"]
     drifts = diff_pallas_contract(registry_contract, mutated)
     fields = {(d["kernel"], d["field"]) for d in drifts}
     assert (name, "vmem_bytes") in fields
     assert (name, "findings.dma-race") in fields
-    assert ("block_flash:float32", "presence") in fields
+    assert ("block_flash:causal:bfloat16", "presence") in fields
 
 
 # ---------------------------------------------------------------------------
@@ -703,8 +705,8 @@ def test_rule12_flags_unregistered_module(tmp_path):
 
 
 def test_rule12_registered_module_is_exempt(tmp_path):
-    # module name matches a registry import (the real pallas_conv row)
-    vs = _scan(tmp_path, _NEW_KERNEL, "mpi4dl_tpu/ops/pallas_conv.py")
+    # module name matches a registry import (the real pallas_attention row)
+    vs = _scan(tmp_path, _NEW_KERNEL, "mpi4dl_tpu/ops/pallas_attention.py")
     assert vs == []
 
 

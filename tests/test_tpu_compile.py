@@ -1,10 +1,13 @@
-"""The Pallas kernels compiled by the chip's own compiler, without the chip.
+"""The Pallas kernel and the convolution paths compiled by the chip's own
+compiler, without the chip.
 
 Interpret mode (every other kernel test here) cannot show a block shape
 Mosaic refuses, a slice off the tiling, or a VMEM overrun; the TPU compiler
 installed in this container can, for a chip that is described and not
-attached.  The shapes are the ones ``chip_smoke.py`` runs on the chip: the
-D2-step tile (512², 208 channels) and a 4096-token, 128-wide head.
+attached.  The kernel's shapes are the ones ``chip_smoke.py`` runs on the
+chip: a 4096-token, 128-wide head.  Each of ``Conv2d.apply``'s paths that
+is not XLA's own convolution as it stands compiles at a shape of the
+benchmark's ResNet cell.
 
 The one-chip train step is compiled the same way, tiny, to see that the
 program's scope names (``cellNN``, ``loss``, ``optimizer_update``) reach the
@@ -29,10 +32,6 @@ from jax.sharding import SingleDeviceSharding
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from chip_smoke import KERNEL_SHAPES, flash_attention  # noqa: E402
-
-from mpi4dl_tpu.ops.pallas_conv import halo_conv2d  # noqa: E402
-
-TILE, CHANNELS = KERNEL_SHAPES["tile"], KERNEL_SHAPES["channels"]
 
 
 @pytest.fixture(scope="module")
@@ -65,29 +64,88 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _conv_args(one_chip):
-    x = jax.ShapeDtypeStruct((1, TILE + 2, TILE + 2, CHANNELS), jnp.bfloat16,
-                             sharding=one_chip)
-    w = jax.ShapeDtypeStruct((3, 3, CHANNELS, CHANNELS), jnp.bfloat16,
-                             sharding=one_chip)
-    return x, w
-
-
 def _assert_mosaic(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_halo_conv2d_compiles_for_v5e(one_chip, no_persistent_cache):
-    _assert_mosaic(
-        halo_conv2d.lower(*_conv_args(one_chip), interpret=False).compile())
+def _lower_fwd_bwd(layer, shape, one_chip):
+    """``layer`` forward and backward in bf16 (float32 parameters, train
+    mode) on shapes placed on the described chip, lowered."""
+    from mpi4dl_tpu.layer_ctx import ApplyCtx
+
+    params = jax.eval_shape(lambda: layer.init(jax.random.key(0), shape)[0])
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(p, x):
+        return jnp.sum(layer.apply(p, x, ApplyCtx(train=True))
+                       .astype(jnp.float32))
+
+    return jax.jit(jax.grad(loss, (0, 1))).lower(params, x)
 
 
-def test_fused_relu_conv_bn_stats_compiles_for_v5e(one_chip,
-                                                   no_persistent_cache):
-    _assert_mosaic(halo_conv2d.lower(
-        *_conv_args(one_chip), interpret=False, fuse_relu=True,
-        stat_window=(0, TILE, 0, TILE),
-    ).compile())
+def test_stem_convolution_at_1024_compiles_in_h_stripes_for_v5e(
+        one_chip, no_persistent_cache, rec):
+    """The ResNet cell's stem, 3 -> 16 channels on 1 x 1024 x 1024 x 3: 42
+    does not divide the row, so the fold leaves it to the H stripes
+    (ops/hstripe_conv.py), the one ``hstripe`` site of the cell's
+    ``conv_paths``; at this size it is one stripe, so no loop.  Temporaries
+    under a bound taken from this compile (536,935,424 B on jax 0.9.0) with
+    20 % room.  Fails if the stem leaves the path, the striped form stops
+    compiling for the chip, or its flat-row padding starts to cost a copy of
+    the image."""
+    from mpi4dl_tpu.layers import Conv2d
+
+    compiled = _lower_fwd_bwd(Conv2d(3, 16, 3), (1, 1024, 1024, 3),
+                              one_chip).compile()
+    assert rec.conv_paths() == {"hstripe": 1}
+    text = compiled.as_text()
+    assert " convolution(" in text and not re.search(r" while\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2 * 536_935_424
+
+
+@pytest.mark.parametrize("conv,shape", [
+    ((64, 64, 3), (1, 1024, 1024, 64)),
+    ((128, 256, 1), (1, 512, 512, 128)),
+], ids=["3x3_64_at_1024", "1x1_128_to_256_at_512"])
+def test_strided_convolution_compiles_in_phase_form_for_v5e(
+        one_chip, no_persistent_cache, rec, conv, shape):
+    """Two of the ResNet cell's four stride-2 convolutions at their own
+    shapes: the ``phase`` path (ops/conv_phase.py), whose input gradient is
+    a sum of stride-1 convolutions over the phases of the cotangent.  Fails
+    if a strided convolution leaves the path, or its backward goes back to a
+    convolution over a dilated cotangent (``lhs_dilate`` in the chip's HLO),
+    which is what the form exists to avoid."""
+    from mpi4dl_tpu.layers import Conv2d
+
+    cin, cout, k = conv
+    compiled = _lower_fwd_bwd(Conv2d(cin, cout, k, stride=2), shape,
+                              one_chip).compile()
+    assert rec.conv_paths() == {"phase": 1}
+    text = compiled.as_text()
+    assert " convolution(" in text and "lhs_dilate" not in text
+
+
+def test_factorized_reduce_keeps_its_barrier_in_the_program_for_v5e(
+        one_chip, no_persistent_cache, rec):
+    """AmoebaNet-D(18, 416)'s first FactorizedReduce at 1024² (104 -> 416
+    channels on 1 x 512 x 512 x 104), forward and backward in bf16:
+    its two halves are ``phase`` sites, and the ``optimization_barrier`` on
+    its output (libtpu 0.0.34 miscompiled the bf16 backward across that
+    boundary: NaN gradients at step 1, PR 22; tests/test_models.py holds it
+    in the forward's jaxpr) is in what the chip's compiler is handed, which
+    it compiles.  The compiled text cannot be asked for it: XLA expands its
+    barriers away after fusion.  Fails if the barrier is dropped from
+    ``apply``, a half leaves the phase form, or the cell's backward stops
+    compiling for the chip at this width."""
+    from mpi4dl_tpu.models.amoebanet import FactorizedReduce
+
+    lowered = _lower_fwd_bwd(FactorizedReduce(104, 416), (1, 512, 512, 104),
+                             one_chip)
+    assert rec.conv_paths() == {"phase": 2}
+    assert "stablehlo.optimization_barrier" in lowered.as_text()
+    lowered.compile()
 
 
 def test_block_flash_fwd_bwd_compiles_for_v5e(one_chip, no_persistent_cache):

@@ -36,15 +36,6 @@ def _rel(a, b):
 
 
 @pytest.fixture
-def rec():
-    """A recorder of this test's own, so that ``conv_paths`` counts what the
-    test traced and nothing else."""
-    spans._reset_recorder()
-    yield spans.recorder()
-    spans._reset_recorder()
-
-
-@pytest.fixture
 def gate_down(monkeypatch):
     """Suite-sized shapes pass the million-pixel gate, and a stripe's patch
     budget is small enough that the striped path would loop."""
@@ -125,9 +116,16 @@ def test_wfold_factor(wid, kw, cin, cout, pad_w, p):
     assert wf.wfold_factor(wid, kw, cin, cout, pad_w) == p
 
 
+def _rows(*rows):
+    """A row may end in a dict that moves a threshold of ``layers`` to the
+    row's own size (the suite has no image of a million pixels) or asks for
+    eval mode; most do not."""
+    return [(*row, {})[:5] for row in rows]
+
+
 @pytest.mark.parametrize(
-    "why,conv,shape,path",
-    [
+    "why,conv,shape,path,knobs",
+    _rows(
         ("Cin 3: W % 42", L.Conv2d(3, 16, 3), (1, 16, 32, 3), "hstripe"),
         ("W % p", L.Conv2d(16, 16, 3), (1, 16, 20, 16), "hstripe"),
         ("VALID W", L.Conv2d(16, 16, 3, padding=(1, 0)), (1, 16, 32, 16),
@@ -135,16 +133,38 @@ def test_wfold_factor(wid, kw, cin, cout, pad_w, p):
         ("stride 2", L.Conv2d(16, 16, 3, stride=2), (1, 16, 32, 16), "phase"),
         ("groups", L.Conv2d(16, 16, 3, feature_group_count=2),
          (1, 16, 32, 16), "xla"),
-    ],
+        # the rows between which the opted-in kernel's gate used to sit
+        # (128 -> 128, 3x3, stride 1 was its home): each fails if a branch
+        # is put back between the stripes and the phase form, or if a
+        # threshold turns from >= to > (or < to <=)
+        ("65 channels", L.Conv2d(65, 65, 3), (1, 16, 32, 65), "xla"),
+        ("128 channels", L.Conv2d(128, 128, 3), (1, 16, 32, 128), "xla"),
+        ("1x1 at 64 channels", L.Conv2d(64, 64, 1), (1, 16, 32, 64), "wfold"),
+        ("at the fold's ceiling", L.Conv2d(16, 16, 3), (1, 16, 32, 16),
+         "hstripe", {"_WFOLD_MAX_PIXELS": 16 * 32}),
+        ("one pixel under the gate", L.Conv2d(16, 16, 3), (1, 16, 32, 16),
+         "xla", {"_HSTRIPE_MIN_PIXELS": 16 * 32 + 1}),
+        ("stride 2 at 64 channels", L.Conv2d(64, 64, 3, stride=2),
+         (1, 16, 32, 64), "phase"),
+        ("groups and stride 2",
+         L.Conv2d(16, 16, 3, stride=2, feature_group_count=2),
+         (1, 16, 32, 16), "xla"),
+        ("eval mode", L.Conv2d(16, 16, 3), (1, 16, 32, 16), "wfold",
+         {"train": False}),
+    ),
     ids=lambda v: v.replace(" ", "_") if isinstance(v, str) else None,
 )
-def test_fall_throughs_take_todays_path(gate_down, rec, why, conv, shape,
-                                        path):
+def test_fall_throughs_take_todays_path(gate_down, rec, monkeypatch, why,
+                                        conv, shape, path, knobs):
     """Where the fold is not exact the dispatch goes where it went before,
     decided from shapes and padding alone, and gives that path's result."""
+    knobs = dict(knobs)
+    ctx = ApplyCtx(train=knobs.pop("train", True))
+    for name, value in knobs.items():
+        monkeypatch.setattr(L, name, value)
     params, _ = conv.init(jax.random.key(2), shape)
     x = jax.random.normal(jax.random.key(3), shape)
-    y = conv.apply(params, x, ApplyCtx(train=True))
+    y = conv.apply(params, x, ctx)
     assert rec.conv_paths() == {path: 1}, why
 
     kh, kw, sh, sw, ph, pw = conv._geometry()
@@ -219,6 +239,86 @@ def test_fold_under_an_h_sharded_context_with_the_margin_pre_exchanged(
     conv.apply(params, jnp.pad(x, ((0, 0), (0, 0), (1, 1), (0, 0))),
                ApplyCtx(train=True, spatial=sp_w))
     assert rec.conv_paths() == {"wfold": 1, "hstripe": 1}
+
+
+# One convolution for each way through Conv2d.apply, on a 16 x 32 image:
+# name, layer, input channels, and the path it takes on a whole image.
+_FIVE = [
+    ("stem", lambda: L.Conv2d(3, 16, 3), 3, "hstripe"),       # W % 42
+    ("narrow3x3", lambda: L.Conv2d(16, 16, 3), 16, "wfold"),
+    ("narrow1x1", lambda: L.Conv2d(16, 64, 1), 16, "wfold"),
+    ("wide", lambda: L.Conv2d(80, 80, 3), 80, "xla"),
+    ("strided", lambda: L.Conv2d(80, 80, 3, stride=2), 80, "phase"),
+]
+
+
+@pytest.mark.parametrize(
+    "sp_kw,narrow3x3",
+    [
+        pytest.param(dict(axis_h="sph", grid_h=2), "wfold",
+                     id="H_sharded_margin_by_the_layer"),
+        pytest.param(dict(axis_w="spw", grid_w=2), "hstripe",
+                     id="W_sharded_margin_by_the_layer"),
+        pytest.param(dict(axis_h="sph", axis_w="spw", grid_h=2, grid_w=2),
+                     "hstripe", id="both_sharded_margin_by_the_layer"),
+        pytest.param(dict(axis_h="sph", grid_h=2, d2_mode=True), "wfold",
+                     id="H_sharded_margin_by_a_D2_run"),
+        pytest.param(dict(axis_h="sph", axis_w="spw", grid_h=2, grid_w=2,
+                          d2_mode=True), "hstripe",
+                     id="both_sharded_margin_by_a_D2_run"),
+        pytest.param(dict(axis_h="sph", axis_w="spw", grid_h=1, grid_w=1,
+                          rep_h=2, rep_w=2), "wfold",
+                     id="degenerate_level_axes_named_grid_1"),
+    ],
+)
+def test_the_four_paths_under_a_spatial_context(devices8, gate_down, rec,
+                                                monkeypatch, sp_kw,
+                                                narrow3x3):
+    """Each of five convolutions as a cell of its own on a 2x2 mesh: the path
+    is chosen from the tile's shape and from which padding a halo exchange
+    replaced, and the tiles together are the convolution of the whole image.
+    A narrow 3x3 folds while its W padding is SAME (H sharded, or a level
+    whose grid is 1) and takes the stripes once W's margin came from the
+    neighbours; a 1x1 has no margin and folds on every tile; the stem, the
+    wide and the strided one go where they go unsharded.  Fails if the
+    dispatch reads anything of the context but its padding (the retired
+    kernel's gate read the context's axes and a flag on it), or if a tile's
+    margin is exchanged twice or not at all under `d2_mode`."""
+    from jax.sharding import PartitionSpec as P
+
+    from mpi4dl_tpu.cells import LayerCell
+    from mpi4dl_tpu.compat import shard_map
+    from mpi4dl_tpu.mesh import MeshSpec, build_mesh
+    from mpi4dl_tpu.ops import d2
+
+    d2_runs = []
+    monkeypatch.setattr(
+        d2, "run_layers_d2",
+        lambda *a, run=d2.run_layers_d2: d2_runs.append(1) or run(*a))
+    sp = SpatialCtx(**sp_kw)
+    ctx = ApplyCtx(train=True, spatial=sp)
+    mesh = build_mesh(MeshSpec(data=1, stage=1, sph=2, spw=2), devices8[:4])
+    spec = (P(None, sp.axis_h, sp.axis_w, None) if sp.active else P())
+    want = {}
+    for i, (name, make, cin, alone) in enumerate(_FIVE):
+        conv = make()
+        cell = LayerCell([conv])
+        shape = (1, 16, 32, cin)
+        params, _ = cell.init(jax.random.key(10 + i), shape)
+        x = jax.random.normal(jax.random.key(20 + i), shape)
+        got = jax.jit(shard_map(
+            lambda p, t, cell=cell: cell.apply(p, t, ctx), mesh=mesh,
+            in_specs=(P(), spec), out_specs=spec))(params, x)
+        kh, kw, sh, sw, ph, pw = conv._geometry()
+        ref = _ref(x, params[0]["kernel"], (ph, ph), (pw, pw),
+                   stride=sh) + params[0]["bias"]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=1e-5, err_msg=name)
+        path = narrow3x3 if name == "narrow3x3" else alone
+        want[path] = want.get(path, 0) + 1
+    assert rec.conv_paths() == want
+    # under d2_mode every convolution with a margin got it from the run
+    assert len(d2_runs) == (4 if sp.d2_mode else 0)
 
 
 def _trace_model(model, dtype=jnp.float32):
