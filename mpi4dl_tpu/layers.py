@@ -57,6 +57,8 @@ def _hstripe_enabled() -> bool:
     return os.environ.get("MPI4DL_NO_HSTRIPE") != "1"
 
 
+# The TPU's lane count: the minor dimension of a tile.
+_LANES = 128
 _HSTRIPE_MIN_PIXELS = 1 << 20
 # The W-fold takes narrow convs below this size.  From 2048² up a narrow
 # block runs H-stripe by H-stripe on flat [N, H, W·C] buffers
@@ -287,6 +289,24 @@ class Conv2d(Layer):
 
                 path = "hstripe"
                 y = hstripe_conv2d(x, kernel, padding[0], padding[1])
+        elif ((kh, kw, sh, sw, groups) == (1, 1, 1, 1, 1)
+              and padding == ((0, 0), (0, 0))
+              and (x.shape[-1] % _LANES or kernel.shape[-1] % _LANES)):
+            # A pointwise convolution is a matrix product over the channel
+            # axis, and goes to XLA as one, on the activation as it stands.
+            # XLA:TPU rewrites a batch-1 convolution into a batched one over
+            # a split of W in a channel-minor layout; where a channel count
+            # is no multiple of the 128 lanes it gives the elementwise work
+            # round it an H-minor layout, and brackets the convolution with
+            # transposes between the two (AmoebaNet-D at 416 and 832
+            # channels: 69 % of a normal cell's copy bytes, a third of the
+            # 2048² step; PERF.md, PR 32).  A product takes the layout of
+            # its neighbours.  Where both widths fill the lanes XLA keeps
+            # channels minor on both sides, there is no transpose to save,
+            # and the product form costs ResNet-110 v2 at 2048² 0.84 GiB
+            # (its 128 -> 256 at 512²): those stay convolutions.
+            path = "dot"
+            y = lax.dot_general(x, kernel[0, 0], (((3,), (0,)), ((), ())))
         elif (sh, sw) != (1, 1) and groups == 1 and _phase_dx_enabled():
             # Strided convs take the phase-decomposed-backward form: same
             # forward conv, but dx avoids XLA's lhs-dilation machinery

@@ -75,8 +75,10 @@ CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 # How ``layers.Conv2d.apply`` handed a convolution to XLA: W-folded
 # (ops/wfold_conv.py), H-striped (ops/hstripe_conv.py), the phase-decomposed
-# strided form (ops/conv_phase.py), or as it stands.
-CONV_PATHS = ("wfold", "hstripe", "phase", "xla")
+# strided form (ops/conv_phase.py), as it stands, or, where it is pointwise
+# (1x1, stride 1, no padding, one group) with a width that is no multiple of
+# the 128 lanes, as a matrix product over channels.
+CONV_PATHS = ("wfold", "hstripe", "phase", "xla", "dot")
 # Which kernel a site of another kind was traced with: attention
 # (models/lfm2.Attention: the Pallas block kernel of ops/pallas_attention.py
 # or the einsum form) and the routed experts' grouped product (ops/moe.py:

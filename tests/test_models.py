@@ -140,26 +140,27 @@ def test_lane_pad_function_preserving(monkeypatch):
     )
 
 
-def test_amoebanet_fine_remat_packed_states_exact(monkeypatch):
-    """remat='fine' (per-op checkpoints with lane-packed DAG states) must
-    be bit-level equivalent to the no-remat path: packing is a reshape and
-    checkpoint recompute replays identical ops."""
+def _packed_fine_remat_against_plain(monkeypatch, batch, dtype):
+    """Two SGD steps of a 3-cell AmoebaNet-D whose DAG states are all
+    lane-packed, under remat='fine' and plain, computed in ``dtype``: the
+    loss of every step to 1e-6 and the parameters to 1e-5 (and 1e-7)."""
     from mpi4dl_tpu import cells as C
     from mpi4dl_tpu.train import Optimizer, TrainState, make_train_step
 
     monkeypatch.setattr(C, "_PACK_MIN_ELEMS", 1)
-    model = amoebanetd((2, 32, 32, 3), num_classes=10, num_layers=3,
+    model = amoebanetd((batch, 32, 32, 3), num_classes=10, num_layers=3,
                        num_filters=16)
     params, _ = model.init(jax.random.key(0))
+    params = jax.tree.map(lambda a: a.astype(dtype), params)
     # Packing really engages on these DAG states (W*C = 16*16=256 | 128).
-    assert C._pack_meta((2, 16, 16, 16)) == (16, 16)
+    assert C._pack_meta((batch, 16, 16, 16)) == (16, 16)
     opt = Optimizer("sgd", lr=0.01)
-    x = jax.random.normal(jax.random.key(1), (2, 32, 32, 3))
-    y = jnp.arange(2, dtype=jnp.int32)
+    x = jax.random.normal(jax.random.key(1), (batch, 32, 32, 3), jnp.float32)
+    y = jnp.arange(batch, dtype=jnp.int32)
     s_f = TrainState.create(params, opt)
     s_o = TrainState.create(params, opt)
-    step_f = make_train_step(model, opt, remat="fine")
-    step_o = make_train_step(model, opt)
+    step_f = make_train_step(model, opt, remat="fine", compute_dtype=dtype)
+    step_o = make_train_step(model, opt, compute_dtype=dtype)
     for _ in range(2):
         s_f, m_f = step_f(s_f, x, y)
         s_o, m_o = step_o(s_o, x, y)
@@ -167,9 +168,35 @@ def test_amoebanet_fine_remat_packed_states_exact(monkeypatch):
             float(m_f["loss"]), float(m_o["loss"]), rtol=1e-6
         )
     for a, b in zip(jax.tree.leaves(s_f.params), jax.tree.leaves(s_o.params)):
+        assert a.dtype == dtype
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7
         )
+
+
+def test_amoebanet_fine_remat_packed_states_exact(monkeypatch):
+    """remat='fine' (per-op checkpoints with lane-packed DAG states) must
+    be equivalent to the no-remat path: packing is a reshape and checkpoint
+    recompute replays identical ops.
+
+    In float64 since PR 32, on the batch-2 input and to the limits the test
+    always had.  Identical ops are not identical bits any more: the cells'
+    1×1 convolutions are matrix products, and XLA:CPU's replayed product
+    differs from the first by one rounding (the first gradients by 1.2e-7 of
+    their largest entry in float32, by 2.9e-16 in float64; PERF.md section 6,
+    PR 32), which this net's batch-2 BatchNorms over 2 to 8 values turn into
+    7.6e-5 of the second float32 loss.  A rounding of float64 is far under
+    the limits; a replay that used a wrong value would not be."""
+    with jax.enable_x64(True):
+        _packed_fine_remat_against_plain(monkeypatch, 2, jnp.float64)
+
+
+def test_amoebanet_fine_remat_packed_states_in_float32_at_batch_8(monkeypatch):
+    """The same two steps in float32, at the smallest batch whose BatchNorms
+    (over 8 to 32 values) do not amplify one rounding of a replayed product
+    past the same limits: both losses read equal and the parameters 0.90 of
+    1e-7 + 1e-6·|b| here."""
+    _packed_fine_remat_against_plain(monkeypatch, 8, jnp.float32)
 
 
 def test_factorized_reduce_keeps_its_fusion_barrier():
@@ -193,14 +220,14 @@ def test_factorized_reduce_keeps_its_fusion_barrier():
     ("lfm2_moe", dict(num_layers=2, seq_len=64, vocab_size=512,
                       experts_held=8)),
 ])
-def test_every_convolution_of_a_model_takes_one_of_the_four_paths(
+def test_every_convolution_of_a_model_takes_one_of_the_five_paths(
         monkeypatch, rec, name, sizes):
     """Each model of ``models.MODELS`` at its smallest size, every
     ``MPI4DL_*`` switch unset, traced on shapes: every ``Conv2d`` site the
     trace applied is counted by ``conv_paths`` under ``wfold``, ``hstripe``,
-    ``phase`` or ``xla``, the recorder's whole vocabulary, so no site went
-    down a path that has no name (the count drops what it cannot name).
-    Fails if a fifth arm is put back into ``Conv2d.apply``, or a model is
+    ``phase``, ``xla`` or ``dot``, the recorder's whole vocabulary, so no
+    site went down a path that has no name (the count drops what it cannot
+    name).  Fails if a sixth arm is put into ``Conv2d.apply``, or a model is
     entered that hands a convolution over some other way."""
     import os
 
@@ -210,7 +237,7 @@ def test_every_convolution_of_a_model_takes_one_of_the_four_paths(
     from mpi4dl_tpu.obs import spans
 
     assert set(MODELS) == {"resnet", "amoebanet", "lfm2_moe"}
-    assert spans.CONV_PATHS == ("wfold", "hstripe", "phase", "xla")
+    assert spans.CONV_PATHS == ("wfold", "hstripe", "phase", "xla", "dot")
     for key in [k for k in os.environ if k.startswith("MPI4DL_")]:
         monkeypatch.delenv(key)
     applied = set()

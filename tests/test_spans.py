@@ -272,9 +272,10 @@ def test_every_step_of_a_tiny_model_is_covered_by_named_spans(
     assert set(summary) == {"kind", "schema", "t", "setup_ms", "jax",
                             "built_in_loop", "conv_paths", "norm_paths"}
     # every convolution of the tiny model, by the path its dispatch chose
-    # (32² is far under the narrow-channel gate: nothing folds or stripes)
+    # (32² is far under the narrow-channel gate: nothing folds or stripes;
+    # the 1×1 that closes a block at stride 1 is a matrix product)
     assert summary["conv_paths"] == rec.conv_paths()
-    assert set(summary["conv_paths"]) == {"phase", "xla"}
+    assert set(summary["conv_paths"]) == {"phase", "xla", "dot"}
     assert set(summary["norm_paths"]) == {"plain"}
     assert summary["setup_ms"]["setup/build_train"] > 0
     assert summary["jax"]["jax/trace"]["top"][0]["program"] == "step"

@@ -7,7 +7,8 @@ installed in this container can, for a chip that is described and not
 attached.  The kernel's shapes are the ones ``chip_smoke.py`` runs on the
 chip: a 4096-token, 128-wide head.  Each of ``Conv2d.apply``'s paths that
 is not XLA's own convolution as it stands compiles at a shape of the
-benchmark's ResNet cell.
+benchmark's ResNet cell, and one normal cell of its AmoebaNet-D whole, for
+the copies the compiler puts round its pointwise convolutions.
 
 The one-chip train step is compiled the same way, tiny, to see that the
 program's scope names (``cellNN``, ``loss``, ``optimizer_update``) reach the
@@ -20,6 +21,7 @@ process may load libtpu, and under xdist every worker imports every file.
 Nothing runs here, so these say nothing about results or times.
 """
 
+import math
 import os
 import re
 import sys
@@ -245,6 +247,64 @@ def test_narrow_resblock_at_1024_folds_lane_dense_for_v5e(
     entry = text[text.index("ENTRY "):]
     f32_activations = re.findall(r"= f32\[1024,8,1[67],\d+\]\S* \S+\(", entry)
     assert not f32_activations, f32_activations[:4]
+
+
+_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+
+
+def _entry_copies(compiled):
+    """``(dtype, dims, bytes)`` of every ``copy`` instruction of the compiled
+    program's entry computation (what it writes; fusions' insides are not
+    the entry's)."""
+    text = compiled.as_text()
+    out = []
+    for m in re.finditer(r"^\s+(?:ROOT )?%copy(?:\.\d+)* = (\w+)\[([\d,]*)\]",
+                         text[text.index("ENTRY "):], re.M):
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        out.append((m.group(1), dims, _BYTES[m.group(1)] * math.prod(dims)))
+    return out
+
+
+def test_amoebanet_normal_cell_keeps_no_transposes_round_its_1x1_for_v5e(
+        one_chip, no_persistent_cache, rec):
+    """One normal cell of AmoebaNet-D(18,416)'s first group at its real size
+    (``AmoebaCell(1664, 1664, 416)`` on two 1 x 256 x 256 x 1664 states in
+    bf16), forward and backward under ``jax.checkpoint``.  Nine of its
+    thirteen convolutions are pointwise at stride 1 and go to XLA as matrix
+    products (``Conv2d.apply``'s ``dot`` form, PR 32).  As convolutions (the
+    parent tree, counted by this function: 50 ``copy`` instructions writing
+    4,634,707,584 B) XLA:TPU ran each as a batch of 8 splits of W in a
+    channel-minor layout, and transposed to and from the H-minor layout of
+    the fusions round it: 33 copies of a ``[256,8,32,416]`` or
+    ``[256,8,32,416,1]`` view and 12 of the 1664-wide forms.  Fails if such a
+    copy comes back into the entry computation, or if the copies together
+    write more than half of what the parent's did (this tree: 59 writing
+    1,429,390,976 B, those round the 1x7 and 7x1 at 104 channels and the
+    probe's own ends)."""
+    from mpi4dl_tpu.layer_ctx import ApplyCtx
+    from mpi4dl_tpu.models.amoebanet import AmoebaCell
+
+    c, shape = 416, (1, 256, 256, 1664)
+    cell = AmoebaCell(4 * c, 4 * c, c, reduction=False, reduction_prev=False)
+    params = jax.eval_shape(
+        lambda: cell.init(jax.random.key(0), (shape, shape))[0])
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(p, x, skip):
+        y = jax.checkpoint(lambda p, x, skip: cell.apply(
+            p, (x, skip), ApplyCtx(train=True)))(p, x, skip)
+        return sum(jnp.sum(t.astype(jnp.float32))
+                   for t in jax.tree.leaves(y))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(params, x, x).compile()
+    assert rec.conv_paths() == {"xla": 4, "dot": 9}
+    copies = _entry_copies(compiled)
+    split = [(dt, dims) for dt, dims, _ in copies
+             if dims[:4] == [256, 8, 32, c]]
+    assert not split, split[:4]
+    assert sum(size for _, _, size in copies) < 4_634_707_584 / 2
 
 
 def test_lfm2_kernels_compile_for_v5e_under_a_highest_default(

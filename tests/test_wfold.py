@@ -332,14 +332,16 @@ def _trace_model(model, dtype=jnp.float32):
 def test_conv_paths_of_a_resnet_v2(monkeypatch, rec):
     """Depth 11 (one block a stage) at 64² with the gate at 64²: the
     16-channel stage's two 3×3 and two 1×1 convolutions fold, the stem (Cin
-    3) keeps the stripes, the strided ones take the phase form, the rest are
-    XLA's; a site counts once however often it is traced."""
+    3) keeps the stripes, the strided ones take the phase form, the 1×1
+    64→128 that closes stage 1's block is a matrix product, the rest (the
+    3×3, and the 1×1 128→256 whose widths both fill the lanes) are XLA's; a
+    site counts once however often it is traced."""
     from mpi4dl_tpu.models.resnet import get_resnet_v2
 
     monkeypatch.setattr(L, "_HSTRIPE_MIN_PIXELS", 64 * 64)
     model = get_resnet_v2((1, 64, 64, 3), depth=11, num_classes=10)
     _trace_model(model)
-    want = {"wfold": 4, "hstripe": 1, "phase": 4, "xla": 4}
+    want = {"wfold": 4, "hstripe": 1, "phase": 4, "xla": 3, "dot": 1}
     assert rec.conv_paths() == want
     # the stage's one block is a folded run (layers.run_fold): its two
     # BatchNorms work on [N, H, W/8, 8·C], and so does the one that opens
@@ -355,13 +357,16 @@ def test_conv_paths_of_a_resnet_v2(monkeypatch, rec):
 
 def test_conv_paths_of_the_resnet_cell(rec):
     """``resnet110_v2.1024.bs1`` as it is: 13 3×3 16→16, 11 3×3 64→16 and 13
-    1×1 16→64 fold; the stem alone is left to the stripes."""
+    1×1 16→64 fold; the stem alone is left to the stripes; the 1×1 that
+    closes each block of stage 1 (12 of 64→128 at 512²) is a matrix product;
+    stage 2's (12 of 128→256 at 256²: both widths fill the lanes) and the 46
+    3×3 of both stages are XLA's."""
     from mpi4dl_tpu.models.resnet import get_resnet_v2
 
     _trace_model(get_resnet_v2((1, 1024, 1024, 3), depth=110,
                                num_classes=1000), jnp.bfloat16)
     assert rec.conv_paths() == {"wfold": 37, "hstripe": 1, "phase": 4,
-                                "xla": 70}
+                                "xla": 58, "dot": 12}
     # stage 0 is twelve folded runs: block 0 has two BatchNorms, blocks 1–11
     # three each, and the BatchNorm(64) at 1024² that opens stage 1 takes the
     # stream as they left it; the stem's, the other 71 of stages 1 and 2 and
@@ -371,16 +376,18 @@ def test_conv_paths_of_the_resnet_cell(rec):
 
 def test_conv_paths_of_resnet_at_2048(rec):
     """At 2048² the 16-channel stage runs block by block in H stripes
-    (``hstripe_layer_run``: each stripe is under the gate, so XLA's own),
-    the shortcut conv left beside it is at ``_WFOLD_MAX_PIXELS`` and keeps
-    the striped path with the stem, and the 64-channel stage at 1024² folds
-    by 2: 12 3×3 64→64 and 12 1×1 64→128."""
+    (``hstripe_layer_run``: each stripe is under the gate, so its 3×3 are
+    XLA's own and its 12 1×1 16→64 matrix products, on the stripe), the
+    shortcut conv left beside it is at ``_WFOLD_MAX_PIXELS`` and keeps the
+    striped path with the stem, the 64-channel stage at 1024² folds by 2 (12
+    3×3 64→64 and 12 1×1 64→128), and the 12 1×1 128→256 at 512² stay
+    XLA's (both widths fill the lanes)."""
     from mpi4dl_tpu.models.resnet import get_resnet_v2
 
     _trace_model(get_resnet_v2((1, 2048, 2048, 3), depth=110,
                                num_classes=1000), jnp.bfloat16)
     assert rec.conv_paths() == {"wfold": 24, "hstripe": 2, "phase": 4,
-                                "xla": 82}
+                                "xla": 70, "dot": 12}
     # no run is folded: stage 0 is over the fold's 2²² pixels, and a block
     # of stage 1 opens with a 128-channel convolution that does not fold
     assert rec.site_paths("norm") == {"plain": 109}
@@ -389,12 +396,19 @@ def test_conv_paths_of_resnet_at_2048(rec):
 def test_conv_paths_of_the_amoebanet_cell(rec):
     """``amoebanet_d.2048.bs1`` as it is: the stem is strided and every
     other convolution at a million pixels is wider than 64 channels, so the
-    traffic bypasses the fold and the stripes."""
+    traffic bypasses the fold and the stripes.  Of its 186 pointwise
+    convolutions at stride 1 (the cells' ``reduce1``/``reduce2``, their
+    ``conv_1x1`` and both ends of each ``conv_1x7_7x1`` bottleneck) the 154
+    with a width of 52, 104, 208, 416 or 832 are matrix products; XLA's own
+    are the 32 between multiples of 128 (1664, 3328, 4992, 6656: the third
+    group's, and the reduction into it) and the 80 1×7 and 7×1; the strided
+    13 (stem, 3×3 and the factorized reductions' 1×1 at stride 2) take the
+    phase form."""
     from mpi4dl_tpu.models.amoebanet import amoebanetd
 
     _trace_model(amoebanetd((1, 2048, 2048, 3), num_classes=1000,
                             num_layers=18, num_filters=416), jnp.bfloat16)
-    assert rec.conv_paths() == {"phase": 13, "xla": 266}
+    assert rec.conv_paths() == {"phase": 13, "xla": 112, "dot": 154}
     assert set(rec.site_paths("norm")) == {"plain"}
 
 
