@@ -215,7 +215,7 @@ def hatches_markdown(include_internal: bool = False) -> str:
 @dataclasses.dataclass
 class ParallelConfig:
     # --- model / problem (reference parser.py) ---
-    model: str = "resnet"  # resnet | amoebanet | lfm2_moe
+    model: str = "resnet"  # resnet | amoebanet | lfm2_moe | deepseek_v3
     batch_size: int = 32
     parts: int = 1  # micro-batches per step (GPipe "parts")
     split_size: int = 1  # number of pipeline stages (LP splits)
@@ -232,10 +232,13 @@ class ParallelConfig:
     num_layers: int = 18  # amoebanet cell count knob
     num_filters: int = 416
     num_classes: int = 10
-    # --- token models (lfm2_moe): the cut and the job; the published sizes
-    # are models/lfm2.py's own.  A sample is a sequence of seq_len ids below
+    # --- token models (lfm2_moe, deepseek_v3): the cut and the job; the
+    # published sizes are the model file's own (models/lfm2.py,
+    # models/deepseek_v3.py).  A sample is a sequence of seq_len ids below
     # vocab_size; of the published experts this process holds experts_held,
-    # from expert_first (one chip's share under expert parallelism).
+    # from expert_first (one chip's share under expert parallelism).  The
+    # defaults are within both models' published counts, the uncut model of
+    # neither but lfm2_moe's.
     seq_len: int = 128
     vocab_size: int = 65536
     experts_held: int = 64
@@ -379,10 +382,13 @@ def get_parser() -> argparse.ArgumentParser:
     p.add_argument("--seq-len", type=int, default=128,
                    help="token models: ids a sequence")
     p.add_argument("--vocab-size", type=int, default=65536,
-                   help="token models: rows of the vocabulary held here")
+                   help="token models: rows of the vocabulary held here, at "
+                        "most the model's own (lfm2_moe 65536, deepseek_v3 "
+                        "128256)")
     p.add_argument("--experts-held", type=int, default=64,
                    help="token models: routed experts this process holds "
-                        "(64 of 64 is the uncut layer)")
+                        "(all of the model's is the uncut layer: lfm2_moe 64, "
+                        "deepseek_v3 128)")
     p.add_argument("--expert-first", type=int, default=0,
                    help="token models: the first expert held")
     p.add_argument("--balance", type=str, default=None)

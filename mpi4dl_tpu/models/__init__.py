@@ -1,6 +1,7 @@
 from mpi4dl_tpu.models.resnet import get_resnet_v1, get_resnet_v2, get_resnet
 from mpi4dl_tpu.models.amoebanet import amoebanetd
 from mpi4dl_tpu.models.lfm2 import lfm2_moe
+from mpi4dl_tpu.models import deepseek_v3  # the module: its builder has its name
 from mpi4dl_tpu.models.seqblock import SeqBlock, make_seq_cp_train_step
 
 __all__ = [
@@ -30,15 +31,18 @@ def _amoebanet(cfg, in_shape):
     )
 
 
-def _lfm2_moe(cfg, in_shape):
-    return lfm2_moe(
-        in_shape,
-        num_layers=cfg.num_layers,
-        vocab_size=cfg.vocab_size,
-        experts_held=cfg.experts_held,
-        expert_first=cfg.expert_first,
-        compute_dtype=cfg.compute_dtype,
-    )
+def _token_model(builder):
+    """A token model's builder from the flags that state its cut."""
+    def build(cfg, in_shape):
+        return builder(
+            in_shape,
+            num_layers=cfg.num_layers,
+            vocab_size=cfg.vocab_size,
+            experts_held=cfg.experts_held,
+            expert_first=cfg.expert_first,
+            compute_dtype=cfg.compute_dtype,
+        )
+    return build
 
 
 # ``--model`` -> (what a sample is, the builder).  A sample is an ``image``
@@ -49,7 +53,8 @@ def _lfm2_moe(cfg, in_shape):
 MODELS = {
     "resnet": ("image", _resnet),
     "amoebanet": ("image", _amoebanet),
-    "lfm2_moe": ("tokens", _lfm2_moe),
+    "lfm2_moe": ("tokens", _token_model(lfm2_moe)),
+    "deepseek_v3": ("tokens", _token_model(deepseek_v3.deepseek_v3)),
 }
 
 
@@ -68,8 +73,9 @@ def build_model(cfg):
     formula 9n+2 (reference hardcodes n=12 → ResNet-110-v2 per benchmark,
     benchmark_resnet_sp.py:161-163; pass --num-layers 12 for parity).  For
     amoebanet it is the NAS cell count as in the reference parser.  For
-    lfm2_moe (a token model: ``[B, S]`` ids in) it is the layers as run, and
-    the vocabulary rows and the experts held come from their own flags."""
+    lfm2_moe and deepseek_v3 (token models: ``[B, S]`` ids in) it is the
+    layers as run, and the vocabulary rows and the experts held come from
+    their own flags."""
     input_kind(cfg.model)  # an unknown model is refused before its shape is asked
     in_shape = (cfg.batch_size // cfg.parts, *cfg.sample_shape)
     return MODELS[cfg.model][1](cfg, in_shape)

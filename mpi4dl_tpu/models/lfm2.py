@@ -27,7 +27,7 @@ router's scores, the softmax and the loss are computed in float32.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Mapping, Tuple
+from typing import Any, Dict, Mapping, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -274,27 +274,29 @@ def _block(config: Lfm2MoeConfig, layer: int, experts_held: int,
                      name=f"layer{layer:02d}_{kind}")
 
 
-def lfm2_moe(in_shape: Tuple[int, int], *, num_layers: int, vocab_size: int,
-             experts_held: int, expert_first: int = 0,
-             compute_dtype=jnp.float32,
-             config: Lfm2MoeConfig = PUBLISHED) -> CellModel:
-    """The model on ``in_shape = (batch, seq_len)`` int32 ids below
-    ``vocab_size``: embedding, ``num_layers`` layers, final norm and head;
-    the logits are ``[batch, seq_len, vocab_size]`` in float32."""
-    d = config.hidden_size
-    if not 1 <= vocab_size <= config.vocab_size:
-        raise ValueError(f"--vocab-size {vocab_size} of {config.vocab_size}")
+def embed_cell(vocab_size: int, features: int, compute_dtype,
+               std: float = EMBED_STD) -> FnCell:
+    """``[B, S]`` ids to ``[B, S, features]`` in the compute dtype; the table
+    normal with standard deviation ``std``."""
 
     def embed_init(key, shape):
-        table = jax.random.normal(key, (vocab_size, d), jnp.float32) * EMBED_STD
-        return {"table": table}, (*shape, d)
+        table = jax.random.normal(
+            key, (vocab_size, features), jnp.float32) * std
+        return {"table": table}, (*shape, features)
 
     def embed(p, ids, ctx):
         # a pipeline stage's input arrives in the compute dtype
         return jnp.take(p["table"].astype(compute_dtype),
                         ids.astype(jnp.int32), axis=0)
 
-    norm, head = RMSNorm(d, config.norm_eps), Dense(d, vocab_size, use_bias=False)
+    return FnCell(embed_init, embed, "embed")
+
+
+def head_cell(vocab_size: int, features: int, eps: float) -> FnCell:
+    """The final RMSNorm and the head (its own parameter, not the embedding's):
+    logits ``[B, S, vocab_size]`` in float32."""
+    norm = RMSNorm(features, eps)
+    head = Dense(features, vocab_size, use_bias=False)
 
     def head_init(key, shape):
         return {"norm": norm.init(None, shape)[0],
@@ -305,20 +307,22 @@ def lfm2_moe(in_shape: Tuple[int, int], *, num_layers: int, vocab_size: int,
         return jnp.dot(x, p["head"]["kernel"].astype(x.dtype),
                        preferred_element_type=jnp.float32)
 
-    blocks = [_block(config, layer, experts_held, expert_first)
-              for layer in layers_run(config, num_layers)]
-    routed = [i + 1 for i, b in enumerate(blocks)
-              if isinstance(b.ffn, RoutedExperts)]
+    return FnCell(head_init, head_apply, "norm_head")
+
+
+def routed_step_metrics(routed: Sequence[int], top_k: int):
+    """``CellModel.step_metrics`` of a token model whose cells ``routed`` keep
+    a routed layer's ``load`` statistic under ``ffn`` (what
+    ``ops/moe.RoutedExperts`` leaves there): the rows computed here (over all
+    expert layers), the assignments made (tokens x experts a token x expert
+    layers), and the largest held expert's load over the mean of its layer,
+    the worst layer's."""
 
     def step_metrics(params, tokens: int) -> Dict[str, jax.Array]:
-        """What a step routed, from the expert layers' ``load`` statistic:
-        the rows computed here (over all expert layers), the assignments
-        made (tokens x experts a token x expert layers), and the largest
-        held expert's load over the mean of its layer, the worst layer's."""
         if not routed:
             return {}
         load = jnp.stack([params[i]["ffn"]["load"] for i in routed])
-        per_layer = tokens * config.num_experts_per_tok
+        per_layer = tokens * top_k
         return {
             "expert_rows": jnp.round(jnp.sum(load) * per_layer),
             "expert_assignments": jnp.float32(per_layer * len(routed)),
@@ -326,8 +330,25 @@ def lfm2_moe(in_shape: Tuple[int, int], *, num_layers: int, vocab_size: int,
                 jnp.max(load, axis=1) / jnp.maximum(jnp.mean(load, axis=1), 1e-30)),
         }
 
+    return step_metrics
+
+
+def lfm2_moe(in_shape: Tuple[int, int], *, num_layers: int, vocab_size: int,
+             experts_held: int, expert_first: int = 0,
+             compute_dtype=jnp.float32,
+             config: Lfm2MoeConfig = PUBLISHED) -> CellModel:
+    """The model on ``in_shape = (batch, seq_len)`` int32 ids below
+    ``vocab_size``: embedding, ``num_layers`` layers, final norm and head;
+    the logits are ``[batch, seq_len, vocab_size]`` in float32."""
+    d = config.hidden_size
+    if not 1 <= vocab_size <= config.vocab_size:
+        raise ValueError(f"--vocab-size {vocab_size} of {config.vocab_size}")
+    blocks = [_block(config, layer, experts_held, expert_first)
+              for layer in layers_run(config, num_layers)]
+    routed = [i + 1 for i, b in enumerate(blocks)
+              if isinstance(b.ffn, RoutedExperts)]
     return CellModel(
-        [FnCell(embed_init, embed, "embed"), *blocks,
-         FnCell(head_init, head_apply, "norm_head")],
+        [embed_cell(vocab_size, d, compute_dtype), *blocks,
+         head_cell(vocab_size, d, config.norm_eps)],
         tuple(in_shape), vocab_size, name="lfm2_moe",
-        step_metrics=step_metrics)
+        step_metrics=routed_step_metrics(routed, config.num_experts_per_tok))

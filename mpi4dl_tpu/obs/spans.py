@@ -79,17 +79,22 @@ CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 # (1x1, stride 1, no padding, one group) with a width that is no multiple of
 # the 128 lanes, as a matrix product over channels.
 CONV_PATHS = ("wfold", "hstripe", "phase", "xla", "dot")
-# Which kernel a site of another kind was traced with: attention
-# (models/lfm2.Attention: the Pallas block kernel of ops/pallas_attention.py
-# or the einsum form) and the routed experts' grouped product (ops/moe.py:
-# ``lax.ragged_dot``, the one path); and on which form of the activation a
+# Which kernel a site of another kind was traced with: attention (the Pallas
+# block kernel of ops/pallas_attention.py or the einsum form; of
+# models/lfm2.Attention, and, as ``latent_*``, of
+# models/deepseek_v3.LatentAttention, whose keys are wider than its values),
+# the routed experts' grouped product (ops/moe.py: ``lax.ragged_dot``, the
+# one path) and the shared expert beside them (models/deepseek_v3.py: a
+# SwiGLU of dense products, the one path); and on which form of the activation a
 # BatchNorm took its sums and applied its affine: ``[N, H, W/p, p·C]`` inside
 # a folded run (``layers.run_fold``), or ``[N, H, W, C]``.
 SITE_PATHS = {
     "conv": CONV_PATHS,
     "norm": ("folded", "plain"),
-    "attention": ("block_flash", "einsum"),
+    "attention": ("block_flash", "einsum", "latent_block_flash",
+                  "latent_einsum"),
     "experts": ("ragged_dot",),
+    "shared_expert": ("swiglu",),
 }
 
 # At least 4,000 steps of the loop's spans (nine a step with the loader's).
@@ -360,8 +365,8 @@ class Recorder:
         events summed by kind with the longest three by program, and the
         programs built or loaded inside a step with its ``gstep`` (a program
         that appears twice was retraced); ``conv_paths`` and ``norm_paths``;
-        and, where the model has such sites, ``attention_paths`` and
-        ``expert_paths``."""
+        and, where the model has such sites, ``attention_paths``,
+        ``expert_paths`` and ``shared_expert_paths``."""
         spans = sorted((s for s in list(self._closed)
                         if s.name.startswith(SETUP_PREFIXES)),
                        key=lambda s: s.start_ns)
@@ -391,7 +396,8 @@ class Recorder:
         }
         # only where the model has such sites
         for key, kind in (("attention_paths", "attention"),
-                          ("expert_paths", "experts")):
+                          ("expert_paths", "experts"),
+                          ("shared_expert_paths", "shared_expert")):
             paths = self.site_paths(kind)
             if paths:
                 out[key] = paths

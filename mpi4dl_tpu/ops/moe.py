@@ -51,18 +51,22 @@ def round_capacity(assignments: int, held: int, total: int) -> int:
     return min(rows, math.ceil(assignments / ROW_TILE) * ROW_TILE)
 
 
-def route(x, kernel, bias, top_k: int, scaling: float = 1.0):
+def route(x, kernel, bias, top_k: int, scaling: float = 1.0,
+          sum_eps: float = 1e-6):
     """The router, in float32: ``s = sigmoid(x W_r)``; a token's experts are
     the ``top_k`` of ``s + bias`` (the bias enters the choice only and takes
-    no gradient); their weights are the chosen ``s`` over their sum + 1e-6,
-    times ``scaling``.  Returns ``(experts [N, k] int32, weights [N, k])``."""
+    no gradient); their weights are the chosen ``s`` over their sum +
+    ``sum_eps``, times ``scaling``.  ``sum_eps`` is the model's own constant
+    (``lfm2_moe`` publishes 1e-6, ``deepseek_v3`` 1e-20: beside a sum of
+    ``top_k`` sigmoids the two differ in the weights' seventh digit).
+    Returns ``(experts [N, k] int32, weights [N, k])``."""
     logits = jnp.dot(x.astype(jnp.float32), kernel.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
     _, chosen = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)),
                           top_k)
     w = jnp.take_along_axis(scores, chosen, axis=-1)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6) * scaling
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + sum_eps) * scaling
     return chosen, w
 
 
@@ -123,7 +127,7 @@ def _grouped_dot(lhs, rhs, sizes):
 
 
 def routed_experts(x, router, experts, *, first: int, held: int, total: int,
-                   top_k: int, scaling: float = 1.0
+                   top_k: int, scaling: float = 1.0, sum_eps: float = 1e-6
                    ) -> Tuple[jax.Array, jax.Array]:
     """``x [N, D]`` through the held experts' part of the layer.
 
@@ -133,7 +137,8 @@ def routed_experts(x, router, experts, *, first: int, held: int, total: int,
     all ``N * top_k`` assignments that fell on it (float32, ``[held]``)."""
     n, _ = x.shape
     a = n * top_k
-    chosen, weights = route(x, router["kernel"], router["bias"], top_k, scaling)
+    chosen, weights = route(x, router["kernel"], router["bias"], top_k, scaling,
+                            sum_eps)
 
     # Counting sort of the assignments by held expert; group ``held`` takes
     # those of absent experts, behind all the others.
@@ -212,6 +217,7 @@ class RoutedExperts(Layer):
     held: int
     first: int = 0
     scaling: float = 1.0
+    sum_eps: float = 1e-6  # beside the chosen scores' sum (see route)
 
     def __post_init__(self):
         if not (0 <= self.first and self.first + self.held <= self.total
@@ -241,7 +247,7 @@ class RoutedExperts(Layer):
         y, load = routed_experts(
             x.reshape(b * s, d), params["router"], params["experts"],
             first=self.first, held=self.held, total=self.total,
-            top_k=self.top_k, scaling=self.scaling)
+            top_k=self.top_k, scaling=self.scaling, sum_eps=self.sum_eps)
         if ctx.bn_sink is not None:
             ctx.bn_sink[id(params["load"])] = load
         return y.reshape(b, s, d)

@@ -154,8 +154,10 @@ def _any_vma(*arrays) -> bool:
 
 def _block_flash_fwd_impl(q, k, v, q_off, k_off, *, causal, scale,
                           tq, tk, interpret):
-    """Pallas forward.  q: [BH, Tq, D]; k, v: [BH, Tk_total, D] (fp32/bf16).
-    Returns (o_hat [BH, Tq, D] fp32, m [BH, Tq] fp32, l [BH, Tq] fp32)."""
+    """Pallas forward.  q: [BH, Tq, D]; k: [BH, Tk_total, D]; v: [BH,
+    Tk_total, Dv] (fp32/bf16); Dv may differ from D (latent attention: keys
+    of 192, values of 128), each padded to the lanes on its own.
+    Returns (o_hat [BH, Tq, Dv] fp32, m [BH, Tq] fp32, l [BH, Tq] fp32)."""
     if interpret and _any_vma(q, k, v, q_off, k_off):
         # Interpret-mode pallas_call under shard_map trips the vma checker
         # (its BlockSpec emulation dynamic_slices varying operands with
@@ -165,14 +167,15 @@ def _block_flash_fwd_impl(q, k, v, q_off, k_off, *, causal, scale,
         return _reference_mlo(q, k, v, q_off, k_off, causal, scale)
     bh, t_q, d = q.shape
     _, t_k, _ = k.shape
+    dv = v.shape[-1]
     tq = min(tq, _round_up(t_q, 8))
     tk = min(tk, _round_up(t_k, 128))
     tq_p = _round_up(t_q, tq)
     tk_p = _round_up(t_k, tk)
-    d_p = _round_up(d, _LANES)
+    d_p, dv_p = _round_up(d, _LANES), _round_up(dv, _LANES)
     qp = jnp.pad(q, ((0, 0), (0, tq_p - t_q), (0, d_p - d)))
     kp = jnp.pad(k, ((0, 0), (0, tk_p - t_k), (0, d_p - d)))
-    vp = jnp.pad(v, ((0, 0), (0, tk_p - t_k), (0, d_p - d)))
+    vp = jnp.pad(v, ((0, 0), (0, tk_p - t_k), (0, dv_p - dv)))
     # Padded key slots (a q·0 = 0 score would pollute m/l) are masked inside
     # the kernel by local column id against the static t_k.
     nq, nk = tq_p // tq, tk_p // tk
@@ -188,15 +191,15 @@ def _block_flash_fwd_impl(q, k, v, q_off, k_off, *, causal, scale,
             in_specs=[
                 pl.BlockSpec((1, tq, d_p), lambda b, i, j, offs: (b, i, 0)),
                 pl.BlockSpec((1, tk, d_p), lambda b, i, j, offs: (b, j, 0)),
-                pl.BlockSpec((1, tk, d_p), lambda b, i, j, offs: (b, j, 0)),
+                pl.BlockSpec((1, tk, dv_p), lambda b, i, j, offs: (b, j, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, tq, d_p), lambda b, i, j, offs: (b, i, 0)),
+                pl.BlockSpec((1, tq, dv_p), lambda b, i, j, offs: (b, i, 0)),
                 pl.BlockSpec((1, tq, _LANES), lambda b, i, j, offs: (b, i, 0)),
                 pl.BlockSpec((1, tq, _LANES), lambda b, i, j, offs: (b, i, 0)),
             ],
             scratch_shapes=[
-                pltpu.VMEM((tq, d_p), jnp.float32),
+                pltpu.VMEM((tq, dv_p), jnp.float32),
                 pltpu.VMEM((tq, _LANES), jnp.float32),
                 pltpu.VMEM((tq, _LANES), jnp.float32),
             ],
@@ -204,7 +207,7 @@ def _block_flash_fwd_impl(q, k, v, q_off, k_off, *, causal, scale,
         out_shape=_out_structs(
             (qp, kp, vp, offs),
             [
-                ((bh, tq_p, d_p), jnp.float32),
+                ((bh, tq_p, dv_p), jnp.float32),
                 ((bh, tq_p, _LANES), jnp.float32),
                 ((bh, tq_p, _LANES), jnp.float32),
             ],
@@ -213,8 +216,7 @@ def _block_flash_fwd_impl(q, k, v, q_off, k_off, *, causal, scale,
         name="block_flash_fwd",
     )
     o, m, l = kern(offs, qp, kp, vp)
-    d_out = q.shape[-1]
-    return o[:, :t_q, :d_out], m[:, :t_q, 0], l[:, :t_q, 0]
+    return o[:, :t_q, :dv], m[:, :t_q, 0], l[:, :t_q, 0]
 
 
 def _reference_mlo(q, k, v, q_off, k_off, causal, scale):
@@ -240,7 +242,8 @@ def block_flash(q, k, v, q_off, k_off, causal=False, scale=1.0,
                 tq=256, tk=512, interpret=False):
     """Unnormalized flash partial state of one attention block.
 
-    q: [BH, Tq, D]; k, v: [BH, Tk, D]; ``q_off``/``k_off``: scalar GLOBAL
+    q: [BH, Tq, D]; k: [BH, Tk, D]; v: [BH, Tk, Dv] (Dv = D, or a width of
+    its own; ``o_hat`` is Dv wide); ``q_off``/``k_off``: scalar GLOBAL
     position offsets (traced values allowed — they ride scalar prefetch).
     Returns ``(o_hat, m, l)`` with ``o_hat = exp(s - m) @ v`` and
     ``l = rowsum(exp(s - m))``; combine across blocks with
@@ -275,6 +278,9 @@ def _block_flash_bwd(causal, scale, tq, tk, interpret, res, cts):
     exactly nothing.  The products take q, k, v as they come (and dô in
     their dtype) and accumulate in float32.
 
+    Of the five products s, dq and dk run at the key width D, and dP and dv
+    at the value width Dv (dô is Dv wide).
+
     With ô = P·V, l = rowsum(P), P = exp(s - m) (m treated as a constant
     plateau — its cotangent is zero almost everywhere):
         dP = dô Vᵀ + dl·1ᵀ ;  ds = P ⊙ dP
@@ -284,7 +290,7 @@ def _block_flash_bwd(causal, scale, tq, tk, interpret, res, cts):
     do, dm, dl = cts  # dm is zero a.e.; fold dl into dP
     del o, dm, l
     bh, t_q, d = q.shape
-    t_k = k.shape[1]
+    t_k, dv = k.shape[1], v.shape[-1]
     f32 = jnp.float32
     del tq, tk  # the forward kernel's; the backward's tiles are its own
     nk = max(1, (t_k + _BWD_TK - 1) // _BWD_TK)
@@ -319,7 +325,7 @@ def _block_flash_bwd(causal, scale, tq, tk, interpret, res, cts):
             return t
 
     def k_tile(dq_acc, inp):
-        kt, vt, ids = inp  # [BH, tk_c, D], [BH, tk_c, D], [tk_c]
+        kt, vt, ids = inp  # [BH, tk_c, D], [BH, tk_c, Dv], [tk_c]
 
         def q_tile(carry, qin):
             dk_t, dv_t, dq_acc = carry
@@ -361,15 +367,17 @@ def _block_flash_bwd(causal, scale, tq, tk, interpret, res, cts):
             return (dk_t, dv_t, dq_acc), None
 
         zero = vary(jnp.zeros((bh, tk_c, d), f32))
+        zero_v = zero if dv == d else vary(jnp.zeros((bh, tk_c, dv), f32))
         (dk_t, dv_t, dq_acc), _ = lax.scan(
-            q_tile, (zero, zero, dq_acc),
+            q_tile, (zero, zero_v, dq_acc),
             (jnp.arange(nq, dtype=jnp.int32), *q_tiles))
         return dq_acc, (dk_t, dv_t)
 
     dq0 = vary(jnp.zeros((nq, bh, tq_c, d), f32))
     dq, (dks, dvs) = lax.scan(k_tile, dq0, (kts, vts, k_ids))
     dq = jnp.moveaxis(dq, 0, 1).reshape(bh, nq * tq_c, d)
-    untile = lambda x: jnp.moveaxis(x, 0, 1).reshape(bh, nk * tk_c, d)[:, :t_k]
+    untile = lambda x: jnp.moveaxis(x, 0, 1).reshape(
+        bh, nk * tk_c, x.shape[-1])[:, :t_k]
     # Integer (position-offset) primals take float0 cotangents.
     import numpy as np
 
@@ -402,16 +410,18 @@ def flash_attention_local(q, k, v, causal=False, scale=None,
                           interpret=False):
     """Single-device exact attention via the block kernel.
 
-    q, k, v: [B, T, H, D] (the ring module's layout).  Returns [B, T, H, D]
-    in q.dtype.  Memory: never materializes [T, T] scores.
+    q, k: [B, T, H, D]; v: [B, T, H, Dv] (the ring module's layout).
+    Returns [B, T, H, Dv] in q.dtype.  Memory: never materializes [T, T]
+    scores.
     """
     b, t, h, d = q.shape
     sc = scale if scale is not None else float(1.0 / (d ** 0.5))
-    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(
+        b * h, x.shape[1], x.shape[3])
     zero = jnp.zeros((), jnp.int32)
     o, m, l = block_flash(
         fold(q), fold(k), fold(v), zero, zero, causal, sc, *LOCAL_TILES,
         interpret,
     )
     out = o / jnp.maximum(l, 1e-30)[..., None]
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3).astype(q.dtype)
+    return out.reshape(b, h, t, v.shape[3]).transpose(0, 2, 1, 3).astype(q.dtype)

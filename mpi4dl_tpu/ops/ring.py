@@ -101,7 +101,8 @@ def ring_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Exact attention over a sequence sharded on `axis_name` ([B, T_local,
-    H, D] per device).  K/V blocks rotate around the ring; each device folds
+    H, D] per device; v may have a width of its own, which is the output's).
+    K/V blocks rotate around the ring; each device folds
     every block into its queries' output with the online-softmax update
 
         m' = max(m, rowmax(s));  c = exp(m - m')
@@ -176,7 +177,7 @@ def ring_attention(
     vcast = lambda t_: pcast(t_, (axis_name,), to="varying")
     m0 = vcast(jnp.full((b, h, t), -jnp.inf, jnp.float32))
     l0 = vcast(jnp.zeros((b, h, t), jnp.float32))
-    o0 = vcast(jnp.zeros((b, h, t, d), jnp.float32))
+    o0 = vcast(jnp.zeros((b, h, t, v.shape[3]), jnp.float32))
     (_, _, _, _, l, o), _ = lax.scan(body, (k, v, my, m0, l0, o0), None, length=n)
     out = o / jnp.maximum(l[..., None], 1e-30)
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
@@ -198,7 +199,8 @@ def _ring_attention_flash(q, k, v, axis_name, n, causal, scale, interpret):
     b, t, h, d = q.shape
     my = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
-    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[3])
+    dv = v.shape[3]  # the values' width, and the output's
     qf = fold(q)
     q_off = my * t
 
@@ -236,9 +238,9 @@ def _ring_attention_flash(q, k, v, axis_name, n, causal, scale, interpret):
 
     m0 = vcast(jnp.full((b * h, t), _NEG_INF, jnp.float32))
     l0 = vcast(jnp.zeros((b * h, t), jnp.float32))
-    o0 = vcast(jnp.zeros((b * h, t, d), jnp.float32))
+    o0 = vcast(jnp.zeros((b * h, t, dv), jnp.float32))
     (_, _, _, _, l, o), _ = lax.scan(
         body, (k, v, my, m0, l0, o0), None, length=n
     )
     out = o / jnp.maximum(l, 1e-30)[..., None]
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3).astype(q.dtype)
+    return out.reshape(b, h, t, dv).transpose(0, 2, 1, 3).astype(q.dtype)

@@ -219,6 +219,8 @@ def test_factorized_reduce_keeps_its_fusion_barrier():
                        num_classes=10)),
     ("lfm2_moe", dict(num_layers=2, seq_len=64, vocab_size=512,
                       experts_held=8)),
+    ("deepseek_v3", dict(num_layers=2, seq_len=64, vocab_size=512,
+                         experts_held=16)),
 ])
 def test_every_convolution_of_a_model_takes_one_of_the_five_paths(
         monkeypatch, rec, name, sizes):
@@ -236,7 +238,7 @@ def test_every_convolution_of_a_model_takes_one_of_the_five_paths(
     from mpi4dl_tpu.models import MODELS, build_model
     from mpi4dl_tpu.obs import spans
 
-    assert set(MODELS) == {"resnet", "amoebanet", "lfm2_moe"}
+    assert set(MODELS) == {"resnet", "amoebanet", "lfm2_moe", "deepseek_v3"}
     assert spans.CONV_PATHS == ("wfold", "hstripe", "phase", "xla", "dot")
     for key in [k for k in os.environ if k.startswith("MPI4DL_")]:
         monkeypatch.delenv(key)

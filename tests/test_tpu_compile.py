@@ -307,6 +307,31 @@ def test_amoebanet_normal_cell_keeps_no_transposes_round_its_1x1_for_v5e(
     assert sum(size for _, _, size in copies) < 4_634_707_584 / 2
 
 
+def _compile_routed_experts(struct, *, held, total, top_k, ffn, **route):
+    """``ops.moe.routed_experts`` forward and backward over 32,768 tokens of
+    2,048 in bf16 (float32 parameters), under a ``"highest"`` default,
+    compiled for the chip ``struct`` places its shapes on."""
+    from mpi4dl_tpu.ops import moe
+
+    def experts(x, router, weights):
+        def loss(x, router, weights):
+            y, _ = moe.routed_experts(x, router, weights, first=0, held=held,
+                                      total=total, top_k=top_k, **route)
+            return jnp.sum(y.astype(jnp.float32))
+
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(loss, (0, 1, 2))(x, router, weights)
+
+    f32 = jnp.float32
+    compiled = jax.jit(experts).lower(
+        struct((32768, 2048)),
+        {"kernel": struct((2048, total), f32), "bias": struct((total,), f32)},
+        {"w1": struct((held, 2048, ffn), f32), "w3": struct((held, 2048, ffn), f32),
+         "w2": struct((held, ffn, 2048), f32)}).compile()
+    _assert_mosaic(compiled)
+    return compiled
+
+
 def test_lfm2_kernels_compile_for_v5e_under_a_highest_default(
         one_chip, no_persistent_cache, monkeypatch):
     """The token model's two kernels at its published widths, bf16, forward
@@ -318,27 +343,10 @@ def test_lfm2_kernels_compile_for_v5e_under_a_highest_default(
     experts), which XLA:TPU compiles to instructions named ``ragged-dot-*``:
     the names by which the benchmark's ``expert_ffn_ms`` picks them from the
     trace.  Attention is one sequence of 8,192 tokens, 32 heads of 64."""
-    from mpi4dl_tpu.ops import moe
-
     def struct(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def experts(x, router, weights):
-        def loss(x, router, weights):
-            y, _ = moe.routed_experts(x, router, weights, first=0, held=8,
-                                      total=64, top_k=4)
-            return jnp.sum(y.astype(jnp.float32))
-
-        with jax.default_matmul_precision("highest"):
-            return jax.grad(loss, (0, 1, 2))(x, router, weights)
-
-    f32 = jnp.float32
-    compiled = jax.jit(experts).lower(
-        struct((32768, 2048)),
-        {"kernel": struct((2048, 64), f32), "bias": struct((64,), f32)},
-        {"w1": struct((8, 2048, 1536), f32), "w3": struct((8, 2048, 1536), f32),
-         "w2": struct((8, 1536, 2048), f32)}).compile()
-    _assert_mosaic(compiled)
+    compiled = _compile_routed_experts(struct, held=8, total=64, top_k=4, ffn=1536)
     products = re.findall(
         r"%ragged-dot-\w+(?:\.\d+)? = bf16\[(20480,1536|20480,2048|8,2048,1536|"
         r"8,1536,2048)\]", compiled.as_text())
@@ -354,3 +362,79 @@ def test_lfm2_kernels_compile_for_v5e_under_a_highest_default(
         return out, vjp(jnp.ones_like(out))
 
     _assert_mosaic(jax.jit(attention).lower(qkv, qkv, qkv).compile())
+
+
+def _executed_keys(text):
+    """``name:type[shape]`` of every instruction of a compiled module that
+    runs on its own (not inside a fusion), as ``perfbench.trace.op_key``
+    names a trace event."""
+    from perfbench.trace import op_key
+
+    keys, fused = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):  # a computation's header, or "}"
+            fused = line.startswith(("%fused_", "fused_"))
+        elif not fused and " = " in line:
+            keys.append(op_key(line.strip().removeprefix("ROOT ")))
+    return keys
+
+
+def test_deepseek_v3_kernels_compile_for_v5e_under_the_names_the_metrics_pick(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The second token model's two kernels at its published widths, bf16,
+    forward and backward, under a ``"highest"`` default as the benchmark's
+    check traces them.  Latent attention whole (one sequence of 8,192 tokens
+    through ``LatentAttention``: keys of 192, values of 128, the Pallas path
+    asked for as on a TPU backend): the compiled layer holds the forward
+    kernel and the backward's tiles at both widths, which are the names
+    ``mla_attention_ms`` picks, and the pattern picks nothing of the layer's
+    projections.  The grouped product at 32,768 tokens, 16 of 128 experts of
+    768, six a token: ``ragged-dot-*`` at this configuration's shapes."""
+    import json
+
+    import mpi4dl_tpu.config as config
+    from mpi4dl_tpu.layer_ctx import ApplyCtx
+    from mpi4dl_tpu.models import deepseek_v3
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perfbench", "layer_metrics",
+                           "mla_attention_ms.json")) as f:
+        pattern = re.compile(json.load(f)["params"]["pattern"])
+    monkeypatch.setattr(config, "is_tpu_backend", lambda: True)
+    layer = deepseek_v3._block(deepseek_v3.PUBLISHED, 1, 16, 0).op
+
+    def struct(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: struct(a.shape, a.dtype),
+        jax.eval_shape(lambda: layer.init(jax.random.key(0), (1, 8192, 2048))[0]))
+
+    def attention(p, x):
+        def loss(p, x):
+            return jnp.sum(layer.apply(p, x, ApplyCtx(train=True))
+                           .astype(jnp.float32))
+
+        with jax.default_matmul_precision("highest"):
+            return jax.grad(loss, (0, 1))(p, x)
+
+    compiled = jax.jit(attention).lower(params, struct((1, 8192, 2048))).compile()
+    _assert_mosaic(compiled)
+    keys = _executed_keys(compiled.as_text())
+    picked = {k for k in keys if pattern.search(k)}
+    assert "block_flash_fwd:f32[32,8192,128]" in picked, sorted(picked)
+    shapes = {k.split(":")[1] for k in picked}
+    for tile in ("[32,1024,512]", "[32,1024,192]", "[32,512,192]", "[32,512,128]"):
+        assert any(s.endswith(tile) for s in shapes), (tile, sorted(shapes))
+    # the copies that bring dq's tile to its accumulator, eight heads at a time
+    assert "slice-done:f32[8,1024,192]" in picked, sorted(picked)
+    # nothing of the projections, the norms or the rotary embedding
+    assert not [k for k in picked if re.search(r"\[(1,)?8192,|2048|4096|6144", k)
+                and not k.startswith("block_flash_fwd")], sorted(picked)
+
+    compiled = _compile_routed_experts(struct, held=16, total=128, top_k=6,
+                                       ffn=768, scaling=2.448, sum_eps=1e-20)
+    products = re.findall(
+        r"%ragged-dot-\w+(?:\.\d+)? = bf16\[(30720,768|30720,2048|16,2048,768|"
+        r"16,768,2048)\]", compiled.as_text())
+    assert len(products) >= 9 and len(set(products)) == 4, products
