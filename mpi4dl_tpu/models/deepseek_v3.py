@@ -122,6 +122,18 @@ def rotary_interleaved(x, theta: float):
     return rotary(evens_then_odds, theta)
 
 
+def _rope_columns_apart(kernel, heads: int, nope: int, rope: int):
+    """A projection's kernel ``[d, heads·(nope + rope)]`` as ``([d,
+    heads·nope], [d, heads·rope])``: every head's ``rope`` columns apart from
+    its others, their evens before their odds, which is the order
+    :func:`rotary_interleaved` gathers an activation into."""
+    w = kernel.reshape(kernel.shape[0], heads, nope + rope)
+    pe = w[..., nope:]
+    pe = jnp.concatenate([pe[..., 0::2], pe[..., 1::2]], axis=-1)
+    return (w[..., :nope].reshape(kernel.shape[0], heads * nope),
+            pe.reshape(kernel.shape[0], heads * rope))
+
+
 @dataclasses.dataclass(frozen=True)
 class LatentAttention(Layer):
     """Causal multi-head latent attention, the parts under the config's names:
@@ -132,10 +144,13 @@ class LatentAttention(Layer):
     ``k = [k_nope, k_pe]``; scale ``(nope + rope) ** -0.5``; the output
     ``v_head`` wide a head, then ``o_proj``.
 
-    The attention itself is ``ops.ring.ring_attention`` on one shard, one
-    sequence at a time, as ``lfm2.Attention``'s: the Pallas block kernel on a
-    TPU backend (keys ``nope + rope`` wide, values ``v_head``: the kernel pads
-    each to the lanes on its own), the einsum form elsewhere."""
+    On a TPU backend the attention itself is
+    ``ops.pallas_latent_attention.latent_flash`` on what the projections
+    wrote (:meth:`_attend_flash`): a head's columns are picked by the kernel's
+    index maps and the rotary key is read once for all heads; nothing is
+    concatenated, broadcast, transposed to heads-first or padded to the lanes
+    in HBM.  Elsewhere it is the einsum form of ``ops.ring.ring_attention`` on
+    one shard, one sequence at a time, on q and k put together a head."""
 
     features: int
     heads: int
@@ -169,7 +184,51 @@ class LatentAttention(Layer):
                 for k, (n, layer) in zip(keys, parts.items())}, in_shape
 
     def apply(self, params, x, ctx):
-        from mpi4dl_tpu.ops.ring import _resolve_flash, ring_attention
+        from mpi4dl_tpu.ops.ring import _resolve_flash
+
+        flash = _resolve_flash(None)
+        recorder().note_site(
+            "attention", self, "latent_block_flash" if flash else "latent_einsum")
+        attend = self._attend_flash if flash else self._attend_einsum
+        return self._parts()["o_proj"].apply(
+            params["o_proj"], attend(params, x, ctx), ctx)
+
+    def _attend_flash(self, params, x, ctx):
+        """``[B, S, H·v_head]`` by the Pallas kernel, from projections it can
+        read in place.  ``q_proj`` and ``kv_a_proj_with_mqa`` run as two
+        products each, the ``rope`` columns of their kernels apart from the
+        others and evens before odds (:func:`_rope_columns_apart`: the same
+        columns, so the same sums): the kernel then finds a head's ``nope``
+        columns on whole lane tiles, and the rotary embedding turns
+        contiguous halves where ``rotary_interleaved`` gathers every second
+        column of an activation (for which XLA:TPU lays the whole projection
+        out sequence-minor and copies it back)."""
+        from mpi4dl_tpu.ops import pallas_latent_attention
+
+        parts = self._parts()
+        b, s, _ = x.shape
+        h, nope, rope = self.heads, self.nope, self.rope
+
+        def product(kernel, y):
+            return Dense(*kernel.shape, use_bias=False).apply(
+                {"kernel": kernel}, y, ctx)
+
+        w_q, w_q_pe = _rope_columns_apart(params["q_proj"]["kernel"], h, nope, rope)
+        w_c, w_k_pe = _rope_columns_apart(
+            params["kv_a_proj_with_mqa"]["kernel"], 1, self.kv_rank, rope)
+        c = parts["kv_a_layernorm"].apply(
+            params["kv_a_layernorm"], product(w_c, x), ctx)
+        kv = parts["kv_b_proj"].apply(params["kv_b_proj"], c, ctx)
+        q_pe = rotary(product(w_q_pe, x).reshape(b, s, h, rope), self.rope_theta)
+        k_pe = rotary(product(w_k_pe, x)[:, :, None, :], self.rope_theta)
+        return pallas_latent_attention.latent_flash(
+            product(w_q, x), q_pe.reshape(b, s, h * rope), kv, k_pe[:, :, 0], h,
+            (nope + rope) ** -0.5)
+
+    def _attend_einsum(self, params, x, ctx):
+        """``[B, S, H·v_head]`` by ``ring_attention``'s einsum form, one
+        sequence at a time, on q and k put together a head."""
+        from mpi4dl_tpu.ops.ring import ring_attention
 
         parts = self._parts()
         b, s, _ = x.shape
@@ -188,16 +247,13 @@ class LatentAttention(Layer):
         q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_pe, (b, s, h, rope))], axis=-1)
-        recorder().note_site(
-            "attention", self,
-            "latent_block_flash" if _resolve_flash(None) else "latent_einsum")
 
         def attend(qkv):
             return ring_attention(*(t[None] for t in qkv), None, 1, causal=True,
-                                  scale=(nope + rope) ** -0.5)[0]
+                                  scale=(nope + rope) ** -0.5,
+                                  use_flash=False)[0]
 
-        o = lax.map(attend, (q, k, v))
-        return part("o_proj", o.reshape(b, s, h * self.v_head))
+        return lax.map(attend, (q, k, v)).reshape(b, s, h * self.v_head)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -82,7 +82,8 @@ CONV_PATHS = ("wfold", "hstripe", "phase", "xla", "dot")
 # Which kernel a site of another kind was traced with: attention (the Pallas
 # block kernel of ops/pallas_attention.py or the einsum form; of
 # models/lfm2.Attention, and, as ``latent_*``, of
-# models/deepseek_v3.LatentAttention, whose keys are wider than its values),
+# models/deepseek_v3.LatentAttention, whose keys are wider than its values
+# and whose Pallas forward is ops/pallas_latent_attention.py's),
 # the routed experts' grouped product (ops/moe.py: ``lax.ragged_dot``, the
 # one path) and the shared expert beside them (models/deepseek_v3.py: a
 # SwiGLU of dense products, the one path); and on which form of the activation a

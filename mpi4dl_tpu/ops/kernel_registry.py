@@ -31,6 +31,7 @@ from typing import Callable, Optional, Sequence, Tuple
 # verified must be imported here (statically parsed, never executed by the
 # analyzer).
 from mpi4dl_tpu.ops.pallas_attention import block_flash
+from mpi4dl_tpu.ops.pallas_latent_attention import latent_flash
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,12 +71,36 @@ def _flash_case(dtype: str, causal: bool):
     return KernelCase(name=f"block_flash:{variant}{dtype}", build=build)
 
 
+def _latent_case(dtype: str):
+    def build():
+        import jax.numpy as jnp
+
+        dt = jnp.dtype(dtype)
+        # Four heads of 128 + 64 with values of 128 (the published widths:
+        # two heads a grid step), 300 tokens padded to three tiles of 128:
+        # grid (1, 2, 3, 3), the padded-key masking tail in the last.
+        heads, s = 4, 300
+        q = jnp.zeros((1, s, heads * 128), dt)
+        q_pe = jnp.zeros((1, s, heads * 64), dt)
+        kv = jnp.zeros((1, s, heads * 256), dt)
+        k_pe = jnp.zeros((1, s, 64), dt)
+        fn = lambda q, q_pe, kv, k_pe: latent_flash(  # noqa: E731
+            q, q_pe, kv, k_pe, heads, 192 ** -0.5, 128, 128, False
+        )
+        return fn, (q, q_pe, kv, k_pe)
+
+    return KernelCase(name=f"latent_flash:causal:{dtype}", build=build)
+
+
 # The raw (fp32) path and the bf16 compute path the mixed-precision/quant
 # engines dispatch (quant/kernels.py itself is pure jnp — no pallas_call,
-# which rule 12 verifies stays true).
+# which rule 12 verifies stays true); latent attention's forward kernel
+# (always causal) in both.
 REGISTRY: Tuple[KernelCase, ...] = (
     _flash_case("float32", causal=False),
     _flash_case("bfloat16", causal=True),
+    _latent_case("float32"),
+    _latent_case("bfloat16"),
 )
 
 

@@ -286,6 +286,47 @@ def test_flash_attention_local_gives_the_values_width():
     _close(got, want, tol=1e-5)
 
 
+@pytest.mark.parametrize("batch, seq", [(2, 40), (1, 24)])
+def test_latent_attention_by_the_pallas_kernel_is_the_einsum_path(
+        monkeypatch, rec, batch, seq):
+    """``LatentAttention`` as a TPU backend takes it (``latent_flash`` on the
+    projections' layout, here in interpret mode: the test steers, the program
+    has no switch) against the einsum path on the same parameters: output and
+    gradients with respect to every parameter and the input.  The flash path
+    projects the ``rope`` columns apart, evens before odds, and turns halves;
+    the einsum path gathers every second column of the activation."""
+    import functools
+
+    import mpi4dl_tpu.config as config
+    from mpi4dl_tpu.ops import pallas_latent_attention
+
+    layer = dsv3._block(TINY, 1, 4, 0).op
+    params, _ = layer.init(jax.random.key(0), (batch, seq, TINY.hidden_size))
+    x = jax.random.normal(jax.random.key(1), (batch, seq, TINY.hidden_size))
+
+    def run():
+        def loss(p, x):
+            y = layer.apply(p, x, CTX)
+            return jnp.sum(jnp.sin(y)), y
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(loss, (0, 1), has_aux=True)(params, x)
+
+    (_, want), g_want = run()
+    assert rec.site_paths("attention") == {"latent_einsum": 1}
+    monkeypatch.setattr(config, "is_tpu_backend", lambda: True)
+    monkeypatch.setattr(
+        pallas_latent_attention, "latent_flash",
+        functools.partial(pallas_latent_attention.latent_flash, interpret=True))
+    (_, got), g_got = run()
+    assert rec.site_paths("attention") == {"latent_einsum": 1,
+                                           "latent_block_flash": 1}
+    assert got.shape == want.shape == (batch, seq, TINY.hidden_size)
+    _close(got, want, tol=1e-5)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        assert a.shape == b.shape
+        _close(a, b, tol=1e-5)
+
+
 # --- the expert layer and its shares --------------------------------------------
 
 

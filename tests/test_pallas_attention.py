@@ -1,6 +1,8 @@
 """Pallas blockwise attention (ops/pallas_attention.py) — kernel vs einsum
 reference in interpret mode, and the flash ring path vs the einsum ring path
-on the 8-device CPU mesh (ops/ring.py use_flash=True)."""
+on the 8-device CPU mesh (ops/ring.py use_flash=True); latent attention's
+forward kernel on the projections' layout (ops/pallas_latent_attention.py)
+against the einsum form, forward and gradients."""
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +16,7 @@ from mpi4dl_tpu.mesh import MeshSpec, build_mesh
 from mpi4dl_tpu.ops.pallas_attention import (
     block_flash, flash_attention_local, mlo_merge,
 )
+from mpi4dl_tpu.ops.pallas_latent_attention import latent_flash
 from mpi4dl_tpu.ops.ring import ring_attention
 
 
@@ -171,3 +174,103 @@ def test_ring_flash_grads_match_einsum_ring(devices8):
     np.testing.assert_allclose(
         np.asarray(gf), np.asarray(ge), rtol=1e-4, atol=1e-5
     )
+
+
+# --- latent attention on the projections' layout ------------------------------
+
+
+def _latent_operands(b, s, h, nope, rope, dv, dtype, seed=0):
+    """q [B, S, H·nope], q_pe [B, S, H·rope], kv [B, S, H·(nope + dv)] (a
+    head's k_nope then its v), k_pe [B, S, rope]."""
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal((b, s, w), np.float32), dtype)
+                 for w in (h * nope, h * rope, h * (nope + dv), rope))
+
+
+def _latent_reference(q, q_pe, kv, k_pe_heads, h, scale):
+    """The einsum form in float32; ``k_pe_heads`` [B, S, H, rope] is the
+    rotary key a head, so that its gradient can be had head by head."""
+    b, s, _ = q.shape
+    heads = lambda x: x.astype(jnp.float32).reshape(b, s, h, -1)
+    nope = q.shape[-1] // h
+    k = jnp.concatenate([heads(kv)[..., :nope],
+                         k_pe_heads.astype(jnp.float32)], axis=-1)
+    scores = jnp.einsum(
+        "bqhd,bkhd->bhqk", jnp.concatenate([heads(q), heads(q_pe)], axis=-1),
+        k) * scale
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1),
+                     heads(kv)[..., nope:])
+    return out.reshape(b, s, -1)
+
+
+def _k_pe_a_head(k_pe, h):
+    return jnp.broadcast_to(k_pe[:, :, None, :], (*k_pe.shape[:2], h,
+                                                  k_pe.shape[-1]))
+
+
+def _rel(a, b):
+    a, b = (np.asarray(t, np.float32) for t in (a, b))
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+@pytest.mark.parametrize("seq", [256, 300], ids=["tiled", "ragged"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_latent_flash_matches_the_einsum_form(dtype, seq, batch):
+    """Heads of 16 + 8 with values of 16 (the published 2:1 of nope to rope,
+    keys wider than values), tiles of 16 queries by 128 keys: at 300 tokens
+    the last tiles of both run past the arrays' end.  Output and gradients
+    with respect to all four operands."""
+    h, nope, rope, dv = 4, 16, 8, 16
+    ops = _latent_operands(batch, seq, h, nope, rope, dv, dtype)
+    scale = (nope + rope) ** -0.5
+
+    def through(fn):
+        def loss(*o):
+            out = fn(*o)
+            return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+        return jax.value_and_grad(loss, (0, 1, 2, 3), has_aux=True)(*ops)
+
+    with jax.default_matmul_precision("highest"):
+        (_, got), g_got = through(
+            lambda *o: latent_flash(*o, h, scale, 16, 128, True))
+        (_, want), g_want = through(
+            lambda q, q_pe, kv, k_pe: _latent_reference(
+                q, q_pe, kv, _k_pe_a_head(k_pe, h), h, scale))
+    assert got.shape == (batch, seq, h * dv) and got.dtype == dtype
+    f32 = dtype == jnp.float32
+    assert _rel(got, want) < (1e-5 if f32 else 1e-2)
+    for a, b in zip(g_got, g_want):
+        assert a.shape == b.shape and a.dtype == dtype
+        assert _rel(a, b) < (1e-4 if f32 else 3e-2)
+
+
+def test_latent_flash_at_the_published_widths_walks_its_groups_of_two_heads():
+    """128 + 64 and values of 128: two heads fill whole lane tiles in every
+    operand, so four heads are two steps of the grid's second axis, a head's
+    columns sliced statically inside the block."""
+    h, nope, rope, dv = 4, 128, 64, 128
+    ops = _latent_operands(1, 64, h, nope, rope, dv, jnp.float32, seed=1)
+    scale = (nope + rope) ** -0.5
+    with jax.default_matmul_precision("highest"):
+        got = latent_flash(*ops, h, scale, 32, 64, True)
+        want = _latent_reference(*ops[:3], _k_pe_a_head(ops[3], h), h, scale)
+    assert _rel(got, want) < 1e-5
+
+
+def test_latent_flash_gives_the_rotary_key_the_sum_of_the_heads_gradients():
+    h, nope, rope, dv = 4, 16, 8, 16
+    q, q_pe, kv, k_pe = _latent_operands(2, 48, h, nope, rope, dv,
+                                         jnp.float32, seed=2)
+    scale = (nope + rope) ** -0.5
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(lambda k_pe: jnp.sum(jnp.cos(latent_flash(
+            q, q_pe, kv, k_pe, h, scale, 16, 128, True))))(k_pe)
+        a_head = jax.grad(lambda k_pe_heads: jnp.sum(jnp.cos(_latent_reference(
+            q, q_pe, kv, k_pe_heads, h, scale))))(_k_pe_a_head(k_pe, h))
+    assert a_head.shape == (2, 48, h, rope)
+    assert _rel(got, a_head.sum(axis=2)) < 1e-4
+    # and no one head's share is the whole of it
+    assert all(_rel(got, a_head[:, :, i]) > 0.1 for i in range(h))

@@ -535,6 +535,23 @@ def test_conv_contract_shape(registry_contract):
             "scratch0", "scratch1", "scratch2"} == names
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_latent_contract_shape(registry_contract, dtype):
+    """Latent attention's forward kernel (PR 34): q, q_pe, kv and the one
+    rotary key as blocked VMEM operands the pipeline copies, two heads a grid
+    step at the published widths (blocks of 2 x 128, 2 x 64, 2 x 256 columns;
+    the key's 64 are the array's own width), the output and the two
+    statistics with the queries along the lanes, a scratch slab a head."""
+    entry = registry_contract["kernels"][f"latent_flash:causal:{dtype}"]
+    assert entry["dma_starts"] == 0 and entry["findings"] == {}
+    assert entry["grid"] == [1, 2, 3, 3]
+    blocks = entry["blocks"]
+    assert [blocks[f"in{i}"][-1] for i in range(4)] == [256, 128, 512, 64]
+    assert blocks["out0"] == [1, 128, 256]
+    assert blocks["out1"] == blocks["out2"] == [1, 2, 1, 128]
+    assert {blocks[f"scratch{i}"][0] for i in range(3)} == {2}
+
+
 def test_pallas_contract_roundtrip(registry_contract):
     from mpi4dl_tpu.analysis.contracts.diff import diff_pallas_contract
 

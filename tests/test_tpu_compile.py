@@ -379,21 +379,14 @@ def _executed_keys(text):
     return keys
 
 
-def test_deepseek_v3_kernels_compile_for_v5e_under_the_names_the_metrics_pick(
-        one_chip, no_persistent_cache, monkeypatch):
-    """The second token model's two kernels at its published widths, bf16,
-    forward and backward, under a ``"highest"`` default as the benchmark's
-    check traces them.  Latent attention whole (one sequence of 8,192 tokens
-    through ``LatentAttention``: keys of 192, values of 128, the Pallas path
-    asked for as on a TPU backend): the compiled layer holds the forward
-    kernel and the backward's tiles at both widths, which are the names
-    ``mla_attention_ms`` picks, and the pattern picks nothing of the layer's
-    projections.  The grouped product at 32,768 tokens, 16 of 128 experts of
-    768, six a token: ``ragged-dot-*`` at this configuration's shapes."""
+def _latent_layer_on(one_chip, monkeypatch, batch):
+    """Kanana-2's ``LatentAttention`` at its published widths with the Pallas
+    path asked for as on a TPU backend, the shapes of its float32 parameters
+    and of ``batch`` sequences of 8,192 tokens in bf16 placed on the described
+    chip, and ``mla_attention_ms``'s pattern."""
     import json
 
     import mpi4dl_tpu.config as config
-    from mpi4dl_tpu.layer_ctx import ApplyCtx
     from mpi4dl_tpu.models import deepseek_v3
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -408,26 +401,53 @@ def test_deepseek_v3_kernels_compile_for_v5e_under_the_names_the_metrics_pick(
 
     params = jax.tree.map(
         lambda a: struct(a.shape, a.dtype),
-        jax.eval_shape(lambda: layer.init(jax.random.key(0), (1, 8192, 2048))[0]))
+        jax.eval_shape(lambda: layer.init(jax.random.key(0), (batch, 8192, 2048))[0]))
+    return layer, params, struct((batch, 8192, 2048)), struct, pattern
+
+
+def test_deepseek_v3_kernels_compile_for_v5e_under_the_names_the_metrics_pick(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The second token model's two kernels at its published widths, bf16,
+    forward and backward, under a ``"highest"`` default as the benchmark's
+    check traces them.  Latent attention whole (four sequences of 8,192
+    tokens through ``LatentAttention``: 32 heads of 128 + 64, values of 128,
+    the Pallas path asked for as on a TPU backend): Mosaic accepts the
+    forward kernel on the projections' layout, and the compiled layer holds
+    it and the backward's tiles at both widths, which are the names
+    ``mla_attention_ms`` picks, and the pattern picks nothing of the layer's
+    projections.  The grouped product at 32,768 tokens, 16 of 128 experts of
+    768, six a token: ``ragged-dot-*`` at this configuration's shapes."""
+    from mpi4dl_tpu.layer_ctx import ApplyCtx
+
+    layer, params, x, struct, pattern = _latent_layer_on(
+        one_chip, monkeypatch, batch=4)
 
     def attention(p, x):
         def loss(p, x):
-            return jnp.sum(layer.apply(p, x, ApplyCtx(train=True))
-                           .astype(jnp.float32))
+            # under per-cell remat, as the step runs it: the kernel is then
+            # there twice, and both times under its own name (differentiated
+            # where it stands it would be ``jvp(block_flash_fwd)``)
+            y = jax.checkpoint(
+                lambda p, x: layer.apply(p, x, ApplyCtx(train=True)))(p, x)
+            return jnp.sum(y.astype(jnp.float32))
 
         with jax.default_matmul_precision("highest"):
             return jax.grad(loss, (0, 1))(p, x)
 
-    compiled = jax.jit(attention).lower(params, struct((1, 8192, 2048))).compile()
+    compiled = jax.jit(attention).lower(params, x).compile()
     _assert_mosaic(compiled)
     keys = _executed_keys(compiled.as_text())
     picked = {k for k in keys if pattern.search(k)}
-    assert "block_flash_fwd:f32[32,8192,128]" in picked, sorted(picked)
+    # the kernel's first result is the normalized output, four sequences of
+    # 32 heads x 128 in the compute dtype
+    assert "block_flash_fwd:bf16[4,8192,4096]" in picked, sorted(picked)
+    assert not [k for k in keys if "block_flash" in k
+                and not k.startswith("block_flash_fwd:")], sorted(set(keys))
     shapes = {k.split(":")[1] for k in picked}
     for tile in ("[32,1024,512]", "[32,1024,192]", "[32,512,192]", "[32,512,128]"):
         assert any(s.endswith(tile) for s in shapes), (tile, sorted(shapes))
     # the copies that bring dq's tile to its accumulator, eight heads at a time
-    assert "slice-done:f32[8,1024,192]" in picked, sorted(picked)
+    assert picked & {"slice-done:f32[8,1024,192]", "slice-done:f32[8,512,192]"}, sorted(picked)
     # nothing of the projections, the norms or the rotary embedding
     assert not [k for k in picked if re.search(r"\[(1,)?8192,|2048|4096|6144", k)
                 and not k.startswith("block_flash_fwd")], sorted(picked)
@@ -438,3 +458,30 @@ def test_deepseek_v3_kernels_compile_for_v5e_under_the_names_the_metrics_pick(
         r"%ragged-dot-\w+(?:\.\d+)? = bf16\[(30720,768|30720,2048|16,2048,768|"
         r"16,768,2048)\]", compiled.as_text())
     assert len(products) >= 9 and len(set(products)) == 4, products
+
+
+def test_latent_attention_forward_writes_no_heads_first_or_padded_operand_for_v5e(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The forward alone, four sequences: between the projections and the
+    kernel nothing is written heads-first (``[*, 32, 8192, 192]``), a head's
+    ``nope`` and ``rope`` columns are not concatenated (``[*, 8192, 32,
+    192]``), nothing is padded to 256 lanes (``[32, 8192, 256]``), the rotary
+    key is not broadcast to the heads, and there is no loop over the
+    sequences: what ``block_flash`` needed and ``latent_flash`` reads in
+    place (PR 34).  The kernel is there once, for all four sequences."""
+    from mpi4dl_tpu.layer_ctx import ApplyCtx
+
+    layer, params, x, _, _ = _latent_layer_on(one_chip, monkeypatch, batch=4)
+
+    def forward(p, x):
+        with jax.default_matmul_precision("highest"):
+            return layer.apply(p, x, ApplyCtx(train=True))
+
+    text = jax.jit(forward).lower(params, x).compile().as_text()
+    results = re.findall(r" = \(?\w+\[([\d,]+)\]", text)
+    gone = [r for r in results if re.fullmatch(
+        r"(\d+,)?32,8192,192|(\d+,)?8192,32,192|32,8192,256", r)]
+    assert not gone, sorted(set(gone))
+    assert not re.search(r" while\(", text)
+    assert [k for k in _executed_keys(text) if k.startswith("block_flash_fwd")
+            ] == ["block_flash_fwd:bf16[4,8192,4096]"]
