@@ -166,7 +166,8 @@ def build_train(cfg: ParallelConfig, family: str, mesh):
     """Return (step, state, eval_params_fn, global_batch).
 
     ``eval_params_fn(state) -> params_list`` reassembles full parameters for
-    the eval step / checkpointing regardless of the family's state layout.
+    the eval step / checkpointing regardless of the family's state layout,
+    one entry a cell as the cell is applied to it.
 
     Recorded as the ``setup/build_train`` span with ``setup/build_model``,
     ``setup/init_params``, ``setup/make_step`` and ``setup/place_state``
@@ -239,7 +240,10 @@ def build_train(cfg: ParallelConfig, family: str, mesh):
                     )
                 with rec.span("setup/place_state"):
                     state = TrainState.create(params, opt)
-                return step, state, (lambda s: s.params), cfg.batch_size * dp
+                # per cell: a tied leaf, which the state holds once, is
+                # there for each cell that reads it (the same array)
+                return (step, state, (lambda s: model.per_cell(s.params)),
+                        cfg.batch_size * dp)
             from mpi4dl_tpu.parallel.partition import StagePartition
             from mpi4dl_tpu.parallel.pipeline import (
                 init_pipeline_state,
@@ -251,6 +255,8 @@ def build_train(cfg: ParallelConfig, family: str, mesh):
                 model, params, cfg.split_size,
                 (mb, *cfg.sample_shape),
                 balance=cfg.balance, compute_dtype=dtype, param_dtype=pdtype,
+                # the GPipe schedule sums a tied leaf's gradients over stages
+                sums_tied_grads=cfg.schedule == "gpipe",
             )
             with rec.span("setup/make_step"):
                 step = make_pipeline_train_step(

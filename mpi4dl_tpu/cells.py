@@ -110,6 +110,44 @@ class CellModel:
     # routed model's expert load); the one-chip step returns it as
     # ``metrics["counted"]`` and the loop writes it on the ``step`` span.
     step_metrics: Optional[Callable[[Any, int], dict]] = None
+    # ``(owner, reader, name)``: the leaf ``name`` of cell ``owner``'s
+    # parameters is read by cell ``reader`` too, under the same name (a head
+    # that is the embedding's table).  ``init`` and the train state hold it
+    # once, with the owner; ``apply`` hands the reader its own parameters with
+    # the leaf beside them, so its gradient is the sum over both uses and its
+    # update is one.  Of the engines that pack each stage's parameters into a
+    # row of its own, the GPipe schedule keeps a copy in the reader's row and
+    # sums the two rows' gradients (``parallel/pipeline.sum_tied_grads``); the
+    # others refuse such a model (:meth:`refuse_tied`).
+    tied: Tuple[Tuple[int, int, str], ...] = ()
+
+    def cell_params(self, params_list, i: int):
+        """What cell ``i`` is applied to: its own parameters, and the tied
+        leaves it reads from their owners'."""
+        read = {name: params_list[owner][name]
+                for owner, reader, name in self.tied if reader == i}
+        return {**params_list[i], **read} if read else params_list[i]
+
+    def per_cell(self, params_list) -> List[Any]:
+        """``params_list`` as the cells are applied to it, one entry a cell
+        (a tied leaf appears with its owner and with its reader, the same
+        array): what a cell-by-cell walk outside :meth:`apply` indexes."""
+        return [self.cell_params(params_list, i) for i in range(len(self.cells))]
+
+    def refuse_tied(self, engine: str) -> None:
+        """The error of an engine that gives each stage a parameter row of
+        its own and does not sum a tied leaf's gradients over the stage
+        axis, for a model with a leaf that two cells read."""
+        if not self.tied:
+            return
+        owner, reader, name = self.tied[0]
+        raise ValueError(
+            f"{self.name}: cell {reader} ({self.cells[reader].name}) reads "
+            f"the leaf {name!r} of cell {owner} ({self.cells[owner].name}), "
+            f"and {engine} keeps each stage's parameters in a row of its "
+            "own: the two uses' gradients have to be summed over the stage "
+            "axis before the update, which only the lp family's GPipe "
+            "schedule does (ROADMAP R6); run it there or on one chip")
 
     def init(self, key) -> Tuple[List[Any], List[ShapeLike]]:
         """Init all cells; returns (params_list, shape_list) where
@@ -170,7 +208,8 @@ class CellModel:
                     return _unpack_act(x, m)
 
                 x, meta = checkpointed_apply(
-                    grp_fn, [params_list[i] for i in grp], x, ctx,
+                    grp_fn, [self.cell_params(params_list, i) for i in grp],
+                    x, ctx,
                     in_meta=meta, pack=True,
                 )
             return _unpack_act(x, meta)
@@ -179,18 +218,19 @@ class CellModel:
             with scope(f"cell{i:02d}"):
                 if remat:
                     x, meta = checkpointed_apply(
-                        self.cells[i].apply, params_list[i], x, ctx,
-                        in_meta=meta, pack=True,
+                        self.cells[i].apply, self.cell_params(params_list, i),
+                        x, ctx, in_meta=meta, pack=True,
                     )
                 else:
-                    x = self.cells[i].apply(params_list[i], x, ctx)
+                    x = self.cells[i].apply(
+                        self.cell_params(params_list, i), x, ctx)
         return _unpack_act(x, meta) if remat else x
 
     def out_shapes(self, params_list) -> List[ShapeLike]:
         """Abstract shape inference via eval_shape (no FLOPs, no memory)."""
         shapes: List[ShapeLike] = []
         x = jax.ShapeDtypeStruct(self.in_shape, jnp.float32)
-        for cell, p in zip(self.cells, params_list):
+        for cell, p in zip(self.cells, self.per_cell(params_list)):
             x = jax.eval_shape(lambda p, x, c=cell: c.apply(p, x, EVAL_CTX), p, x)
             shapes.append(
                 tuple(t.shape for t in x) if isinstance(x, tuple) else x.shape

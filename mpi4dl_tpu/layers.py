@@ -668,25 +668,33 @@ class RMSNorm(Layer):
 class CausalConv1d(Layer):
     """Depthwise causal convolution along the sequence axis of ``[B, S, C]``:
     ``y[t] = sum_j kernel[j] * x[t - (K-1) + j]``, zeros left of the
-    sequence, no bias.  The spatial layers' one-dimensional case, written as
-    K shifted multiply-adds: a depthwise kernel of three taps has no matrix
-    product in it.  (``ops/ring.ghost_conv1d`` is dense and centred.)"""
+    sequence, plus a bias a channel where ``use_bias`` (both uniform within
+    ``1/sqrt(K)``, torch's Conv1d at one input channel a group).  The spatial layers'
+    one-dimensional case, written as K shifted multiply-adds: a depthwise
+    kernel of a few taps has no matrix product in it.
+    (``ops/ring.ghost_conv1d`` is dense and centred.)"""
 
     features: int
     kernel_size: int = 3
+    use_bias: bool = False
 
     def init(self, key, in_shape):
         assert in_shape[-1] == self.features, (in_shape, self.features)
         bound = 1.0 / math.sqrt(self.kernel_size)
-        return {"kernel": _uniform(
-            key, (self.kernel_size, self.features), bound)}, in_shape
+        params = {"kernel": _uniform(
+            key, (self.kernel_size, self.features), bound)}
+        if self.use_bias:  # a key of its own: the kernel is the bias-free one
+            params["bias"] = _uniform(
+                jax.random.fold_in(key, 1), (self.features,), bound)
+        return params, in_shape
 
     def apply(self, params, x, ctx):
         k = self.kernel_size
         w = params["kernel"].astype(x.dtype)
         padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
         s = x.shape[1]
-        return sum(padded[:, j:j + s] * w[j] for j in range(k))
+        y = sum(padded[:, j:j + s] * w[j] for j in range(k))
+        return y + params["bias"].astype(x.dtype) if self.use_bias else y
 
 
 @dataclasses.dataclass(frozen=True)

@@ -2,6 +2,7 @@ from mpi4dl_tpu.models.resnet import get_resnet_v1, get_resnet_v2, get_resnet
 from mpi4dl_tpu.models.amoebanet import amoebanetd
 from mpi4dl_tpu.models.lfm2 import lfm2_moe
 from mpi4dl_tpu.models import deepseek_v3  # the module: its builder has its name
+from mpi4dl_tpu.models import granitemoehybrid  # likewise
 from mpi4dl_tpu.models.seqblock import SeqBlock, make_seq_cp_train_step
 
 __all__ = [
@@ -31,16 +32,19 @@ def _amoebanet(cfg, in_shape):
     )
 
 
-def _token_model(builder):
-    """A token model's builder from the flags that state its cut."""
+def _token_model(builder, routed: bool = True):
+    """A token model's builder from the flags that state its cut: the layers
+    and the vocabulary's rows, and, for a ``routed`` model, the experts this
+    process holds (a model without routed experts is not handed them)."""
     def build(cfg, in_shape):
+        experts = ({"experts_held": cfg.experts_held,
+                    "expert_first": cfg.expert_first} if routed else {})
         return builder(
             in_shape,
             num_layers=cfg.num_layers,
             vocab_size=cfg.vocab_size,
-            experts_held=cfg.experts_held,
-            expert_first=cfg.expert_first,
             compute_dtype=cfg.compute_dtype,
+            **experts,
         )
     return build
 
@@ -55,6 +59,8 @@ MODELS = {
     "amoebanet": ("image", _amoebanet),
     "lfm2_moe": ("tokens", _token_model(lfm2_moe)),
     "deepseek_v3": ("tokens", _token_model(deepseek_v3.deepseek_v3)),
+    "granitemoehybrid": ("tokens", _token_model(
+        granitemoehybrid.granitemoehybrid, routed=False)),
 }
 
 
@@ -72,10 +78,10 @@ def build_model(cfg):
     For resnet, ``cfg.num_layers`` is the block-count n of the v2 depth
     formula 9n+2 (reference hardcodes n=12 → ResNet-110-v2 per benchmark,
     benchmark_resnet_sp.py:161-163; pass --num-layers 12 for parity).  For
-    amoebanet it is the NAS cell count as in the reference parser.  For
-    lfm2_moe and deepseek_v3 (token models: ``[B, S]`` ids in) it is the
-    layers as run, and the vocabulary rows and the experts held come from
-    their own flags."""
+    amoebanet it is the NAS cell count as in the reference parser.  For the
+    token models (``[B, S]`` ids in: lfm2_moe, deepseek_v3, granitemoehybrid)
+    it is the layers as run; the vocabulary rows and, where the model has
+    routed experts, the experts held come from their own flags."""
     input_kind(cfg.model)  # an unknown model is refused before its shape is asked
     in_shape = (cfg.batch_size // cfg.parts, *cfg.sample_shape)
     return MODELS[cfg.model][1](cfg, in_shape)

@@ -18,8 +18,9 @@ each with its own published ``layer_type`` (nine layers: the dense layer 1,
 then two whole periods of ``full_attention, conv, conv, conv``, layers 2-9).
 
 One cell a layer, an embedding cell before and a cell of final norm and head
-after; each cell owns its parameters (embedding and head are not tied), as
-``split_even`` and the benchmark's cell-by-cell check need.  Activations
+after; each cell owns its parameters (embedding and head are not tied here; a
+model that ties them says so in ``CellModel.tied``), as ``split_even`` and the
+benchmark's cell-by-cell check need.  Activations
 between cells are ``[B, S, hidden]`` in the compute dtype; norms, the
 router's scores, the softmax and the loss are computed in float32.
 """
@@ -27,7 +28,7 @@ router's scores, the softmax and the loss are computed in float32.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Mapping, Sequence, Tuple
+from typing import Any, Dict, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -136,7 +137,10 @@ def rotary(x, theta: float):
 class Attention(Layer):
     """Causal grouped-query attention: RMSNorm over each head of q and of k,
     the rotary embedding on both, each key-value head serving
-    ``heads // kv_heads`` query heads, scale ``head_dim ** -0.5``.
+    ``heads // kv_heads`` query heads, scale ``head_dim ** -0.5``.  A model
+    without them says so: ``qk_norm`` false (no ``q_norm``, ``k_norm``
+    parameters), ``rope_theta`` None (no position signal at all), a ``scale``
+    of its own.
 
     The attention itself is ``ops.ring.ring_attention`` on one shard: the
     Pallas block kernel on a TPU backend, the einsum form elsewhere.  At
@@ -149,19 +153,23 @@ class Attention(Layer):
     heads: int
     kv_heads: int
     head_dim: int
-    rope_theta: float
+    rope_theta: Optional[float]
     eps: float
+    qk_norm: bool = True
+    scale: Optional[float] = None
 
     def _parts(self) -> Dict[str, Layer]:
         d, hd = self.features, self.head_dim
-        return {
+        parts: Dict[str, Layer] = {
             "q_proj": Dense(d, self.heads * hd, use_bias=False),
             "k_proj": Dense(d, self.kv_heads * hd, use_bias=False),
             "v_proj": Dense(d, self.kv_heads * hd, use_bias=False),
             "out_proj": Dense(self.heads * hd, d, use_bias=False),
-            "q_norm": RMSNorm(hd, self.eps),
-            "k_norm": RMSNorm(hd, self.eps),
         }
+        if self.qk_norm:
+            parts.update(q_norm=RMSNorm(hd, self.eps),
+                         k_norm=RMSNorm(hd, self.eps))
+        return parts
 
     def init(self, key, in_shape):
         parts = self._parts()
@@ -181,9 +189,14 @@ class Attention(Layer):
             y = parts[name + "_proj"].apply(params[name + "_proj"], x, ctx)
             return y.reshape(b, s, n, self.head_dim)
 
-        q = parts["q_norm"].apply(params["q_norm"], heads("q", self.heads), ctx)
-        k = parts["k_norm"].apply(params["k_norm"], heads("k", self.kv_heads), ctx)
-        q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+        def normed(name, n):
+            y = heads(name, n)
+            return (parts[name + "_norm"].apply(params[name + "_norm"], y, ctx)
+                    if self.qk_norm else y)
+
+        q, k = normed("q", self.heads), normed("k", self.kv_heads)
+        if self.rope_theta is not None:
+            q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
         v = heads("v", self.kv_heads)
         rep = self.heads // self.kv_heads
         k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
@@ -191,9 +204,11 @@ class Attention(Layer):
             "attention", self,
             "block_flash" if _resolve_flash(None) else "einsum")
 
+        scale = self.head_dim ** -0.5 if self.scale is None else self.scale
+
         def attend(qkv):
             return ring_attention(*(t[None] for t in qkv), None, 1, causal=True,
-                                  scale=self.head_dim ** -0.5)[0]
+                                  scale=scale)[0]
 
         o = lax.map(attend, (q, k, v))
         return parts["out_proj"].apply(
@@ -228,12 +243,15 @@ class SwiGLU(Layer):
 
 @dataclasses.dataclass
 class BlockCell(Cell):
-    """One layer: ``h += op(norm(h))``, then ``h += ffn(norm(h))``."""
+    """One layer: ``h += m op(norm(h))``, then ``h += m ffn(norm(h))``, with
+    ``m`` the ``residual_multiplier`` (1 in most models: nothing is traced
+    for it then)."""
 
     op: Layer
     ffn: Layer
     norm: RMSNorm
     name: str = "layer"
+    residual_multiplier: float = 1.0
 
     def init(self, key, in_shape):
         k_op, k_ffn = jax.random.split(key)
@@ -243,10 +261,12 @@ class BlockCell(Cell):
                 }, in_shape
 
     def apply(self, params, x, ctx):
-        x = x + self.op.apply(
-            params["op"], self.norm.apply(params["op_norm"], x, ctx), ctx)
-        return x + self.ffn.apply(
-            params["ffn"], self.norm.apply(params["ffn_norm"], x, ctx), ctx)
+        m = self.residual_multiplier
+        branch = (lambda y: y) if m == 1 else (lambda y: y * m)
+        x = x + branch(self.op.apply(
+            params["op"], self.norm.apply(params["op_norm"], x, ctx), ctx))
+        return x + branch(self.ffn.apply(
+            params["ffn"], self.norm.apply(params["ffn_norm"], x, ctx), ctx))
 
 
 def _block(config: Lfm2MoeConfig, layer: int, experts_held: int,
@@ -275,9 +295,10 @@ def _block(config: Lfm2MoeConfig, layer: int, experts_held: int,
 
 
 def embed_cell(vocab_size: int, features: int, compute_dtype,
-               std: float = EMBED_STD) -> FnCell:
-    """``[B, S]`` ids to ``[B, S, features]`` in the compute dtype; the table
-    normal with standard deviation ``std``."""
+               std: float = EMBED_STD, multiplier: float = 1.0) -> FnCell:
+    """``[B, S]`` ids to ``[B, S, features]`` in the compute dtype, times
+    ``multiplier`` where it is not 1; the table normal with standard
+    deviation ``std``."""
 
     def embed_init(key, shape):
         table = jax.random.normal(
@@ -286,26 +307,43 @@ def embed_cell(vocab_size: int, features: int, compute_dtype,
 
     def embed(p, ids, ctx):
         # a pipeline stage's input arrives in the compute dtype
-        return jnp.take(p["table"].astype(compute_dtype),
+        rows = jnp.take(p["table"].astype(compute_dtype),
                         ids.astype(jnp.int32), axis=0)
+        return rows if multiplier == 1 else rows * multiplier
 
     return FnCell(embed_init, embed, "embed")
 
 
-def head_cell(vocab_size: int, features: int, eps: float) -> FnCell:
-    """The final RMSNorm and the head (its own parameter, not the embedding's):
-    logits ``[B, S, vocab_size]`` in float32."""
+def head_cell(vocab_size: int, features: int, eps: float, *,
+              logits_scaling: float = 1.0, tied: bool = False) -> FnCell:
+    """The final RMSNorm and the head: logits ``[B, S, vocab_size]`` in
+    float32, over ``logits_scaling`` where it is not 1.  The head is this
+    cell's own parameter ``head.kernel`` ``[features, vocab_size]``, or,
+    ``tied``, the embedding's ``table`` ``[vocab_size, features]``: the cell
+    then initialises no head and reads ``table`` beside its ``norm``, which
+    the model's ``CellModel.tied`` puts there from the embedding cell's
+    parameters."""
     norm = RMSNorm(features, eps)
     head = Dense(features, vocab_size, use_bias=False)
 
     def head_init(key, shape):
-        return {"norm": norm.init(None, shape)[0],
-                "head": head.init(key, shape)[0]}, (*shape[:-1], vocab_size)
+        params = {"norm": norm.init(None, shape)[0]}
+        if not tied:
+            params["head"] = head.init(key, shape)[0]
+        return params, (*shape[:-1], vocab_size)
 
     def head_apply(p, x, ctx):
         x = norm.apply(p["norm"], x, ctx)
-        return jnp.dot(x, p["head"]["kernel"].astype(x.dtype),
-                       preferred_element_type=jnp.float32)
+        if tied:
+            recorder().note_site("tied_head", norm, "table_transposed")
+            logits = lax.dot_general(
+                x, p["table"].astype(x.dtype),
+                (((x.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.dot(x, p["head"]["kernel"].astype(x.dtype),
+                             preferred_element_type=jnp.float32)
+        return logits if logits_scaling == 1 else logits / logits_scaling
 
     return FnCell(head_init, head_apply, "norm_head")
 

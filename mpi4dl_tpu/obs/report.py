@@ -117,7 +117,8 @@ def render_run(path: str) -> str:
                  for b in closing.get("built_in_loop") or []]
         if built:  # the same program at two steps is a retrace
             lines.append("programs built in the loop: " + ", ".join(built[:8]))
-        for kind in ("conv", "norm", "attention", "expert", "shared_expert"):
+        for kind in ("conv", "norm", "attention", "expert", "shared_expert",
+                     "ssm_scan", "tied_head"):
             paths = closing.get(f"{kind}_paths") or {}
             if paths:
                 lines.append(f"{kind} paths (sites): " + "  ".join(

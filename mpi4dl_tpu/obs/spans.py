@@ -86,7 +86,10 @@ CONV_PATHS = ("wfold", "hstripe", "phase", "xla", "dot")
 # and whose Pallas forward is ops/pallas_latent_attention.py's),
 # the routed experts' grouped product (ops/moe.py: ``lax.ragged_dot``, the
 # one path) and the shared expert beside them (models/deepseek_v3.py: a
-# SwiGLU of dense products, the one path); and on which form of the activation a
+# SwiGLU of dense products, the one path); the state-space recurrence of a
+# Mamba-2 mixer (models/granitemoehybrid.py: ``ops/ssd.ssd_chunked``, XLA's
+# products over chunks, the one path) and a head that multiplies by the
+# embedding's table (models/lfm2.head_cell, ``tied``); and on which form of the activation a
 # BatchNorm took its sums and applied its affine: ``[N, H, W/p, p·C]`` inside
 # a folded run (``layers.run_fold``), or ``[N, H, W, C]``.
 SITE_PATHS = {
@@ -96,6 +99,8 @@ SITE_PATHS = {
                   "latent_einsum"),
     "experts": ("ragged_dot",),
     "shared_expert": ("swiglu",),
+    "ssm_scan": ("chunked",),
+    "tied_head": ("table_transposed",),
 }
 
 # At least 4,000 steps of the loop's spans (nine a step with the loader's).
@@ -367,7 +372,8 @@ class Recorder:
         programs built or loaded inside a step with its ``gstep`` (a program
         that appears twice was retraced); ``conv_paths`` and ``norm_paths``;
         and, where the model has such sites, ``attention_paths``,
-        ``expert_paths`` and ``shared_expert_paths``."""
+        ``expert_paths``, ``shared_expert_paths``, ``ssm_scan_paths`` and
+        ``tied_head_paths``."""
         spans = sorted((s for s in list(self._closed)
                         if s.name.startswith(SETUP_PREFIXES)),
                        key=lambda s: s.start_ns)
@@ -398,7 +404,9 @@ class Recorder:
         # only where the model has such sites
         for key, kind in (("attention_paths", "attention"),
                           ("expert_paths", "experts"),
-                          ("shared_expert_paths", "shared_expert")):
+                          ("shared_expert_paths", "shared_expert"),
+                          ("ssm_scan_paths", "ssm_scan"),
+                          ("tied_head_paths", "tied_head")):
             paths = self.site_paths(kind)
             if paths:
                 out[key] = paths

@@ -157,6 +157,12 @@ class StagePartition:
     # buffers, the GEMS mirror ppermute traffic, and the grad cotangents;
     # update arithmetic stays fp32 inside Optimizer).
     param_dtype: Any = jnp.float32
+    # A leaf that two cells read (``CellModel.tied``), as ``(owner's stage,
+    # offset in its row, reader's stage, offset in its row, size)``: the
+    # reader's stage keeps a copy of the leaf in its own row, and the engine
+    # sums the two rows' gradients for it over the stage axis before the
+    # update (``pipeline.sum_tied_grads``), so the copies stay one value.
+    tied_slots: Tuple[Tuple[int, int, int, int, int], ...] = ()
 
     @property
     def num_stages(self) -> int:
@@ -172,11 +178,20 @@ class StagePartition:
         balance: Optional[Sequence[int]] = None,
         compute_dtype=jnp.float32,
         param_dtype=jnp.float32,
+        sums_tied_grads: bool = False,
     ) -> "StagePartition":
         """``microbatch_shape`` is either a plain shape tuple or a pytree of
         ``jax.ShapeDtypeStruct`` (tuple activations entering stage 0 — the
         SP→LP junction of sp_pipeline.py hands tail stages AmoebaNet's
-        (x, skip) state)."""
+        (x, skip) state).  ``sums_tied_grads``: the engine sums a tied
+        leaf's gradients over the stage axis (``tied_slots``; the GPipe
+        schedule of ``pipeline.py`` does); any other refuses a model with
+        such a leaf."""
+        if not sums_tied_grads:
+            model.refuse_tied("this pipeline engine")
+        # one entry a cell as the cell is applied to it: a tied leaf is in
+        # its owner's stage row and in its reader's
+        params_list = model.per_cell(params_list)
         ranges = split_even(len(model.cells), split_size, balance)
         param_packs = [
             TreePack.of([params_list[i] for i in range(r0, r1)]) for r0, r1 in ranges
@@ -214,9 +229,26 @@ class StagePartition:
             if stat_max
             else None
         )
+        def slot(cell: int, name: str) -> Tuple[int, int, int]:
+            """(stage, offset in its row, size) of cell ``cell``'s ``name``."""
+            stage = next(s for s, (r0, r1) in enumerate(ranges) if r0 <= cell < r1)
+            r0, r1 = ranges[stage]
+            off = 0
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                    [params_list[i] for i in range(r0, r1)])[0]:
+                if (path[0].idx, getattr(path[1], "key", None)) == (cell - r0, name) \
+                        and len(path) == 2:
+                    return stage, off, int(leaf.size)
+                off += int(leaf.size)
+            raise KeyError((cell, name))
+
+        tied_slots = tuple(
+            (*slot(owner, name)[:2], *slot(reader, name))
+            for owner, reader, name in model.tied)
         return cls(
             model, ranges, param_packs, act_packs, out_pack, param_max, act_max,
             stat_leaf_ids, stat_slots, stat_max, stat_idx, param_dtype,
+            tied_slots,
         )
 
     # ---- parameter buffers ----
@@ -224,6 +256,7 @@ class StagePartition:
     def pack_params(self, params_list) -> jax.Array:
         """[S, param_max] buffer in ``param_dtype`` (row s = stage s's flat
         params)."""
+        params_list = self.model.per_cell(params_list)
         rows = []
         for (r0, r1), pk in zip(self.ranges, self.param_packs):
             rows.append(

@@ -46,7 +46,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mpi4dl_tpu.layer_ctx import ApplyCtx
 from mpi4dl_tpu.obs.scopes import scope
-from mpi4dl_tpu.parallel.partition import StagePartition
+from mpi4dl_tpu.parallel.partition import StagePartition, lax_slice
 from mpi4dl_tpu.parallel.stage_common import (
     gpipe_scan,
     make_1f1b_scan,
@@ -74,6 +74,26 @@ def grad_pmean(x, axes, quant: Optional[QuantPolicy]):  # analysis: ok(unscoped-
     if mode:
         return quantized_pmean(x, axes, mode, quant.block)
     return lax.pmean(x, axes)
+
+
+def sum_tied_grads(part: StagePartition, grads):
+    """A stage's gradient row with the gradients of every tied leaf
+    (``StagePartition.tied_slots``) summed over its two uses: the owner's
+    stage and the reader's each hold a copy of the leaf and the gradient of
+    their own use of it; both get the sum, so one update keeps the copies
+    one value.  Inside ``shard_map`` over the stage axis."""
+    stage = lax.axis_index(AXIS_STAGE)
+    for owner, owner_off, reader, reader_off, size in part.tied_slots:
+        uses = ((owner, owner_off), (reader, reader_off))
+        with scope("tied_grad_reduce"):
+            total = lax.psum(  # analysis: ok(unquantized-collective) — exact: the leaf's two copies must stay one value
+                sum(jnp.where(stage == s, lax_slice(grads, off, size), 0)
+                    for s, off in uses), AXIS_STAGE)
+        for s, off in uses:
+            grads = lax.dynamic_update_slice(
+                grads, jnp.where(stage == s, total, lax_slice(grads, off, size)),
+                (off,))
+    return grads
 
 
 @dataclasses.dataclass
@@ -127,6 +147,9 @@ def make_pipeline_train_step(
     """
     if schedule not in ("gpipe", "1f1b"):
         raise ValueError(f"unknown schedule {schedule!r}; use 'gpipe' or '1f1b'")
+    if schedule != "gpipe":
+        # its manual backward hands each stage its own row's cotangent
+        part.model.refuse_tied(f"the {schedule} schedule")
     S = part.num_stages
     Pn = parts
     ctx = ApplyCtx(train=True)
@@ -191,6 +214,8 @@ def make_pipeline_train_step(
         if loss_scale != 1.0:
             grads = grads / loss_scale
             loss = loss / loss_scale
+        if part.tied_slots:
+            grads = sum_tied_grads(part, grads)
         if grad_axes:
             with scope("grad_reduce"):
                 grads = grad_pmean(grads, grad_axes, quant)

@@ -368,15 +368,7 @@ def _executed_keys(text):
     """``name:type[shape]`` of every instruction of a compiled module that
     runs on its own (not inside a fusion), as ``perfbench.trace.op_key``
     names a trace event."""
-    from perfbench.trace import op_key
-
-    keys, fused = [], False
-    for line in text.splitlines():
-        if line and not line.startswith(" "):  # a computation's header, or "}"
-            fused = line.startswith(("%fused_", "fused_"))
-        elif not fused and " = " in line:
-            keys.append(op_key(line.strip().removeprefix("ROOT ")))
-    return keys
+    return [key for key, _ in _executed_with_scopes(text)]
 
 
 def _latent_layer_on(one_chip, monkeypatch, batch):
@@ -485,3 +477,114 @@ def test_latent_attention_forward_writes_no_heads_first_or_padded_operand_for_v5
     assert not re.search(r" while\(", text)
     assert [k for k in _executed_keys(text) if k.startswith("block_flash_fwd")
             ] == ["block_flash_fwd:bf16[4,8192,4096]"]
+
+
+def _executed_with_scopes(text):
+    """``(key, op_name)`` of every instruction of a compiled module that runs
+    on its own: :func:`_executed_keys` with the scope path that the
+    instruction's metadata carries ("" where the compiler made it and gave
+    it none)."""
+    from perfbench.trace import op_key
+
+    out, fused = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):  # a computation's header, or "}"
+            fused = line.startswith(("%fused_", "fused_"))
+        elif not fused and " = " in line:
+            scope = re.search(r'op_name="([^"]*)"', line)
+            out.append((op_key(line.strip().removeprefix("ROOT ")),
+                        scope.group(1) if scope else ""))
+    return out
+
+
+def test_granitemoehybrid_layers_compile_for_v5e_under_the_names_the_metrics_pick(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The third token model's two kinds of layer at the published widths and
+    the cell's batch (two sequences of 8,192 tokens), bf16, under per-cell
+    remat and a ``"highest"`` default as the step and the benchmark's check
+    trace them.  The state-space layer (``Mamba2Mixer`` with its MLP and
+    norms): XLA's compiler accepts the chunked scan, every large instruction
+    that the scan's scope made is among the names ``ssm_scan_ms`` picks, the
+    pattern picks nothing at the widths of the projections, the gate, the
+    norms or the MLP, and ``attention_ms``'s picks nothing there.  The
+    attention layer (no rotary embedding, no head norms, scale 1/64): Mosaic
+    accepts the forward kernel at the LFM2 cell's shape, ``attention_ms``
+    picks it and the backward's tiles, and ``ssm_scan_ms`` picks nothing."""
+    import json
+
+    import mpi4dl_tpu.config as config
+    from mpi4dl_tpu.layer_ctx import ApplyCtx
+    from mpi4dl_tpu.models import granitemoehybrid as gmh
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def pattern(metric):
+        with open(os.path.join(root, "perfbench", "layer_metrics",
+                               metric + ".json")) as f:
+            return re.compile(json.load(f)["params"]["pattern"])
+
+    scan_ms, attention_ms = pattern("ssm_scan_ms"), pattern("attention_ms")
+    monkeypatch.setattr(config, "is_tpu_backend", lambda: True)
+
+    def struct(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def executed(layer_index):
+        cell = gmh._block(gmh.PUBLISHED, layer_index)
+        params = jax.tree.map(
+            lambda a: struct(a.shape, a.dtype),
+            jax.eval_shape(lambda: cell.init(jax.random.key(0), (2, 8192, 2048))[0]))
+
+        def grads(p, x):
+            def loss(p, x):
+                y = jax.checkpoint(
+                    lambda p, x: cell.apply(p, x, ApplyCtx(train=True)))(p, x)
+                return jnp.sum(y.astype(jnp.float32))
+
+            with jax.default_matmul_precision("highest"):
+                return jax.grad(loss, (0, 1))(p, x)
+
+        compiled = jax.jit(grads).lower(params, struct((2, 8192, 2048))).compile()
+        return compiled, _executed_with_scopes(compiled.as_text())
+
+    # a state-space layer
+    assert gmh.PUBLISHED.layer_types[0] == "mamba"
+    _, ops = executed(0)
+    elements = lambda key: math.prod(
+        int(d) for d in re.search(r"\[([\d,]*)\]", key).group(1).split(",") if d)
+    in_scope = {k for k, scope in ops if "ssm_scan" in scope
+                and not k.startswith(("while:", "conditional:", "call:",
+                                      # no work of their own
+                                      "get-tuple-element:", "tuple:",
+                                      "parameter:", "constant:"))
+                and re.search(r"\[[\d,]+\]", k) and elements(k) >= 1 << 20}
+    assert len(in_scope) >= 10, sorted(in_scope)
+    assert not [k for k in in_scope if not scan_ms.search(k)], sorted(in_scope)
+    picked = {k for k, _ in ops if scan_ms.search(k)}
+    shapes = {k.split(":")[1] for k in picked}
+    for block in ("[2,32,256,256]", "[2,32,256,64,64]", "[32,2,64,64,128]",
+                  "[2,64,64,128]", "[2,32,256,64]"):
+        assert any(s.endswith(block) for s in shapes), (block, sorted(shapes))
+    # nothing at the widths of the projections (2048, 8512, 4352), of the MLP
+    # (8192 wide) or of the gate and the norm (bf16 or float32 [2,8192,4096]
+    # other than x on its way in and y on its way out)
+    assert not [k for k in picked if re.search(r"2048|8512|4352|8192,8192", k)], sorted(picked)
+    assert {k for k in picked if "4096" in k} <= {
+        "slice_convert_fusion:f32[2,8192,4096]", "reshape:bf16[2,8192,4096]",
+        "multiply_reduce_fusion:f32[4096]"}, sorted(picked)
+    assert "multiply_reduce_fusion:f32[4096]" in picked  # D's gradient
+    assert not [k for k, _ in ops if attention_ms.search(k)]
+
+    # the attention layer
+    assert gmh.PUBLISHED.layer_types[5] == "attention"
+    compiled, ops = executed(5)
+    _assert_mosaic(compiled)
+    keys = [k for k, _ in ops]
+    assert not [k for k in keys if scan_ms.search(k)]
+    picked = {k for k in keys if attention_ms.search(k)}
+    assert any(k.startswith("block_flash_fwd:") for k in picked), sorted(picked)
+    assert not [k for k in keys if "block_flash" in k
+                and not k.startswith("block_flash_fwd:")], sorted(set(keys))
+    shapes = {k.split(":")[1] for k in picked}
+    for tile in ("[32,1024,512]", "[32,1024,64]", "[32,512,64]"):
+        assert any(s.endswith(tile) for s in shapes), (tile, sorted(shapes))
