@@ -24,6 +24,7 @@ import sys
 import time
 from typing import Any, Dict, List, Optional
 
+from perfbench import optable
 from perfbench import trace as trace_mod
 from perfbench.catalog import Catalog, Cell
 from perfbench.references.plain import (
@@ -234,12 +235,19 @@ def reference_check(cell: Cell, cfg, params, x, y) -> Dict[str, Any]:
     return {
         "reference_loss": float(ref_loss),
         "cell_rel_err": errors,
-        "cell_rel_err_max": max(errors),
+        "cell_rel_err_max": worst(errors),
         "forward_macs_per_img": tally.macs // x.shape[0],
         "forward_macs_per_img_by_kind": {
             k: v // x.shape[0] for k, v in tally.by_kind.items()},
         "model_flops_per_img": model_flops(tally.macs) // x.shape[0],
     }
+
+
+def worst(errors: List[float]) -> float:
+    """The largest error, and not a number where any is none: ``max()`` keeps
+    its first argument against a NaN, so a cell that is not a number passed
+    by wherever it was not the first (PR 35)."""
+    return float("nan") if any(map(math.isnan, errors)) else max(errors)
 
 
 def check_stored_flops(cell: Cell, counted: int) -> None:
@@ -317,12 +325,13 @@ def trace_options():
     return opts
 
 
-def hbm_bytes(step, state, x, y) -> Dict[str, int]:
-    """``memory_analysis()`` of the step executable for the live arguments:
-    what the program holds on a device while it runs.  The runtime's
-    ``peak_bytes_in_use`` leaves out the program's temporaries (it read
-    2.2 GiB where this reads 12.55)."""
-    ma = step.lower(state, x, y).compile().memory_analysis()
+def hbm_bytes(compiled) -> Dict[str, int]:
+    """``memory_analysis()`` of the step executable for the live arguments
+    (``step.lower(state, x, y).compile()``: the program that ran, from jax's
+    own cache): what the program holds on a device while it runs.  The
+    runtime's ``peak_bytes_in_use`` leaves out the program's temporaries (it
+    read 2.2 GiB where this reads 12.55)."""
+    ma = compiled.memory_analysis()
     out = {k: int(getattr(ma, k + "_size_in_bytes"))
            for k in ("argument", "output", "temp", "alias", "generated_code")}
     out["total"] = (out["argument"] + out["output"] + out["temp"]
@@ -417,7 +426,8 @@ def run_cell(catalog: Catalog, cell: Cell, *, seed: int, seconds: float,
         failed = sum(not math.isfinite(v) for v in losses[lo:hi])
 
         t_mem = time.perf_counter()
-        hbm = hbm_bytes(step, state, x0, y0)
+        compiled = step.lower(state, x0, y0).compile()
+        hbm = hbm_bytes(compiled)
         mem_s = time.perf_counter() - t_mem
         state_finite = all_finite(state)
 
@@ -425,6 +435,18 @@ def run_cell(catalog: Catalog, cell: Cell, *, seed: int, seconds: float,
             trace_info = trace_mod.reduce_trace(trace_dir, STEP_PROGRAM)
             if not keep_trace:
                 shutil.rmtree(trace_dir, ignore_errors=True)
+            # What each traced instruction is, from the text of the same
+            # executable: after the window, so neither it nor set-up sees it.
+            t_join = time.perf_counter()
+            text = compiled.as_text()
+            if keep_trace:
+                with open(os.path.join(out_dir, f"step.{tag}.hlo.txt"), "w",
+                          encoding="utf-8") as f:
+                    f.write(text)
+            trace_info["joined"] = optable.join(
+                trace_info["inst_seconds"], optable.parse(text))
+            trace_info["joined"]["seconds_to_join"] = (
+                time.perf_counter() - t_join)
 
     tolerances = cell.config["tolerances"]
     table = compared(
@@ -481,6 +503,9 @@ def run_cell(catalog: Catalog, cell: Cell, *, seed: int, seconds: float,
             device["busy_s"] = trace_info["busy_s"]
             device["window_s"] = trace_info["window_s"]
             result["breakdown"] = breakdown(trace_info, spans)
+            result["device_classes"] = classes_line(trace_info)
+            say(f"perfbench: device time by class "
+                f"{json.dumps(result['device_classes'])}")
             stretch = (statistics.median(traced_spans["period"])
                        / statistics.median(spans["period"]))
             say(f"perfbench: tracing stretched the traced steps' period by "
@@ -530,16 +555,38 @@ def run_cell(catalog: Catalog, cell: Cell, *, seed: int, seconds: float,
     return result
 
 
+def classes_line(trace_info: Dict[str, Any]) -> Dict[str, Any]:
+    """What a traced line says of the join: the share of the op time that
+    found its instruction in the compiled step's text (under
+    ``optable.JOIN_FLOOR`` the class metrics read nothing), the classes' ms a
+    traced period, and their sum over the step program's device time, which
+    is 1 within a hundredth where nothing is counted twice or left out."""
+    joined = trace_info["joined"]
+    periods = max(trace_info["periods"], 1)
+    ms = {c: 0.0 for c in optable.CLASSES}
+    for inst in joined["instructions"].values():
+        ms[inst["cls"]] = ms.get(inst["cls"], 0.0) + inst["seconds"] * 1e3 / periods
+    device_step = statistics.median(trace_info["chips"][0]["step_ms"])
+    return {"found_share": joined["found_share"], "join_holds": joined["holds"],
+            "lost_ms": joined["lost_seconds"] * 1e3 / periods, "ms": ms,
+            "sum_over_device_step": sum(ms.values()) / device_step}
+
+
 def breakdown(trace_info: Dict[str, Any], spans: Dict[str, List[float]]
               ) -> Dict[str, Any]:
-    """The ten largest sums of device time by instruction, and the idle gap
-    of the traced periods shared out over what the host was doing.  The loop
-    is synchronous (the next step is called once this one's loss is on the
-    host), so the device idles through all of ``fetch`` and ``loop_other``;
-    what is left of the gap is host time inside the step call and the loss
-    fetch that the device did not cover: first ``dispatch``, then
-    ``loss_wait``."""
-    ops = sorted(trace_info["op_seconds"].items(), key=lambda kv: -kv[1])[:10]
+    """The ten largest sums of device time by instruction (by what the
+    compiled step says the instruction is, ``optable``'s key with every result
+    and the class, where the join holds; else by name and first result), and
+    the idle gap of the traced periods shared out over what the host was
+    doing.  The loop is synchronous (the next step is called once this one's
+    loss is on the host), so the device idles through all of ``fetch`` and
+    ``loop_other``; what is left of the gap is host time inside the step call
+    and the loss fetch that the device did not cover: first ``dispatch``,
+    then ``loss_wait``."""
+    joined = trace_info.get("joined")
+    by_key = (optable.by_key(joined) if joined and joined["holds"]
+              else trace_info["op_seconds"])
+    ops = sorted(by_key.items(), key=lambda kv: -kv[1])[:10]
     med = {k: statistics.median(v) / 1e3 for k, v in spans.items()}
     device_step = statistics.median(trace_info["chips"][0]["step_ms"]) / 1e3
     gap = max(med["period"] - device_step, 0.0)
@@ -562,7 +609,8 @@ def main(argv: List[str], t0: float) -> int:
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
     p.add_argument("--keep-trace", action="store_true",
-                   help="leave the profiler's files under perfbench/out")
+                   help="leave the profiler's files and the step executable's "
+                        "text under perfbench/out")
     args = p.parse_args(argv)
     catalog = Catalog()
     cell = catalog.cell(args.workload)

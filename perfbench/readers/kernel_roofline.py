@@ -1,8 +1,8 @@
 """A kernel's share of its roofline, in %: the least time the chip could take
 for the work the kernel executed in a step (the larger of operations over the
 chip's bf16 peak and bytes over its memory bandwidth, ``peaks.json``), over
-the device time its instructions took (``trace_ops_ms``'s sum for
-``pattern``).  ``work`` names one of the functions below, which give the
+the device time its instructions took (``trace_ops_ms``'s sum for ``scope``
+or ``pattern``).  ``work`` names one of the functions below, which give the
 operations and bytes a step executes; each states its factor for the forward
 pass that per-cell remat runs twice, and takes the number of its ``layers``
 from the metric's file (the configuration's: the process holds the sites of
@@ -99,8 +99,8 @@ def attention_work(record, *, seq_len, head_dim, heads, kv_heads, layers):
     return (2 + 2.5) * 2 * macs_img * batch, 3 * 2 * values
 
 
-def read(record, pattern, work, params):
-    seconds = _ops.op_seconds(record, pattern)
+def read(record, work, params, pattern=None, scope=None):
+    seconds = _ops.op_seconds(record, pattern, scope)
     flops_peak = record["peaks"].get("bf16_flops")
     if seconds is None or not flops_peak:
         return None

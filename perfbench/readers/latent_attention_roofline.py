@@ -2,9 +2,9 @@
 could take for the causal attention a step executed, with keys wider than
 values (the larger of operations over the chip's bf16 peak and bytes over its
 memory bandwidth, ``peaks.json``), over the device time of the instructions
-``pattern`` picks (``trace_ops_ms``'s sum).  Beside ``kernel_roofline``'s
-``attention_work``, which counts a head of one width: here the five products
-of the backward pass run at their own widths.  None without a trace, a peak,
+``scope`` or ``pattern`` picks (``trace_ops_ms``'s sum).  Beside
+``kernel_roofline``'s ``attention_work``, which counts a head of one width:
+here the five products of the backward pass run at their own widths.  None without a trace, a peak,
 the instructions, the reference's count or the run's batch.
 """
 
@@ -46,8 +46,8 @@ def latent_attention_work(record, *, seq_len, heads, qk_head_dim, v_head_dim,
     return (2 + backward) * 2 * macs_img * batch, 3 * 2 * values
 
 
-def read(record, pattern, params):
-    seconds = _ops.op_seconds(record, pattern)
+def read(record, params, pattern=None, scope=None):
+    seconds = _ops.op_seconds(record, pattern, scope)
     flops_peak = record["peaks"].get("bf16_flops")
     if seconds is None or not flops_peak:
         return None

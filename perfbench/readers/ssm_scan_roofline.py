@@ -2,8 +2,8 @@
 could take for the recurrence a step executed, whatever implements it (the
 larger of operations over the chip's bf16 peak and bytes over its memory
 bandwidth, ``peaks.json``), over the device time of the instructions
-``pattern`` picks (``trace_ops_ms``'s sum).  None without a trace, a peak, the
-instructions, the reference's count or the run's batch.
+``scope`` or ``pattern`` picks (``trace_ops_ms``'s sum).  None without a
+trace, a peak, the instructions, the reference's count or the run's batch.
 """
 
 import os
@@ -46,8 +46,8 @@ def ssm_scan_work(record, *, seq_len, heads, head_dim, state, layers):
     return passes * 2 * macs_img * batch, passes * 2 * values
 
 
-def read(record, pattern, params):
-    seconds = _ops.op_seconds(record, pattern)
+def read(record, params, pattern=None, scope=None):
+    seconds = _ops.op_seconds(record, pattern, scope)
     flops_peak = record["peaks"].get("bf16_flops")
     if seconds is None or not flops_peak:
         return None

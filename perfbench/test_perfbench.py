@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench import harness, trace
+from perfbench import harness, optable, trace
 from perfbench.catalog import ROOT, Catalog
 from perfbench.references import plain
 
@@ -211,15 +211,15 @@ def test_seed_is_reduced_below_2_to_the_31():
 SEED = 2147483659
 
 
-def _run(tiny, cell, chips=1, say=lambda text: None):
+def _run(tiny, cell, chips=1, say=lambda text: None, trace=False):
     """One short run of ``cell``: the result and the details file."""
     out_dir = os.path.join(tiny.bench_dir, "out")
     result = harness.run_cell(
-        tiny, cell, seed=SEED, seconds=0.2, trace=False,
+        tiny, cell, seed=SEED, seconds=0.2, trace=trace,
         t0=time.perf_counter(), devices=jax.devices()[:chips],
         out_dir=out_dir, say=say)
     with open(os.path.join(
-            out_dir, f"{cell.name}.seed{SEED}.trace0.json")) as f:
+            out_dir, f"{cell.name}.seed{SEED}.trace{int(trace)}.json")) as f:
         return result, json.load(f)
 
 
@@ -244,6 +244,23 @@ def test_cell_runs_through_build_train_and_run_supervised(tiny):
     assert details["compiles"].get("window") is None
     assert details["memory_analysis"]["temp"] > 0
     assert len(details["spans_ms"]["period"]) == result["attempted"] - 1
+
+
+def test_a_traced_run_without_a_device_plane_reads_no_device_metric(tiny):
+    """``--trace 1`` through the whole of ``run_cell`` here: the step
+    executable gives its text and the table is built, the CPU's trace has no
+    device plane to join it to, so the line carries the host's metrics and
+    none of the device's, and stays correct."""
+    cell = tiny.cell("tiny_resnet.32.bs2")
+    result, details = _run(tiny, cell, trace=True)
+    assert result["correct"] and list(result)[-1] == "compared"
+    joined = details["trace"]["joined"]
+    assert joined["rows"] > 10 and not joined["holds"]
+    assert joined["instructions"] == {} and details["trace"]["periods"] == 0
+    assert "fetch_ms" in result["metrics"]
+    assert not set(result["metrics"]) & {
+        "device_step_ms", "product_ms", "move_ms", "recompute_ms"}
+    assert "breakdown" not in result and "device_classes" not in result
 
 
 # --- a token model and a spatial cell, added as files only -------------------
@@ -429,10 +446,23 @@ GOOD = dict(losses=[6.91, 6.90, 6.92], anomalies=0, state_finite=True,
     # the loss of another batch: 0.2 % off and more
     ({"first_loss": 6.926}, "first_loss_matches_reference"),
     ({"cell_rel_err_max": 0.3}, "cells_match_reference"),
+    # a cell that is not a number, wherever it stands among the cells
+    ({"cell_rel_err_max": harness.worst([0.004, math.nan, 0.003])},
+     "cells_match_reference"),
 ])
 def test_each_condition_of_correct_fails_when_it_should(change, fails):
     checks = harness.verdict(harness.compared(**{**GOOD, **change}))
     assert [k for k, ok in checks.items() if not ok] == ([fails] if fails else [])
+
+
+def test_the_worst_cell_is_not_a_number_where_any_cell_is_none():
+    """``max()`` keeps its first argument against a NaN: PR 35's planted
+    overflow of the state read as the cell before it."""
+    assert max([0.004, math.nan]) == 0.004  # what the harness took
+    assert math.isnan(harness.worst([0.004, math.nan]))
+    assert math.isnan(harness.worst([math.nan, 0.004]))
+    assert harness.worst([0.004, 0.3, 0.003]) == 0.3
+    assert harness.worst([0.004, math.inf]) == math.inf  # fails by its size
 
 
 def test_nan_in_a_leaf_is_found():
@@ -635,9 +665,14 @@ def test_busy_time_comes_from_modules_not_from_ops():
               "fusion(f32[1,1,3328,1664] %a)")
     tup = "%fusion.15248 = (bf16[416]{0:T(512)}, bf16[416]{0}) fusion(bf16[4] %q)"
     loop = "%while.7 = (s32[], bf16[8]{0}) while((s32[], bf16[8]{0}) %t), body=%b"
+    # a conditional is named cond and a call closed_call: containers by opcode
+    cond = ("%cond.3 = f32[32,512,192]{2,1,0:T(8,128)} conditional(s32[] %i, "
+            "f32[8] %a, f32[8] %b), branch_computations={%x, %y}")
+    call = "%closed_call.9 = bf16[8]{0} call(bf16[8]{0} %p), to_apply=%body.1"
     ops = []
     for base in (0, 1000, 2000):  # the while spans the two ops of its body
         ops += [(fusion, base + 0, 200), (loop, base + 300, 600),
+                (cond, base + 350, 500), (call, base + 380, 300),
                 (update, base + 400, 200), (tup, base + 800, 100)]
     prof = _profile([
         ("/host:CPU", [("python", [("step", 0, 3000)])]),
@@ -657,6 +692,9 @@ def test_busy_time_comes_from_modules_not_from_ops():
         "fusion:bf16[1052676,208]": 400e-9,
         "multiply_subtract_fusion:f32[1,1,3328,1664]": 400e-9,
         "fusion:bf16[416]": 200e-9})
+    assert out["inst_seconds"] == pytest.approx({
+        "fusion.33": 400e-9, "multiply_subtract_fusion.13": 400e-9,
+        "fusion.15248": 200e-9})
     record = {"trace": out, "spans": {"period": [1000e-6] * 5}}
     cat = Catalog()
     assert cat.read_layer_metric("device_step_ms", record) == pytest.approx(900e-6)
@@ -685,6 +723,293 @@ def test_chips_of_a_mesh_are_averaged_and_the_first_is_broken_down():
     record = {"trace": out, "spans": {"period": [1000e-6] * 5}}
     assert Catalog().read_layer_metric("device_step_ms", record) == (
         pytest.approx(800e-6))
+
+
+# --- what an instruction is ---------------------------------------------------
+
+CUTS = os.path.join(ROOT, "perfbench", "fixtures", "compiled_step_cuts.hlo.txt")
+SIX = ("attention_ms", "attention_roofline_pct", "mla_attention_ms",
+       "mla_attention_roofline_pct", "ssm_scan_ms", "ssm_scan_roofline_pct")
+
+
+@pytest.fixture(scope="module")
+def cuts():
+    with open(CUTS, encoding="utf-8") as f:
+        return optable.parse(f.read())
+
+
+@pytest.mark.parametrize("name, cls, op_pass, key", [
+    # granite: a kOutput fusion whose FIRST result is a norm's scale gradient
+    # and whose body is the backward's input-gradient product
+    ("fusion.1069", "product", "backward",
+     "fusion:f32[2048]+f32[2,8192]+bf16[2,8192,2048]{product}"),
+    # ResNet: a 3x3 convolution with BatchNorm's two sums as by-outputs ...
+    ("fusion.1207", "product", "forward",
+     "fusion:f32[128]+f32[128]+bf16[1024,8,17,128]{product}"),
+    # ... and the stand-alone sums of the same name and first result
+    ("fusion.889", "reduce", "recompute", "fusion:f32[128]+f32[128]{reduce}"),
+    ("fusion.8724", "move", "recompute",
+     "fusion:bf16[512,1,528,128]+bf16[512,1,528,128]{move}"),
+    # Kanana-2: a conditional named cond; one operation in two instructions
+    ("cond.1540", "container", "recompute", None),
+    ("slice-start.804", "move", "forward", None),
+    ("slice-done.804", "move", "forward", "slice-done:pred[8192,6]{move}"),
+    # the chip's own text: an async-done is read from what its start calls
+    ("slice-start.482", "move", "forward", None),
+    ("slice-done.482", "move", "forward", "slice-done:f32[1,32,8192]{move}"),
+])
+def test_the_compiled_text_says_what_an_instruction_is(cuts, name, cls, op_pass,
+                                                       key):
+    said = optable.describe(cuts[name])
+    assert (said["cls"], said["pass"]) == (cls, op_pass)
+    assert key is None or said["key"] == key
+    if cuts[name]["opcode"] == "fusion":  # XLA's own word for the two kinds
+        assert cuts[name]["kind"] == ("Output" if cls == "product" else "Loop")
+    if cls == "product":  # by name and first result it read as a reduction
+        line = next(l for l in open(CUTS) if l.lstrip().startswith(f"%{name} = "))
+        assert re.match(r"fusion:f32\[(2048|128)\]$", trace.op_key(line.strip()))
+
+
+def _plane_of(cuts, busy):
+    """Module events of 1000 ns and, back to back inside each, op events for
+    ``busy`` = [(instruction, ns)], their text the fixture's own lines, with
+    the conditional spanning what follows it."""
+    lines = {}
+    for line in open(CUTS):
+        head = trace.instruction_head(line.strip())
+        if head:
+            lines[head[0]] = line.strip()
+    ops, modules = [], []
+    for base in (0, 2000, 4000):
+        at = base
+        modules.append(("jit_step(5)", base, sum(ns for _, ns in busy)))
+        for name, ns in busy:
+            if cuts.get(name, {}).get("opcode") == "conditional":
+                ops.append((lines[name], at, base + modules[-1][2] - at))
+                continue
+            ops.append((lines.get(name, f"%{name} = f32[8]{{0}} fusion(f32[8] %p)"),
+                        at, ns))
+            at += ns
+    return _profile([("/device:TPU:0", [("XLA Modules", modules), ("XLA Ops", ops)])])
+
+
+def test_the_five_classes_sum_to_the_module_time(cuts):
+    """Every op event of the step program is in one of the five classes, the
+    conditional's in none (its body is listed op by op), a start and its done
+    once each: the sum is the step program's device time."""
+    busy = [("fusion.1069", 400), ("fusion.1207", 150), ("fusion.889", 50),
+            ("cond.1540", 0), ("fusion.8724", 100), ("slice-start.804", 10),
+            ("slice-done.804", 40)]
+    out = trace.reduce_planes(trace.read_planes(_plane_of(cuts, busy)),
+                              harness.STEP_PROGRAM)
+    assert "cond.1540" not in out["inst_seconds"]
+    out["joined"] = optable.join(out["inst_seconds"], cuts)
+    line = harness.classes_line(out)
+    assert line["join_holds"] and line["found_share"] == pytest.approx(1.0)
+    assert line["sum_over_device_step"] == pytest.approx(1.0)
+    record = {"trace": out}
+    cat = Catalog()
+    read = {m: cat.read_layer_metric(m + "_ms", record) for m in optable.CLASSES}
+    # nothing of a class ran: nothing to read, not a nought
+    assert read["kernel"] is None and read["elementwise"] is None
+    assert (read["product"], read["reduce"], read["move"]) == pytest.approx(
+        (550e-6, 50e-6, 150e-6))
+    assert sum(v for v in read.values() if v) == pytest.approx(
+        cat.read_layer_metric("device_step_ms", record))
+    # what remat costs: the recomputed sums and relayout
+    assert cat.read_layer_metric("recompute_ms", record) == pytest.approx(150e-6)
+    # the breakdown's keys carry every result and the class; no container
+    b = harness.breakdown(out, {k: [1.0] for k in (
+        "period", "fetch", "loop_other", "dispatch")})
+    keys = [k for k, _ in b["device_ops"]]
+    assert keys[0] == "fusion:f32[2048]+f32[2,8192]+bf16[2,8192,2048]{product}"
+    assert all(re.search(r"\{(product|kernel|reduce|move|elementwise)\}$", k)
+               for k in keys)
+    assert not [k for k in keys
+                if k.startswith(("cond:", "while:", "conditional:", "call:"))]
+
+
+def test_a_trace_of_another_executable_reads_no_class(cuts):
+    """At least 99 % of the op time has to find its instruction; a step whose
+    text is not the traced program's reads nothing, and the line says how
+    much was lost."""
+    busy = [("fusion.1069", 980), ("fusion.424242", 20)]
+    out = trace.reduce_planes(trace.read_planes(_plane_of(cuts, busy)),
+                              harness.STEP_PROGRAM)
+    out["joined"] = optable.join(out["inst_seconds"], cuts)
+    line = harness.classes_line(out)
+    assert not line["join_holds"] and line["found_share"] == pytest.approx(0.98)
+    assert line["lost_ms"] == pytest.approx(20e-6)
+    cat = Catalog()
+    assert cat.read_layer_metric("product_ms", {"trace": out}) is None
+    assert cat.read_layer_metric("recompute_ms", {"trace": out}) is None
+    # the breakdown falls back to name and first result
+    b = harness.breakdown(out, {k: [1.0] for k in (
+        "period", "fetch", "loop_other", "dispatch")})
+    assert b["device_ops"][0][0] == "fusion:f32[2048]"
+    busy[1] = ("fusion.424242", 9)  # under a hundredth lost: the join holds
+    out = trace.reduce_planes(trace.read_planes(_plane_of(cuts, busy)),
+                              harness.STEP_PROGRAM)
+    out["joined"] = optable.join(out["inst_seconds"], cuts)
+    assert cat.read_layer_metric("product_ms", {"trace": out}) == (
+        pytest.approx(980e-6))
+
+
+@pytest.mark.parametrize("metric", SIX)
+def test_the_six_kernel_metrics_name_a_scope(metric):
+    """Each names a scope of the program; a pattern may stand beside it for a
+    program that has not opened the scope (and for tests/test_tpu_compile.py,
+    which holds the compiled layers to the three ``_ms`` patterns)."""
+    with open(os.path.join(ROOT, "perfbench", "layer_metrics",
+                           metric + ".json")) as f:
+        spec = json.load(f)
+    twin = metric.replace("_roofline_pct", "_ms")
+    with open(os.path.join(ROOT, "perfbench", "layer_metrics",
+                           twin + ".json")) as f:
+        twin_params = json.load(f)["params"]
+    assert spec["params"]["scope"] == (
+        "ssm_scan" if metric.startswith("ssm") else "attention_core")
+    assert spec["params"]["scope"] == twin_params["scope"]
+    assert spec["params"].get("pattern") == twin_params.get("pattern")
+    assert "padded to 256 lanes" not in spec["what"]
+    assert spec["params"]["scope"] in spec["what"]
+
+
+def _attention_layer_with_an_opaque_backward():
+    """A layer compiled here: projections round an attention whose forward
+    and whose backward rule are each ONE instruction the compiler cannot look
+    into (a callback: what a Pallas kernel is to XLA), the scope
+    ``attention_core`` round the call and inside the backward rule, under
+    ``jax.checkpoint`` as the step runs its cells."""
+    def opaque(*args, like):
+        return jax.pure_callback(
+            lambda *a: np.zeros(like.shape, like.dtype),
+            jax.ShapeDtypeStruct(like.shape, like.dtype), *args)
+
+    @jax.custom_vjp
+    def attention(q, k, v):
+        return opaque(q, k, v, like=q)
+
+    def forward(q, k, v):
+        return attention(q, k, v), (q, k, v)
+
+    def backward(res, do):
+        with jax.named_scope("attention_core"):
+            dq = opaque(*res, do, like=do)
+        return dq, dq, dq
+
+    attention.defvjp(forward, backward)
+
+    def layer(p, x):
+        q, k, v = (jnp.tanh(x @ p[n]) for n in ("q", "k", "v"))
+        with jax.named_scope("attention_core"):
+            o = attention(q, k, v)
+        return jnp.tanh(o) @ p["o"]
+
+    def grads(p, x):
+        # the loss too: a forward pass nobody reads is not compiled
+        return jax.value_and_grad(
+            lambda p, x: jnp.sum(jax.checkpoint(layer)(p, x)))(p, x)
+
+    p = {n: jnp.ones((64, 64)) for n in "qkvo"}
+    return jax.jit(grads).lower(p, jnp.ones((128, 64))).compile().as_text()
+
+
+def test_an_opaque_backward_under_the_scope_keeps_the_roofline_under_100(
+        monkeypatch):
+    """The four attention metrics on a program whose backward is one opaque
+    instruction: picked by the scope, forward, recomputed and backward, and
+    nothing of the projections; the roofline of two forwards and a backward
+    stays under 100 %.  By the shapes of XLA's tiles alone (PR 35's files)
+    the same trace keeps the forward under the whole work's count: 117 %."""
+    import mpi4dl_tpu.obs.spans as spans
+
+    rows = optable.parse(_attention_layer_with_an_opaque_backward())
+    fused = {id(f) for r in rows.values() for f in r["fused"]}
+    said = {name: optable.describe(row) for name, row in rows.items()
+            if id(row) not in fused}
+    under = {n for n, d in said.items() if "attention_core" in d["scopes"]
+             and d["cls"] != "container"}
+    calls = {n for n in under if rows[n]["opcode"] == "custom-call"}
+    passes = sorted(said[n]["pass"] for n in calls)
+    assert passes == ["backward", "forward", "recompute"], passes
+    assert not [n for n in under if said[n]["cls"] == "product"], under
+    # Kanana-2's readings (PR 34): the forward kernel 273.8 ms in two passes,
+    # the work's least time 28.87 % of 1,111.8 ms; a backward kernel of 400
+    least_s = 0.2887 * 1.1118
+    seconds = {n: 0.0 for n in said if said[n]["cls"] != "container"}
+    for n in calls:
+        seconds[n] = {"forward": 0.1369, "recompute": 0.1369,
+                      "backward": 0.400}[said[n]["pass"]]
+    projections = [n for n, d in said.items() if d["cls"] == "product"]
+    assert projections
+    for n in projections:
+        seconds[n] = 0.05
+    rec = spans.Recorder(annotate=False)
+    monkeypatch.setattr(spans, "_RECORDER", rec)
+    with rec.span("run", steps=3, profile=False, global_batch=4):
+        for g in range(3):
+            with rec.span("step", gstep=g):
+                pass
+    record = {
+        "spans": {"dispatch": [1.0] * 3},
+        "trace": {"periods": 1, "inst_seconds": seconds,
+                  # by name and first result: the forward kernel alone
+                  "op_seconds": {"block_flash_fwd:bf16[4,8192,4096]": 0.2738,
+                                 "fusion:bf16[4,8192,2048]": 0.4},
+                  "joined": optable.join(seconds, rows)},
+        "model": {"forward_macs_per_img": {
+            "attn_scores": least_s * 197e12 / ((2 + 832 / 320) * 2 * 4)}},
+        "peaks": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+    }
+    cat = Catalog()
+    assert cat.read_layer_metric("mla_attention_ms", record) == pytest.approx(
+        1e3 * (0.2738 + 0.400))
+    roofline = cat.read_layer_metric("mla_attention_roofline_pct", record)
+    assert roofline == pytest.approx(100 * least_s / 0.6738) and roofline < 100
+    # the same program without the scope, read by the tiles' shapes
+    record["trace"]["joined"] = None
+    assert cat.read_layer_metric("mla_attention_roofline_pct", record) == (
+        pytest.approx(117.2, abs=0.1))
+
+
+def test_a_scope_is_read_where_the_program_opens_it_else_the_pattern(cuts):
+    """``ssm_scan`` on instructions made up under and outside the scope; a
+    program that opens no such scope, or a trace that found no rows, is read
+    by the pattern; neither gives nothing."""
+    text = """
+%fused_computation.1 (p: f32[8]) -> f32[2,32,256,64] {
+  %p = f32[8]{0} parameter(0)
+  %d = f32[2,32,256,64]{3,2,1,0} dot(%p, %p), metadata={op_name="jit(step)/transpose(jvp(cell03))/jvp(cell03)/checkpoint/ssm_scan/dot_general"}
+  ROOT %s = f32[2,32,256,64]{3,2,1,0} multiply(%d, %d), metadata={op_name="jit(step)/transpose(jvp(cell03))/jvp(cell03)/checkpoint/mul"}
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %fusion.1 = f32[2,32,256,64]{3,2,1,0} fusion(%a), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(step)/transpose(jvp(cell03))/jvp(cell03)/checkpoint/mul"}
+  %copy.2 = f32[2,32,256,64,64]{4,3,2,1,0} copy(%a), metadata={op_name="jit(step)/jvp(cell03)/convert_element_type"}
+  %fusion.3 = bf16[2,8192,8512]{2,1,0} fusion(%a), kind=kLoop, calls=%fused_computation.1
+}
+"""
+    rows = optable.parse(text)
+    # the fusion's own op_name is its root's (the gate's); its product speaks
+    assert "ssm_scan" in optable.describe(rows["fusion.1"])["scopes"]
+    seconds = {"fusion.1": 0.3, "copy.2": 0.1, "fusion.3": 0.6}
+    by_key = {"fusion:f32[2,32,256,64]": 0.3, "copy:f32[2,32,256,64,64]": 0.1,
+              "fusion:bf16[2,8192,8512]": 0.6}
+    trace_info = {"periods": 2, "op_seconds": by_key, "inst_seconds": seconds,
+                  "joined": optable.join(seconds, rows)}
+    cat = Catalog()
+    # by the scope: the fusion; the copy XLA gave a neighbour's name is out
+    # (fusion.3 shares the fused computation: a made-up text, not a program)
+    assert cat.read_layer_metric("ssm_scan_ms", {"trace": trace_info}) == (
+        pytest.approx(1e3 * (0.3 + 0.6) / 2))
+    trace_info["joined"] = optable.join(seconds, {})  # no row found
+    assert cat.read_layer_metric("ssm_scan_ms", {"trace": trace_info}) == (
+        pytest.approx(1e3 * (0.3 + 0.1) / 2))  # PR 35's pattern
+    del trace_info["joined"]
+    assert cat.read_layer_metric("ssm_scan_ms", {"trace": trace_info}) == (
+        pytest.approx(1e3 * (0.3 + 0.1) / 2))
+    assert cat.read_layer_metric("attention_ms", {"trace": trace_info}) is None
 
 
 def test_mfu_reader():
