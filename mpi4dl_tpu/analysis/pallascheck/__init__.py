@@ -54,7 +54,9 @@ accumulator-init coverage (interp.py):
 - ``uninit-accumulator`` — a scratch/output ref read before any write, or
   scratch read at the start of a revisited-output run while still holding
   the previous block's values (an ``@pl.when(k == 0)`` guard that does not
-  cover every revisit).
+  cover every revisit).  A scratch's runs are those of the outputs its
+  values are stored into: accumulators of outputs that move at two grains
+  (a flash backward's dq beside its dk and dv) are each held to their own.
 
 Entry points: :func:`check_spec`, :func:`check_case`,
 :func:`check_registry`, :func:`pallas_contract` (the contract gate's

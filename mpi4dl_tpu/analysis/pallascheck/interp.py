@@ -15,7 +15,13 @@ is an ``unmatched-dma`` finding by construction.
 Tracked state:
 
 - per-ref written/maybe-written (global across the grid — scratch
-  persists) and per-output-visit-run written sets (grid.output_runs);
+  persists) and per-visit-run written sets.  A scratch's runs are those of
+  the outputs its values are stored into (:func:`_scratch_feeds`, a static
+  value-flow pass): an accumulator that lives across several blocks of one
+  output for the sake of another, coarser one (a flash backward's dq,
+  resident over the k tiles while dk and dv leave a tile at a time) is held
+  to the run of the output it feeds; a scratch that reaches no output is
+  held to the runs in which EVERY output block stands (grid.output_runs);
 - in-flight DMAs keyed by semaphore ref, carrying src/dst refs: a read of
   a dst before its wait or a write to a src/dst while in flight is a
   ``dma-race`` (the WAR hazard Mosaic does not fence, as an invariant);
@@ -25,13 +31,15 @@ Tracked state:
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax.tree_util as jtu
 
 from mpi4dl_tpu.analysis.pallascheck import Finding, point_class
-from mpi4dl_tpu.analysis.pallascheck.grid import grid_points, output_runs
+from mpi4dl_tpu.analysis.pallascheck.grid import (
+    block_offsets, grid_points, output_runs)
 from mpi4dl_tpu.analysis.pallascheck.trace import KernelSpec
 
 UNKNOWN = object()
@@ -78,7 +86,7 @@ class _Ctx:
         self._seen: set = set()
         self.point: Tuple[int, ...] = ()
         self.cls: str = ""
-        self.run_revisit = False
+        self.run_revisit: Dict[int, bool] = {}  # by scratch pos, this point
         self.remote_ids: List[Tuple[Tuple[int, ...], Any]] = []
 
     def emit(self, kind: str, message: str, cls: Optional[str] = None) -> None:
@@ -191,7 +199,7 @@ def _check_read(ctx: _Ctx, state: _State, pos: int) -> None:
             f"{op.name} ({op.role}) is read at grid point {ctx.point} "
             "before anything ever wrote it",
         )
-    elif (ctx.run_revisit and op.role == "scratch"
+    elif (op.role == "scratch" and ctx.run_revisit.get(pos, False)
           and pos not in state.run_written and pos not in state.run_maybe
           and pos in state.written):
         ctx.emit(
@@ -446,23 +454,108 @@ def _device_map_findings(ctx: _Ctx) -> None:
             seen[dev] = pt
 
 
+def _scratch_feeds(spec: KernelSpec) -> Dict[int, frozenset]:
+    """For each scratch ref (by pos), the OUTPUT refs that a value read from
+    it is ever stored into, directly or through other scratch: one static
+    pass over the kernel jaxpr, every branch walked, a value carrying the
+    scratch refs it was computed from."""
+    roles = {op.pos: op.role for op in spec.operands}
+    stored: Dict[int, set] = {}  # scratch pos -> refs its values reach
+
+    def walk(jaxpr, env: Dict) -> None:
+        def bound(v):  # a ref, the scratch refs a value came from, or nothing
+            return None if hasattr(v, "val") else env.get(v)
+
+        def flow(v):
+            got = bound(v)
+            return got if isinstance(got, frozenset) else frozenset()
+
+        def enter(inner, operands) -> List:
+            ij = getattr(inner, "jaxpr", inner)
+            for bv, ov in zip(ij.invars, operands):
+                env[bv] = bound(ov)
+            walk(ij, env)
+            return [flow(v) for v in ij.outvars]
+
+        for eqn in jaxpr.eqns:
+            prim = eqn.primitive.name
+            ref = bound(eqn.invars[0]) if eqn.invars else None
+            if prim in ("get", "swap", "addupdate") and isinstance(ref, _Ref):
+                own = (frozenset({ref.pos}) if roles[ref.pos] == "scratch"
+                       else frozenset())
+                if prim != "get":
+                    for src in flow(eqn.invars[1]):
+                        stored.setdefault(src, set()).add(ref.pos)
+                for ov in eqn.outvars:
+                    env[ov] = own
+                continue
+            if prim == "cond":
+                outs = [enter(b, eqn.invars[1:]) for b in eqn.params["branches"]]
+            elif prim in ("while", "scan"):
+                inner = eqn.params.get("jaxpr") or eqn.params.get("body_jaxpr")
+                n = len(getattr(inner, "jaxpr", inner).invars)
+                outs = [enter(inner, eqn.invars[-n:])] if inner is not None else []
+            else:
+                inner = (eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
+                         or eqn.params.get("fun_jaxpr"))
+                outs = [enter(inner, eqn.invars)] if inner is not None else []
+            through = frozenset().union(*(flow(v) for v in eqn.invars))
+            for i, ov in enumerate(eqn.outvars):
+                env[ov] = through.union(*(o[i] for o in outs if i < len(o)))
+
+    walk(spec.jaxpr, {spec.jaxpr.invars[op.pos]: _Ref(op.pos)
+                      for op in spec.operands})
+    feeds: Dict[int, frozenset] = {}
+    for op in spec.operands:
+        if op.role != "scratch":
+            continue
+        seen, todo = set(), [op.pos]
+        while todo:
+            for nxt in stored.get(todo.pop(), ()):
+                if nxt not in seen:
+                    seen.add(nxt)
+                    todo.append(nxt)
+        feeds[op.pos] = frozenset(p for p in seen if roles[p] == "out")
+    return feeds
+
+
+def _scratch_runs(spec: KernelSpec) -> Dict[int, List[int]]:
+    """For each scratch ref, the id of its visit run at every grid point: a
+    new run starts where the block of EVERY output it feeds moves on (the
+    outputs of the registered kernels' accumulators move together); a
+    scratch that feeds none keeps :func:`grid.output_runs`."""
+    offsets = block_offsets(spec)
+    fallback = output_runs(spec)
+    out: Dict[int, List[int]] = {}
+    for pos, fed in _scratch_feeds(spec).items():
+        fed = [p for p in fed if p in offsets]
+        if not fed:
+            out[pos] = fallback
+            continue
+        runs, run = [], 0
+        for t in range(len(fallback)):
+            if t and all(offsets[p][t] != offsets[p][t - 1]
+                         or offsets[p][t] is None for p in fed):
+                run += 1
+            runs.append(run)
+        out[pos] = runs
+    return out
+
+
 def interp_findings(spec: KernelSpec, case=None) -> List[Finding]:
     ctx = _Ctx(spec, case)
-    runs = output_runs(spec)
-    run_sizes: Dict[int, int] = {}
-    for r in runs:
-        run_sizes[r] = run_sizes.get(r, 0) + 1
+    runs = _scratch_runs(spec)
+    sizes = {pos: collections.Counter(r) for pos, r in runs.items()}
     points = grid_points(spec.grid)
     state = _State.fresh()
-    prev_run = None
     for t, point in enumerate(points):
         ctx.point = point
         ctx.cls = point_class(spec.grid, point)
-        ctx.run_revisit = run_sizes[runs[t]] > 1
-        if runs[t] != prev_run:
-            state.run_written = set()
-            state.run_maybe = set()
-            prev_run = runs[t]
+        for pos, r in runs.items():
+            ctx.run_revisit[pos] = sizes[pos][r[t]] > 1
+            if t == 0 or r[t] != r[t - 1]:
+                state.run_written.discard(pos)
+                state.run_maybe.discard(pos)
         env: Dict = {}
         for op in spec.operands:
             env[spec.jaxpr.invars[op.pos]] = _Ref(op.pos)

@@ -221,9 +221,12 @@ class LatentAttention(Layer):
         kv = parts["kv_b_proj"].apply(params["kv_b_proj"], c, ctx)
         q_pe = rotary(product(w_q_pe, x).reshape(b, s, h, rope), self.rope_theta)
         k_pe = rotary(product(w_k_pe, x)[:, :, None, :], self.rope_theta)
-        return pallas_latent_attention.latent_flash(
-            product(w_q, x), q_pe.reshape(b, s, h * rope), kv, k_pe[:, :, 0], h,
-            (nope + rope) ** -0.5)
+        q, q_pe, k_pe = product(w_q, x), q_pe.reshape(b, s, h * rope), k_pe[:, :, 0]
+        # the kernel alone: the scope by which a device trace finds attention
+        # itself, forward and recomputed (the backward rule opens its own)
+        with jax.named_scope("attention_core"):
+            return pallas_latent_attention.latent_flash(
+                q, q_pe, kv, k_pe, h, (nope + rope) ** -0.5)
 
     def _attend_einsum(self, params, x, ctx):
         """``[B, S, H·v_head]`` by ``ring_attention``'s einsum form, one

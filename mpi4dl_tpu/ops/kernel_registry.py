@@ -31,7 +31,8 @@ from typing import Callable, Optional, Sequence, Tuple
 # verified must be imported here (statically parsed, never executed by the
 # analyzer).
 from mpi4dl_tpu.ops.pallas_attention import block_flash
-from mpi4dl_tpu.ops.pallas_latent_attention import latent_flash
+from mpi4dl_tpu.ops.pallas_latent_attention import (
+    latent_flash, latent_flash_backward)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,36 +72,59 @@ def _flash_case(dtype: str, causal: bool):
     return KernelCase(name=f"block_flash:{variant}{dtype}", build=build)
 
 
+_LATENT_HEADS, _LATENT_TOKENS = 4, 300
+
+
+def _latent_operands(dtype: str):
+    """q, q_pe, kv, k_pe: four heads of 128 + 64 with values of 128 (the
+    published widths: two heads a grid step), 300 tokens padded to three
+    tiles of 128: grid (1, 2, 3, 3), a zero-padded tail in the last."""
+    import jax.numpy as jnp
+
+    dt = jnp.dtype(dtype)
+    return tuple(jnp.zeros((1, _LATENT_TOKENS, w), dt) for w in (
+        _LATENT_HEADS * 128, _LATENT_HEADS * 64, _LATENT_HEADS * 256, 64))
+
+
 def _latent_case(dtype: str):
     def build():
-        import jax.numpy as jnp
-
-        dt = jnp.dtype(dtype)
-        # Four heads of 128 + 64 with values of 128 (the published widths:
-        # two heads a grid step), 300 tokens padded to three tiles of 128:
-        # grid (1, 2, 3, 3), the padded-key masking tail in the last.
-        heads, s = 4, 300
-        q = jnp.zeros((1, s, heads * 128), dt)
-        q_pe = jnp.zeros((1, s, heads * 64), dt)
-        kv = jnp.zeros((1, s, heads * 256), dt)
-        k_pe = jnp.zeros((1, s, 64), dt)
         fn = lambda q, q_pe, kv, k_pe: latent_flash(  # noqa: E731
-            q, q_pe, kv, k_pe, heads, 192 ** -0.5, 128, 128, False
+            q, q_pe, kv, k_pe, _LATENT_HEADS, 192 ** -0.5, 128, 128, False
         )
-        return fn, (q, q_pe, kv, k_pe)
+        return fn, _latent_operands(dtype)
 
     return KernelCase(name=f"latent_flash:causal:{dtype}", build=build)
 
 
+def _latent_backward_case(dtype: str):
+    def build():
+        import jax.numpy as jnp
+
+        # The forward case's operands, k tiles before q tiles in the grid:
+        # three of the nine tiles above the diagonal (skipped), three on it
+        # (masked) and three below (whole).
+        q, q_pe, kv, k_pe = _latent_operands(dtype)
+        stat = jnp.zeros((1, _LATENT_HEADS, _LATENT_TOKENS), jnp.float32)
+        fn = lambda q, q_pe, kv, k_pe, o, m, l, do: latent_flash_backward(  # noqa: E731
+            q, q_pe, kv, k_pe, o, m, l, do, _LATENT_HEADS, 192 ** -0.5,
+            128, 128, False
+        )
+        return fn, (q, q_pe, kv, k_pe, q, stat, stat, q)
+
+    return KernelCase(name=f"latent_flash_backward:causal:{dtype}", build=build)
+
+
 # The raw (fp32) path and the bf16 compute path the mixed-precision/quant
 # engines dispatch (quant/kernels.py itself is pure jnp — no pallas_call,
-# which rule 12 verifies stays true); latent attention's forward kernel
-# (always causal) in both.
+# which rule 12 verifies stays true); latent attention's forward and
+# backward kernels (always causal) in both.
 REGISTRY: Tuple[KernelCase, ...] = (
     _flash_case("float32", causal=False),
     _flash_case("bfloat16", causal=True),
     _latent_case("float32"),
     _latent_case("bfloat16"),
+    _latent_backward_case("float32"),
+    _latent_backward_case("bfloat16"),
 )
 
 

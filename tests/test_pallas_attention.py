@@ -1,8 +1,10 @@
 """Pallas blockwise attention (ops/pallas_attention.py) — kernel vs einsum
 reference in interpret mode, and the flash ring path vs the einsum ring path
 on the 8-device CPU mesh (ops/ring.py use_flash=True); latent attention's
-forward kernel on the projections' layout (ops/pallas_latent_attention.py)
-against the einsum form, forward and gradients."""
+forward and backward kernels on the projections' layout
+(ops/pallas_latent_attention.py) against the einsum form, forward and
+gradients, and the backward against the rule it replaced (heads-first
+operands through ``_block_flash_bwd``, kept here as the oracle)."""
 
 import jax
 import jax.numpy as jnp
@@ -14,9 +16,9 @@ from jax.sharding import PartitionSpec as P
 
 from mpi4dl_tpu.mesh import MeshSpec, build_mesh
 from mpi4dl_tpu.ops.pallas_attention import (
-    block_flash, flash_attention_local, mlo_merge,
+    _block_flash_bwd, block_flash, flash_attention_local, mlo_merge,
 )
-from mpi4dl_tpu.ops.pallas_latent_attention import latent_flash
+from mpi4dl_tpu.ops.pallas_latent_attention import _forward, latent_flash
 from mpi4dl_tpu.ops.ring import ring_attention
 
 
@@ -214,6 +216,14 @@ def _rel(a, b):
     return float(np.linalg.norm(a - b) / np.linalg.norm(b))
 
 
+def _through(fn, ops):
+    """Output and gradients with respect to all four operands."""
+    def loss(*o):
+        out = fn(*o)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+    return jax.value_and_grad(loss, (0, 1, 2, 3), has_aux=True)(*ops)
+
+
 @pytest.mark.parametrize("batch", [1, 2])
 @pytest.mark.parametrize("seq", [256, 300], ids=["tiled", "ragged"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
@@ -227,18 +237,12 @@ def test_latent_flash_matches_the_einsum_form(dtype, seq, batch):
     ops = _latent_operands(batch, seq, h, nope, rope, dv, dtype)
     scale = (nope + rope) ** -0.5
 
-    def through(fn):
-        def loss(*o):
-            out = fn(*o)
-            return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
-        return jax.value_and_grad(loss, (0, 1, 2, 3), has_aux=True)(*ops)
-
     with jax.default_matmul_precision("highest"):
-        (_, got), g_got = through(
-            lambda *o: latent_flash(*o, h, scale, 16, 128, True))
-        (_, want), g_want = through(
+        (_, got), g_got = _through(
+            lambda *o: latent_flash(*o, h, scale, 16, 128, True), ops)
+        (_, want), g_want = _through(
             lambda q, q_pe, kv, k_pe: _latent_reference(
-                q, q_pe, kv, _k_pe_a_head(k_pe, h), h, scale))
+                q, q_pe, kv, _k_pe_a_head(k_pe, h), h, scale), ops)
     assert got.shape == (batch, seq, h * dv) and got.dtype == dtype
     f32 = dtype == jnp.float32
     assert _rel(got, want) < (1e-5 if f32 else 1e-2)
@@ -274,3 +278,106 @@ def test_latent_flash_gives_the_rotary_key_the_sum_of_the_heads_gradients():
     assert _rel(got, a_head.sum(axis=2)) < 1e-4
     # and no one head's share is the whole of it
     assert all(_rel(got, a_head[:, :, i]) > 0.1 for i in range(h))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_latent_flash_gradients_at_the_published_widths(dtype):
+    """Heads of 128 + 64 and values of 128, four heads (two steps of the
+    grid's second axis, two heads each), 200 tokens in tiles of 64 (ragged:
+    the last tile holds 8 tokens and 56 zero rows; the grid has tiles above,
+    on and below the diagonal): all four gradients against the float32 einsum
+    form on the same operands."""
+    h, nope, rope, dv = 4, 128, 64, 128
+    ops = _latent_operands(1, 200, h, nope, rope, dv, dtype, seed=3)
+    scale = (nope + rope) ** -0.5
+    with jax.default_matmul_precision("highest"):
+        _, g_got = _through(
+            lambda *o: latent_flash(*o, h, scale, 64, 64, True), ops)
+        _, g_want = _through(
+            lambda q, q_pe, kv, k_pe: _latent_reference(
+                q, q_pe, kv, _k_pe_a_head(k_pe, h), h, scale),
+            tuple(o.astype(jnp.float32) for o in ops))
+    for a, b in zip(g_got, g_want):
+        assert a.shape == b.shape and a.dtype == dtype
+        assert _rel(a, b) < (1e-4 if dtype == jnp.float32 else 3e-2)
+
+
+def _parent_rule(heads, scale, res, do):
+    """``latent_flash``'s backward as it was before it became a kernel (PR
+    34): one sequence's heads-first q, k (the rotary key broadcast to every
+    head and concatenated), v and dô ÷ l in float32 through
+    ``pallas_attention._block_flash_bwd``'s einsum tiles, ``dl = −Σ(dô·o) ÷
+    l``; the rotary key's gradient summed over the heads in float32."""
+    s, nope = res[0].shape[1], res[0].shape[-1] // heads
+    rope = res[3].shape[-1]
+    f32 = jnp.float32
+    zero = jnp.zeros((), jnp.int32)
+    heads_first = lambda x: x.reshape(s, heads, -1).transpose(1, 0, 2)
+    tokens_first = lambda x: x.transpose(1, 0, 2).reshape(s, -1)
+
+    def sequence(args):
+        q, q_pe, kv, k_pe, o, m, l, do = args
+        kv = heads_first(kv)
+        qh = jnp.concatenate([heads_first(q), heads_first(q_pe)], axis=-1)
+        kh = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_pe, (heads, s, rope))], axis=-1)
+        do = heads_first(do).astype(f32)
+        inv_l = 1.0 / jnp.maximum(l, 1e-30)
+        dl = -jnp.sum(do * heads_first(o).astype(f32), axis=-1) * inv_l
+        dq, dk, dv, _, _ = _block_flash_bwd(
+            True, scale, None, None, False,
+            (qh, kh, kv[..., nope:], zero, zero, None, m, None),
+            (do * inv_l[..., None], None, dl))
+        return (tokens_first(dq[..., :nope]), tokens_first(dq[..., nope:]),
+                tokens_first(jnp.concatenate([dk[..., :nope], dv], axis=-1)),
+                jnp.sum(dk[..., nope:].astype(f32), axis=0).astype(k_pe.dtype))
+
+    return jax.lax.map(sequence, (*res, do))
+
+
+@pytest.mark.parametrize("widths", [(16, 8, 16), (128, 64, 128)],
+                         ids=["test_widths", "published_widths"])
+def test_latent_flash_bf16_gradients_are_the_parents_within_rounding(widths):
+    """bf16 operands, the same residuals and cotangent to both rules: the
+    kernels round P̂ and dS to bf16 once before their products where the
+    parent rounded p, dô ÷ l and ds, and each rounds its result to bf16: the
+    two differ by a bf16 rounding or two (2⁻⁷; they read 4e-3, as either does
+    against the float32 form) and by no more."""
+    nope, rope, dv = widths
+    h, b, s = 4, 2, 200
+    ops = _latent_operands(b, s, h, nope, rope, dv, jnp.bfloat16, seed=4)
+    scale = (nope + rope) ** -0.5
+    do = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (b, s, h * dv), np.float32), jnp.bfloat16)
+    out, vjp = jax.vjp(lambda *o: latent_flash(*o, h, scale, 64, 64, True), *ops)
+    got = vjp(do)
+    o, m, l = _forward(*ops, h, scale, 64, 64, True)
+    assert jnp.array_equal(o, out)
+    want = _parent_rule(h, scale, (*ops, o, m, l), do)
+    for a, w in zip(got, want):
+        assert a.shape == w.shape and a.dtype == w.dtype == jnp.bfloat16
+        assert _rel(a, w) < 2 ** -7
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_latent_flash_zero_cotangent_rows_and_the_padded_tail_give_exactly_zero(
+        dtype):
+    """The cotangent's rows from token 100 on are zero: dô = 0 and Δ = 0
+    there, so dS and P̂ᵀ·dô are exactly zero: those queries get no gradient
+    and give none to the keys that only they see, nor do the 56 zero rows
+    that pad 200 tokens to whole tiles of 64 (were a padded row to count, the
+    last real keys' gradients would hold it).  Before token 100 every
+    gradient is there."""
+    h, nope, rope, dv = 4, 16, 8, 16
+    ops = _latent_operands(2, 200, h, nope, rope, dv, dtype, seed=6)
+    scale = (nope + rope) ** -0.5
+    do = np.random.default_rng(7).standard_normal((2, 200, h * dv), np.float32)
+    do[:, 100:] = 0.0
+    _, vjp = jax.vjp(lambda *o: latent_flash(*o, h, scale, 64, 64, True), *ops)
+    for g in vjp(jnp.asarray(do, dtype)):
+        g = np.asarray(g, np.float32)
+        assert g.shape[1] == 200 and np.all(np.isfinite(g))
+        assert not np.any(g[:, 100:])
+        assert np.all(np.any(g[:, 1:100] != 0.0, axis=-1))

@@ -495,6 +495,73 @@ def test_stale_accumulator_across_revisited_outputs():
     assert check_case(_case("fx:fresh", make(guard_outer=False))) == []
 
 
+def _two_grain_fixture(fine_init, coarse_init):
+    """A flash backward's shape: grid (2 groups, 2 k tiles, 2 q tiles); the
+    FINE output (dk, dv) leaves a k tile at a time, its accumulator zeroed at
+    the first q tile; the COARSE output (dq) is resident over a group's k
+    tiles, its accumulator (a row block a q tile) zeroed at the first k tile.
+    ``fine_init`` / ``coarse_init``: the grid axis whose index being 0 guards
+    each initialisation (2 and 1 are right)."""
+    def k(fine_ref, coarse_ref, fine_acc, coarse_acc):
+        ids = [pl.program_id(a) for a in range(3)]
+        ki, qi = ids[1], ids[2]
+        rows = pl.ds(qi * 8, 8)
+
+        @pl.when(ids[fine_init] == 0)
+        def _():
+            fine_acc[...] = jnp.zeros_like(fine_acc)
+
+        @pl.when(ids[coarse_init] == 0)
+        def _():
+            coarse_acc[rows] = jnp.zeros((8, 128), F32)
+
+        fine_acc[...] += jnp.ones_like(fine_acc)
+        coarse_acc[rows] += jnp.ones((8, 128), F32)
+
+        @pl.when(ki == 1)
+        def _():
+            coarse_ref[0, rows] = coarse_acc[rows]
+
+        @pl.when(qi == 1)
+        def _():
+            fine_ref[0] = fine_acc[...]
+
+    def build():
+        f = pl.pallas_call(
+            k,
+            grid=(2, 2, 2),
+            in_specs=[],
+            out_specs=[pl.BlockSpec((1, 8, 128), lambda g, j, i: (g, j, 0)),
+                       pl.BlockSpec((1, 16, 128), lambda g, j, i: (g, 0, 0))],
+            out_shape=[jax.ShapeDtypeStruct((2, 16, 128), F32),
+                       jax.ShapeDtypeStruct((2, 16, 128), F32)],
+            scratch_shapes=[pltpu.VMEM((8, 128), F32),
+                            pltpu.VMEM((16, 128), F32)],
+        )
+        return f, ()
+
+    return build
+
+
+@pytest.mark.parametrize("fine_init,coarse_init,stale", [
+    (2, 1, None), (1, 1, "scratch0"), (2, 0, "scratch1")],
+    ids=["guards_right", "fine_guarded_on_k", "coarse_guarded_on_group"])
+def test_accumulators_of_two_grains_are_held_to_the_output_each_feeds(
+        fine_init, coarse_init, stale):
+    """A scratch's runs are those of the output its values are stored into:
+    the coarse accumulator lives across the fine output's blocks without a
+    finding, and each accumulator guarded on the axis outside its own run is
+    found, by its name."""
+    fs = check_case(_case("fx:two-grain",
+                          _two_grain_fixture(fine_init, coarse_init)))
+    if stale is None:
+        assert fs == []
+        return
+    found = _by_kind(fs, "uninit-accumulator")
+    assert found and all(stale in f.message and "revisit" in f.message
+                         for f in found)
+
+
 # ---------------------------------------------------------------------------
 # the registry itself
 # ---------------------------------------------------------------------------
@@ -550,6 +617,32 @@ def test_latent_contract_shape(registry_contract, dtype):
     assert blocks["out0"] == [1, 128, 256]
     assert blocks["out1"] == blocks["out2"] == [1, 2, 1, 128]
     assert {blocks[f"scratch{i}"][0] for i in range(3)} == {2}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_latent_backward_contract_shape(registry_contract, dtype):
+    """Latent attention's backward kernel (PR 37): the forward's four
+    operands, its output and the cotangent as blocked VMEM operands the
+    pipeline copies (no DMA of its own), m and l with the queries along the
+    lanes; k tiles before q tiles in the grid; dq and dq_pe resident for a
+    group's whole sequence (three tiles of 128) over float32 scratch of the
+    same rows, dkv a k tile at a time over float32 scratch, the rotary key's
+    gradient a float32 partial a group of heads, Δ a row a q tile and head.
+    ``findings`` empty: every accumulator is written before it is read in
+    the run of the output it feeds (initialised at the first tile, stored at
+    the last)."""
+    entry = registry_contract["kernels"][
+        f"latent_flash_backward:causal:{dtype}"]
+    assert entry["dma_starts"] == 0 and entry["findings"] == {}
+    assert entry["grid"] == [1, 2, 3, 3]
+    blocks = entry["blocks"]
+    assert [blocks[f"in{i}"][-1] for i in range(6)] == [
+        256, 128, 512, 64, 256, 256]
+    assert blocks["in6"] == blocks["in7"] == [1, 2, 1, 128]
+    assert blocks["out0"] == [1, 384, 256] and blocks["out1"] == [1, 384, 128]
+    assert blocks["out2"] == [1, 128, 512] and blocks["out3"] == [1, 1, 128, 64]
+    assert [blocks[f"scratch{i}"] for i in range(5)] == [
+        [384, 256], [384, 128], [128, 512], [128, 64], [3, 2, 1, 128]]
 
 
 def test_pallas_contract_roundtrip(registry_contract):

@@ -373,18 +373,12 @@ def _executed_keys(text):
 
 def _latent_layer_on(one_chip, monkeypatch, batch):
     """Kanana-2's ``LatentAttention`` at its published widths with the Pallas
-    path asked for as on a TPU backend, the shapes of its float32 parameters
-    and of ``batch`` sequences of 8,192 tokens in bf16 placed on the described
-    chip, and ``mla_attention_ms``'s pattern."""
-    import json
-
+    path asked for as on a TPU backend, and the shapes of its float32
+    parameters and of ``batch`` sequences of 8,192 tokens in bf16 placed on
+    the described chip."""
     import mpi4dl_tpu.config as config
     from mpi4dl_tpu.models import deepseek_v3
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "perfbench", "layer_metrics",
-                           "mla_attention_ms.json")) as f:
-        pattern = re.compile(json.load(f)["params"]["pattern"])
     monkeypatch.setattr(config, "is_tpu_backend", lambda: True)
     layer = deepseek_v3._block(deepseek_v3.PUBLISHED, 1, 16, 0).op
 
@@ -394,55 +388,73 @@ def _latent_layer_on(one_chip, monkeypatch, batch):
     params = jax.tree.map(
         lambda a: struct(a.shape, a.dtype),
         jax.eval_shape(lambda: layer.init(jax.random.key(0), (batch, 8192, 2048))[0]))
-    return layer, params, struct((batch, 8192, 2048)), struct, pattern
+    return layer, params, struct((batch, 8192, 2048)), struct
 
 
 def test_deepseek_v3_kernels_compile_for_v5e_under_the_names_the_metrics_pick(
         one_chip, no_persistent_cache, monkeypatch):
-    """The second token model's two kernels at its published widths, bf16,
+    """The second token model's kernels at its published widths, bf16,
     forward and backward, under a ``"highest"`` default as the benchmark's
     check traces them.  Latent attention whole (four sequences of 8,192
-    tokens through ``LatentAttention``: 32 heads of 128 + 64, values of 128,
-    the Pallas path asked for as on a TPU backend): Mosaic accepts the
-    forward kernel on the projections' layout, and the compiled layer holds
-    it and the backward's tiles at both widths, which are the names
-    ``mla_attention_ms`` picks, and the pattern picks nothing of the layer's
-    projections.  The grouped product at 32,768 tokens, 16 of 128 experts of
-    768, six a token: ``ragged-dot-*`` at this configuration's shapes."""
+    tokens through ``LatentAttention`` under per-cell remat: 32 heads of 128 +
+    64, values of 128, the Pallas path asked for as on a TPU backend): Mosaic
+    accepts the forward and the backward kernel on the projections' layout;
+    every Mosaic kernel of the compiled layer carries the scope
+    ``attention_core``, by which the four attention metrics pick
+    (``perfbench/optable.py``), forward, recomputed and backward; beside the
+    kernels the scope holds only Δ's row sums and the sum of the rotary key's
+    partials, no product and nothing as large as a projection's, a norm's or
+    the rotary embedding's result.  The backward is the kernel: no loop, no
+    heads-first array (which the broadcast of the rotary key to the heads
+    was), no tile of the einsum backward, no accumulator of tiles.  The
+    grouped product at 32,768 tokens, 16 of 128 experts of 768, six a token:
+    ``ragged-dot-*`` at this configuration's shapes."""
     from mpi4dl_tpu.layer_ctx import ApplyCtx
+    from perfbench import optable
 
-    layer, params, x, struct, pattern = _latent_layer_on(
-        one_chip, monkeypatch, batch=4)
+    layer, params, x, struct = _latent_layer_on(one_chip, monkeypatch, batch=4)
 
     def attention(p, x):
         def loss(p, x):
-            # under per-cell remat, as the step runs it: the kernel is then
-            # there twice, and both times under its own name (differentiated
-            # where it stands it would be ``jvp(block_flash_fwd)``)
+            # under per-cell remat, as the step runs it: the forward kernel is
+            # then there twice (the loss is returned, so the first stays)
             y = jax.checkpoint(
                 lambda p, x: layer.apply(p, x, ApplyCtx(train=True)))(p, x)
             return jnp.sum(y.astype(jnp.float32))
 
         with jax.default_matmul_precision("highest"):
-            return jax.grad(loss, (0, 1))(p, x)
+            return jax.value_and_grad(loss, (0, 1))(p, x)
 
     compiled = jax.jit(attention).lower(params, x).compile()
     _assert_mosaic(compiled)
-    keys = _executed_keys(compiled.as_text())
-    picked = {k for k in keys if pattern.search(k)}
-    # the kernel's first result is the normalized output, four sequences of
-    # 32 heads x 128 in the compute dtype
-    assert "block_flash_fwd:bf16[4,8192,4096]" in picked, sorted(picked)
-    assert not [k for k in keys if "block_flash" in k
-                and not k.startswith("block_flash_fwd:")], sorted(set(keys))
-    shapes = {k.split(":")[1] for k in picked}
-    for tile in ("[32,1024,512]", "[32,1024,192]", "[32,512,192]", "[32,512,128]"):
-        assert any(s.endswith(tile) for s in shapes), (tile, sorted(shapes))
-    # the copies that bring dq's tile to its accumulator, eight heads at a time
-    assert picked & {"slice-done:f32[8,1024,192]", "slice-done:f32[8,512,192]"}, sorted(picked)
-    # nothing of the projections, the norms or the rotary embedding
-    assert not [k for k in picked if re.search(r"\[(1,)?8192,|2048|4096|6144", k)
-                and not k.startswith("block_flash_fwd")], sorted(picked)
+    text = compiled.as_text()
+    rows = optable.parse(text)
+    kernels = [r for r in rows.values() if r["target"] == "tpu_custom_call"]
+    assert all("attention_core" in optable.scopes_of(r["op_name"])
+               for r in kernels), [r["op_name"] for r in kernels]
+    passes = [(r["name"].split(".")[0], optable.pass_of(r["op_name"]))
+              for r in kernels]
+    assert sorted(passes) == [("block_flash_fwd", "forward"),
+                              ("block_flash_fwd", "recompute"),
+                              ("latent_flash_bwd", "backward")], passes
+    # what else the scope holds does no product and is small
+    scoped = [r for r in rows.values() if r not in kernels
+              and "attention_core" in optable.scopes_of(r["op_name"])]
+    assert not [r["name"] for r in scoped
+                if r["opcode"] in ("dot", "convolution", "ragged-dot")]
+    elements = lambda t: math.prod(
+        int(d) for d in re.search(r"\[([\d,]*)\]", t).group(1).split(",") if d)
+    executed = _executed_names(text)
+    large = [(r["name"], r["types"]) for r in scoped
+             if r["name"] in executed and r["opcode"] != "get-tuple-element"
+             and max(map(elements, r["types"])) > 4 * 8192 * 64]
+    assert not large, large
+    # the backward is the kernel
+    assert not re.search(r" while\(", text)
+    results = re.findall(r" = \(?\w+\[([\d,]+)\]", text)
+    gone = [r for r in results if re.fullmatch(
+        r"(\d+,)?32,8192,\d+|(\d+,)?32,(1024|512),\d+|8,32,1024,192", r)]
+    assert not gone, sorted(set(gone))
 
     compiled = _compile_routed_experts(struct, held=16, total=128, top_k=6,
                                        ffn=768, scaling=2.448, sum_eps=1e-20)
@@ -463,7 +475,7 @@ def test_latent_attention_forward_writes_no_heads_first_or_padded_operand_for_v5
     place (PR 34).  The kernel is there once, for all four sequences."""
     from mpi4dl_tpu.layer_ctx import ApplyCtx
 
-    layer, params, x, _, _ = _latent_layer_on(one_chip, monkeypatch, batch=4)
+    layer, params, x, _ = _latent_layer_on(one_chip, monkeypatch, batch=4)
 
     def forward(p, x):
         with jax.default_matmul_precision("highest"):
@@ -479,22 +491,32 @@ def test_latent_attention_forward_writes_no_heads_first_or_padded_operand_for_v5
             ] == ["block_flash_fwd:bf16[4,8192,4096]"]
 
 
-def _executed_with_scopes(text):
-    """``(key, op_name)`` of every instruction of a compiled module that runs
-    on its own: :func:`_executed_keys` with the scope path that the
-    instruction's metadata carries ("" where the compiler made it and gave
-    it none)."""
-    from perfbench.trace import op_key
-
-    out, fused = [], False
+def _executed_lines(text):
+    """``(line, op_name)`` of every instruction of a compiled module that
+    runs on its own (not inside a fusion); ``op_name`` is the scope path in
+    the instruction's metadata ("" where the compiler made it and gave it
+    none)."""
+    fused = False
     for line in text.splitlines():
         if line and not line.startswith(" "):  # a computation's header, or "}"
             fused = line.startswith(("%fused_", "fused_"))
         elif not fused and " = " in line:
             scope = re.search(r'op_name="([^"]*)"', line)
-            out.append((op_key(line.strip().removeprefix("ROOT ")),
-                        scope.group(1) if scope else ""))
-    return out
+            yield (line.strip().removeprefix("ROOT "),
+                   scope.group(1) if scope else "")
+
+
+def _executed_names(text):
+    """The instruction names of :func:`_executed_lines`."""
+    return {line.split(" = ")[0].lstrip("%") for line, _ in _executed_lines(text)}
+
+
+def _executed_with_scopes(text):
+    """``(key, op_name)`` of :func:`_executed_lines`, the key as
+    ``perfbench.trace.op_key`` names a trace event."""
+    from perfbench.trace import op_key
+
+    return [(op_key(line), scope) for line, scope in _executed_lines(text)]
 
 
 def test_granitemoehybrid_layers_compile_for_v5e_under_the_names_the_metrics_pick(
