@@ -88,7 +88,7 @@ def capture(args, runlog=None) -> str:
     print(f"[profile] compile+warmup {time.perf_counter() - t0:.1f}s",
           file=sys.stderr)
 
-    from mpi4dl_tpu.obs import step_annotation
+    from mpi4dl_tpu.obs.spans import recorder
 
     os.makedirs(args.out, exist_ok=True)
     jax.profiler.start_trace(args.out)
@@ -96,9 +96,10 @@ def capture(args, runlog=None) -> str:
     try:
         for i in range(args.steps):
             # Scope-named trace: the step ops carry obs.scope paths; the
-            # host-side annotation lines the trace's step view up with the
-            # RunLog step records (match on step number).
-            with step_annotation(i):
+            # recorder's step span (a StepTraceAnnotation while the profiler
+            # runs) lines the trace's step view up with the RunLog step
+            # records (match on step number).
+            with recorder().span("step", gstep=i):
                 ts = time.perf_counter()
                 state, metrics = step(state, xs[i % 2], ys[i % 2])
                 if runlog is not None:

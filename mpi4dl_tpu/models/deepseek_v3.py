@@ -40,6 +40,7 @@ from mpi4dl_tpu.layers import Dense, Layer, RMSNorm
 from mpi4dl_tpu.models.lfm2 import (
     BlockCell, SwiGLU, embed_cell, head_cell, layers_run, rotary,
     routed_step_metrics)
+from mpi4dl_tpu.obs.scopes import scope
 from mpi4dl_tpu.obs.spans import recorder
 from mpi4dl_tpu.ops.moe import RoutedExperts
 
@@ -224,7 +225,7 @@ class LatentAttention(Layer):
         q, q_pe, k_pe = product(w_q, x), q_pe.reshape(b, s, h * rope), k_pe[:, :, 0]
         # the kernel alone: the scope by which a device trace finds attention
         # itself, forward and recomputed (the backward rule opens its own)
-        with jax.named_scope("attention_core"):
+        with scope("attention_core"):
             return pallas_latent_attention.latent_flash(
                 q, q_pe, kv, k_pe, h, (nope + rope) ** -0.5)
 
@@ -278,8 +279,10 @@ class SharedAndRoutedExperts(Layer):
 
     def apply(self, params, x, ctx):
         recorder().note_site("shared_expert", self, "swiglu")
-        return (self.routed.apply(params, x, ctx)
-                + self.shared.apply(params["shared_experts"], x, ctx))
+        routed = self.routed.apply(params, x, ctx)
+        with scope("shared_expert"):
+            shared = self.shared.apply(params["shared_experts"], x, ctx)
+        return routed + shared
 
 
 def _check(config: DeepseekV3Config) -> None:

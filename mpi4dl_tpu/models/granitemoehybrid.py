@@ -40,6 +40,7 @@ from mpi4dl_tpu.cells import CellModel
 from mpi4dl_tpu.layers import CausalConv1d, Dense, Layer, RMSNorm
 from mpi4dl_tpu.models.lfm2 import (
     Attention, BlockCell, SwiGLU, embed_cell, head_cell, layers_run)
+from mpi4dl_tpu.obs.scopes import scope
 from mpi4dl_tpu.obs.spans import recorder
 from mpi4dl_tpu.ops.ssd import ssd_chunked
 
@@ -168,6 +169,11 @@ class Mamba2Mixer(Layer):
         return params, in_shape
 
     def apply(self, params, u, ctx):
+        # the whole mixer under ssm_mixer, the scan alone under ssm_scan
+        with scope("ssm_mixer"):
+            return self._mix(params, u, ctx)
+
+    def _mix(self, params, u, ctx):
         parts = self._parts()
         recorder().note_site("ssm_scan", self, "chunked")
         b, s, _ = u.shape
@@ -177,7 +183,7 @@ class Mamba2Mixer(Layer):
             [self.inner, 2 * self.inner + 2 * self.state], axis=-1)
         xbc = jax.nn.silu(parts["conv1d"].apply(params["conv1d"], xbc, ctx))
         x, b_t, c_t = jnp.split(xbc, [self.inner, self.inner + self.state], axis=-1)
-        with jax.named_scope("ssm_scan"):
+        with scope("ssm_scan"):
             dt = jax.nn.softplus(dt.astype(f32) + params["dt_bias"].astype(f32))
             y, carried = ssd_chunked(
                 x.reshape(b, s, self.heads, self.head_dim), dt,

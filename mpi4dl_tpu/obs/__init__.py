@@ -41,7 +41,7 @@ Forensics + fleet telemetry (ISSUE 17) ride on the same records:
 
 from __future__ import annotations
 
-from mpi4dl_tpu.obs.scopes import scope, scopes_enabled, step_annotation
+from mpi4dl_tpu.obs.scopes import scope, scopes_enabled
 from mpi4dl_tpu.obs.runlog import (
     RunLog,
     active_hatches,
@@ -164,7 +164,6 @@ __all__ = [
     "stablehlo_collectives",
     "stablehlo_debug_text",
     "stablehlo_sharding_annotations",
-    "step_annotation",
     "step_cost",
     "structural_overlap",
     "top_scope",

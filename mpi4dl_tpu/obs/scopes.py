@@ -13,11 +13,8 @@ of anonymous fusions — the per-phase attribution T3-style overlap work needs
 Scopes are trace-time only (zero steady-state runtime cost: the context
 manager runs while JAX builds the jaxpr, never per step on device) and can be
 disabled outright with ``MPI4DL_NO_SCOPES=1`` for pristine A/B compiles.
-
-:func:`step_annotation` is the host-side counterpart: a
-``jax.profiler.StepTraceAnnotation`` marking one optimizer step so XProf's
-step view can attribute device time to steps.  Benchmark loops use it only
-while a profiler trace is active (it costs a TraceMe per step).
+Every scope of the package goes through :func:`scope`; the host-side step
+marker is the recorder's ``step`` span (:mod:`~mpi4dl_tpu.obs.spans`).
 """
 
 from __future__ import annotations
@@ -54,14 +51,3 @@ def scope(name: str) -> ContextManager[None]:
 
     return jax.named_scope(name)
 
-
-def step_annotation(step_num: int, name: str = "train") -> ContextManager[None]:
-    """Host-side step marker for XProf's step view (wrap ONE step dispatch).
-
-    Only meaningful while a profiler trace is active; disabled along with
-    scopes."""
-    if not scopes_enabled():
-        return contextlib.nullcontext()
-    import jax
-
-    return jax.profiler.StepTraceAnnotation(name, step_num=step_num)

@@ -20,6 +20,12 @@ Gather and scatter are a pair of transposes (:func:`_dispatch`,
 :func:`_combine`), each the other's backward pass, so neither direction
 lowers to a scatter-add of rows.
 
+Two scopes name the layer's work beside the grouped products, which carry
+neither: ``expert_route`` (the router, its top-k and weights, the counting
+sort and the ``load`` statistic) and ``expert_dispatch`` (a round's row
+bookkeeping, the gathers and the weighted scatter back, forward and inside
+both backward rules).
+
 The grouped product is ``jax.lax.ragged_dot``, on every backend.  (PERF.md,
 PR 29: megablox ``gmm`` read the same in the layer alone and 0.45 % more
 sequences a second in the cell, which did not pay for a second path.)
@@ -37,6 +43,7 @@ from jax import lax
 
 from mpi4dl_tpu.layer_ctx import ApplyCtx
 from mpi4dl_tpu.layers import Layer, _uniform
+from mpi4dl_tpu.obs.scopes import scope
 from mpi4dl_tpu.obs.spans import recorder
 
 CAPACITY_FACTOR = 1.25
@@ -90,7 +97,8 @@ def _dispatch_fwd(x, tok, slot, valid):
 
 
 def _dispatch_bwd(res, g):
-    return (_gather_rows(g, *res).astype(g.dtype), None, None, None)
+    with scope("expert_dispatch"):
+        return (_gather_rows(g, *res).astype(g.dtype), None, None, None)
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -110,8 +118,9 @@ def _combine_fwd(ys, tok, row_valid, slot, valid):
 
 def _combine_bwd(res, g):
     tok, row_valid, slot, valid, like = res
-    rows = jnp.where(row_valid[:, None], jnp.take(g, tok, axis=0), 0.0)
-    return (rows.astype(like.dtype), None, None, None, None)
+    with scope("expert_dispatch"):
+        rows = jnp.where(row_valid[:, None], jnp.take(g, tok, axis=0), 0.0)
+        return (rows.astype(like.dtype), None, None, None, None)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -137,48 +146,50 @@ def routed_experts(x, router, experts, *, first: int, held: int, total: int,
     all ``N * top_k`` assignments that fell on it (float32, ``[held]``)."""
     n, _ = x.shape
     a = n * top_k
-    chosen, weights = route(x, router["kernel"], router["bias"], top_k, scaling,
-                            sum_eps)
-
-    # Counting sort of the assignments by held expert; group ``held`` takes
-    # those of absent experts, behind all the others.
-    local = chosen.reshape(a) - first
-    group = jnp.where((local >= 0) & (local < held), local, held)
-    onehot = (group[:, None] == jnp.arange(held + 1, dtype=jnp.int32)[None, :]
-              ).astype(jnp.int32)
-    sizes = jnp.sum(onehot, axis=0)
-    starts = jnp.cumsum(sizes) - sizes
-    rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0), group[:, None],
-                               axis=1)[:, 0] - 1
-    pos = (starts[group] + rank).reshape(n, top_k)  # place in the sorted order
-    order = jnp.argsort(group, stable=True)  # assignment at each place
-    mine = (group < held).reshape(n, top_k)
-    rows = jnp.sum(sizes[:held])
-
     capacity = round_capacity(a, held, total)
     rounds = math.ceil(a / capacity)
-    order = jnp.pad(order, (0, rounds * capacity - a))
-    w_flat = weights.reshape(a)
+    with scope("expert_route"):
+        chosen, weights = route(x, router["kernel"], router["bias"], top_k,
+                                scaling, sum_eps)
+
+        # Counting sort of the assignments by held expert; group ``held``
+        # takes those of absent experts, behind all the others.
+        local = chosen.reshape(a) - first
+        group = jnp.where((local >= 0) & (local < held), local, held)
+        onehot = (group[:, None] == jnp.arange(held + 1, dtype=jnp.int32)[None, :]
+                  ).astype(jnp.int32)
+        sizes = jnp.sum(onehot, axis=0)
+        starts = jnp.cumsum(sizes) - sizes
+        rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0), group[:, None],
+                                   axis=1)[:, 0] - 1
+        pos = (starts[group] + rank).reshape(n, top_k)  # place in sorted order
+        order = jnp.argsort(group, stable=True)  # assignment at each place
+        mine = (group < held).reshape(n, top_k)
+        rows = jnp.sum(sizes[:held])
+        order = jnp.pad(order, (0, rounds * capacity - a))
+        w_flat = weights.reshape(a)
     cast = lambda w: w.astype(x.dtype)
     w1, w3, w2 = cast(experts["w1"]), cast(experts["w3"]), cast(experts["w2"])
     ends = starts[:held] + sizes[:held]
 
     def one_round(r):
-        lo = r * capacity
-        asg = lax.dynamic_slice_in_dim(order, lo, capacity)
-        tok = asg // top_k
-        row_valid = lo + jnp.arange(capacity, dtype=jnp.int32) < rows
-        slot = jnp.clip(pos - lo, 0, capacity - 1)
-        valid = mine & (pos >= lo) & (pos < lo + capacity)
-        sizes_r = (jnp.clip(ends - lo, 0, capacity)
-                   - jnp.clip(starts[:held] - lo, 0, capacity))
-        xs = _dispatch(x, tok, slot, valid)
+        with scope("expert_dispatch"):
+            lo = r * capacity
+            asg = lax.dynamic_slice_in_dim(order, lo, capacity)
+            tok = asg // top_k
+            row_valid = lo + jnp.arange(capacity, dtype=jnp.int32) < rows
+            slot = jnp.clip(pos - lo, 0, capacity - 1)
+            valid = mine & (pos >= lo) & (pos < lo + capacity)
+            sizes_r = (jnp.clip(ends - lo, 0, capacity)
+                       - jnp.clip(starts[:held] - lo, 0, capacity))
+            xs = _dispatch(x, tok, slot, valid)
         h = jax.nn.silu(_grouped_dot(xs, w1, sizes_r)) * _grouped_dot(
             xs, w3, sizes_r)
         ys = _grouped_dot(h, w2, sizes_r)
-        w_r = jnp.where(row_valid, jnp.take(w_flat, asg), 0.0)
-        ys = (ys.astype(jnp.float32) * w_r[:, None]).astype(x.dtype)
-        return _combine(ys, tok, row_valid, slot, valid)
+        with scope("expert_dispatch"):
+            w_r = jnp.where(row_valid, jnp.take(w_flat, asg), 0.0)
+            ys = (ys.astype(jnp.float32) * w_r[:, None]).astype(x.dtype)
+            return _combine(ys, tok, row_valid, slot, valid)
 
     out = one_round(0)
     if rounds > 1:
@@ -195,7 +206,10 @@ def routed_experts(x, router, experts, *, first: int, held: int, total: int,
             lambda out: lax.scan(
                 more, out, jnp.arange(1, rounds, dtype=jnp.int32))[0],
             lambda out: out, out)
-    return out.astype(x.dtype), sizes[:held].astype(jnp.float32) / a
+    out = out.astype(x.dtype)
+    with scope("expert_route"):
+        load = sizes[:held].astype(jnp.float32) / a
+    return out, load
 
 
 @dataclasses.dataclass(frozen=True)

@@ -48,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from mpi4dl_tpu.compat import pcast
+from mpi4dl_tpu.obs.scopes import scope
 
 _NEG_INF = -1e30  # large-negative instead of -inf: exp() of it is exactly 0
                   # and max() never produces nan from (-inf) - (-inf).
@@ -271,6 +272,15 @@ _BWD_TQ, _BWD_TK = 1024, 512  # query, key rows of a backward tile
 
 
 def _block_flash_bwd(causal, scale, tq, tk, interpret, res, cts):
+    """:func:`block_flash`'s backward rule, under the scope by which a device
+    trace finds attention itself, as its callers open it round the forward
+    kernel."""
+    with scope("attention_core"):
+        return _block_flash_bwd_tiles(causal, scale, tq, tk, interpret, res,
+                                      cts)
+
+
+def _block_flash_bwd_tiles(causal, scale, tq, tk, interpret, res, cts):
     """Blockwise backward: a scan over Tk tiles, and inside it a scan over
     Tq tiles, of einsum blocks — never more than a [BH, TQ, TK] score tile
     at a time, whatever the sequence length.  Under ``causal`` a tile whose
@@ -419,9 +429,11 @@ def flash_attention_local(q, k, v, causal=False, scale=None,
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(
         b * h, x.shape[1], x.shape[3])
     zero = jnp.zeros((), jnp.int32)
-    o, m, l = block_flash(
-        fold(q), fold(k), fold(v), zero, zero, causal, sc, *LOCAL_TILES,
-        interpret,
-    )
+    qf, kf, vf = fold(q), fold(k), fold(v)
+    # the kernel alone: the scope by which a device trace finds attention
+    # itself, forward and recomputed (the backward rule opens its own)
+    with scope("attention_core"):
+        o, m, l = block_flash(qf, kf, vf, zero, zero, causal, sc,
+                              *LOCAL_TILES, interpret)
     out = o / jnp.maximum(l, 1e-30)[..., None]
     return out.reshape(b, h, t, v.shape[3]).transpose(0, 2, 1, 3).astype(q.dtype)

@@ -72,6 +72,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mpi4dl_tpu.obs.scopes import scope
 from mpi4dl_tpu.ops.pallas_attention import (
     _LANES, _NEG_INF, _out_structs, _round_up)
 
@@ -340,7 +341,7 @@ def _bwd_kernel(q_ref, qpe_ref, kv_ref, kpe_ref, o_ref, do_ref, m_ref, l_ref,
 
 
 def _latent_flash_bwd(heads, scale, tq, tk, interpret, res, do):
-    with jax.named_scope("attention_core"):
+    with scope("attention_core"):
         return latent_flash_backward(*res, do, heads, scale, tq, tk, interpret)
 
 

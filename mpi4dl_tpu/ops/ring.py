@@ -208,10 +208,12 @@ def _ring_attention_flash(q, k, v, axis_name, n, causal, scale, interpret):
         kblk, vblk, src, m, l, o = carry
 
         def compute(m, l, o):
-            blk = block_flash(  # all-positional: custom_vjp + nondiff args
-                qf, fold(kblk), fold(vblk), q_off, src * t, causal, scale,
-                256, 512, interpret,
-            )
+            kf, vf = fold(kblk), fold(vblk)
+            with scope("attention_core"):  # the kernel alone, as local's
+                blk = block_flash(  # all-positional: custom_vjp + nondiff args
+                    qf, kf, vf, q_off, src * t, causal, scale, 256, 512,
+                    interpret,
+                )
             return mlo_merge((o, m, l), blk)
 
         with scope("ring_step_compute"):

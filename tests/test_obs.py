@@ -92,12 +92,96 @@ def test_scope_disabled_is_nullcontext(monkeypatch):
     _reset_enabled_cache()
     try:
         assert isinstance(obs.scope("x"), contextlib.nullcontext)
-        assert isinstance(obs.step_annotation(0), contextlib.nullcontext)
         assert not obs.scopes_enabled()
     finally:
         monkeypatch.delenv("MPI4DL_NO_SCOPES")
         _reset_enabled_cache()
     assert obs.scopes_enabled()
+
+
+_DEBUG_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
+
+
+def _without_metadata(text: str) -> str:
+    """A compiled module's text without what names and places its
+    instructions: every ``metadata={...}`` and the tables of files,
+    functions, locations and stack frames that the metadata points into."""
+    import re
+
+    blocks = [b for b in text.split("\n\n")
+              if b.split("\n", 1)[0].strip() not in _DEBUG_TABLES]
+    return re.sub(r", metadata=\{[^{}]*\}", "", "\n\n".join(blocks))
+
+
+def _token_step(name):
+    """A small train step of a token model at toy widths (the tests'
+    configurations), lowered: LFM2 (routed experts, attention), granite (the
+    Mamba-2 mixer and its scan), Kanana-2 (the shared expert beside the routed
+    ones), and ``block_flash`` in interpret mode, forward and backward under
+    ``jax.checkpoint``, for the scope round the kernel and in its rule."""
+    import test_deepseek_v3
+    import test_granitemoehybrid
+    import test_lfm2
+
+    if name == "block_flash":
+        from mpi4dl_tpu.ops.pallas_attention import flash_attention_local
+
+        def loss(q, k, v):
+            o = jax.checkpoint(lambda q, k, v: flash_attention_local(
+                q, k, v, causal=True, interpret=True))(q, k, v)
+            return jnp.sum(o)
+
+        qkv = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.float32)
+        return jax.jit(jax.grad(loss, (0, 1, 2))).lower(qkv, qkv, qkv)
+    module = {"lfm2": test_lfm2, "granite": test_granitemoehybrid,
+              "deepseek_v3": test_deepseek_v3}[name]
+    model, params, _ = module._model()
+    x, y = (test_granitemoehybrid._ids() if name == "granite"
+            else test_lfm2._batch())
+    opt = Optimizer("sgd", lr=0.1)
+    step = make_train_step(model, opt, compute_dtype=jnp.bfloat16, remat=True)
+    return step.lower(TrainState.create(params, opt), x, y)
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """The persistent cache keys a program without its metadata, so with it
+    on the second compile is the first one loaded, names and all."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("name,scopes", [
+    ("lfm2", ("expert_route", "expert_dispatch")),
+    ("granite", ("ssm_mixer", "ssm_scan")),
+    ("deepseek_v3", ("expert_route", "expert_dispatch", "shared_expert")),
+    ("block_flash", ("attention_core",)),
+])
+def test_scopes_are_metadata_only(monkeypatch, no_persistent_cache, name,
+                                  scopes):
+    """The step compiled with ``MPI4DL_NO_SCOPES=1`` and without is the same
+    program once the metadata is taken out: a scope names instructions and
+    changes none (so the driver's measurement with tracing off is the
+    parent's).  The scoped program does carry each of the model's scopes."""
+    texts = {}
+    for off in (True, False):
+        if off:
+            monkeypatch.setenv("MPI4DL_NO_SCOPES", "1")
+        else:
+            monkeypatch.delenv("MPI4DL_NO_SCOPES")
+        _reset_enabled_cache()
+        try:
+            texts[off] = _token_step(name).compile().as_text()
+        finally:
+            _reset_enabled_cache()
+    for scope in scopes:
+        assert f"/{scope}/" in texts[False] and f"/{scope}/" not in texts[True]
+    assert _without_metadata(texts[True]) == _without_metadata(texts[False])
 
 
 def _debug_text(step, *args) -> str:

@@ -14,13 +14,17 @@ The one-chip train step is compiled the same way, tiny, to see that the
 program's scope names (``cellNN``, ``loss``, ``optimizer_update``) reach the
 ``op_name`` metadata of the instructions the chip would run: a device trace
 names an op by its HLO instruction and nothing else, so that metadata is the
-only road from a trace event back to the model.
+only road from a trace event back to the model.  The token models' layers
+are compiled at their published widths to hold each scope a benchmark metric
+reads (``attention_core``, ``expert_route``, ``expert_dispatch``,
+``shared_expert``, ``ssm_mixer``) to the instructions of its mechanism.
 
 All in ONE file and the topology in a fixture, never at import: only one
 process may load libtpu, and under xdist every worker imports every file.
 Nothing runs here, so these say nothing about results or times.
 """
 
+import functools
 import math
 import os
 import re
@@ -610,3 +614,186 @@ def test_granitemoehybrid_layers_compile_for_v5e_under_the_names_the_metrics_pic
     shapes = {k.split(":")[1] for k in picked}
     for tile in ("[32,1024,512]", "[32,1024,64]", "[32,512,64]"):
         assert any(s.endswith(tile) for s in shapes), (tile, sorted(shapes))
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_layer(model, layer, batch, one_chip, part="block"):
+    """One layer of a token model at its published widths (``lfm2``,
+    ``deepseek_v3``: experts held as in the cells, 8 of 64 and 16 of 128;
+    ``granitemoehybrid``), ``batch`` sequences of 8,192 tokens in bf16 with
+    float32 parameters, forward under ``jax.checkpoint`` (per-cell remat, as
+    the step runs it) and backward with the loss returned, under a
+    ``"highest"`` default and the Pallas path asked for as on a TPU backend;
+    ``part`` ``"ffn"`` compiles the layer's feed-forward alone.  Returns
+    ``(row, optable.describe(row))`` of every instruction that runs on its own
+    and does work.  Compiled once for the tests that read it."""
+    from unittest import mock
+
+    import mpi4dl_tpu.config as config
+    from mpi4dl_tpu.layer_ctx import ApplyCtx
+    from mpi4dl_tpu.models import deepseek_v3, granitemoehybrid, lfm2
+    from perfbench import optable
+
+    cell = {"lfm2": lambda: lfm2._block(lfm2.PUBLISHED, layer, 8, 0),
+            "deepseek_v3": lambda: deepseek_v3._block(
+                deepseek_v3.PUBLISHED, layer, 16, 0),
+            "granitemoehybrid": lambda: granitemoehybrid._block(
+                granitemoehybrid.PUBLISHED, layer)}[model]()
+    if part == "ffn":
+        cell = cell.ffn
+    shape = (batch, 8192, 2048)  # every published hidden size
+
+    def struct(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: struct(a.shape, a.dtype), jax.eval_shape(
+        lambda: cell.init(jax.random.key(0), shape)[0]))
+
+    def grads(p, x):
+        def loss(p, x):
+            y = jax.checkpoint(
+                lambda p, x: cell.apply(p, x, ApplyCtx(train=True)))(p, x)
+            return jnp.sum(y.astype(jnp.float32))
+
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(loss, (0, 1))(p, x)
+
+    with mock.patch.object(config, "is_tpu_backend", lambda: True):
+        text = jax.jit(grads).lower(params, struct(shape)).compile().as_text()
+    rows = optable.parse(text)
+    said = []
+    for name in sorted(_executed_names(text)):
+        row = rows.get(name)
+        if row is None or row["opcode"] in ("parameter", "constant", "tuple",
+                                            "get-tuple-element"):
+            continue
+        d = optable.describe(row)
+        if d["cls"] != "container":
+            said.append((row, d))
+    return said
+
+
+def _leaf_opcodes(row):
+    from perfbench import optable
+
+    return {optable._base(r["opcode"]) for r in optable._leaves(row)}
+
+
+def _own_scopes(row):
+    """The scopes in the instruction's OWN ``op_name`` (a fusion's own
+    metadata), beside what ``optable.describe`` reads from its fused ones."""
+    from perfbench import optable
+
+    return optable.scopes_of(row["op_name"])
+
+
+_BWD_TILE = re.compile(r"\[32,(1024|512),(512|64)\]")  # pallas_attention._BWD_TQ, _BWD_TK
+
+
+def _assert_attention_core_is_the_kernel(said):
+    """Every ``block_flash_fwd`` (forward and recomputed) and every product
+    of ``_block_flash_bwd``'s tiles carries ``attention_core`` as
+    ``optable`` reads it; every product under the scope is such a tile, so no
+    q/k/v/out projection (nor anything of the experts, the MLP or the scan)."""
+    kernels = [d for r, d in said if r["name"].startswith("block_flash_fwd")]
+    assert sorted(d["pass"] for d in kernels) == ["forward", "recompute"]
+    assert all("attention_core" in d["scopes"] for d in kernels)
+    products = [(r, d) for r, d in said if d["cls"] == "product"]
+    tiles = [(r, d) for r, d in products
+             if any(_BWD_TILE.search(t) for t in r["types"])]
+    assert len(tiles) >= 4
+    assert all("attention_core" in d["scopes"] and d["pass"] == "backward"
+               for _, d in tiles), [d["key"] for _, d in tiles]
+    assert not [d["key"] for r, d in products
+                if "attention_core" in d["scopes"] and (r, d) not in tiles]
+
+
+def _assert_the_routed_layer_carries_route_and_dispatch(said, width=2048):
+    """``ops/moe.routed_experts``' two scopes: no grouped product
+    (``ragged-dot-*``) carries either; the sorts (the router's top-k, the
+    assignments' argsort) and the cumulative counts (``reduce-window``) carry
+    ``expert_route`` as ``optable`` reads them, where the compiler left them
+    an ``op_name``; every gather and scatter carries one of the two, and those
+    that move rows (``width`` wide) carry ``expert_dispatch``, forward,
+    recomputed and backward.  A gather fusion's fused instructions carry the
+    bare ``op_name`` ``gather`` (XLA:TPU's expander), so for the rows the
+    fusion's own ``op_name`` is what carries the scope (PERF.md section 7)."""
+    from perfbench import optable
+
+    both = {"expert_route", "expert_dispatch"}
+    ragged = [(r, d) for r, d in said if r["name"].startswith("ragged-dot")]
+    assert len(ragged) >= 9
+    assert not [d["key"] for r, d in ragged
+                if both & (set(d["scopes"]) | _own_scopes(r))]
+    named = lambda r: r["op_name"] or any(
+        leaf["op_name"] for leaf in r["fused"])
+    counted = [(r, d) for r, d in said
+               if _leaf_opcodes(r) & {"sort", "reduce-window"} and named(r)]
+    assert {"sort", "reduce-window"} <= set().union(
+        *(_leaf_opcodes(r) for r, _ in counted))
+    assert all("expert_route" in d["scopes"] for _, d in counted), [
+        d["key"] for _, d in counted]
+    moved = [(r, d) for r, d in said
+             if _leaf_opcodes(r) & {"gather", "scatter"} and named(r)]
+    assert all(both & (set(d["scopes"]) | _own_scopes(r)) for r, d in moved)
+    rows = [r for r, _ in moved if r["types"][0].endswith(f",{width}]")]
+    assert all("expert_dispatch" in _own_scopes(r) for r in rows)
+    assert {optable.pass_of(r["op_name"]) for r in rows} == {
+        "forward", "recompute", "backward"}
+
+
+def test_lfm2_attention_and_expert_layer_carry_the_scopes_the_metrics_read_for_v5e(
+        one_chip, no_persistent_cache):
+    """LFM2's layer 2 (attention, then the routed experts) as the cell runs it,
+    four sequences: ``attention_core`` round ``block_flash`` and inside its
+    backward rule, ``expert_route`` and ``expert_dispatch`` round the routed
+    layer's routing and row traffic, which ``attention_ms``,
+    ``attention_roofline_pct``, ``expert_route_ms`` and ``expert_dispatch_ms``
+    read (``perfbench/optable.py``)."""
+    said = _compiled_layer("lfm2", 2, 4, one_chip)
+    _assert_attention_core_is_the_kernel(said)
+    _assert_the_routed_layer_carries_route_and_dispatch(said)
+
+
+def test_kanana_2_expert_layer_carries_its_shared_and_routed_scopes_for_v5e(
+        one_chip, no_persistent_cache):
+    """Kanana-2's expert layer (layer 1's ``SharedAndRoutedExperts``) over
+    the cell's 32,768 tokens: the shared SwiGLU's products (1,536 wide: two
+    shared experts of 768) carry ``shared_expert``, which ``shared_expert_ms``
+    reads; no instruction of the routed layer does (no ``ragged-dot-*``,
+    nothing under ``expert_route`` or ``expert_dispatch``), and the routed
+    layer carries its own two scopes as in LFM2's."""
+    said = _compiled_layer("deepseek_v3", 1, 4, one_chip, part="ffn")
+    shared = [(r, d) for r, d in said if d["cls"] == "product"
+              and not r["name"].startswith("ragged-dot")
+              and any("1536" in t for t in r["types"])]
+    assert len(shared) >= 6
+    assert all("shared_expert" in d["scopes"] for _, d in shared)
+    routed = {"expert_route", "expert_dispatch"}
+    for r, d in said:
+        scopes = set(d["scopes"]) | _own_scopes(r)
+        if r["name"].startswith("ragged-dot") or scopes & routed:
+            assert "shared_expert" not in scopes, d["key"]
+    _assert_the_routed_layer_carries_route_and_dispatch(said)
+
+
+def test_granitemoehybrid_mixer_and_attention_carry_their_scopes_for_v5e(
+        one_chip, no_persistent_cache):
+    """granite's two kinds of layer, the cell's two sequences: in a
+    state-space layer every instruction that carries ``ssm_scan`` also
+    carries ``ssm_mixer`` (the whole of ``Mamba2Mixer.apply``, which
+    ``ssm_mixer_ms`` reads), as ``optable`` reads it and in its own
+    ``op_name``, and the mixer's projections carry it; the attention layer
+    carries ``attention_core`` as LFM2's does."""
+    said = _compiled_layer("granitemoehybrid", 0, 2, one_chip)
+    for r, d in said:
+        if "ssm_scan" in d["scopes"]:
+            assert "ssm_mixer" in d["scopes"], d["key"]
+        if "ssm_scan" in _own_scopes(r):
+            assert "ssm_mixer" in _own_scopes(r), d["key"]
+    mixer = [d for _, d in said if "ssm_mixer" in d["scopes"]]
+    assert len(mixer) > len([d for d in mixer if "ssm_scan" in d["scopes"]])
+    # in_proj's product, 8,512 wide, and out_proj's, under the mixer's scope
+    assert any("8512" in d["key"] and d["cls"] == "product" for d in mixer)
+    _assert_attention_core_is_the_kernel(
+        _compiled_layer("granitemoehybrid", 5, 2, one_chip))
