@@ -30,7 +30,7 @@ from typing import Callable, Optional, Sequence, Tuple
 # Imports below double as rule-12 registration: a module whose kernels are
 # verified must be imported here (statically parsed, never executed by the
 # analyzer).
-from mpi4dl_tpu.ops.pallas_attention import block_flash
+from mpi4dl_tpu.ops.pallas_attention import block_flash, block_flash_backward
 from mpi4dl_tpu.ops.pallas_latent_attention import (
     latent_flash, latent_flash_backward)
 
@@ -70,6 +70,27 @@ def _flash_case(dtype: str, causal: bool):
 
     variant = "causal:" if causal else ""
     return KernelCase(name=f"block_flash:{variant}{dtype}", build=build)
+
+
+def _flash_backward_case(dtype: str, causal: bool):
+    def build():
+        import jax.numpy as jnp
+
+        # Grid (2, 3, 3): k tiles before q tiles; 300 tokens in tiles of 128
+        # (a zero-padded tail in the last of each), feature-major blocks;
+        # under causal three tiles skipped, three masked, three whole.
+        dt = jnp.dtype(dtype)
+        q = jnp.zeros((2, 300, 64), dt)
+        stat = jnp.zeros((2, 300), jnp.float32)
+        z = jnp.zeros((), jnp.int32)
+        fn = lambda q, k, v, m, do, dl: block_flash_backward(  # noqa: E731
+            q, k, v, z, z, m, do, dl, causal, 0.125, 128, 128, False
+        )
+        return fn, (q, q, q, stat, q, stat)
+
+    variant = "causal:" if causal else ""
+    return KernelCase(name=f"block_flash_backward:{variant}{dtype}",
+                      build=build)
 
 
 _LATENT_HEADS, _LATENT_TOKENS = 4, 300
@@ -116,11 +137,13 @@ def _latent_backward_case(dtype: str):
 
 # The raw (fp32) path and the bf16 compute path the mixed-precision/quant
 # engines dispatch (quant/kernels.py itself is pure jnp — no pallas_call,
-# which rule 12 verifies stays true); latent attention's forward and
-# backward kernels (always causal) in both.
+# which rule 12 verifies stays true), forward and backward; latent
+# attention's forward and backward kernels (always causal) in both.
 REGISTRY: Tuple[KernelCase, ...] = (
     _flash_case("float32", causal=False),
     _flash_case("bfloat16", causal=True),
+    _flash_backward_case("float32", causal=False),
+    _flash_backward_case("bfloat16", causal=True),
     _latent_case("float32"),
     _latent_case("bfloat16"),
     _latent_backward_case("float32"),

@@ -26,11 +26,15 @@ last k tile).  Causal masking is by GLOBAL token position: the q/k block
 offsets arrive as scalar-prefetch arguments so one compiled kernel serves
 every ring hop (the k offset is a traced, device-varying value).
 
-Training: :func:`block_flash` carries a custom VJP whose backward is a
-``lax.scan`` of einsum tiles over the Tk dimension — memory stays
-O(TQ·TK) per step (never the full score matrix) while the matmuls stay on
-the MXU.  Reference: the flash-attention backward recurrences; residuals
-saved are (q, k, v, o_hat, m, l).
+Training: :func:`block_flash` carries a custom VJP whose backward is ONE
+Pallas kernel, :func:`block_flash_backward` (the flash-attention backward
+recurrences; residuals saved are (q, k, v, o_hat, m, l), of which it reads
+q, k, v and m beside the cotangents of o_hat and l).  Its grid is (B·H, Tk
+tiles, Tq tiles): dk and dv of a k tile, and dq of the B·H index's whole
+sequence, accumulate in float32 VMEM; it reads and writes its operands
+feature-major (tokens along the lanes), so that a head of 64 is no operand
+padded to 128 lanes in HBM; it masks by the same GLOBAL positions, from the
+same scalar prefetch, so one compiled kernel serves every ring hop too.
 
 Used by :func:`mpi4dl_tpu.ops.ring.ring_attention` when ``use_flash``
 resolves on (auto: TPU backends).  Interpret mode runs on CPU for tests.
@@ -39,15 +43,14 @@ resolves on (auto: TPU backends).  Interpret mode runs on CPU for tests.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+import numpy as np
 
-from mpi4dl_tpu.compat import pcast
 from mpi4dl_tpu.obs.scopes import scope
 
 _NEG_INF = -1e30  # large-negative instead of -inf: exp() of it is exactly 0
@@ -262,141 +265,258 @@ def _block_flash_fwd(q, k, v, q_off, k_off, causal, scale, tq, tk, interpret):
     return (o, m, l), (q, k, v, q_off, k_off, o, m, l)
 
 
-# Tiles, measured on a v5e at 32 heads of 64 over 8,192 tokens in bf16
-# (PERF.md, PR 29): the forward kernel alone takes 15.9 ms at (256, 512),
-# 13.0 at (512, 512), 8.7 at (512, 1024) and 7.3 at (1024, 1024); the
-# backward's einsum tiles are fastest at 1,024 queries by 512 keys (21 ms;
-# 33 at 1,024 keys, 43 at 2,048 queries), whatever the forward's were.
+# Tiles, measured on a v5e at 32 heads of 64 over 8,192 tokens in bf16.  The
+# forward kernel alone (PERF.md) takes 15.9 ms at (256, 512), 13.0 at
+# (512, 512), 8.7 at (512, 1024) and 7.3 at (1024, 1024).  The backward
+# kernel alone, causal (PERF.md, section 6): 7.87 ms at (1024, 1024), 8.26 at
+# (512, 1024), 8.39 at (1024, 512), 9.13 at (512, 512), 12.44 at (256, 512),
+# 7.95 at (2048, 1024), 8.00 at (1024, 2048) and 7.83 at (2048, 2048), which
+# needs a VMEM limit named (below); the einsum tiles it replaced took 20.86.
 LOCAL_TILES = (1024, 1024)  # query, key rows of a tile, flash_attention_local
-_BWD_TQ, _BWD_TK = 1024, 512  # query, key rows of a backward tile
+_BWD_TQ, _BWD_TK = 1024, 1024  # the most query, key rows of a backward tile
+# The compiler's own VMEM budget for a kernel on a v5e.  The backward kernel
+# asks for more only where it needs more: a kernel that names any limit
+# (even this one) makes XLA give every instruction of the program a scoped
+# VMEM reservation, and the LFM2 step then takes 0.28 GiB more HBM
+# (PERF.md, section 6).
+_DEFAULT_VMEM = 16 * 2 ** 20
+
+# Traced once for all the calls of a step that make it alike, and put into the
+# caller's trace where it stands (its instructions carry the caller's scopes):
+# a kernel's body is a large jaxpr, and on the chip's host the Kanana-2 step's
+# five backward kernels took 0.8 s each to trace and 0.3 s each to lower
+# (PERF.md).  Equations with one jaxpr are lowered once.
+_traced_once = functools.partial(jax.jit, inline=True)
 
 
-def _block_flash_bwd(causal, scale, tq, tk, interpret, res, cts):
-    """:func:`block_flash`'s backward rule, under the scope by which a device
-    trace finds attention itself, as its callers open it round the forward
-    kernel."""
-    with scope("attention_core"):
-        return _block_flash_bwd_tiles(causal, scale, tq, tk, interpret, res,
-                                      cts)
+def _bwd_tile(t: int, most: int) -> int:
+    """Rows of a backward tile over ``t`` rows: as few tiles as ``most``
+    allows, of equal size rounded up to whole lanes (a tile of either kind
+    lies along the lanes of its feature-major blocks)."""
+    n = max(1, -(-t // most))
+    return _round_up(max(1, -(-t // n)), _LANES)
 
 
-def _block_flash_bwd_tiles(causal, scale, tq, tk, interpret, res, cts):
-    """Blockwise backward: a scan over Tk tiles, and inside it a scan over
-    Tq tiles, of einsum blocks — never more than a [BH, TQ, TK] score tile
-    at a time, whatever the sequence length.  Under ``causal`` a tile whose
-    every key lies after its last query is skipped (``lax.cond``): it adds
-    exactly nothing.  The products take q, k, v as they come (and dô in
-    their dtype) and accumulate in float32.
+def _bwd_vmem_bytes(tq, tk, tq_p, d, dv, itemsize):
+    """An upper bound of the VMEM :func:`_bwd_kernel` takes: the resident dq
+    (float32 sums and one output block), the blocks of a grid step (double
+    buffered, and dk's and dv's float32 sums) and about ten bytes an element
+    of a [TK, TQ] tile for the scores and their kin.  Fitted to the limits
+    under which the kernel compiled for a v5e (PERF.md, section 6): 14.3 MiB at
+    tiles of 1,024, heads of 64 and 8,192 tokens (this gives 15.0), 23.6 at
+    keys of 192 and values of 128 (24.0), 23.1 at 32,768 tokens (24.0)."""
+    resident = (4 + itemsize) * d * tq_p
+    blocks = (2 * itemsize * (d + dv) * (tq + 2 * tk)
+              + 4 * (d + dv) * tk)
+    return resident + blocks + 10 * tq * tk
 
-    Of the five products s, dq and dk run at the key width D, and dP and dv
-    at the value width Dv (dô is Dv wide).
 
-    With ô = P·V, l = rowsum(P), P = exp(s - m) (m treated as a constant
-    plateau — its cotangent is zero almost everywhere):
-        dP = dô Vᵀ + dl·1ᵀ ;  ds = P ⊙ dP
-        dq = ds K · scale ;  dk = dsᵀ Q · scale ;  dv = Pᵀ dô
-    """
-    q, k, v, q_off, k_off, o, m, l = res
-    do, dm, dl = cts  # dm is zero a.e.; fold dl into dP
-    del o, dm, l
+def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, m_ref, dl_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                tq, tk, nq, nk, causal, t_k_real, scale):
+    """One (bh, k-tile, q-tile) step of the backward on FEATURE-MAJOR blocks
+    (``q_ref`` [1, D, TQ], ``k_ref`` [1, D, TK], …: tokens along the lanes),
+    the scores transposed (keys on the sublanes, queries along the lanes,
+    where ``m`` and ``dl`` lie).  ``dk_acc`` and ``dv_acc`` persist across
+    the innermost q dimension: zeroed at its first tile, written at its last.
+    ``dq_acc`` holds the whole sequence of the bh index across both: a q
+    tile's columns are zeroed at the first k tile and written after the last
+    one its queries see.  Positions are GLOBAL (``offs_ref``: the q and k
+    offsets), as in the forward kernel."""
+    ki, qi = pl.program_id(1), pl.program_id(2)
+    cols = pl.ds(pl.multiple_of(qi * tq, tq), tq)
+    q0 = offs_ref[0] + qi * tq          # the tile's first query
+    k0 = offs_ref[1] + ki * tk          # and first key
+    ragged = t_k_real % tk != 0
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[:, cols] = jnp.zeros((dq_acc.shape[0], tq), jnp.float32)
+
+    def product(a, b, contract):
+        # DEFAULT, said outright: as in the forward kernel
+        return lax.dot_general(
+            a, b, ((contract[:1], contract[1:]), ((), ())),
+            precision=lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32)
+
+    def fold(masked: bool):
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        s = product(k, q, (0, 0)) * scale               # [TK, TQ]
+        p = jnp.exp(s - m_ref[0])                       # m: [1, TQ]
+        if masked:
+            key = ki * tk + lax.broadcasted_iota(jnp.int32, (tk, tq), 0)
+            mask = key < t_k_real
+            if causal:
+                mask = mask & (q0 + lax.broadcasted_iota(jnp.int32, (tk, tq), 1)
+                               >= offs_ref[1] + key)
+            # also a row without a visible key: m = _NEG_INF there, and
+            # exp(s − m) would count every masked key
+            p = jnp.where(mask, p, 0.0)
+        ds = (p * (product(v, do, (0, 0)) + dl_ref[0])).astype(q.dtype)
+        dv_acc[:] += product(do, p.astype(do.dtype), (1, 1))   # [Dv, TK]
+        dk_acc[:] += product(q, ds, (1, 1))                    # [D, TK]
+        dq_acc[:, cols] += product(k, ds, (1, 0))              # [D, TQ]
+
+    # A tile all of whose keys every one of its queries sees (its last key at
+    # or before its first query, and inside the keys) needs no mask; under
+    # ``causal`` one whose first key lies after its last query adds nothing
+    # and is skipped.
+    inside = (ki + 1) * tk <= t_k_real
+    if causal:
+        whole = k0 + tk - 1 <= q0
+        if ragged:
+            whole = jnp.logical_and(whole, inside)
+        pl.when(whole)(functools.partial(fold, False))
+        pl.when(jnp.logical_and(jnp.logical_not(whole), q0 + tq - 1 >= k0))(
+            functools.partial(fold, True))
+        # the last k tile whose first key the tile's last query sees (the
+        # first, where none is: its columns are then zero)
+        last = jnp.minimum(
+            lax.div(jnp.maximum(q0 + tq - 1 - offs_ref[1], 0), tk), nk - 1)
+    else:
+        if ragged:
+            pl.when(inside)(functools.partial(fold, False))
+            pl.when(jnp.logical_not(inside))(functools.partial(fold, True))
+        else:
+            fold(False)
+        last = nk - 1
+
+    @pl.when(ki == last)
+    def _():
+        dq_ref[0, :, cols] = (dq_acc[:, cols] * scale).astype(dq_ref.dtype)
+
+    @pl.when(qi == nq - 1)
+    def _():
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _reference_backward(q, k, v, q_off, k_off, m, do, dl, causal, scale):
+    """The backward's mathematics as einsums over the whole score matrix in
+    float32: what interpret mode runs under ``shard_map`` (see
+    :func:`_block_flash_fwd_impl`)."""
+    f32 = jnp.float32
+    q32, k32, v32, do32 = (x.astype(f32) for x in (q, k, v, do))
+    p = jnp.exp(jnp.einsum("bqd,bkd->bqk", q32, k32) * scale - m[..., None])
+    if causal:
+        q_pos = q_off + jnp.arange(q.shape[1], dtype=jnp.int32)
+        k_pos = k_off + jnp.arange(k.shape[1], dtype=jnp.int32)
+        p = jnp.where(q_pos[:, None] >= k_pos[None, :], p, 0.0)
+    ds = p * (jnp.einsum("bqd,bkd->bqk", do32, v32) + dl[..., None])
+    return ((jnp.einsum("bqk,bkd->bqd", ds, k32) * scale).astype(q.dtype),
+            (jnp.einsum("bqk,bqd->bkd", ds, q32) * scale).astype(k.dtype),
+            jnp.einsum("bqk,bqd->bkd", p, do32).astype(v.dtype))
+
+
+@functools.partial(_traced_once, static_argnums=(8, 9, 10, 11, 12))
+def block_flash_backward(q, k, v, q_off, k_off, m, do, dl, causal, scale,
+                         tq, tk, interpret=False):
+    """:func:`block_flash`'s backward as ONE kernel over the grid (bh, k tile,
+    q tile): from the operands, the row maxima ``m`` [BH, Tq] and the
+    cotangents ``do`` [BH, Tq, Dv] of ``o_hat`` and ``dl`` [BH, Tq] of ``l``,
+    the cotangents ``(dq, dk, dv)`` in the operands' shapes and dtypes.
+
+    With P = exp(s − m), ô = P·V and l = rowsum(P) (m a constant plateau:
+    its cotangent is zero almost everywhere):
+        dP = dô Vᵀ + dl·1ᵀ ;  dS = P ⊙ dP
+        dq = dS K · scale ;  dk = dSᵀ Q · scale ;  dv = Pᵀ dô
+    The scores are computed transposed (keys on the sublanes), so that m and
+    dl are rows along the lanes.  ``dô`` goes to the products in the
+    operands' dtype, P and dS are rounded to it once before theirs, and every
+    product sums in float32: dk and dv of a k tile across the q tiles, dq of
+    the bh index's whole sequence across the k tiles, all in VMEM.  A tile
+    above the diagonal is skipped and asks for the blocks it already holds,
+    so nothing is copied for it.  Padded keys are masked by index; padded
+    queries carry dô = dl = m = 0 and give exactly nothing, as does a row
+    that sees no key.  ``tq`` and ``tk`` are multiples of 128: tokens lie
+    along the lanes of every block."""
+    if interpret and _any_vma(q, k, v, q_off, k_off, m, do, dl):
+        # as the forward: interpret mode cannot run under shard_map
+        return _reference_backward(q, k, v, q_off, k_off, m, do, dl, causal,
+                                   scale)
     bh, t_q, d = q.shape
     t_k, dv = k.shape[1], v.shape[-1]
     f32 = jnp.float32
-    del tq, tk  # the forward kernel's; the backward's tiles are its own
-    nk = max(1, (t_k + _BWD_TK - 1) // _BWD_TK)
-    tk_c = _round_up(t_k, nk) // nk if t_k else t_k
-    nq = max(1, (t_q + _BWD_TQ - 1) // _BWD_TQ)
-    tq_c = _round_up(t_q, nq) // nq if t_q else t_q
-    k_pad, q_pad = nk * tk_c - t_k, nq * tq_c - t_q
+    tq_p, tk_p = _round_up(t_q, tq), _round_up(t_k, tk)
+    # feature-major, tokens along the lanes: a 64-wide operand row-major
+    # would be padded to 128 lanes in HBM, and so would the residuals that
+    # XLA lays out to match (PERF.md, section 6)
+    tokens_last = lambda x, t_p: jnp.pad(
+        jnp.swapaxes(x, 1, 2), ((0, 0), (0, 0), (0, t_p - x.shape[1])))
+    q, do = tokens_last(q, tq_p), tokens_last(do.astype(q.dtype), tq_p)
+    k, v = tokens_last(k, tk_p), tokens_last(v, tk_p)
+    m, dl = (jnp.pad(x.astype(f32), ((0, 0), (0, tq_p - t_q)))[:, None, :]
+             for x in (m, dl))
+    nq, nk = tq_p // tq, tk_p // tk
+    offs = jnp.stack([q_off, k_off]).astype(jnp.int32)
+    vmem = _bwd_vmem_bytes(tq, tk, tq_p, d, dv, q.dtype.itemsize)
 
-    def tiles(x, n, pad):
-        """[BH, T, ...] as [n, BH, T/n, ...], zero rows appended."""
-        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        x = x.reshape(bh, n, x.shape[1] // n, *x.shape[2:])
-        return jnp.moveaxis(x, 1, 0)
+    def tile_of_q(j, i, offs):
+        """Under ``causal`` a skipped tile (its queries before its keys) asks
+        for the q tile of the first that is not, the pipeline then copying
+        nothing for it."""
+        if not causal:
+            return i
+        first = lax.div(jnp.maximum(offs[1] + j * tk - offs[0], 0), tq)
+        return jnp.maximum(i, jnp.minimum(first, nq - 1))
 
-    # Padded queries have dô = dl = 0, so they add nothing to dk and dv, and
-    # their dq rows are cut off; padded keys are masked by their index.
-    kts, vts = tiles(k, nk, k_pad), tiles(v, nk, k_pad)
-    k_ids = jnp.arange(nk * tk_c, dtype=jnp.int32).reshape(nk, tk_c)
-    q_tiles = (tiles(q, nq, q_pad), tiles(do.astype(q.dtype), nq, q_pad),
-               tiles(m.astype(f32), nq, q_pad), tiles(dl.astype(f32), nq, q_pad),
-               (q_off + jnp.arange(nq * tq_c, dtype=jnp.int32)).reshape(nq, tq_c))
+    of_q = lambda w: pl.BlockSpec(
+        (1, w, tq), lambda b, j, i, offs: (b, 0, tile_of_q(j, i, offs)))
+    of_k = lambda w: pl.BlockSpec((1, w, tk), lambda b, j, i, offs: (b, 0, j))
+    dq, dk, dv_ = pl.pallas_call(
+        functools.partial(_bwd_kernel, tq=tq, tk=tk, nq=nq, nk=nk,
+                          causal=causal, t_k_real=t_k, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nk, nq),
+            in_specs=[of_q(d), of_k(d), of_k(dv), of_q(dv), of_q(1), of_q(1)],
+            out_specs=[
+                # the whole sequence, written back when bh moves on: one
+                # buffer, which the pipeline drains before the next bh
+                pl.BlockSpec((1, d, tq_p), lambda b, j, i, offs: (b, 0, 0),
+                             pipeline_mode=pl.Buffered(1)),
+                of_k(d), of_k(dv),
+            ],
+            scratch_shapes=[pltpu.VMEM((d, tq_p), f32),
+                            pltpu.VMEM((d, tk), f32),
+                            pltpu.VMEM((dv, tk), f32)],
+        ),
+        out_shape=_out_structs(
+            (q, k, v, do, m, dl, offs),
+            [((bh, d, tq_p), q.dtype), ((bh, d, tk_p), k.dtype),
+             ((bh, dv, tk_p), v.dtype)]),
+        compiler_params=(
+            None if vmem <= _DEFAULT_VMEM
+            else pltpu.CompilerParams(vmem_limit_bytes=vmem + 2 ** 21)),
+        interpret=interpret,
+        name="block_flash_bwd",
+    )(offs, q, k, v, do, m, dl)
+    return (jnp.swapaxes(dq[..., :t_q], 1, 2), jnp.swapaxes(dk[..., :t_k], 1, 2),
+            jnp.swapaxes(dv_[..., :t_k], 1, 2))
 
-    # Under shard_map the accumulators become device-varying inside the
-    # scans; their initial values must be marked varying up front.
-    def vary(t):
-        try:
-            vma = frozenset()
-            for a in (q, k, v, do):
-                vma = vma | frozenset(jax.typeof(a).vma)
-            return pcast(t, tuple(vma), to="varying") if vma else t
-        except (AttributeError, TypeError):
-            return t
 
-    def k_tile(dq_acc, inp):
-        kt, vt, ids = inp  # [BH, tk_c, D], [BH, tk_c, Dv], [tk_c]
-
-        def q_tile(carry, qin):
-            dk_t, dv_t, dq_acc = carry
-            i, qt, dot, mt, dlt, q_pos = qin
-
-            def fold(dk_t, dv_t):
-                s = jnp.einsum("bqd,bkd->bqk", qt, kt,
-                               preferred_element_type=f32) * scale
-                mask = jnp.broadcast_to((ids < t_k)[None, :], s.shape[1:])
-                if causal:
-                    mask = mask & (q_pos[:, None] >= (k_off + ids)[None, :])
-                s = jnp.where(mask[None], s, _NEG_INF)
-                p = jnp.where(s > _NEG_INF * 0.5, jnp.exp(s - mt[..., None]), 0.0)
-                dp = jnp.einsum("bqd,bkd->bqk", dot, vt,
-                                preferred_element_type=f32) + dlt[..., None]
-                ds = (p * dp).astype(qt.dtype)
-                dq_t = jnp.einsum("bqk,bkd->bqd", ds, kt,
-                                  preferred_element_type=f32)
-                dk_t = dk_t + jnp.einsum("bqk,bqd->bkd", ds, qt,
-                                         preferred_element_type=f32)
-                dv_t = dv_t + jnp.einsum("bqk,bqd->bkd", p.astype(dot.dtype),
-                                         dot, preferred_element_type=f32)
-                return dk_t, dv_t, dq_t
-
-            if causal:
-                # the tile's last query is at or after its first key
-                dk_t, dv_t, dq_t = lax.cond(
-                    q_pos[-1] >= k_off + ids[0], fold,
-                    lambda dk_t, dv_t: (dk_t, dv_t,
-                                        vary(jnp.zeros((bh, tq_c, d), f32))),
-                    dk_t, dv_t)
-            else:
-                dk_t, dv_t, dq_t = fold(dk_t, dv_t)
-            # dq's tile is added where it lies: the accumulator is a loop
-            # carry, updated in place, and never passes over as a whole
-            dq_acc = lax.dynamic_update_index_in_dim(
-                dq_acc, lax.dynamic_index_in_dim(dq_acc, i, 0, False) + dq_t,
-                i, 0)
-            return (dk_t, dv_t, dq_acc), None
-
-        zero = vary(jnp.zeros((bh, tk_c, d), f32))
-        zero_v = zero if dv == d else vary(jnp.zeros((bh, tk_c, dv), f32))
-        (dk_t, dv_t, dq_acc), _ = lax.scan(
-            q_tile, (zero, zero_v, dq_acc),
-            (jnp.arange(nq, dtype=jnp.int32), *q_tiles))
-        return dq_acc, (dk_t, dv_t)
-
-    dq0 = vary(jnp.zeros((nq, bh, tq_c, d), f32))
-    dq, (dks, dvs) = lax.scan(k_tile, dq0, (kts, vts, k_ids))
-    dq = jnp.moveaxis(dq, 0, 1).reshape(bh, nq * tq_c, d)
-    untile = lambda x: jnp.moveaxis(x, 0, 1).reshape(
-        bh, nk * tk_c, x.shape[-1])[:, :t_k]
+def _block_flash_bwd(causal, scale, tq, tk, interpret, res, cts):
+    """:func:`block_flash`'s backward rule: :func:`block_flash_backward` at
+    tiles of its own, chosen from the lengths, under the scope by which a
+    device trace finds attention itself, as its callers open it round the
+    forward kernel."""
+    q, k, v, q_off, k_off, o, m, l = res
+    do, dm, dl = cts  # dm is zero almost everywhere
+    del tq, tk, o, l, dm  # the forward kernel's tiles; the backward's are its own
+    with scope("attention_core"):
+        dq, dk, dv = block_flash_backward(
+            q, k, v, q_off, k_off, m, do, dl, causal, scale,
+            _bwd_tile(q.shape[1], _BWD_TQ), _bwd_tile(k.shape[1], _BWD_TK),
+            interpret)
     # Integer (position-offset) primals take float0 cotangents.
-    import numpy as np
-
     f0 = np.zeros((), jax.dtypes.float0)
-    return (
-        (dq[:, :t_q] * scale).astype(q.dtype),
-        (untile(dks) * scale).astype(k.dtype), untile(dvs).astype(v.dtype),
-        f0, f0,
-    )
+    return dq, dk, dv, f0, f0
 
 
 block_flash.defvjp(_block_flash_fwd, _block_flash_bwd)

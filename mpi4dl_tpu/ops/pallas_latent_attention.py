@@ -74,7 +74,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from mpi4dl_tpu.obs.scopes import scope
 from mpi4dl_tpu.ops.pallas_attention import (
-    _LANES, _NEG_INF, _out_structs, _round_up)
+    _LANES, _NEG_INF, _out_structs, _round_up, _traced_once)
 
 # Query and key rows of a tile, forward and backward, and the VMEM each kernel
 # may take.  Measured on a v5e at 4 sequences of 8,192 tokens, 32 heads of 128
@@ -176,14 +176,6 @@ def _kernel(q_ref, qpe_ref, kv_ref, kpe_ref, o_ref, m_ref, l_ref,
                 acc[h] / jnp.maximum(l[:, :1], 1e-30)).astype(o_ref.dtype)
             m_ref[0, h] = m_scr[h].T[:1]
             l_ref[0, h] = l.T[:1]
-
-
-# Traced once for all the layers of a step that call it alike, and put into
-# the caller's trace where it stands (its instructions carry the caller's
-# scopes): a kernel's body is a large jaxpr, and on the chip's host the step's
-# five backward kernels took 0.8 s each to trace and 0.3 s each to lower
-# (PERF.md, PR 37).  Equations with one jaxpr are lowered once.
-_traced_once = functools.partial(jax.jit, inline=True)
 
 
 @functools.partial(_traced_once, static_argnums=(4, 5, 6, 7, 8))

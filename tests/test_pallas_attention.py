@@ -3,20 +3,25 @@ reference in interpret mode, and the flash ring path vs the einsum ring path
 on the 8-device CPU mesh (ops/ring.py use_flash=True); latent attention's
 forward and backward kernels on the projections' layout
 (ops/pallas_latent_attention.py) against the einsum form, forward and
-gradients, and the backward against the rule it replaced (heads-first
-operands through ``_block_flash_bwd``, kept here as the oracle)."""
+gradients.  ``block_flash``'s backward kernel against ``jax.vjp`` of the
+einsum reference and against the rule it replaced (a scan of einsum tiles,
+kept here as ``_einsum_tile_rule``), which is also the oracle of
+latent attention's backward (heads-first operands, ``_parent_rule``)."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mpi4dl_tpu.compat import shard_map
+from mpi4dl_tpu.compat import pcast, shard_map
 from jax.sharding import PartitionSpec as P
 
 from mpi4dl_tpu.mesh import MeshSpec, build_mesh
 from mpi4dl_tpu.ops.pallas_attention import (
-    _block_flash_bwd, block_flash, flash_attention_local, mlo_merge,
+    _NEG_INF, _reference_mlo, block_flash, block_flash_backward,
+    flash_attention_local, mlo_merge,
 )
 from mpi4dl_tpu.ops.pallas_latent_attention import _forward, latent_flash
 from mpi4dl_tpu.ops.ring import ring_attention
@@ -178,6 +183,228 @@ def test_ring_flash_grads_match_einsum_ring(devices8):
     )
 
 
+# --- block_flash's backward kernel -------------------------------------------
+
+
+def _einsum_tile_rule(causal, scale, res, cts, tq=1024, tk=512):
+    """``block_flash``'s backward as it was written before it became a
+    kernel: a scan over Tk tiles, and inside it a scan over Tq tiles, of
+    einsum blocks; under ``causal`` a tile whose every key lies after its last
+    query is skipped (``lax.cond``).  The products take q, k, v as they come
+    (and dô in their dtype) and accumulate in float32.  ``res`` is ``(q, k,
+    v, q_off, k_off, m)``, ``cts`` is ``(dô, dl)``; returns ``(dq, dk, dv)``.
+
+        dP = dô Vᵀ + dl·1ᵀ ;  ds = P ⊙ dP
+        dq = ds K · scale ;  dk = dsᵀ Q · scale ;  dv = Pᵀ dô
+    """
+    q, k, v, q_off, k_off, m = res
+    do, dl = cts
+    bh, t_q, d = q.shape
+    t_k, dv = k.shape[1], v.shape[-1]
+    f32 = jnp.float32
+    nk = max(1, (t_k + tk - 1) // tk)
+    tk_c = -(-t_k // nk)
+    nq = max(1, (t_q + tq - 1) // tq)
+    tq_c = -(-t_q // nq)
+    k_pad, q_pad = nk * tk_c - t_k, nq * tq_c - t_q
+
+    def tiles(x, n, pad):
+        """[BH, T, ...] as [n, BH, T/n, ...], zero rows appended."""
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape(bh, n, x.shape[1] // n, *x.shape[2:])
+        return jnp.moveaxis(x, 1, 0)
+
+    kts, vts = tiles(k, nk, k_pad), tiles(v, nk, k_pad)
+    k_ids = jnp.arange(nk * tk_c, dtype=jnp.int32).reshape(nk, tk_c)
+    q_tiles = (tiles(q, nq, q_pad), tiles(do.astype(q.dtype), nq, q_pad),
+               tiles(m.astype(f32), nq, q_pad), tiles(dl.astype(f32), nq, q_pad),
+               (q_off + jnp.arange(nq * tq_c, dtype=jnp.int32)).reshape(nq, tq_c))
+
+    def vary(t):
+        """Under shard_map the accumulators become device-varying inside the
+        scans; their initial values are marked varying up front."""
+        vma = frozenset().union(*(jax.typeof(a).vma for a in (q, k, v, do)))
+        return pcast(t, tuple(vma), to="varying") if vma else t
+
+    def k_tile(dq_acc, inp):
+        kt, vt, ids = inp
+
+        def q_tile(carry, qin):
+            dk_t, dv_t, dq_acc = carry
+            i, qt, dot, mt, dlt, q_pos = qin
+
+            def fold(dk_t, dv_t):
+                s = jnp.einsum("bqd,bkd->bqk", qt, kt,
+                               preferred_element_type=f32) * scale
+                mask = jnp.broadcast_to((ids < t_k)[None, :], s.shape[1:])
+                if causal:
+                    mask = mask & (q_pos[:, None] >= (k_off + ids)[None, :])
+                s = jnp.where(mask[None], s, _NEG_INF)
+                p = jnp.where(s > _NEG_INF * 0.5, jnp.exp(s - mt[..., None]), 0.0)
+                dp = jnp.einsum("bqd,bkd->bqk", dot, vt,
+                                preferred_element_type=f32) + dlt[..., None]
+                ds = (p * dp).astype(qt.dtype)
+                dq_t = jnp.einsum("bqk,bkd->bqd", ds, kt,
+                                  preferred_element_type=f32)
+                dk_t = dk_t + jnp.einsum("bqk,bqd->bkd", ds, qt,
+                                         preferred_element_type=f32)
+                dv_t = dv_t + jnp.einsum("bqk,bqd->bkd", p.astype(dot.dtype),
+                                         dot, preferred_element_type=f32)
+                return dk_t, dv_t, dq_t
+
+            if causal:
+                dk_t, dv_t, dq_t = jax.lax.cond(
+                    q_pos[-1] >= k_off + ids[0], fold,
+                    lambda dk_t, dv_t: (dk_t, dv_t,
+                                        vary(jnp.zeros((bh, tq_c, d), f32))),
+                    dk_t, dv_t)
+            else:
+                dk_t, dv_t, dq_t = fold(dk_t, dv_t)
+            dq_acc = jax.lax.dynamic_update_index_in_dim(
+                dq_acc, jax.lax.dynamic_index_in_dim(dq_acc, i, 0, False) + dq_t,
+                i, 0)
+            return (dk_t, dv_t, dq_acc), None
+
+        zero = vary(jnp.zeros((bh, tk_c, d), f32))
+        zero_v = vary(jnp.zeros((bh, tk_c, dv), f32))
+        (dk_t, dv_t, dq_acc), _ = jax.lax.scan(
+            q_tile, (zero, zero_v, dq_acc),
+            (jnp.arange(nq, dtype=jnp.int32), *q_tiles))
+        return dq_acc, (dk_t, dv_t)
+
+    dq0 = vary(jnp.zeros((nq, bh, tq_c, d), f32))
+    dq, (dks, dvs) = jax.lax.scan(k_tile, dq0, (kts, vts, k_ids))
+    dq = jnp.moveaxis(dq, 0, 1).reshape(bh, nq * tq_c, d)
+    untile = lambda x: jnp.moveaxis(x, 0, 1).reshape(
+        bh, nk * tk_c, x.shape[-1])[:, :t_k]
+    return ((dq[:, :t_q] * scale).astype(q.dtype),
+            (untile(dks) * scale).astype(k.dtype), untile(dvs).astype(v.dtype))
+
+
+# (queries, keys, key width, value width, causal, q_off, k_off): heads-first
+# blocks as ring attention hands them over, hop by hop.
+_BWD_CASES = {
+    "causal": (256, 256, 32, 32, True, 0, 0),
+    "not_causal": (256, 256, 32, 32, False, 0, 0),
+    "later_hop": (256, 256, 32, 32, True, 256, 0),         # sees every key
+    "diagonal_hop": (256, 384, 32, 32, True, 128, 0),      # some tiles masked
+    "earlier_hop": (256, 256, 32, 32, True, 0, 256),       # sees no key
+    "ragged": (300, 200, 32, 32, True, 100, 0),            # no tile divides
+    "narrow_values": (256, 256, 24, 16, True, 0, 0),       # Dv != D
+    "narrow_values_not_causal": (200, 300, 24, 16, False, 0, 0),
+}
+
+
+def _bwd_operands(case, dtype, seed=0):
+    """q, k, v, the offsets, and ``(o, m, l)`` of the reference on them."""
+    t_q, t_k, d, dv, causal, q_off, k_off = _BWD_CASES[case]
+    rng = np.random.default_rng(seed)
+    make = lambda t, w: jnp.asarray(rng.standard_normal((3, t, w), np.float32),
+                                    dtype)
+    q, k, v = make(t_q, d), make(t_k, d), make(t_k, dv)
+    offs = jnp.int32(q_off), jnp.int32(k_off)
+    with jax.default_matmul_precision("highest"):
+        oml = _reference_mlo(q, k, v, *offs, causal, d ** -0.5)
+    return (q, k, v, *offs), causal, d ** -0.5, oml, rng
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(_BWD_CASES))
+def test_block_flash_gradients_are_the_vjp_of_the_reference(case, dtype):
+    """Through ``block_flash`` and its rule (tiles chosen from the lengths)
+    against ``jax.vjp`` of ``_reference_mlo`` in float32, on a loss of the
+    normalized output, whose cotangents carry a nonzero ``dl`` (there the
+    reference's dependence on m cancels, so the two rules must agree)."""
+    ops, causal, scale, _, rng = _bwd_operands(case, dtype)
+    w = jnp.asarray(rng.standard_normal((3, ops[0].shape[1], ops[2].shape[-1]),
+                                        np.float32))
+
+    def loss(fn, q, k, v):
+        o, _, l = fn(q, k, v)
+        return jnp.sum(w * o / jnp.maximum(l, 1e-30)[..., None])
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(functools.partial(loss, lambda q, k, v: block_flash(
+            q, k, v, *ops[3:], causal, scale, 256, 512, True)), (0, 1, 2))(
+                *ops[:3])
+        want = jax.grad(functools.partial(loss, lambda q, k, v: _reference_mlo(
+            q, k, v, *ops[3:], causal, scale)), (0, 1, 2))(
+                *(x.astype(jnp.float32) for x in ops[:3]))
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == dtype
+        if case == "earlier_hop":
+            assert not np.any(np.asarray(a, np.float32))
+        else:
+            assert _rel(a, b) < (1e-5 if dtype == jnp.float32 else 2e-2)
+
+
+@pytest.mark.parametrize("tiles", [(128, 128), (128, 256), (256, 128)],
+                         ids=lambda t: f"{t[0]}x{t[1]}")
+@pytest.mark.parametrize("case", sorted(_BWD_CASES))
+def test_block_flash_backward_kernel_is_the_einsum_tile_rule(case, tiles):
+    """The kernel at small tiles (grids of several k and q tiles, tiles
+    skipped, masked and whole) against the rule it replaced, float32, on
+    random cotangents ``dô`` and ``dl`` (not those of a normalized output)."""
+    ops, causal, scale, (_, m, _), rng = _bwd_operands(case, jnp.float32, 1)
+    do = jnp.asarray(rng.standard_normal((3, ops[0].shape[1], ops[2].shape[-1]),
+                                         np.float32))
+    dl = jnp.asarray(rng.standard_normal(m.shape, np.float32))
+    with jax.default_matmul_precision("highest"):
+        got = block_flash_backward(*ops, m, do, dl, causal, scale, *tiles, True)
+        want = _einsum_tile_rule(causal, scale, (*ops, m), (do, dl), 96, 64)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        if case == "earlier_hop":
+            assert not np.any(np.asarray(a)) and not np.any(np.asarray(b))
+        else:
+            assert _rel(a, b) < 1e-5
+
+
+@pytest.mark.parametrize("case", ["causal", "ragged", "narrow_values"])
+def test_block_flash_backward_kernel_in_bf16_is_the_einsum_tile_rule_within_rounding(
+        case):
+    """bf16 operands and the same float32 residuals and cotangents to both:
+    each rounds dô to bf16 before its products, P and dS once after their
+    float32 sums, and its results to bf16, so the two differ by a bf16
+    rounding or two (2⁻⁷) and by no more."""
+    ops, causal, scale, (_, m, _), rng = _bwd_operands(case, jnp.bfloat16, 2)
+    do = jnp.asarray(rng.standard_normal((3, ops[0].shape[1], ops[2].shape[-1]),
+                                         np.float32))
+    dl = jnp.asarray(rng.standard_normal(m.shape, np.float32))
+    got = block_flash_backward(*ops, m, do, dl, causal, scale, 128, 128, True)
+    want = _einsum_tile_rule(causal, scale, (*ops, m), (do, dl), 96, 64)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype == jnp.bfloat16
+        assert _rel(a, b) < 2 ** -7
+
+
+def test_block_flash_backward_rows_that_see_no_key_give_exactly_nothing():
+    """A hop whose first keys lie after the first queries (causal, k_off =
+    100): queries 0–99 see no key (m = _NEG_INF, l = 0), so their dq rows
+    are exactly zero whatever their cotangents, and they add nothing to dk
+    and dv; the 84 rows that pad 300 queries to whole tiles of 128 are cut
+    off.  Every other row has its gradient."""
+    rng = np.random.default_rng(3)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, t, 32), np.float32))
+               for t in (300, 256, 256))
+    offs = jnp.int32(0), jnp.int32(100)
+    _, m, _ = _reference_mlo(q, k, v, *offs, True, 0.2)
+    do = jnp.asarray(rng.standard_normal((2, 300, 32), np.float32))
+    dl = jnp.asarray(rng.standard_normal((2, 300), np.float32))
+    dq, dk, dv = block_flash_backward(q, k, v, *offs, m, do, dl, True, 0.2,
+                                      128, 128, True)
+    dq, dk, dv = (np.asarray(x) for x in (dq, dk, dv))
+    assert dq.shape == (2, 300, 32) and np.all(np.isfinite(dq))
+    assert not np.any(dq[:, :100]) and np.all(np.any(dq[:, 100:] != 0, -1))
+    # the same with those rows' cotangents zeroed: dk and dv do not move
+    do0, dl0 = do.at[:, :100].set(0.0), dl.at[:, :100].set(0.0)
+    _, dk0, dv0 = block_flash_backward(q, k, v, *offs, m, do0, dl0, True, 0.2,
+                                       128, 128, True)
+    np.testing.assert_array_equal(dk, np.asarray(dk0))
+    np.testing.assert_array_equal(dv, np.asarray(dv0))
+
+
 # --- latent attention on the projections' layout ------------------------------
 
 
@@ -307,7 +534,7 @@ def _parent_rule(heads, scale, res, do):
     """``latent_flash``'s backward as it was before it became a kernel (PR
     34): one sequence's heads-first q, k (the rotary key broadcast to every
     head and concatenated), v and dô ÷ l in float32 through
-    ``pallas_attention._block_flash_bwd``'s einsum tiles, ``dl = −Σ(dô·o) ÷
+    ``_einsum_tile_rule``, ``dl = −Σ(dô·o) ÷
     l``; the rotary key's gradient summed over the heads in float32."""
     s, nope = res[0].shape[1], res[0].shape[-1] // heads
     rope = res[3].shape[-1]
@@ -325,10 +552,9 @@ def _parent_rule(heads, scale, res, do):
         do = heads_first(do).astype(f32)
         inv_l = 1.0 / jnp.maximum(l, 1e-30)
         dl = -jnp.sum(do * heads_first(o).astype(f32), axis=-1) * inv_l
-        dq, dk, dv, _, _ = _block_flash_bwd(
-            True, scale, None, None, False,
-            (qh, kh, kv[..., nope:], zero, zero, None, m, None),
-            (do * inv_l[..., None], None, dl))
+        dq, dk, dv = _einsum_tile_rule(
+            True, scale, (qh, kh, kv[..., nope:], zero, zero, m),
+            (do * inv_l[..., None], dl))
         return (tokens_first(dq[..., :nope]), tokens_first(dq[..., nope:]),
                 tokens_first(jnp.concatenate([dk[..., :nope], dv], axis=-1)),
                 jnp.sum(dk[..., nope:].astype(f32), axis=0).astype(k_pe.dtype))

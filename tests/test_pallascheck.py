@@ -645,6 +645,28 @@ def test_latent_backward_contract_shape(registry_contract, dtype):
         [384, 256], [384, 128], [128, 512], [128, 64], [3, 2, 1, 128]]
 
 
+@pytest.mark.parametrize("name", ["float32", "causal:bfloat16"])
+def test_flash_backward_contract_shape(registry_contract, name):
+    """``block_flash``'s backward kernel: q, k, v and the cotangent
+    as FEATURE-MAJOR blocks the pipeline copies (64 rows by a tile of 128
+    tokens along the lanes: no operand padded to the lanes in HBM), m and dl
+    rows of a q tile; k tiles before q tiles in the grid; dq resident for the
+    whole sequence (three tiles of 128) over float32 scratch of the same
+    columns, dk and dv a k tile at a time over float32 scratch.  ``findings``
+    empty: every accumulator is written before it is read in the run of the
+    output it feeds."""
+    entry = registry_contract["kernels"][f"block_flash_backward:{name}"]
+    assert entry["dma_starts"] == 0 and entry["findings"] == {}
+    assert entry["grid"] == [2, 3, 3]
+    blocks = entry["blocks"]
+    assert [blocks[f"in{i}"] for i in range(4)] == [[1, 64, 128]] * 4
+    assert blocks["in4"] == blocks["in5"] == [1, 1, 128]
+    assert blocks["out0"] == [1, 64, 384]
+    assert blocks["out1"] == blocks["out2"] == [1, 64, 128]
+    assert [blocks[f"scratch{i}"] for i in range(3)] == [
+        [64, 384], [64, 128], [64, 128]]
+
+
 def test_pallas_contract_roundtrip(registry_contract):
     from mpi4dl_tpu.analysis.contracts.diff import diff_pallas_contract
 
