@@ -179,9 +179,11 @@ class Attention(Layer):
         return {n: layer.init(k, shapes.get(n, in_shape))[0]
                 for k, (n, layer) in zip(keys, parts.items())}, in_shape
 
-    def apply(self, params, x, ctx):
-        from mpi4dl_tpu.ops.ring import _resolve_flash, ring_attention
-
+    def project(self, params, x, ctx):
+        """``q [B, S, heads, head_dim]``, ``k`` and ``v [B, S, kv_heads,
+        head_dim]``: the projections, q's and k's per-head norms and rotary
+        embedding; a key-value head serves ``heads // kv_heads`` query heads
+        (the caller repeats them)."""
         parts = self._parts()
         b, s, _ = x.shape
 
@@ -197,7 +199,14 @@ class Attention(Layer):
         q, k = normed("q", self.heads), normed("k", self.kv_heads)
         if self.rope_theta is not None:
             q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
-        v = heads("v", self.kv_heads)
+        return q, k, heads("v", self.kv_heads)
+
+    def apply(self, params, x, ctx):
+        from mpi4dl_tpu.ops.ring import _resolve_flash, ring_attention
+
+        parts = self._parts()
+        b, s, _ = x.shape
+        q, k, v = self.project(params, x, ctx)
         rep = self.heads // self.kv_heads
         k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
         recorder().note_site(

@@ -89,18 +89,22 @@ CONV_PATHS = ("wfold", "hstripe", "phase", "xla", "dot")
 # SwiGLU of dense products, the one path); the state-space recurrence of a
 # Mamba-2 mixer (models/granitemoehybrid.py: ``ops/ssd.ssd_chunked``, XLA's
 # products over chunks, the one path) and a head that multiplies by the
-# embedding's table (models/lfm2.head_cell, ``tied``); and on which form of the activation a
+# embedding's table (models/lfm2.head_cell, ``tied``); the sparse attention
+# of models/keye_vl2.py (``sparse_*``: its Pallas kernels or the einsum form
+# over a dense mask) and its indexer (ops/sparse_indexer.py: the Pallas
+# kernels or XLA's products); and on which form of the activation a
 # BatchNorm took its sums and applied its affine: ``[N, H, W/p, p·C]`` inside
 # a folded run (``layers.run_fold``), or ``[N, H, W, C]``.
 SITE_PATHS = {
     "conv": CONV_PATHS,
     "norm": ("folded", "plain"),
     "attention": ("block_flash", "einsum", "latent_block_flash",
-                  "latent_einsum"),
+                  "latent_einsum", "sparse_block_flash", "sparse_einsum"),
     "experts": ("ragged_dot",),
     "shared_expert": ("swiglu",),
     "ssm_scan": ("chunked",),
     "tied_head": ("table_transposed",),
+    "sparse_indexer": ("pallas", "xla"),
 }
 
 # At least 4,000 steps of the loop's spans (nine a step with the loader's).
@@ -406,7 +410,8 @@ class Recorder:
                           ("expert_paths", "experts"),
                           ("shared_expert_paths", "shared_expert"),
                           ("ssm_scan_paths", "ssm_scan"),
-                          ("tied_head_paths", "tied_head")):
+                          ("tied_head_paths", "tied_head"),
+                          ("sparse_indexer_paths", "sparse_indexer")):
             paths = self.site_paths(kind)
             if paths:
                 out[key] = paths

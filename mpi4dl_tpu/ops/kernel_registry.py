@@ -30,9 +30,12 @@ from typing import Callable, Optional, Sequence, Tuple
 # Imports below double as rule-12 registration: a module whose kernels are
 # verified must be imported here (statically parsed, never executed by the
 # analyzer).
-from mpi4dl_tpu.ops.pallas_attention import block_flash, block_flash_backward
+from mpi4dl_tpu.ops.pallas_attention import (
+    block_flash, block_flash_backward, sparse_flash_backward,
+    sparse_flash_forward)
 from mpi4dl_tpu.ops.pallas_latent_attention import (
     latent_flash, latent_flash_backward)
+from mpi4dl_tpu.ops.sparse_indexer import indexer_backward, indexer_select
 
 
 @dataclasses.dataclass(frozen=True)
@@ -135,10 +138,67 @@ def _latent_backward_case(dtype: str):
     return KernelCase(name=f"latent_flash_backward:causal:{dtype}", build=build)
 
 
+_SPARSE_TOKENS = 300  # three tiles of 128, a zero-padded tail in the last
+
+
+def _sparse_flash_case(backward: bool):
+    def build():
+        import jax.numpy as jnp
+
+        # Grid (2·3, 3, 3): three heads a sequence share its selection's
+        # words (128 a query: at 300 tokens a forward tile of 128 keys is
+        # one bit; the backward's own three tiles of 768 over 2,300 tokens,
+        # a zero-padded tail in the last, are six).
+        t = 2300 if backward else _SPARSE_TOKENS
+        q = jnp.zeros((6, t, 64), jnp.bfloat16)
+        words = jnp.zeros((2, t, 128), jnp.int32)
+        if not backward:
+            fn = lambda q, k, v, words: sparse_flash_forward(  # noqa: E731
+                q, k, v, words, heads=3, scale=0.125, tq=128, tk=128)
+            return fn, (q, q, q, words)
+        stat = jnp.zeros((6, t), jnp.float32)
+        fn = lambda q, k, v, m, do, dl, w: sparse_flash_backward(  # noqa: E731
+            q, k, v, m, do, dl, w, heads=3, scale=0.125)
+        return fn, (q, q, q, stat, q, stat, jnp.swapaxes(words, 1, 2))
+
+    name = "sparse_flash_backward" if backward else "sparse_flash_forward"
+    return KernelCase(name=f"{name}:causal:bfloat16", build=build)
+
+
+def _indexer_case(backward: bool):
+    def build():
+        import jax.numpy as jnp
+
+        # One sequence of 300 tokens: the selection's grid (1, 3) over
+        # blocks of 128 queries, the gradient's (1, 2, 32) over q tiles of
+        # 256 and k tiles of the selection's width (128 words: 4,096 keys,
+        # the last 29 tiles past the sequence); an indexer of 4 heads of 8.
+        t = _SPARSE_TOKENS
+        iq = jnp.zeros((1, t, 4, 8), jnp.bfloat16)
+        ik = jnp.zeros((1, t, 8), jnp.bfloat16)
+        w = jnp.zeros((1, t, 4), jnp.float32)
+        if not backward:
+            fn = lambda iq, ik, w: indexer_select(iq, ik, w, 16)  # noqa: E731
+            return fn, (iq, ik, w)
+        q, k = jnp.zeros((1, t, 4, 16), jnp.bfloat16), jnp.zeros(
+            (1, t, 2, 16), jnp.bfloat16)
+        c = jnp.zeros((1, 4, t), jnp.float32)
+        words_t = jnp.zeros((1, 128, t), jnp.int32)
+        lse = jnp.zeros((1, t), jnp.float32)
+        fn = lambda *a: indexer_backward(  # noqa: E731
+            *a, scale=0.25, inv_n=1.0 / t)
+        return fn, (q, k, c, iq, ik, w, words_t, lse)
+
+    name = "indexer_backward" if backward else "indexer_select"
+    return KernelCase(name=f"{name}:bfloat16", build=build)
+
+
 # The raw (fp32) path and the bf16 compute path the mixed-precision/quant
 # engines dispatch (quant/kernels.py itself is pure jnp — no pallas_call,
 # which rule 12 verifies stays true), forward and backward; latent
-# attention's forward and backward kernels (always causal) in both.
+# attention's forward and backward kernels (always causal) in both; the
+# sparse attention's kernels under a key selection and its indexer's two
+# (the bf16 compute path, the only one they run on).
 REGISTRY: Tuple[KernelCase, ...] = (
     _flash_case("float32", causal=False),
     _flash_case("bfloat16", causal=True),
@@ -148,6 +208,10 @@ REGISTRY: Tuple[KernelCase, ...] = (
     _latent_case("bfloat16"),
     _latent_backward_case("float32"),
     _latent_backward_case("bfloat16"),
+    _sparse_flash_case(backward=False),
+    _sparse_flash_case(backward=True),
+    _indexer_case(backward=False),
+    _indexer_case(backward=True),
 )
 
 

@@ -59,16 +59,27 @@ def round_capacity(assignments: int, held: int, total: int) -> int:
 
 
 def route(x, kernel, bias, top_k: int, scaling: float = 1.0,
-          sum_eps: float = 1e-6):
+          sum_eps: float = 1e-6, scoring: str = "sigmoid"):
     """The router, in float32: ``s = sigmoid(x W_r)``; a token's experts are
     the ``top_k`` of ``s + bias`` (the bias enters the choice only and takes
     no gradient); their weights are the chosen ``s`` over their sum +
     ``sum_eps``, times ``scaling``.  ``sum_eps`` is the model's own constant
     (``lfm2_moe`` publishes 1e-6, ``deepseek_v3`` 1e-20: beside a sum of
     ``top_k`` sigmoids the two differ in the weights' seventh digit).
+    ``scoring="softmax"`` (Qwen3-MoE's router): ``s = softmax(x W_r)`` over
+    all the experts, the ``top_k`` of ``s`` with no bias (``bias`` None),
+    their weights the chosen ``s`` over their sum, times ``scaling``.
     Returns ``(experts [N, k] int32, weights [N, k])``."""
     logits = jnp.dot(x.astype(jnp.float32), kernel.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, chosen = lax.top_k(scores, top_k)
+        w = jnp.take_along_axis(scores, chosen, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + sum_eps) * scaling
+        return chosen, w
+    if scoring != "sigmoid":
+        raise ValueError(f"route: no scoring {scoring!r}")
     scores = jax.nn.sigmoid(logits)
     _, chosen = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)),
                           top_k)
@@ -136,11 +147,12 @@ def _grouped_dot(lhs, rhs, sizes):
 
 
 def routed_experts(x, router, experts, *, first: int, held: int, total: int,
-                   top_k: int, scaling: float = 1.0, sum_eps: float = 1e-6
-                   ) -> Tuple[jax.Array, jax.Array]:
+                   top_k: int, scaling: float = 1.0, sum_eps: float = 1e-6,
+                   scoring: str = "sigmoid") -> Tuple[jax.Array, jax.Array]:
     """``x [N, D]`` through the held experts' part of the layer.
 
-    ``router``: ``{"kernel" [D, total], "bias" [total]}``; ``experts``:
+    ``router``: ``{"kernel" [D, total], "bias" [total]}`` (no ``bias`` under
+    ``scoring="softmax"``, see :func:`route`); ``experts``:
     ``{"w1", "w3" [held, D, F], "w2" [held, F, D]}``.  Returns the partial
     sum ``[N, D]`` in ``x``'s dtype and, for each held expert, the share of
     all ``N * top_k`` assignments that fell on it (float32, ``[held]``)."""
@@ -149,8 +161,8 @@ def routed_experts(x, router, experts, *, first: int, held: int, total: int,
     capacity = round_capacity(a, held, total)
     rounds = math.ceil(a / capacity)
     with scope("expert_route"):
-        chosen, weights = route(x, router["kernel"], router["bias"], top_k,
-                                scaling, sum_eps)
+        chosen, weights = route(x, router["kernel"], router.get("bias"),
+                                top_k, scaling, sum_eps, scoring)
 
         # Counting sort of the assignments by held expert; group ``held``
         # takes those of absent experts, behind all the others.
@@ -218,7 +230,8 @@ class RoutedExperts(Layer):
     ``held`` experts from ``first`` as SwiGLUs of width ``ffn``.
 
     Parameters: ``router.kernel`` (trained), ``router.bias`` (enters the
-    choice only, no gradient, zero unless a balancing rule writes it),
+    choice only, no gradient, zero unless a balancing rule writes it; none
+    under ``scoring="softmax"``, whose router has no bias),
     ``experts.w1/w3/w2``, and ``load``: the share of the assignments that
     fell on each held expert in the last step, a running statistic written
     through ``ctx.bn_sink`` as BatchNorm's are (what a balancing rule for the
@@ -232,6 +245,7 @@ class RoutedExperts(Layer):
     first: int = 0
     scaling: float = 1.0
     sum_eps: float = 1e-6  # beside the chosen scores' sum (see route)
+    scoring: str = "sigmoid"  # or "softmax" (see route)
 
     def __post_init__(self):
         if not (0 <= self.first and self.first + self.held <= self.total
@@ -243,9 +257,11 @@ class RoutedExperts(Layer):
         d, f = self.features, self.ffn
         assert in_shape[-1] == d, (in_shape, d)
         kr, k1, k3, k2 = jax.random.split(key, 4)
+        router = {"kernel": _uniform(kr, (d, self.total), d ** -0.5)}
+        if self.scoring == "sigmoid":
+            router["bias"] = jnp.zeros((self.total,), jnp.float32)
         params = {
-            "router": {"kernel": _uniform(kr, (d, self.total), d ** -0.5),
-                       "bias": jnp.zeros((self.total,), jnp.float32)},
+            "router": router,
             "experts": {
                 "w1": _uniform(k1, (self.held, d, f), d ** -0.5),
                 "w3": _uniform(k3, (self.held, d, f), d ** -0.5),
@@ -261,7 +277,8 @@ class RoutedExperts(Layer):
         y, load = routed_experts(
             x.reshape(b * s, d), params["router"], params["experts"],
             first=self.first, held=self.held, total=self.total,
-            top_k=self.top_k, scaling=self.scaling, sum_eps=self.sum_eps)
+            top_k=self.top_k, scaling=self.scaling, sum_eps=self.sum_eps,
+            scoring=self.scoring)
         if ctx.bn_sink is not None:
             ctx.bn_sink[id(params["load"])] = load
         return y.reshape(b, s, d)

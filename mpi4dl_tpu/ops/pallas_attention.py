@@ -56,6 +56,12 @@ from mpi4dl_tpu.obs.scopes import scope
 _NEG_INF = -1e30  # large-negative instead of -inf: exp() of it is exactly 0
                   # and max() never produces nan from (-inf) - (-inf).
 _LANES = 128
+# Tiles of the forward kernel (measured: beside block_flash_backward below).
+LOCAL_TILES = (1024, 1024)  # query, key rows of a tile, flash_attention_local
+# Under a key selection the forward also holds the words of its queries and
+# unpacks a tile's bits: at heads of 128 (1024, 1024) runs out of the
+# compiler's VMEM budget for a v5e, (512, 1024) fits it (PERF.md).
+LOCAL_TILES_SPARSE = (512, 1024)
 
 
 def _round_up(n: int, m: int) -> int:
@@ -77,8 +83,7 @@ def _out_structs(operands, shapes_dtypes):
         return [jax.ShapeDtypeStruct(s, d) for s, d in shapes_dtypes]
 
 
-def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-            acc, m_scr, l_scr, *, tq, tk, nk, causal, t_k_real, scale):
+def _kernel(*refs, tq, tk, nk, causal, t_k_real, scale, width=None):
     """One (bh, q-tile, k-tile) step.  Scratch (acc, m, l) persists across
     the innermost k dimension; outputs are written at the last k tile.
     ``t_k_real``: un-padded key count (static) — key slots past it are
@@ -88,7 +93,18 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     the MXU as bf16; float32 ones as float32) and accumulate in float32;
     the scale is applied to the float32 scores.  Under ``causal`` a tile
     whose every key lies after its last query is skipped: it would add
-    exactly nothing (the guard below), so only the causal half is computed."""
+    exactly nothing (the guard below), so only the causal half is computed.
+
+    ``width`` (static) adds a key selection: the refs then hold the
+    selection's words of the tile's queries (:func:`selection_width`, that
+    many a query) after the values, and a key outside a query's selection is
+    masked."""
+    if width is None:
+        (offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+         acc, m_scr, l_scr) = refs
+    else:
+        (offs_ref, q_ref, k_ref, v_ref, words_ref, o_ref, m_ref, l_ref,
+         acc, m_scr, l_scr) = refs
     ki = pl.program_id(2)
     qi = pl.program_id(1)
 
@@ -115,6 +131,8 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                 jnp.int32, (tq, tk), 0
             )
             s = jnp.where(q_pos >= offs_ref[1] + col, s, _NEG_INF)
+        if width is not None:
+            s = jnp.where(_plane(words_ref[0], ki, tk, width, 1), s, _NEG_INF)
 
         m_prev = m_scr[:, 0]                        # [TQ]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -147,6 +165,25 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         o_ref[0] = acc[:].astype(o_ref.dtype)
         m_ref[0] = m_scr[...].astype(m_ref.dtype)
         l_ref[0] = l_scr[...].astype(l_ref.dtype)
+
+
+def selection_width(t_k: int) -> int:
+    """Words a query's row of a key selection takes: key ``s`` is bit
+    ``s // W`` of word ``s % W``, so that the keys of a bit are ``W``
+    consecutive ones, whole lanes (``W`` a multiple of 128)."""
+    return _round_up(max(1, -(-t_k // 32)), _LANES)
+
+
+def _plane(words, ki, tk, width, axis):
+    """The selection of a tile of ``tk`` keys from the words of its queries
+    (``axis`` 1: ``[TQ, W]`` to ``[TQ, TK]``; ``axis`` 0, transposed: ``[W,
+    TQ]`` to ``[TK, TQ]``): the ``tk // width`` bits of the tile, each over
+    the whole words, side by side."""
+    per = tk // width
+    planes = [jnp.bitwise_and(lax.shift_right_logical(
+        words, jnp.full(words.shape, ki * per + i, jnp.int32)), 1) != 0
+        for i in range(per)]
+    return planes[0] if per == 1 else jnp.concatenate(planes, axis=axis)
 
 
 def _any_vma(*arrays) -> bool:
@@ -239,6 +276,68 @@ def _reference_mlo(q, k, v, q_off, k_off, causal, scale):
     return o, m, l
 
 
+def sparse_flash_forward(q, k, v, words, *, heads, scale,
+                         tq=LOCAL_TILES_SPARSE[0], tk=LOCAL_TILES_SPARSE[1],
+                         interpret=False):
+    """The block state ``(o_hat, m, l)`` of causal attention in which query
+    ``t`` of a sequence sees only the keys its row of ``words`` [B, Tq, W]
+    selects (:func:`selection_width`), all ``heads`` of the sequence alike:
+    ``q`` [B·heads, Tq, D], ``k`` [B·heads, Tk, D], ``v`` [B·heads, Tk, Dv].
+    Positions are the sequence's own (no ring offsets).  The kernel is
+    :func:`_kernel` under the selection, ``sparse_flash_fwd``.  Its backward
+    is :func:`sparse_flash_backward`."""
+    bh, t_q, d = q.shape
+    _, t_k, _ = k.shape
+    dv = v.shape[-1]
+    width = words.shape[-1]
+    # whole lanes of keys, a multiple of the selection's width
+    tq = min(tq, _round_up(t_q, 8))
+    tk = _round_up(min(tk, _round_up(t_k, 128)), width)
+    tq_p, tk_p = _round_up(t_q, tq), _round_up(t_k, tk)
+    assert tk_p <= 32 * width, (t_k, width)
+    d_p, dv_p = _round_up(d, _LANES), _round_up(dv, _LANES)
+    qp = jnp.pad(q, ((0, 0), (0, tq_p - t_q), (0, d_p - d)))
+    kp = jnp.pad(k, ((0, 0), (0, tk_p - t_k), (0, d_p - d)))
+    vp = jnp.pad(v, ((0, 0), (0, tk_p - t_k), (0, dv_p - dv)))
+    wp = jnp.pad(words, ((0, 0), (0, tq_p - words.shape[1]), (0, 0)))
+    nq, nk = tq_p // tq, tk_p // tk
+    offs = jnp.zeros((2,), jnp.int32)
+    kern = pl.pallas_call(
+        functools.partial(_kernel, tq=tq, tk=tk, nk=nk, causal=True,
+                          t_k_real=t_k, scale=scale, width=width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, tq, d_p), lambda b, i, j, *_: (b, i, 0)),
+                pl.BlockSpec((1, tk, d_p), lambda b, i, j, *_: (b, j, 0)),
+                pl.BlockSpec((1, tk, dv_p), lambda b, i, j, *_: (b, j, 0)),
+                pl.BlockSpec((1, tq, width),
+                             lambda b, i, j, *_: (b // heads, i, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, tq, dv_p), lambda b, i, j, *_: (b, i, 0)),
+                pl.BlockSpec((1, tq, _LANES), lambda b, i, j, *_: (b, i, 0)),
+                pl.BlockSpec((1, tq, _LANES), lambda b, i, j, *_: (b, i, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((tq, dv_p), jnp.float32),
+                pltpu.VMEM((tq, _LANES), jnp.float32),
+                pltpu.VMEM((tq, _LANES), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, tq_p, dv_p), jnp.float32),
+            jax.ShapeDtypeStruct((bh, tq_p, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((bh, tq_p, _LANES), jnp.float32),
+        ],
+        interpret=interpret,
+        name="sparse_flash_fwd",
+    )
+    o, m, l = kern(offs, qp, kp, vp, wp)
+    return o[:, :t_q, :dv], m[:, :t_q, 0], l[:, :t_q, 0]
+
+
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9)
 )
@@ -272,7 +371,6 @@ def _block_flash_fwd(q, k, v, q_off, k_off, causal, scale, tq, tk, interpret):
 # (512, 1024), 8.39 at (1024, 512), 9.13 at (512, 512), 12.44 at (256, 512),
 # 7.95 at (2048, 1024), 8.00 at (1024, 2048) and 7.83 at (2048, 2048), which
 # needs a VMEM limit named (below); the einsum tiles it replaced took 20.86.
-LOCAL_TILES = (1024, 1024)  # query, key rows of a tile, flash_attention_local
 _BWD_TQ, _BWD_TK = 1024, 1024  # the most query, key rows of a backward tile
 # The compiler's own VMEM budget for a kernel on a v5e.  The backward kernel
 # asks for more only where it needs more: a kernel that names any limit
@@ -311,9 +409,7 @@ def _bwd_vmem_bytes(tq, tk, tq_p, d, dv, itemsize):
     return resident + blocks + 10 * tq * tk
 
 
-def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, m_ref, dl_ref,
-                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                tq, tk, nq, nk, causal, t_k_real, scale):
+def _bwd_kernel(*refs, tq, tk, nq, nk, causal, t_k_real, scale, width=None):
     """One (bh, k-tile, q-tile) step of the backward on FEATURE-MAJOR blocks
     (``q_ref`` [1, D, TQ], ``k_ref`` [1, D, TK], …: tokens along the lanes),
     the scores transposed (keys on the sublanes, queries along the lanes,
@@ -322,7 +418,15 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, m_ref, dl_ref,
     ``dq_acc`` holds the whole sequence of the bh index across both: a q
     tile's columns are zeroed at the first k tile and written after the last
     one its queries see.  Positions are GLOBAL (``offs_ref``: the q and k
-    offsets), as in the forward kernel."""
+    offsets), as in the forward kernel.  ``width`` as in :func:`_kernel`,
+    the words transposed (``[1, W, TQ]``: a tile's selection comes out as
+    its scores do, keys on the sublanes)."""
+    if width is None:
+        (offs_ref, q_ref, k_ref, v_ref, do_ref, m_ref, dl_ref,
+         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
+    else:
+        (offs_ref, q_ref, k_ref, v_ref, do_ref, m_ref, dl_ref, words_ref,
+         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
     ki, qi = pl.program_id(1), pl.program_id(2)
     cols = pl.ds(pl.multiple_of(qi * tq, tq), tq)
     q0 = offs_ref[0] + qi * tq          # the tile's first query
@@ -349,6 +453,8 @@ def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, m_ref, dl_ref,
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         s = product(k, q, (0, 0)) * scale               # [TK, TQ]
         p = jnp.exp(s - m_ref[0])                       # m: [1, TQ]
+        if width is not None:
+            p = jnp.where(_plane(words_ref[0], ki, tk, width, 0), p, 0.0)
         if masked:
             key = ki * tk + lax.broadcasted_iota(jnp.int32, (tk, tq), 0)
             mask = key < t_k_real
@@ -499,6 +605,83 @@ def block_flash_backward(q, k, v, q_off, k_off, m, do, dl, causal, scale,
     )(offs, q, k, v, do, m, dl)
     return (jnp.swapaxes(dq[..., :t_q], 1, 2), jnp.swapaxes(dk[..., :t_k], 1, 2),
             jnp.swapaxes(dv_[..., :t_k], 1, 2))
+
+
+@functools.partial(_traced_once, static_argnums=(7, 8, 9, 10, 11))
+def _sparse_bwd(q, k, v, m, do, dl, words_t, heads, scale, tq, tk, interpret):
+    """:func:`block_flash_backward`'s kernel under a key selection
+    (:func:`sparse_flash_backward`), ``sparse_flash_bwd``."""
+    bh, t_q, d = q.shape
+    t_k, dv = k.shape[1], v.shape[-1]
+    width = words_t.shape[1]
+    assert tk % width == 0, (tk, width)
+    f32 = jnp.float32
+    tq_p, tk_p = _round_up(t_q, tq), _round_up(t_k, tk)
+    assert tk_p <= 32 * width, (t_k, width)
+    tokens_last = lambda x, t_p: jnp.pad(
+        jnp.swapaxes(x, 1, 2), ((0, 0), (0, 0), (0, t_p - x.shape[1])))
+    q, do = tokens_last(q, tq_p), tokens_last(do.astype(q.dtype), tq_p)
+    k, v = tokens_last(k, tk_p), tokens_last(v, tk_p)
+    m, dl = (jnp.pad(x.astype(f32), ((0, 0), (0, tq_p - t_q)))[:, None, :]
+             for x in (m, dl))
+    words_t = jnp.pad(words_t, ((0, 0), (0, 0), (0, tq_p - words_t.shape[2])))
+    nq, nk = tq_p // tq, tk_p // tk
+    offs = jnp.zeros((2,), jnp.int32)
+    vmem = (_bwd_vmem_bytes(tq, tk, tq_p, d, dv, q.dtype.itemsize)
+            + 2 * 4 * width * tq + 4 * tk * tq)
+
+    def tile_of_q(j, i):
+        first = lax.div(j * tk, tq)  # causal: the first q tile that sees j
+        return jnp.maximum(i, jnp.minimum(first, nq - 1))
+
+    of_q = lambda w: pl.BlockSpec(
+        (1, w, tq), lambda b, j, i, *_: (b, 0, tile_of_q(j, i)))
+    of_k = lambda w: pl.BlockSpec((1, w, tk), lambda b, j, i, *_: (b, 0, j))
+    dq, dk, dv_ = pl.pallas_call(
+        functools.partial(_bwd_kernel, tq=tq, tk=tk, nq=nq, nk=nk,
+                          causal=True, t_k_real=t_k, scale=scale,
+                          width=width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nk, nq),
+            in_specs=[of_q(d), of_k(d), of_k(dv), of_q(dv), of_q(1), of_q(1),
+                      pl.BlockSpec((1, width, tq), lambda b, j, i, *_: (
+                          b // heads, 0, tile_of_q(j, i)))],
+            out_specs=[
+                pl.BlockSpec((1, d, tq_p), lambda b, j, i, *_: (b, 0, 0),
+                             pipeline_mode=pl.Buffered(1)),
+                of_k(d), of_k(dv),
+            ],
+            scratch_shapes=[pltpu.VMEM((d, tq_p), f32),
+                            pltpu.VMEM((d, tk), f32),
+                            pltpu.VMEM((dv, tk), f32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((bh, d, tq_p), q.dtype),
+                   jax.ShapeDtypeStruct((bh, d, tk_p), k.dtype),
+                   jax.ShapeDtypeStruct((bh, dv, tk_p), v.dtype)],
+        compiler_params=(
+            None if vmem <= _DEFAULT_VMEM
+            else pltpu.CompilerParams(vmem_limit_bytes=vmem + 2 ** 21)),
+        interpret=interpret,
+        name="sparse_flash_bwd",
+    )(offs, q, k, v, do, m, dl, words_t)
+    return (jnp.swapaxes(dq[..., :t_q], 1, 2), jnp.swapaxes(dk[..., :t_k], 1, 2),
+            jnp.swapaxes(dv_[..., :t_k], 1, 2))
+
+
+def sparse_flash_backward(q, k, v, m, do, dl, words_t, *, heads, scale,
+                          interpret=False):
+    """:func:`sparse_flash_forward`'s backward, ``(dq, dk, dv)`` from the row
+    maxima ``m`` and the cotangents ``do`` of ``o_hat`` and ``dl`` of ``l``:
+    :func:`block_flash_backward`'s kernel (its docstring has the
+    mathematics), each tile's scores masked by the selection, on the words
+    transposed (``words_t`` [B, W, Tq]: a tile's selection comes out keys on
+    the sublanes, as its scores do), at tiles of its own that are whole
+    multiples of the selection's width."""
+    return _sparse_bwd(q, k, v, m, do, dl, words_t, heads, scale,
+                       _bwd_tile(q.shape[1], _BWD_TQ),
+                       _round_up(_bwd_tile(k.shape[1], _BWD_TK),
+                                 words_t.shape[1]), interpret)
 
 
 def _block_flash_bwd(causal, scale, tq, tk, interpret, res, cts):

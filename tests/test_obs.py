@@ -117,10 +117,12 @@ def _token_step(name):
     """A small train step of a token model at toy widths (the tests'
     configurations), lowered: LFM2 (routed experts, attention), granite (the
     Mamba-2 mixer and its scan), Kanana-2 (the shared expert beside the routed
-    ones), and ``block_flash`` in interpret mode, forward and backward under
+    ones), Keye-VL-2.0 (the sparse attention and its indexer), and
+    ``block_flash`` in interpret mode, forward and backward under
     ``jax.checkpoint``, for the scope round the kernel and in its rule."""
     import test_deepseek_v3
     import test_granitemoehybrid
+    import test_keye_vl2
     import test_lfm2
 
     if name == "block_flash":
@@ -134,9 +136,10 @@ def _token_step(name):
         qkv = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.float32)
         return jax.jit(jax.grad(loss, (0, 1, 2))).lower(qkv, qkv, qkv)
     module = {"lfm2": test_lfm2, "granite": test_granitemoehybrid,
-              "deepseek_v3": test_deepseek_v3}[name]
+              "deepseek_v3": test_deepseek_v3, "keye_vl2": test_keye_vl2}[name]
     model, params, _ = module._model()
     x, y = (test_granitemoehybrid._ids() if name == "granite"
+            else test_lfm2._batch(seq=test_keye_vl2.SEQ) if name == "keye_vl2"
             else test_lfm2._batch())
     opt = Optimizer("sgd", lr=0.1)
     step = make_train_step(model, opt, compute_dtype=jnp.bfloat16, remat=True)
@@ -160,6 +163,8 @@ def no_persistent_cache():
     ("lfm2", ("expert_route", "expert_dispatch")),
     ("granite", ("ssm_mixer", "ssm_scan")),
     ("deepseek_v3", ("expert_route", "expert_dispatch", "shared_expert")),
+    ("keye_vl2", ("sparse_indexer", "attention_core", "expert_route",
+                  "expert_dispatch")),
     ("block_flash", ("attention_core",)),
 ])
 def test_scopes_are_metadata_only(monkeypatch, no_persistent_cache, name,

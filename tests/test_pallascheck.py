@@ -862,3 +862,32 @@ def test_rule12_benchmark_pragma_allowlists(tmp_path):
 def test_rule12_tests_are_exempt(tmp_path):
     vs = _scan(tmp_path, _NEW_KERNEL, "tests/test_fixture_kernels.py")
     assert vs == []
+
+
+@pytest.mark.parametrize("name, grid", [
+    ("sparse_flash_forward:causal:bfloat16", [6, 3, 3]),
+    ("sparse_flash_backward:causal:bfloat16", [6, 3, 3]),
+    ("indexer_select:bfloat16", [1, 3]),
+    ("indexer_backward:bfloat16", [1, 2, 32]),
+])
+def test_sparse_attention_contract_shape(registry_contract, name, grid):
+    """The sparse attention's kernels (PR 40): the forward and backward of
+    ``block_flash`` under a key selection take the selection's words as one
+    more blocked operand (a q tile's rows of 128 words; transposed for the
+    backward) beside the forward's or backward's own blocks; the indexer's
+    selection holds a block's keys as int32 images in scratch for the whole
+    padded key range (32 bits of 128 words: 4,096), and its gradient's key
+    accumulator is resident for the sequence.  No DMA of their own, and
+    ``findings`` empty."""
+    entry = registry_contract["kernels"][name]
+    assert entry["dma_starts"] == 0 and entry["findings"] == {}
+    assert entry["grid"] == grid
+    blocks = entry["blocks"]
+    if name.startswith("sparse_flash_forward"):
+        assert blocks["in3"] == [1, 128, 128]
+    elif name.startswith("sparse_flash_backward"):
+        assert blocks["in6"] == [1, 128, 768] and blocks["out0"] == [1, 64, 2304]
+    elif name.startswith("indexer_select"):
+        assert blocks["scratch0"] == [4096, 128] and blocks["out0"] == [1, 128, 128]
+    else:
+        assert blocks["out2"] == [1, 8, 4096] and blocks["scratch2"] == [8, 4096]

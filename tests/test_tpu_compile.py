@@ -642,10 +642,10 @@ def test_granitemoehybrid_layers_compile_for_v5e_under_the_names_the_metrics_pic
 
 
 @functools.lru_cache(maxsize=None)
-def _compiled_layer(model, layer, batch, one_chip, part="block"):
+def _compiled_layer(model, layer, batch, one_chip, part="block", seq=8192):
     """One layer of a token model at its published widths (``lfm2``,
-    ``deepseek_v3``: experts held as in the cells, 8 of 64 and 16 of 128;
-    ``granitemoehybrid``), ``batch`` sequences of 8,192 tokens in bf16 with
+    ``deepseek_v3``, ``keye_vl2``: experts held as in the cells, 8 of 64 and
+    16 of 128; ``granitemoehybrid``), ``batch`` sequences of ``seq`` tokens in bf16 with
     float32 parameters, forward under ``jax.checkpoint`` (per-cell remat, as
     the step runs it) and backward with the loss returned, under a
     ``"highest"`` default and the Pallas path asked for as on a TPU backend;
@@ -656,17 +656,18 @@ def _compiled_layer(model, layer, batch, one_chip, part="block"):
 
     import mpi4dl_tpu.config as config
     from mpi4dl_tpu.layer_ctx import ApplyCtx
-    from mpi4dl_tpu.models import deepseek_v3, granitemoehybrid, lfm2
+    from mpi4dl_tpu.models import deepseek_v3, granitemoehybrid, keye_vl2, lfm2
     from perfbench import optable
 
     cell = {"lfm2": lambda: lfm2._block(lfm2.PUBLISHED, layer, 8, 0),
             "deepseek_v3": lambda: deepseek_v3._block(
                 deepseek_v3.PUBLISHED, layer, 16, 0),
+            "keye_vl2": lambda: keye_vl2._block(keye_vl2.PUBLISHED, layer, 16, 0),
             "granitemoehybrid": lambda: granitemoehybrid._block(
                 granitemoehybrid.PUBLISHED, layer)}[model]()
     if part == "ffn":
         cell = cell.ffn
-    shape = (batch, 8192, 2048)  # every published hidden size
+    shape = (batch, seq, 2048)  # every published hidden size
 
     def struct(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -823,3 +824,34 @@ def test_granitemoehybrid_mixer_and_attention_carry_their_scopes_for_v5e(
     assert any("8512" in d["key"] and d["cls"] == "product" for d in mixer)
     _assert_attention_core_is_the_kernel(
         _compiled_layer("granitemoehybrid", 5, 2, one_chip))
+
+
+def test_keye_vl2_layer_compiles_for_v5e_with_its_kernels_in_their_scopes(
+        one_chip, no_persistent_cache):
+    """Keye-VL-2.0's layer (sparse attention, then 16 of 128 softmax-routed
+    experts) as its cell runs it, one sequence of 16,384 tokens: the four
+    Mosaic kernels compile for the chip, each in the scope its metric reads
+    (``sparse_flash_fwd`` forward and recomputed and ``sparse_flash_bwd`` in
+    ``attention_core``; ``sparse_indexer_select`` forward and recomputed and
+    ``sparse_indexer_bwd`` in ``sparse_indexer``), no product of the
+    projections in ``attention_core``, the indexer's own projections in
+    ``sparse_indexer``, and the routed layer's scopes as in LFM2's."""
+    said = _compiled_layer("keye_vl2", 0, 1, one_chip, seq=16384)
+    kernels = [(r["name"].split(".")[0], d) for r, d in said
+               if d["cls"] == "kernel" and not r["name"].startswith("ragged-dot")]
+    assert sorted((n, d["pass"]) for n, d in kernels) == [
+        ("sparse_flash_bwd", "backward"), ("sparse_flash_fwd", "forward"),
+        ("sparse_flash_fwd", "recompute"), ("sparse_indexer_bwd", "backward"),
+        ("sparse_indexer_select", "forward"),
+        ("sparse_indexer_select", "recompute")], [(n, d["pass"]) for n, d in kernels]
+    for name, d in kernels:
+        want = "attention_core" if name.startswith("sparse_flash") else "sparse_indexer"
+        assert want in d["scopes"], (name, d["scopes"])
+        assert {"attention_core", "sparse_indexer"} - {want} - set(d["scopes"])
+    assert not [d["key"] for _, d in said
+                if d["cls"] == "product" and "attention_core" in d["scopes"]]
+    # W_q^I [2048, 1024] forward and its weight gradient carry the indexer's scope
+    indexer_products = [d["key"] for _, d in said if d["cls"] == "product"
+                        and "sparse_indexer" in d["scopes"]]
+    assert any("1024" in k for k in indexer_products), indexer_products
+    _assert_the_routed_layer_carries_route_and_dispatch(said)
