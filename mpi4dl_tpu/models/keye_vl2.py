@@ -243,17 +243,17 @@ def sparse_attention(q, k, v, iq, ik, w, topk: int, scale: float,
 
 def _sparse_fwd(q, k, v, iq, ik, w, topk, scale, flash):
     b, t, h, _ = q.shape
-    rep = h // k.shape[2]
     with scope("sparse_indexer"):
         select = (sparse_indexer.indexer_select if flash
                   else sparse_indexer.select_dense)
         words_t, lse = select(iq, ik, w, topk)
     words = jnp.swapaxes(words_t, 1, 2)
     if flash:
-        kf, vf = (_fold(jnp.repeat(x, rep, axis=2)) for x in (k, v))
+        # the key-value heads as they are: a grid step of the kernel reads
+        # one k and one v block for the query heads of their group
         with scope("attention_core"):
             o_hat, m, l = pallas_attention.sparse_flash_forward(
-                _fold(q), kf, vf, words, heads=h, scale=scale)
+                _fold(q), _fold(k), _fold(v), words, heads=h, scale=scale)
         o = o_hat / jnp.maximum(l, 1e-30)[..., None]
         out = o.reshape(b, h, t, -1).transpose(0, 2, 1, 3).astype(q.dtype)
         rows = (m, l, o)
@@ -337,6 +337,9 @@ class SparseAttention(Layer):
         rec.note_site("sparse_indexer", self.indexer,
                       "pallas" if flash else "xla")
         att = self.attention
+        if flash:  # the query heads that share a plane: the kernel's group
+            rec.note_site("sparse_plane_heads", self,
+                          str(att.heads // att.kv_heads))
         b, s, _ = x.shape
         q, k, v = att.project(params, x, ctx)
         with scope("sparse_indexer"):

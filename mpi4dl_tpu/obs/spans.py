@@ -94,7 +94,10 @@ CONV_PATHS = ("wfold", "hstripe", "phase", "xla", "dot")
 # over a dense mask) and its indexer (ops/sparse_indexer.py: the Pallas
 # kernels or XLA's products); and on which form of the activation a
 # BatchNorm took its sums and applied its affine: ``[N, H, W/p, p·C]`` inside
-# a folded run (``layers.run_fold``), or ``[N, H, W, C]``.
+# a folded run (``layers.run_fold``), or ``[N, H, W, C]``.  A kind whose
+# paths are None takes any path: ``sparse_plane_heads``, how many query heads
+# of models/keye_vl2.py's Pallas sparse attention share one decoded plane of
+# the selection in a grid step of ``sparse_flash_fwd`` (the key-value group).
 SITE_PATHS = {
     "conv": CONV_PATHS,
     "norm": ("folded", "plain"),
@@ -105,6 +108,7 @@ SITE_PATHS = {
     "ssm_scan": ("chunked",),
     "tied_head": ("table_transposed",),
     "sparse_indexer": ("pallas", "xla"),
+    "sparse_plane_heads": None,
 }
 
 # At least 4,000 steps of the loop's spans (nine a step with the loader's).
@@ -302,7 +306,8 @@ class Recorder:
         far in the process, by the path their dispatch chose."""
         with self._lock:
             paths = [path for k, _, path in self._sites if k == kind]
-        return {path: paths.count(path) for path in SITE_PATHS[kind]
+        return {path: paths.count(path)
+                for path in SITE_PATHS[kind] or sorted(set(paths))
                 if path in paths}
 
     def note_conv(self, layer: Any, path: str) -> None:
@@ -376,8 +381,9 @@ class Recorder:
         programs built or loaded inside a step with its ``gstep`` (a program
         that appears twice was retraced); ``conv_paths`` and ``norm_paths``;
         and, where the model has such sites, ``attention_paths``,
-        ``expert_paths``, ``shared_expert_paths``, ``ssm_scan_paths`` and
-        ``tied_head_paths``."""
+        ``expert_paths``, ``shared_expert_paths``, ``ssm_scan_paths``,
+        ``tied_head_paths``, ``sparse_indexer_paths`` and
+        ``sparse_plane_heads``."""
         spans = sorted((s for s in list(self._closed)
                         if s.name.startswith(SETUP_PREFIXES)),
                        key=lambda s: s.start_ns)
@@ -411,7 +417,8 @@ class Recorder:
                           ("shared_expert_paths", "shared_expert"),
                           ("ssm_scan_paths", "ssm_scan"),
                           ("tied_head_paths", "tied_head"),
-                          ("sparse_indexer_paths", "sparse_indexer")):
+                          ("sparse_indexer_paths", "sparse_indexer"),
+                          ("sparse_plane_heads", "sparse_plane_heads")):
             paths = self.site_paths(kind)
             if paths:
                 out[key] = paths

@@ -145,17 +145,20 @@ def _sparse_flash_case(backward: bool):
     def build():
         import jax.numpy as jnp
 
-        # Grid (2·3, 3, 3): three heads a sequence share its selection's
-        # words (128 a query: at 300 tokens a forward tile of 128 keys is
-        # one bit; the backward's own three tiles of 768 over 2,300 tokens,
-        # a zero-padded tail in the last, are six).
+        # Three heads a sequence share its selection's words (128 a query:
+        # at 300 tokens a forward tile of 128 keys is one bit; the
+        # backward's own three tiles of 768 over 2,300 tokens, a zero-padded
+        # tail in the last, are six).  The forward's grid (2, 3, 3) is one
+        # key-value group of the three a sequence, on k and v as they are;
+        # the backward's (2·3, 3, 3) a head, on k and v repeated.
         t = 2300 if backward else _SPARSE_TOKENS
         q = jnp.zeros((6, t, 64), jnp.bfloat16)
         words = jnp.zeros((2, t, 128), jnp.int32)
         if not backward:
+            kv = jnp.zeros((2, t, 64), jnp.bfloat16)
             fn = lambda q, k, v, words: sparse_flash_forward(  # noqa: E731
                 q, k, v, words, heads=3, scale=0.125, tq=128, tk=128)
-            return fn, (q, q, q, words)
+            return fn, (q, kv, kv, words)
         stat = jnp.zeros((6, t), jnp.float32)
         fn = lambda q, k, v, m, do, dl, w: sparse_flash_backward(  # noqa: E731
             q, k, v, m, do, dl, w, heads=3, scale=0.125)

@@ -58,10 +58,11 @@ _NEG_INF = -1e30  # large-negative instead of -inf: exp() of it is exactly 0
 _LANES = 128
 # Tiles of the forward kernel (measured: beside block_flash_backward below).
 LOCAL_TILES = (1024, 1024)  # query, key rows of a tile, flash_attention_local
-# Under a key selection the forward also holds the words of its queries and
-# unpacks a tile's bits: at heads of 128 (1024, 1024) runs out of the
-# compiler's VMEM budget for a v5e, (512, 1024) fits it (PERF.md).
-LOCAL_TILES_SPARSE = (512, 1024)
+# Under a key selection a grid step is a key-value group of heads: measured
+# on a v5e at the Keye cell's shape (eight heads of 128 a group, 16,384
+# tokens; PERF.md), 22.8 ms a call at (256, 1024), 21.0 at (512, 1024) and
+# 20.1 at (1024, 1024), which names a VMEM limit (below).
+LOCAL_TILES_SPARSE = (1024, 1024)
 
 
 def _round_up(n: int, m: int) -> int:
@@ -83,7 +84,8 @@ def _out_structs(operands, shapes_dtypes):
         return [jax.ShapeDtypeStruct(s, d) for s, d in shapes_dtypes]
 
 
-def _kernel(*refs, tq, tk, nk, causal, t_k_real, scale, width=None):
+def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+            acc, m_scr, l_scr, *, tq, tk, nk, causal, t_k_real, scale):
     """One (bh, q-tile, k-tile) step.  Scratch (acc, m, l) persists across
     the innermost k dimension; outputs are written at the last k tile.
     ``t_k_real``: un-padded key count (static) — key slots past it are
@@ -93,18 +95,7 @@ def _kernel(*refs, tq, tk, nk, causal, t_k_real, scale, width=None):
     the MXU as bf16; float32 ones as float32) and accumulate in float32;
     the scale is applied to the float32 scores.  Under ``causal`` a tile
     whose every key lies after its last query is skipped: it would add
-    exactly nothing (the guard below), so only the causal half is computed.
-
-    ``width`` (static) adds a key selection: the refs then hold the
-    selection's words of the tile's queries (:func:`selection_width`, that
-    many a query) after the values, and a key outside a query's selection is
-    masked."""
-    if width is None:
-        (offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-         acc, m_scr, l_scr) = refs
-    else:
-        (offs_ref, q_ref, k_ref, v_ref, words_ref, o_ref, m_ref, l_ref,
-         acc, m_scr, l_scr) = refs
+    exactly nothing (the guard below), so only the causal half is computed."""
     ki = pl.program_id(2)
     qi = pl.program_id(1)
 
@@ -131,8 +122,6 @@ def _kernel(*refs, tq, tk, nk, causal, t_k_real, scale, width=None):
                 jnp.int32, (tq, tk), 0
             )
             s = jnp.where(q_pos >= offs_ref[1] + col, s, _NEG_INF)
-        if width is not None:
-            s = jnp.where(_plane(words_ref[0], ki, tk, width, 1), s, _NEG_INF)
 
         m_prev = m_scr[:, 0]                        # [TQ]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -184,6 +173,80 @@ def _plane(words, ki, tk, width, axis):
         words, jnp.full(words.shape, ki * per + i, jnp.int32)), 1) != 0
         for i in range(per)]
     return planes[0] if per == 1 else jnp.concatenate(planes, axis=axis)
+
+
+def _sparse_kernel(q_ref, k_ref, v_ref, words_ref, o_ref, m_ref, l_ref,
+                   m_scr, l_scr, *, rep, tq, tk, nk, t_k_real, scale, width):
+    """One (key-value group, q tile, k tile) step of
+    :func:`sparse_flash_forward`: the ``rep`` query heads of a group (``q_ref``
+    [rep, TQ, D]) against the group's one k and v block.  The selection's
+    bits of the tile (:func:`_plane` of the words of its queries), the causal
+    mask and the padded keys' are combined once into one ``[TQ, TK]`` plane,
+    which each head applies to its scores with one select and uses again as
+    the guard of its ``exp``: where the plane holds, the score is finite, so
+    ``where(plane, exp(s − m), 0)`` is the dense kernel's
+    ``where(s > −∞, exp(s − m), 0)``.  Each head's scores, maxima, sums and
+    products are :func:`_kernel`'s, in the same k-tile order.  The group's
+    block of ``o_hat`` (``o_ref``, float32) stays in VMEM across the k tiles
+    and is the accumulator; m and l of each head are kept in scratch and
+    written at the last k tile."""
+    ki = pl.program_id(2)
+    qi = pl.program_id(1)
+
+    @pl.when(ki == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+
+    def fold():
+        col = ki * tk + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+        plane = _plane(words_ref[0], ki, tk, width, 1) & (
+            qi * tq + lax.broadcasted_iota(jnp.int32, (tq, tk), 0) >= col)
+        if t_k_real % tk:
+            plane = plane & (col < t_k_real)
+        k, v = k_ref[0], v_ref[0]
+        for h in range(rep):
+            # DEFAULT, said outright: as in _kernel
+            s = jnp.where(plane, jax.lax.dot_general(   # [TQ, TK]
+                q_ref[h], k, (((1,), (1,)), ((), ())),
+                precision=lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32,
+            ) * scale, _NEG_INF)
+            m_prev = m_scr[h, :, 0]                     # [TQ]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+            c = jnp.exp(m_prev - m_new)
+            p = jnp.where(plane, jnp.exp(s - m_new[:, None]), 0.0)
+            l_new = l_scr[h, :, 0] * c + jnp.sum(p, axis=-1)
+            o_ref[h] = o_ref[h] * c[:, None] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                precision=lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32,
+            )
+            m_scr[h] = jnp.broadcast_to(m_new[:, None], m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new[:, None], l_scr.shape[1:])
+
+    # causal: the tile's last query is at or after its first key
+    pl.when((qi + 1) * tq - 1 >= ki * tk)(fold)
+
+    @pl.when(ki == nk - 1)
+    def _():
+        m_ref[...] = m_scr[...]
+        l_ref[...] = l_scr[...]
+
+
+def _sparse_fwd_vmem_bytes(rep, tq, tk, d, dv, width, itemsize):
+    """An upper bound of the VMEM :func:`_sparse_kernel` takes: the blocks of
+    a grid step double buffered (the group's q, one k and one v block, the
+    words of its queries, and o, m and l of each head in float32), m and l
+    of each head in scratch, about sixteen bytes an element of one head's
+    [TQ, TK] tile for the plane, the scores and their kin, and 2 MiB.
+    Fitted to the limits under which the kernel compiled for a v5e at the
+    Keye cell's shape (PERF.md, section 6): 29.0 MiB at tiles of (512, 1024)
+    (this gives 31.0), 56.7 at (1024, 1024) (59.0)."""
+    blocks = 2 * (itemsize * (rep * tq * d + tk * (d + dv)) + 4 * tq * width
+                  + 4 * rep * tq * (dv + 2 * _LANES))
+    return blocks + 2 * 4 * rep * tq * _LANES + 16 * tq * tk + 2 ** 21
 
 
 def _any_vma(*arrays) -> bool:
@@ -282,14 +345,21 @@ def sparse_flash_forward(q, k, v, words, *, heads, scale,
     """The block state ``(o_hat, m, l)`` of causal attention in which query
     ``t`` of a sequence sees only the keys its row of ``words`` [B, Tq, W]
     selects (:func:`selection_width`), all ``heads`` of the sequence alike:
-    ``q`` [B·heads, Tq, D], ``k`` [B·heads, Tk, D], ``v`` [B·heads, Tk, Dv].
-    Positions are the sequence's own (no ring offsets).  The kernel is
-    :func:`_kernel` under the selection, ``sparse_flash_fwd``.  Its backward
-    is :func:`sparse_flash_backward`."""
+    ``q`` [B·heads, Tq, D] and the key-value heads ``k`` [B·KV, Tk, D], ``v``
+    [B·KV, Tk, Dv], query head ``h`` reading key-value head ``h // (heads //
+    KV)`` (``jnp.repeat``'s order).  Positions are the sequence's own.  The
+    kernel is :func:`_sparse_kernel`, ``sparse_flash_fwd``, over the grid
+    (B·KV, q tile, k tile): a step reads one k and one v block for the
+    group's heads and decodes one plane of the selection for them all.
+    ``o_hat`` [B·heads, Tq, Dv], m and l [B·heads, Tq], in float32.  Its
+    backward is :func:`sparse_flash_backward`."""
     bh, t_q, d = q.shape
-    _, t_k, _ = k.shape
+    bkv, t_k, _ = k.shape
     dv = v.shape[-1]
     width = words.shape[-1]
+    rep = bh // bkv
+    kv = heads // rep
+    assert bkv * rep == bh and kv * rep == heads, (bh, bkv, heads)
     # whole lanes of keys, a multiple of the selection's width
     tq = min(tq, _round_up(t_q, 8))
     tk = _round_up(min(tk, _round_up(t_k, 128)), width)
@@ -301,40 +371,48 @@ def sparse_flash_forward(q, k, v, words, *, heads, scale,
     vp = jnp.pad(v, ((0, 0), (0, tk_p - t_k), (0, dv_p - dv)))
     wp = jnp.pad(words, ((0, 0), (0, tq_p - words.shape[1]), (0, 0)))
     nq, nk = tq_p // tq, tk_p // tk
-    offs = jnp.zeros((2,), jnp.int32)
-    kern = pl.pallas_call(
-        functools.partial(_kernel, tq=tq, tk=tk, nk=nk, causal=True,
+
+    def tile_of_k(i, j):
+        """A tile above the diagonal (skipped) asks for the k tile of the
+        last that is not, the pipeline then copying nothing for it."""
+        return jnp.minimum(j, lax.div((i + 1) * tq - 1, tk))
+
+    f32 = jnp.float32
+    vmem = _sparse_fwd_vmem_bytes(rep, tq, tk, d_p, dv_p, width,
+                                  q.dtype.itemsize)
+    o, m, l = pl.pallas_call(
+        functools.partial(_sparse_kernel, rep=rep, tq=tq, tk=tk, nk=nk,
                           t_k_real=t_k, scale=scale, width=width),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, nq, nk),
+            num_scalar_prefetch=0,
+            grid=(bkv, nq, nk),
             in_specs=[
-                pl.BlockSpec((1, tq, d_p), lambda b, i, j, *_: (b, i, 0)),
-                pl.BlockSpec((1, tk, d_p), lambda b, i, j, *_: (b, j, 0)),
-                pl.BlockSpec((1, tk, dv_p), lambda b, i, j, *_: (b, j, 0)),
-                pl.BlockSpec((1, tq, width),
-                             lambda b, i, j, *_: (b // heads, i, 0)),
+                pl.BlockSpec((rep, tq, d_p), lambda g, i, j: (g, i, 0)),
+                pl.BlockSpec((1, tk, d_p),
+                             lambda g, i, j: (g, tile_of_k(i, j), 0)),
+                pl.BlockSpec((1, tk, dv_p),
+                             lambda g, i, j: (g, tile_of_k(i, j), 0)),
+                pl.BlockSpec((1, tq, width), lambda g, i, j: (g // kv, i, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, tq, dv_p), lambda b, i, j, *_: (b, i, 0)),
-                pl.BlockSpec((1, tq, _LANES), lambda b, i, j, *_: (b, i, 0)),
-                pl.BlockSpec((1, tq, _LANES), lambda b, i, j, *_: (b, i, 0)),
+                pl.BlockSpec((rep, tq, dv_p), lambda g, i, j: (g, i, 0)),
+                pl.BlockSpec((rep, tq, _LANES), lambda g, i, j: (g, i, 0)),
+                pl.BlockSpec((rep, tq, _LANES), lambda g, i, j: (g, i, 0)),
             ],
-            scratch_shapes=[
-                pltpu.VMEM((tq, dv_p), jnp.float32),
-                pltpu.VMEM((tq, _LANES), jnp.float32),
-                pltpu.VMEM((tq, _LANES), jnp.float32),
-            ],
+            scratch_shapes=[pltpu.VMEM((rep, tq, _LANES), f32),
+                            pltpu.VMEM((rep, tq, _LANES), f32)],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq_p, dv_p), jnp.float32),
-            jax.ShapeDtypeStruct((bh, tq_p, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((bh, tq_p, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((bh, tq_p, dv_p), f32),
+            jax.ShapeDtypeStruct((bh, tq_p, _LANES), f32),
+            jax.ShapeDtypeStruct((bh, tq_p, _LANES), f32),
         ],
+        compiler_params=(
+            None if vmem <= _DEFAULT_VMEM
+            else pltpu.CompilerParams(vmem_limit_bytes=vmem + 2 ** 21)),
         interpret=interpret,
         name="sparse_flash_fwd",
-    )
-    o, m, l = kern(offs, qp, kp, vp, wp)
+    )(qp, kp, vp, wp)
     return o[:, :t_q, :dv], m[:, :t_q, 0], l[:, :t_q, 0]
 
 

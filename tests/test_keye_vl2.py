@@ -313,17 +313,22 @@ def _selection(b, t, seed, density=0.3):
     return sel
 
 
+@pytest.mark.parametrize("rep", [1, 3])
 @pytest.mark.parametrize("t, tk", [(300, 128), (300, 256), (64, 128)])
 def test_the_attention_kernels_under_a_selection_match_the_dense_masked_einsum(
-        t, tk):
+        t, tk, rep):
     """``sparse_flash_fwd`` and ``sparse_flash_bwd`` in interpret mode
     against einsums over the whole masked score matrix, three heads a
     sequence sharing its selection: the block state and every cotangent,
-    with a tile that spans one bit and one that spans two."""
+    with a tile that spans one bit and one that spans two.  The forward
+    takes the key-value heads, ``rep`` query heads a group (3: one group a
+    sequence); the backward and the einsums take them repeated."""
     b, heads, d = 2, 3, 32
     rng = np.random.default_rng(t)
-    q, k, v = (jnp.asarray(rng.standard_normal((b * heads, t, d)), jnp.float32)
-               for _ in range(3))
+    q = jnp.asarray(rng.standard_normal((b * heads, t, d)), jnp.float32)
+    k_kv, v_kv = (jnp.asarray(rng.standard_normal((b * heads // rep, t, d)),
+                              jnp.float32) for _ in range(2))
+    k, v = (jnp.repeat(x, rep, axis=0) for x in (k_kv, v_kv))
     sel = _selection(b, t, 0)
     words = sparse_indexer.pack_selection(jnp.asarray(sel))
     mask = jnp.repeat(jnp.asarray(sel), heads, axis=0)
@@ -337,7 +342,7 @@ def test_the_attention_kernels_under_a_selection_match_the_dense_masked_einsum(
 
     with jax.default_matmul_precision("highest"):
         o, m, l = pallas_attention.sparse_flash_forward(
-            q, k, v, words, heads=heads, scale=scale, tq=128, tk=tk,
+            q, k_kv, v_kv, words, heads=heads, scale=scale, tq=128, tk=tk,
             interpret=True)
         o_d, m_d, l_d = dense(q, k, v)
         do = jnp.asarray(rng.standard_normal(o.shape), jnp.float32)
@@ -353,13 +358,15 @@ def test_the_attention_kernels_under_a_selection_match_the_dense_masked_einsum(
 
 
 def test_the_whole_causal_selection_is_block_flash():
-    """Every causal key selected: ``sparse_flash_fwd`` and ``sparse_flash_bwd``
-    give what ``block_flash`` and its backward give under ``causal``, the
-    dense kernels they share their tile code with."""
+    """Every causal key selected: ``sparse_flash_fwd`` (one key-value group
+    of three heads a sequence) and ``sparse_flash_bwd`` give what
+    ``block_flash`` and its backward give under ``causal`` on the key-value
+    heads repeated, the dense kernels whose tile code they follow."""
     b, heads, t, d = 2, 3, 300, 32
     rng = np.random.default_rng(2)
-    q, k, v = (jnp.asarray(rng.standard_normal((b * heads, t, d)), jnp.float32)
-               for _ in range(3))
+    q, k_kv, v_kv = (jnp.asarray(rng.standard_normal((n, t, d)), jnp.float32)
+                     for n in (b * heads, b, b))
+    k, v = (jnp.repeat(x, heads, axis=0) for x in (k_kv, v_kv))
     words = sparse_indexer.pack_selection(
         jnp.asarray(np.tril(np.ones((b, t, t), bool))))
     scale, zero = d ** -0.5, jnp.int32(0)
@@ -367,7 +374,7 @@ def test_the_whole_causal_selection_is_block_flash():
     dl = jnp.asarray(rng.standard_normal((b * heads, t)), jnp.float32)
     with jax.default_matmul_precision("highest"):
         got = pallas_attention.sparse_flash_forward(
-            q, k, v, words, heads=heads, scale=scale, tq=128, tk=128,
+            q, k_kv, v_kv, words, heads=heads, scale=scale, tq=128, tk=128,
             interpret=True)
         want = pallas_attention.block_flash(q, k, v, zero, zero, True, scale,
                                             128, 128, True)
@@ -378,6 +385,39 @@ def test_the_whole_causal_selection_is_block_flash():
             q, k, v, zero, zero, want[1], do, dl, True, scale, 128, 128, True)
     for a, w_ in zip((*got, *g_got), (*want, *g_want)):
         _close(a, w_, tol=1e-5)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("rep", [1, 2, 8])
+def test_the_grouped_forward_is_the_forward_on_repeated_heads_to_the_bit(
+        rep, causal):
+    """``sparse_flash_fwd`` over key-value groups of ``rep`` query heads
+    gives, bit for bit, what it gives on k and v repeated to one head a group
+    (``rep`` 1: a head a grid step), in bf16 at a length that is no multiple
+    of the tiles, under a selection with a tile no query of its q tile chose
+    a key in, whose queries' first k tile holds none of their keys (the rows
+    the exp's guard is for), and under ``causal``'s: every causal key."""
+    b, heads, t, d = 2, 8, 300, 32
+    rng = np.random.default_rng(rep)
+    q, k, v = (jnp.asarray(rng.standard_normal((n, t, d)), jnp.bfloat16)
+               for n in (b * heads, b * heads // rep, b * heads // rep))
+    if causal:
+        sel = np.tril(np.ones((b, t, t), bool))
+    else:
+        sel = _selection(b, t, 1)
+        sel[:, 256:, :256] = False  # q tile 2 (of 128) sees nothing in k tile 0
+    words = sparse_indexer.pack_selection(jnp.asarray(sel))
+
+    def forward(k, v):
+        return pallas_attention.sparse_flash_forward(
+            q, k, v, words, heads=heads, scale=d ** -0.5, tq=128, tk=256,
+            interpret=True)
+
+    got = forward(k, v)
+    want = forward(*(jnp.repeat(x, rep, axis=0) for x in (k, v)))
+    for a, w_ in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w_))
+    assert np.all(np.asarray(got[2]) >= 1)  # every query sees its own key
 
 
 def test_the_indexer_gradient_kernel_is_the_indexer_loss_gradient():
@@ -428,9 +468,17 @@ def test_the_step_reports_the_sampled_indexer_loss(monkeypatch):
         layer.apply(params, x, dataclasses.replace(CTX, bn_sink=sink))
         return float(sink[id(params["sparse_kl"])])
 
+    from mpi4dl_tpu.obs.spans import recorder
+
+    noted = lambda: {(i, path) for kind, i, path in recorder()._sites
+                     if kind == "sparse_plane_heads" and i == id(layer)}
     xla = kl()
+    assert noted() == set()  # no plane on the einsum path
     _pallas(monkeypatch)
     assert 0 < xla and kl() == pytest.approx(xla, rel=1e-4)
+    # TINY's 4 query heads over 2 key-value heads share a plane two by two
+    assert noted() == {(id(layer), "2")}
+    assert recorder().site_paths("sparse_plane_heads")["2"] >= 1
 
 
 # --- the expert layer --------------------------------------------------------------

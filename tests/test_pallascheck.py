@@ -865,16 +865,19 @@ def test_rule12_tests_are_exempt(tmp_path):
 
 
 @pytest.mark.parametrize("name, grid", [
-    ("sparse_flash_forward:causal:bfloat16", [6, 3, 3]),
+    ("sparse_flash_forward:causal:bfloat16", [2, 3, 3]),
     ("sparse_flash_backward:causal:bfloat16", [6, 3, 3]),
     ("indexer_select:bfloat16", [1, 3]),
     ("indexer_backward:bfloat16", [1, 2, 32]),
 ])
 def test_sparse_attention_contract_shape(registry_contract, name, grid):
-    """The sparse attention's kernels (PR 40): the forward and backward of
-    ``block_flash`` under a key selection take the selection's words as one
+    """The sparse attention's kernels (PR 40): the attention's forward and
+    backward under a key selection take the selection's words as one
     more blocked operand (a q tile's rows of 128 words; transposed for the
-    backward) beside the forward's or backward's own blocks; the indexer's
+    backward) beside the forward's or backward's own blocks.  The forward's
+    grid step is a key-value group: the q block and o, m and l hold its three
+    heads, the k and v blocks one head as they are, and scratch holds m and l
+    of the three (o's block is the accumulator); the indexer's
     selection holds a block's keys as int32 images in scratch for the whole
     padded key range (32 bits of 128 words: 4,096), and its gradient's key
     accumulator is resident for the sequence.  No DMA of their own, and
@@ -885,6 +888,9 @@ def test_sparse_attention_contract_shape(registry_contract, name, grid):
     blocks = entry["blocks"]
     if name.startswith("sparse_flash_forward"):
         assert blocks["in3"] == [1, 128, 128]
+        assert blocks["in0"] == blocks["out0"] == blocks["scratch1"] == [3, 128, 128]
+        assert blocks["in1"] == blocks["in2"] == [1, 128, 128]
+        assert "scratch2" not in blocks
     elif name.startswith("sparse_flash_backward"):
         assert blocks["in6"] == [1, 128, 768] and blocks["out0"] == [1, 64, 2304]
     elif name.startswith("indexer_select"):

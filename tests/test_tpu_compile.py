@@ -651,7 +651,9 @@ def _compiled_layer(model, layer, batch, one_chip, part="block", seq=8192):
     ``"highest"`` default and the Pallas path asked for as on a TPU backend;
     ``part`` ``"ffn"`` compiles the layer's feed-forward alone.  Returns
     ``(row, optable.describe(row))`` of every instruction that runs on its own
-    and does work.  Compiled once for the tests that read it."""
+    and does work, a custom call's row with the types of its operands
+    (``operands``, from ``operand_layout_constraints``).  Compiled once for
+    the tests that read it."""
     from unittest import mock
 
     import mpi4dl_tpu.config as config
@@ -687,6 +689,12 @@ def _compiled_layer(model, layer, batch, one_chip, part="block", seq=8192):
     with mock.patch.object(config, "is_tpu_backend", lambda: True):
         text = jax.jit(grads).lower(params, struct(shape)).compile().as_text()
     rows = optable.parse(text)
+    for line in text.splitlines():
+        head, sep, rest = line.partition(" custom-call(")
+        if sep and "operand_layout_constraints={" in rest:
+            constraints = rest.split("operand_layout_constraints={", 1)[1]
+            rows[head.strip().split(" ", 1)[0].lstrip("%")]["operands"] = (
+                re.findall(r"\w+\[[\d,]*\]", constraints.split("}}", 1)[0]))
     said = []
     for name in sorted(_executed_names(text)):
         row = rows.get(name)
@@ -835,8 +843,16 @@ def test_keye_vl2_layer_compiles_for_v5e_with_its_kernels_in_their_scopes(
     ``attention_core``; ``sparse_indexer_select`` forward and recomputed and
     ``sparse_indexer_bwd`` in ``sparse_indexer``), no product of the
     projections in ``attention_core``, the indexer's own projections in
-    ``sparse_indexer``, and the routed layer's scopes as in LFM2's."""
+    ``sparse_indexer``, and the routed layer's scopes as in LFM2's.
+    ``sparse_flash_fwd`` takes the 4 key-value heads as they are (a grid
+    step a group of 8 query heads): no broadcast or copy of k or v to the 32
+    query heads feeds it."""
     said = _compiled_layer("keye_vl2", 0, 1, one_chip, seq=16384)
+    forward = [r["operands"] for r in (r for r, _ in said)
+               if r["name"].startswith("sparse_flash_fwd")]
+    assert len(forward) == 2 and all(ops == [
+        "bf16[32,16384,128]", "bf16[4,16384,128]", "bf16[4,16384,128]",
+        "s32[1,16384,512]"] for ops in forward), forward
     kernels = [(r["name"].split(".")[0], d) for r, d in said
                if d["cls"] == "kernel" and not r["name"].startswith("ragged-dot")]
     assert sorted((n, d["pass"]) for n, d in kernels) == [
