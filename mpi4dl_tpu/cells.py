@@ -110,14 +110,16 @@ class CellModel:
     # routed model's expert load); the one-chip step returns it as
     # ``metrics["counted"]`` and the loop writes it on the ``step`` span.
     step_metrics: Optional[Callable[[Any, int], dict]] = None
-    # ``(owner, reader, name)``: the leaf ``name`` of cell ``owner``'s
-    # parameters is read by cell ``reader`` too, under the same name (a head
-    # that is the embedding's table).  ``init`` and the train state hold it
-    # once, with the owner; ``apply`` hands the reader its own parameters with
-    # the leaf beside them, so its gradient is the sum over both uses and its
+    # ``(owner, reader, name)``: the leaf or subtree ``name`` of cell
+    # ``owner``'s parameters is read by cell ``reader`` too, under the same
+    # name (a head that is the embedding's table; a layer applied again on
+    # its own weights, a tie of each of its top-level names).  A name may
+    # have several readers.  ``init`` and the train state hold it once, with
+    # the owner; ``apply`` hands each reader its own parameters with the tied
+    # names beside them, so the gradient is the sum over every use and the
     # update is one.  Of the engines that pack each stage's parameters into a
-    # row of its own, the GPipe schedule keeps a copy in the reader's row and
-    # sums the two rows' gradients (``parallel/pipeline.sum_tied_grads``); the
+    # row of its own, the GPipe schedule keeps a copy in each reader's row and
+    # sums all uses' gradients (``parallel/pipeline.sum_tied_grads``); the
     # others refuse such a model (:meth:`refuse_tied`).
     tied: Tuple[Tuple[int, int, str], ...] = ()
 
@@ -137,7 +139,7 @@ class CellModel:
     def refuse_tied(self, engine: str) -> None:
         """The error of an engine that gives each stage a parameter row of
         its own and does not sum a tied leaf's gradients over the stage
-        axis, for a model with a leaf that two cells read."""
+        axis, for a model with a leaf that several cells read."""
         if not self.tied:
             return
         owner, reader, name = self.tied[0]
@@ -145,7 +147,7 @@ class CellModel:
             f"{self.name}: cell {reader} ({self.cells[reader].name}) reads "
             f"the leaf {name!r} of cell {owner} ({self.cells[owner].name}), "
             f"and {engine} keeps each stage's parameters in a row of its "
-            "own: the two uses' gradients have to be summed over the stage "
+            "own: its uses' gradients have to be summed over the stage "
             "axis before the update, which only the lp family's GPipe "
             "schedule does (ROADMAP R6); run it there or on one chip")
 

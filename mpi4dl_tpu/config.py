@@ -216,6 +216,7 @@ def hatches_markdown(include_internal: bool = False) -> str:
 class ParallelConfig:
     # --- model / problem (reference parser.py) ---
     # resnet | amoebanet | lfm2_moe | deepseek_v3 | granitemoehybrid | keye_vl2
+    # | ouro
     model: str = "resnet"
     batch_size: int = 32
     parts: int = 1  # micro-batches per step (GPipe "parts")
@@ -233,15 +234,16 @@ class ParallelConfig:
     num_layers: int = 18  # amoebanet cell count knob
     num_filters: int = 416
     num_classes: int = 10
-    # --- token models (lfm2_moe, deepseek_v3, granitemoehybrid, keye_vl2): the
-    # cut and the job; the published sizes are the model file's own
-    # (models/lfm2.py, models/deepseek_v3.py, models/granitemoehybrid.py,
-    # models/keye_vl2.py).  A sample is a sequence of seq_len ids below
-    # vocab_size; of a routed model's published experts this process holds
-    # experts_held, from expert_first (one chip's
-    # share under expert parallelism; granitemoehybrid has no routed experts
-    # and is not handed them).  The defaults are within every model's
-    # published counts, the uncut model of none but lfm2_moe's.
+    # --- token models (lfm2_moe, deepseek_v3, granitemoehybrid, keye_vl2,
+    # ouro): the cut and the job; the published sizes are the model file's
+    # own (models/lfm2.py, models/deepseek_v3.py, models/granitemoehybrid.py,
+    # models/keye_vl2.py, models/ouro.py).  A sample is a sequence of seq_len
+    # ids below vocab_size; of a routed model's published experts this
+    # process holds experts_held, from expert_first (one chip's share under
+    # expert parallelism; granitemoehybrid and ouro have no routed experts
+    # and are not handed them).  The defaults are within every model's
+    # published counts but ouro's vocabulary (49,152), the uncut model of
+    # none but lfm2_moe's.
     seq_len: int = 128
     vocab_size: int = 65536
     experts_held: int = 64
@@ -369,7 +371,7 @@ def get_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description="mpi4dl_tpu benchmarks")
     p.add_argument("--model", type=str, default="resnet",
                    help="resnet | amoebanet (images) | lfm2_moe | deepseek_v3 "
-                        "| granitemoehybrid | keye_vl2 (token models)")
+                        "| granitemoehybrid | keye_vl2 | ouro (token models)")
     p.add_argument("--batch-size", type=int, default=32)
     p.add_argument("--parts", type=int, default=1)
     p.add_argument("--split-size", type=int, default=1)
@@ -389,15 +391,16 @@ def get_parser() -> argparse.ArgumentParser:
     p.add_argument("--vocab-size", type=int, default=65536,
                    help="token models: rows of the vocabulary held here, at "
                         "most the model's own (lfm2_moe 65536, deepseek_v3 "
-                        "128256, granitemoehybrid 100352, keye_vl2 151936)")
+                        "128256, granitemoehybrid 100352, keye_vl2 151936, "
+                        "ouro 49152)")
     p.add_argument("--experts-held", type=int, default=64,
                    help="token models with routed experts: how many this "
                         "process holds (all of the model's is the uncut "
                         "layer: lfm2_moe 64, deepseek_v3 and keye_vl2 128); "
-                        "ignored by granitemoehybrid, which has none")
+                        "ignored by granitemoehybrid and ouro, which have none")
     p.add_argument("--expert-first", type=int, default=0,
                    help="token models with routed experts: the first expert "
-                        "held; ignored by granitemoehybrid")
+                        "held; ignored by granitemoehybrid and ouro")
     p.add_argument("--balance", type=str, default=None)
     # the reference spells it --halo-D2 (parser.py); accept both
     p.add_argument("--halo-d2", "--halo-D2", dest="halo_d2", action="store_true")

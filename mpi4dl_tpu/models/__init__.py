@@ -4,6 +4,7 @@ from mpi4dl_tpu.models.lfm2 import lfm2_moe
 from mpi4dl_tpu.models import deepseek_v3  # the module: its builder has its name
 from mpi4dl_tpu.models import granitemoehybrid  # likewise
 from mpi4dl_tpu.models import keye_vl2  # likewise
+from mpi4dl_tpu.models import ouro  # likewise
 from mpi4dl_tpu.models.seqblock import SeqBlock, make_seq_cp_train_step
 
 __all__ = [
@@ -63,6 +64,7 @@ MODELS = {
     "granitemoehybrid": ("tokens", _token_model(
         granitemoehybrid.granitemoehybrid, routed=False)),
     "keye_vl2": ("tokens", _token_model(keye_vl2.keye_vl2)),
+    "ouro": ("tokens", _token_model(ouro.ouro, routed=False)),
 }
 
 
@@ -82,7 +84,7 @@ def build_model(cfg):
     benchmark_resnet_sp.py:161-163; pass --num-layers 12 for parity).  For
     amoebanet it is the NAS cell count as in the reference parser.  For the
     token models (``[B, S]`` ids in: lfm2_moe, deepseek_v3, granitemoehybrid,
-    keye_vl2)
+    keye_vl2, ouro)
     it is the layers as run; the vocabulary rows and, where the model has
     routed experts, the experts held come from their own flags."""
     input_kind(cfg.model)  # an unknown model is refused before its shape is asked

@@ -208,7 +208,8 @@ class Attention(Layer):
         b, s, _ = x.shape
         q, k, v = self.project(params, x, ctx)
         rep = self.heads // self.kv_heads
-        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+        if rep > 1:
+            k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
         recorder().note_site(
             "attention", self,
             "block_flash" if _resolve_flash(None) else "einsum")
@@ -254,28 +255,38 @@ class SwiGLU(Layer):
 class BlockCell(Cell):
     """One layer: ``h += m op(norm(h))``, then ``h += m ffn(norm(h))``, with
     ``m`` the ``residual_multiplier`` (1 in most models: nothing is traced
-    for it then)."""
+    for it then).  ``post_norms``: each branch's output is normalised too
+    before the add, ``h += m norm2(op(norm1(h)))`` (sandwich norms, scales
+    ``op_post_norm`` and ``ffn_post_norm``); off, nothing is traced for it."""
 
     op: Layer
     ffn: Layer
     norm: RMSNorm
     name: str = "layer"
     residual_multiplier: float = 1.0
+    post_norms: bool = False
 
     def init(self, key, in_shape):
         k_op, k_ffn = jax.random.split(key)
         scale = lambda: self.norm.init(None, in_shape)[0]
-        return {"op_norm": scale(), "op": self.op.init(k_op, in_shape)[0],
-                "ffn_norm": scale(), "ffn": self.ffn.init(k_ffn, in_shape)[0],
-                }, in_shape
+        params = {"op_norm": scale(), "op": self.op.init(k_op, in_shape)[0],
+                  "ffn_norm": scale(), "ffn": self.ffn.init(k_ffn, in_shape)[0]}
+        if self.post_norms:
+            params.update(op_post_norm=scale(), ffn_post_norm=scale())
+        return params, in_shape
 
     def apply(self, params, x, ctx):
         m = self.residual_multiplier
-        branch = (lambda y: y) if m == 1 else (lambda y: y * m)
-        x = x + branch(self.op.apply(
-            params["op"], self.norm.apply(params["op_norm"], x, ctx), ctx))
-        return x + branch(self.ffn.apply(
-            params["ffn"], self.norm.apply(params["ffn_norm"], x, ctx), ctx))
+
+        def branch(name, layer, x):
+            y = layer.apply(
+                params[name], self.norm.apply(params[name + "_norm"], x, ctx), ctx)
+            if self.post_norms:
+                y = self.norm.apply(params[name + "_post_norm"], y, ctx)
+            return y if m == 1 else y * m
+
+        x = x + branch("op", self.op, x)
+        return x + branch("ffn", self.ffn, x)
 
 
 def _block(config: Lfm2MoeConfig, layer: int, experts_held: int,
@@ -324,27 +335,30 @@ def embed_cell(vocab_size: int, features: int, compute_dtype,
 
 
 def head_cell(vocab_size: int, features: int, eps: float, *,
-              logits_scaling: float = 1.0, tied: bool = False) -> FnCell:
+              logits_scaling: float = 1.0, tied: bool = False,
+              norm: bool = True) -> FnCell:
     """The final RMSNorm and the head: logits ``[B, S, vocab_size]`` in
     float32, over ``logits_scaling`` where it is not 1.  The head is this
     cell's own parameter ``head.kernel`` ``[features, vocab_size]``, or,
     ``tied``, the embedding's ``table`` ``[vocab_size, features]``: the cell
     then initialises no head and reads ``table`` beside its ``norm``, which
     the model's ``CellModel.tied`` puts there from the embedding cell's
-    parameters."""
-    norm = RMSNorm(features, eps)
+    parameters.  ``norm`` false: the head alone, on an activation that a
+    cell before it has normalised."""
+    rms = RMSNorm(features, eps)
     head = Dense(features, vocab_size, use_bias=False)
 
     def head_init(key, shape):
-        params = {"norm": norm.init(None, shape)[0]}
+        params = {"norm": rms.init(None, shape)[0]} if norm else {}
         if not tied:
             params["head"] = head.init(key, shape)[0]
         return params, (*shape[:-1], vocab_size)
 
     def head_apply(p, x, ctx):
-        x = norm.apply(p["norm"], x, ctx)
+        if norm:
+            x = rms.apply(p["norm"], x, ctx)
         if tied:
-            recorder().note_site("tied_head", norm, "table_transposed")
+            recorder().note_site("tied_head", rms, "table_transposed")
             logits = lax.dot_general(
                 x, p["table"].astype(x.dtype),
                 (((x.ndim - 1,), (1,)), ((), ())),
@@ -354,7 +368,7 @@ def head_cell(vocab_size: int, features: int, eps: float, *,
                              preferred_element_type=jnp.float32)
         return logits if logits_scaling == 1 else logits / logits_scaling
 
-    return FnCell(head_init, head_apply, "norm_head")
+    return FnCell(head_init, head_apply, "norm_head" if norm else "head")
 
 
 def routed_step_metrics(routed: Sequence[int], top_k: int):

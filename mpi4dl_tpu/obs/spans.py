@@ -97,7 +97,9 @@ CONV_PATHS = ("wfold", "hstripe", "phase", "xla", "dot")
 # a folded run (``layers.run_fold``), or ``[N, H, W, C]``.  A kind whose
 # paths are None takes any path: ``sparse_plane_heads``, how many query heads
 # of models/keye_vl2.py's Pallas sparse attention share one decoded plane of
-# the selection in a grid step of ``sparse_flash_fwd`` (the key-value group).
+# the selection in a grid step of ``sparse_flash_fwd`` (the key-value group);
+# ``ut_loop``, how many cells of models/ouro.py apply a held layer (its
+# passes, ``total_ut_steps``).
 SITE_PATHS = {
     "conv": CONV_PATHS,
     "norm": ("folded", "plain"),
@@ -109,6 +111,7 @@ SITE_PATHS = {
     "tied_head": ("table_transposed",),
     "sparse_indexer": ("pallas", "xla"),
     "sparse_plane_heads": None,
+    "ut_loop": None,
 }
 
 # At least 4,000 steps of the loop's spans (nine a step with the loader's).
@@ -382,8 +385,8 @@ class Recorder:
         that appears twice was retraced); ``conv_paths`` and ``norm_paths``;
         and, where the model has such sites, ``attention_paths``,
         ``expert_paths``, ``shared_expert_paths``, ``ssm_scan_paths``,
-        ``tied_head_paths``, ``sparse_indexer_paths`` and
-        ``sparse_plane_heads``."""
+        ``tied_head_paths``, ``sparse_indexer_paths``,
+        ``sparse_plane_heads`` and ``ut_loop``."""
         spans = sorted((s for s in list(self._closed)
                         if s.name.startswith(SETUP_PREFIXES)),
                        key=lambda s: s.start_ns)
@@ -418,7 +421,8 @@ class Recorder:
                           ("ssm_scan_paths", "ssm_scan"),
                           ("tied_head_paths", "tied_head"),
                           ("sparse_indexer_paths", "sparse_indexer"),
-                          ("sparse_plane_heads", "sparse_plane_heads")):
+                          ("sparse_plane_heads", "sparse_plane_heads"),
+                          ("ut_loop", "ut_loop")):
             paths = self.site_paths(kind)
             if paths:
                 out[key] = paths

@@ -22,7 +22,7 @@ stage handoff is a single uniform ppermute.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -134,6 +134,15 @@ def pad_to(vec: jax.Array, n: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+class TiedLeaf(NamedTuple):
+    """A parameter leaf that several cells read (``CellModel.tied``): its
+    size, and where each use keeps its copy, ``(stage, offset in that
+    stage's row)``, the owner's first."""
+
+    size: int
+    uses: Tuple[Tuple[int, int], ...]
+
+
 @dataclasses.dataclass
 class StagePartition:
     """Static description of a model split into S pipeline stages."""
@@ -157,12 +166,12 @@ class StagePartition:
     # buffers, the GEMS mirror ppermute traffic, and the grad cotangents;
     # update arithmetic stays fp32 inside Optimizer).
     param_dtype: Any = jnp.float32
-    # A leaf that two cells read (``CellModel.tied``), as ``(owner's stage,
-    # offset in its row, reader's stage, offset in its row, size)``: the
-    # reader's stage keeps a copy of the leaf in its own row, and the engine
-    # sums the two rows' gradients for it over the stage axis before the
-    # update (``pipeline.sum_tied_grads``), so the copies stay one value.
-    tied_slots: Tuple[Tuple[int, int, int, int, int], ...] = ()
+    # Every leaf that several cells read (``CellModel.tied``, a leaf or each
+    # leaf under a tied subtree): each reader keeps a copy of it in its
+    # stage's row, and the engine sums the gradients of all its uses over the
+    # stage axis before the update (``pipeline.sum_tied_grads``), so the
+    # copies stay one value.
+    tied_slots: Tuple[TiedLeaf, ...] = ()
 
     @property
     def num_stages(self) -> int:
@@ -229,22 +238,30 @@ class StagePartition:
             if stat_max
             else None
         )
-        def slot(cell: int, name: str) -> Tuple[int, int, int]:
-            """(stage, offset in its row, size) of cell ``cell``'s ``name``."""
+        def slots(cell: int, name: str) -> List[Tuple[int, int, int]]:
+            """(stage, offset in its row, size) of each leaf under cell
+            ``cell``'s ``name``, in the order the tree flattens."""
             stage = next(s for s, (r0, r1) in enumerate(ranges) if r0 <= cell < r1)
             r0, r1 = ranges[stage]
-            off = 0
+            found, off = [], 0
             for path, leaf in jax.tree_util.tree_flatten_with_path(
                     [params_list[i] for i in range(r0, r1)])[0]:
-                if (path[0].idx, getattr(path[1], "key", None)) == (cell - r0, name) \
-                        and len(path) == 2:
-                    return stage, off, int(leaf.size)
+                if len(path) > 1 and (path[0].idx, getattr(path[1], "key", None)
+                                      ) == (cell - r0, name):
+                    found.append((stage, off, int(leaf.size)))
                 off += int(leaf.size)
-            raise KeyError((cell, name))
+            if not found:
+                raise KeyError((cell, name))
+            return found
 
+        # by the owner's name: the owner's leaves, then each reader's copies
+        uses: Dict[Tuple[int, str], List[List[Tuple[int, int, int]]]] = {}
+        for owner, reader, name in model.tied:
+            uses.setdefault((owner, name), [slots(owner, name)]).append(
+                slots(reader, name))
         tied_slots = tuple(
-            (*slot(owner, name)[:2], *slot(reader, name))
-            for owner, reader, name in model.tied)
+            TiedLeaf(copies[0][2], tuple((s, off) for s, off, _ in copies))
+            for per_use in uses.values() for copies in zip(*per_use))
         return cls(
             model, ranges, param_packs, act_packs, out_pack, param_max, act_max,
             stat_leaf_ids, stat_slots, stat_max, stat_idx, param_dtype,

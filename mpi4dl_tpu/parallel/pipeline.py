@@ -77,16 +77,15 @@ def grad_pmean(x, axes, quant: Optional[QuantPolicy]):  # analysis: ok(unscoped-
 
 
 def sum_tied_grads(part: StagePartition, grads):
-    """A stage's gradient row with the gradients of every tied leaf
-    (``StagePartition.tied_slots``) summed over its two uses: the owner's
-    stage and the reader's each hold a copy of the leaf and the gradient of
-    their own use of it; both get the sum, so one update keeps the copies
-    one value.  Inside ``shard_map`` over the stage axis."""
+    """A stage's gradient row with the gradient of every tied leaf
+    (``StagePartition.tied_slots``) summed over all its uses at once: each
+    use's stage holds a copy of the leaf and the gradient of that use; every
+    copy gets the one sum, so one update keeps the copies one value.  Inside
+    ``shard_map`` over the stage axis."""
     stage = lax.axis_index(AXIS_STAGE)
-    for owner, owner_off, reader, reader_off, size in part.tied_slots:
-        uses = ((owner, owner_off), (reader, reader_off))
+    for size, uses in part.tied_slots:
         with scope("tied_grad_reduce"):
-            total = lax.psum(  # analysis: ok(unquantized-collective) — exact: the leaf's two copies must stay one value
+            total = lax.psum(  # analysis: ok(unquantized-collective) — exact: the leaf's copies must stay one value
                 sum(jnp.where(stage == s, lax_slice(grads, off, size), 0)
                     for s, off in uses), AXIS_STAGE)
         for s, off in uses:

@@ -431,7 +431,7 @@ def test_the_other_pipelined_engines_refuse_the_tie_by_the_leafs_name():
     with pytest.raises(ValueError, match=r"reads the leaf 'table' of cell 0"):
         StagePartition.build(model, params, 2, (2, SEQ))
     part = StagePartition.build(model, params, 2, (2, SEQ), sums_tied_grads=True)
-    (owner, owner_off, reader, reader_off, size), = part.tied_slots
+    (size, ((owner, owner_off), (reader, reader_off))), = part.tied_slots
     assert (owner, owner_off, reader, size) == (0, 0, 1, VOCAB * TINY.hidden_size)
     assert reader_off > 0  # after the head cell's norm, and after a layer
     from mpi4dl_tpu.parallel.pipeline import make_pipeline_train_step

@@ -224,6 +224,7 @@ def test_factorized_reduce_keeps_its_fusion_barrier():
     ("granitemoehybrid", dict(num_layers=6, seq_len=256, vocab_size=512)),
     ("keye_vl2", dict(num_layers=1, seq_len=64, vocab_size=512,
                       experts_held=16)),
+    ("ouro", dict(num_layers=1, seq_len=64, vocab_size=512)),
 ])
 def test_every_convolution_of_a_model_takes_one_of_the_five_paths(
         monkeypatch, rec, name, sizes):
@@ -242,7 +243,7 @@ def test_every_convolution_of_a_model_takes_one_of_the_five_paths(
     from mpi4dl_tpu.obs import spans
 
     assert set(MODELS) == {"resnet", "amoebanet", "lfm2_moe", "deepseek_v3",
-                           "granitemoehybrid", "keye_vl2"}
+                           "granitemoehybrid", "keye_vl2", "ouro"}
     assert spans.CONV_PATHS == ("wfold", "hstripe", "phase", "xla", "dot")
     for key in [k for k in os.environ if k.startswith("MPI4DL_")]:
         monkeypatch.delenv(key)

@@ -117,13 +117,14 @@ def _token_step(name):
     """A small train step of a token model at toy widths (the tests'
     configurations), lowered: LFM2 (routed experts, attention), granite (the
     Mamba-2 mixer and its scan), Kanana-2 (the shared expert beside the routed
-    ones), Keye-VL-2.0 (the sparse attention and its indexer), and
-    ``block_flash`` in interpret mode, forward and backward under
+    ones), Keye-VL-2.0 (the sparse attention and its indexer), Ouro (the
+    loop's passes), and ``block_flash`` in interpret mode, forward and backward under
     ``jax.checkpoint``, for the scope round the kernel and in its rule."""
     import test_deepseek_v3
     import test_granitemoehybrid
     import test_keye_vl2
     import test_lfm2
+    import test_ouro
 
     if name == "block_flash":
         from mpi4dl_tpu.ops.pallas_attention import flash_attention_local
@@ -136,7 +137,8 @@ def _token_step(name):
         qkv = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.float32)
         return jax.jit(jax.grad(loss, (0, 1, 2))).lower(qkv, qkv, qkv)
     module = {"lfm2": test_lfm2, "granite": test_granitemoehybrid,
-              "deepseek_v3": test_deepseek_v3, "keye_vl2": test_keye_vl2}[name]
+              "deepseek_v3": test_deepseek_v3, "keye_vl2": test_keye_vl2,
+              "ouro": test_ouro}[name]
     model, params, _ = module._model()
     x, y = (test_granitemoehybrid._ids() if name == "granite"
             else test_lfm2._batch(seq=test_keye_vl2.SEQ) if name == "keye_vl2"
@@ -165,6 +167,7 @@ def no_persistent_cache():
     ("deepseek_v3", ("expert_route", "expert_dispatch", "shared_expert")),
     ("keye_vl2", ("sparse_indexer", "attention_core", "expert_route",
                   "expert_dispatch")),
+    ("ouro", ("ut_loop", "ut_step0", "ut_step3")),
     ("block_flash", ("attention_core",)),
 ])
 def test_scopes_are_metadata_only(monkeypatch, no_persistent_cache, name,

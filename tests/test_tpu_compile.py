@@ -645,7 +645,8 @@ def test_granitemoehybrid_layers_compile_for_v5e_under_the_names_the_metrics_pic
 def _compiled_layer(model, layer, batch, one_chip, part="block", seq=8192):
     """One layer of a token model at its published widths (``lfm2``,
     ``deepseek_v3``, ``keye_vl2``: experts held as in the cells, 8 of 64 and
-    16 of 128; ``granitemoehybrid``), ``batch`` sequences of ``seq`` tokens in bf16 with
+    16 of 128; ``granitemoehybrid``; ``ouro``: its second pass's application
+    of the layer), ``batch`` sequences of ``seq`` tokens in bf16 with
     float32 parameters, forward under ``jax.checkpoint`` (per-cell remat, as
     the step runs it) and backward with the loss returned, under a
     ``"highest"`` default and the Pallas path asked for as on a TPU backend;
@@ -658,7 +659,7 @@ def _compiled_layer(model, layer, batch, one_chip, part="block", seq=8192):
 
     import mpi4dl_tpu.config as config
     from mpi4dl_tpu.layer_ctx import ApplyCtx
-    from mpi4dl_tpu.models import deepseek_v3, granitemoehybrid, keye_vl2, lfm2
+    from mpi4dl_tpu.models import deepseek_v3, granitemoehybrid, keye_vl2, lfm2, ouro
     from perfbench import optable
 
     cell = {"lfm2": lambda: lfm2._block(lfm2.PUBLISHED, layer, 8, 0),
@@ -666,7 +667,11 @@ def _compiled_layer(model, layer, batch, one_chip, part="block", seq=8192):
                 deepseek_v3.PUBLISHED, layer, 16, 0),
             "keye_vl2": lambda: keye_vl2._block(keye_vl2.PUBLISHED, layer, 16, 0),
             "granitemoehybrid": lambda: granitemoehybrid._block(
-                granitemoehybrid.PUBLISHED, layer)}[model]()
+                granitemoehybrid.PUBLISHED, layer),
+            # pass 1's application, handed the parameters here
+            "ouro": lambda: ouro.LoopCell(
+                ouro._block(ouro.PUBLISHED, layer), 1, 4, True,
+                f"ut1_layer{layer:02d}")}[model]()
     if part == "ffn":
         cell = cell.ffn
     shape = (batch, seq, 2048)  # every published hidden size
@@ -871,3 +876,29 @@ def test_keye_vl2_layer_compiles_for_v5e_with_its_kernels_in_their_scopes(
                         and "sparse_indexer" in d["scopes"]]
     assert any("1024" in k for k in indexer_products), indexer_products
     _assert_the_routed_layer_carries_route_and_dispatch(said)
+
+
+def test_ouro_layer_compiles_for_v5e_in_its_loop_and_attention_scopes(
+        one_chip, no_persistent_cache):
+    """Ouro's layer as its cell applies it in the second pass, one sequence
+    of 8,192 tokens: ``block_flash`` forward, recomputed and backward compile
+    for the chip in ``attention_core`` on 16 heads of 128 whose keys and
+    values are not repeated (as many key-value heads as query heads), and
+    every product of the layer (the attention's four projections, the MLP's
+    three, forward, recomputed and backward) carries ``ut_loop`` and the
+    pass's ``ut_step1``, which ``ut_loop_ms`` reads."""
+    said = _compiled_layer("ouro", 0, 1, one_chip)
+    _assert_attention_core_is_the_kernel(said)
+    forward = [r["operands"] for r, _ in said
+               if r["name"].startswith("block_flash_fwd")]
+    # the scalar-prefetched offsets, then q, k and v
+    assert len(forward) == 2 and all(
+        ops == ["s32[2]"] + ["bf16[16,8192,128]"] * 3 for ops in forward), forward
+    products = [d for _, d in said if d["cls"] == "product"]
+    assert len(products) >= 3 * 7, [d["key"] for d in products]
+    assert all({"ut_loop", "ut_step1"} <= set(d["scopes"]) for d in products), [
+        (d["key"], d["scopes"]) for d in products
+        if not {"ut_loop", "ut_step1"} <= set(d["scopes"])]
+    assert any("5632" in d["key"] for d in products)
+    kernels = [d for r, d in said if r["name"].startswith("block_flash_")]
+    assert all("ut_loop" in d["scopes"] for d in kernels)
