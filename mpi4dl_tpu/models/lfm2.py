@@ -202,6 +202,8 @@ class Attention(Layer):
         return q, k, heads("v", self.kv_heads)
 
     def apply(self, params, x, ctx):
+        from mpi4dl_tpu.ops.pallas_attention import (
+            LOCAL_TILES, causal_tile_split)
         from mpi4dl_tpu.ops.ring import _resolve_flash, ring_attention
 
         parts = self._parts()
@@ -210,9 +212,13 @@ class Attention(Layer):
         rep = self.heads // self.kv_heads
         if rep > 1:
             k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-        recorder().note_site(
-            "attention", self,
-            "block_flash" if _resolve_flash(None) else "einsum")
+        flash = _resolve_flash(None)
+        recorder().note_site("attention", self,
+                             "block_flash" if flash else "einsum")
+        if flash:  # the forward kernel's live tiles that fold whole, in %
+            whole, diagonal, _ = causal_tile_split(s, s, *LOCAL_TILES)
+            recorder().note_site("flash_whole_tile_pct", self,
+                                 str(round(100 * whole / (whole + diagonal))))
 
         scale = self.head_dim ** -0.5 if self.scale is None else self.scale
 

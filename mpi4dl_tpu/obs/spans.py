@@ -99,7 +99,9 @@ CONV_PATHS = ("wfold", "hstripe", "phase", "xla", "dot")
 # of models/keye_vl2.py's Pallas sparse attention share one decoded plane of
 # the selection in a grid step of ``sparse_flash_fwd`` (the key-value group);
 # ``ut_loop``, how many cells of models/ouro.py apply a held layer (its
-# passes, ``total_ut_steps``).
+# passes, ``total_ut_steps``); ``flash_whole_tile_pct``, the share in percent
+# of the live tiles of ``block_flash_fwd`` that fold whole (no mask, no
+# guard) in a call of models/lfm2.Attention's Pallas path.
 SITE_PATHS = {
     "conv": CONV_PATHS,
     "norm": ("folded", "plain"),
@@ -112,6 +114,7 @@ SITE_PATHS = {
     "sparse_indexer": ("pallas", "xla"),
     "sparse_plane_heads": None,
     "ut_loop": None,
+    "flash_whole_tile_pct": None,
 }
 
 # At least 4,000 steps of the loop's spans (nine a step with the loader's).
@@ -386,7 +389,7 @@ class Recorder:
         and, where the model has such sites, ``attention_paths``,
         ``expert_paths``, ``shared_expert_paths``, ``ssm_scan_paths``,
         ``tied_head_paths``, ``sparse_indexer_paths``,
-        ``sparse_plane_heads`` and ``ut_loop``."""
+        ``sparse_plane_heads``, ``ut_loop`` and ``flash_whole_tile_pct``."""
         spans = sorted((s for s in list(self._closed)
                         if s.name.startswith(SETUP_PREFIXES)),
                        key=lambda s: s.start_ns)
@@ -422,7 +425,8 @@ class Recorder:
                           ("tied_head_paths", "tied_head"),
                           ("sparse_indexer_paths", "sparse_indexer"),
                           ("sparse_plane_heads", "sparse_plane_heads"),
-                          ("ut_loop", "ut_loop")):
+                          ("ut_loop", "ut_loop"),
+                          ("flash_whole_tile_pct", "flash_whole_tile_pct")):
             paths = self.site_paths(kind)
             if paths:
                 out[key] = paths

@@ -61,13 +61,16 @@ def _flash_case(dtype: str, causal: bool):
 
         dt = jnp.dtype(dtype)
         # Grid (2, 3, 3): batch·heads edge-only, q/k dims with interior
-        # points; Tk=300 exercises the padded-key masking tail.
-        q = jnp.zeros((2, 48, 64), dt)
+        # points; Tk=300 exercises the padded-key masking tail.  Under
+        # causal 300 queries in tiles of 128 too: three tiles whole, three
+        # on the diagonal (masked) and three past it (skipped, their k and
+        # v index maps clamped to the last live tile).
+        q = jnp.zeros((2, 300 if causal else 48, 64), dt)
         k = jnp.zeros((2, 300, 64), dt)
         v = jnp.zeros((2, 300, 64), dt)
         z = jnp.zeros((), jnp.int32)
         fn = lambda q, k, v: block_flash(  # noqa: E731
-            q, k, v, z, z, causal, 0.125, 16, 128, False
+            q, k, v, z, z, causal, 0.125, 128 if causal else 16, 128, False
         )
         return fn, (q, k, v)
 

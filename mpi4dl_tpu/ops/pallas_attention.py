@@ -84,6 +84,43 @@ def _out_structs(operands, shapes_dtypes):
         return [jax.ShapeDtypeStruct(s, d) for s, d in shapes_dtypes]
 
 
+def _fwd_tiles(t_q: int, t_k: int, tq: int, tk: int):
+    """The forward kernel's tiles over ``t_q`` queries and ``t_k`` keys when
+    ``(tq, tk)`` are asked for: no more rows than the lengths take in whole
+    sublanes and lanes."""
+    return min(tq, _round_up(t_q, 8)), min(tk, _round_up(t_k, 128))
+
+
+def _tile_kind(q0, k0, ki, *, tq, tk, t_k_real, causal):
+    """``(whole, live)`` of the forward tile whose first query is at GLOBAL
+    position ``q0`` and first key at ``k0``, the ``ki``-th k tile: whole when
+    every query sees every key (its last key at or before its first query,
+    and inside the ``t_k_real`` keys), live when some query sees some key
+    (its last query at or after its first key).  A whole tile folds with no
+    mask and no guard; a live one that is not whole (the diagonal, a ragged
+    tail) with both; a tile that is not live is skipped.  The kernel reads
+    it on traced scalars, :func:`causal_tile_split` on arrays of ints."""
+    whole = True if t_k_real % tk == 0 else (ki + 1) * tk <= t_k_real
+    if not causal:
+        return whole, True
+    return (k0 + tk - 1 <= q0) & whole, q0 + tq - 1 >= k0
+
+
+def causal_tile_split(t_q: int, t_k: int, tq: int, tk: int):
+    """``(whole, diagonal, skipped)``: how many tiles of a causal call of the
+    forward kernel over ``t_q`` queries and ``t_k`` keys at zero offsets,
+    asked for at tiles ``(tq, tk)``, are of each kind (:func:`_tile_kind`);
+    they sum to the grid's tiles of one ``bh`` index."""
+    tq, tk = _fwd_tiles(t_q, t_k, tq, tk)
+    nq, nk = -(-t_q // tq), -(-t_k // tk)
+    ki = np.arange(nk)[None, :]
+    whole, live = (np.broadcast_to(x, (nq, nk)) for x in _tile_kind(
+        np.arange(nq)[:, None] * tq, ki * tk, ki, tq=tq, tk=tk, t_k_real=t_k,
+        causal=True))
+    n_whole, n_diagonal = int(whole.sum()), int((live & ~whole).sum())
+    return n_whole, n_diagonal, nq * nk - n_whole - n_diagonal
+
+
 def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
             acc, m_scr, l_scr, *, tq, tk, nk, causal, t_k_real, scale):
     """One (bh, q-tile, k-tile) step.  Scratch (acc, m, l) persists across
@@ -93,11 +130,17 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
     Both products take their operands as they come (bf16 operands run on
     the MXU as bf16; float32 ones as float32) and accumulate in float32;
-    the scale is applied to the float32 scores.  Under ``causal`` a tile
-    whose every key lies after its last query is skipped: it would add
-    exactly nothing (the guard below), so only the causal half is computed."""
+    the scale is applied to the float32 scores.  A step is of one of three
+    kinds (:func:`_tile_kind`, from the scalar-prefetched GLOBAL offsets):
+    a whole tile folds with no mask and no guard (there the mask is all
+    true and every score finite, so both would be identities); a diagonal
+    or ragged tile folds masked and guarded; under ``causal`` a tile whose
+    every key lies after its last query is skipped, and its k and v blocks
+    are not fetched (the index maps of :func:`_block_flash_fwd_impl`)."""
     ki = pl.program_id(2)
     qi = pl.program_id(1)
+    q0 = offs_ref[0] + qi * tq          # the tile's first query
+    k0 = offs_ref[1] + ki * tk          # and first key
 
     @pl.when(ki == 0)
     def _():
@@ -105,7 +148,7 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    def fold():
+    def fold(masked: bool):
         # DEFAULT, said outright: a caller's "highest" default (the
         # benchmark's check traces under one) asks Mosaic for a float32
         # product of bf16 operands, which it refuses.
@@ -114,25 +157,24 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
             precision=lax.Precision.DEFAULT,
             preferred_element_type=jnp.float32,
         ) * scale
-        col = ki * tk + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-        if t_k_real % tk:
-            s = jnp.where(col < t_k_real, s, _NEG_INF)
-        if causal:
-            q_pos = offs_ref[0] + qi * tq + lax.broadcasted_iota(
-                jnp.int32, (tq, tk), 0
-            )
-            s = jnp.where(q_pos >= offs_ref[1] + col, s, _NEG_INF)
+        if masked:
+            col = ki * tk + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+            if t_k_real % tk:
+                s = jnp.where(col < t_k_real, s, _NEG_INF)
+            if causal:
+                q_pos = q0 + lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+                s = jnp.where(q_pos >= offs_ref[1] + col, s, _NEG_INF)
 
         m_prev = m_scr[:, 0]                        # [TQ]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
         c = jnp.exp(m_prev - m_new)
-        # Guard fully-masked rows: there m_new == _NEG_INF and the naive
-        # exp(s - m_new) = exp(0) = 1 would count every masked key (the
-        # classic flash pitfall — causal ring hops from later devices mask
-        # whole rows).
-        p = jnp.where(
-            s > _NEG_INF * 0.5, jnp.exp(s - m_new[:, None]), 0.0
-        )                                           # [TQ, TK]
+        p = jnp.exp(s - m_new[:, None])             # [TQ, TK]
+        if masked:
+            # Guard fully-masked rows: there m_new == _NEG_INF and the naive
+            # exp(s - m_new) = exp(0) = 1 would count every masked key (the
+            # classic flash pitfall — causal ring hops from later devices
+            # mask whole rows).
+            p = jnp.where(s > _NEG_INF * 0.5, p, 0.0)
         l_new = l_scr[:, 0] * c + jnp.sum(p, axis=-1)
         v = v_ref[0]
         acc[:] = acc[:] * c[:, None] + jax.lax.dot_general(
@@ -143,11 +185,14 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
 
-    if causal:
-        # the tile's last query is at or after its first key
-        pl.when(offs_ref[0] + (qi + 1) * tq - 1 >= offs_ref[1] + ki * tk)(fold)
+    whole, live = _tile_kind(q0, k0, ki, tq=tq, tk=tk, t_k_real=t_k_real,
+                             causal=causal)
+    if whole is True:  # not causal, and no ragged tail: every tile is whole
+        fold(False)
     else:
-        fold()
+        pl.when(whole)(functools.partial(fold, False))
+        pl.when(jnp.logical_and(live, jnp.logical_not(whole)))(
+            functools.partial(fold, True))
 
     @pl.when(ki == nk - 1)
     def _():
@@ -272,8 +317,7 @@ def _block_flash_fwd_impl(q, k, v, q_off, k_off, *, causal, scale,
     bh, t_q, d = q.shape
     _, t_k, _ = k.shape
     dv = v.shape[-1]
-    tq = min(tq, _round_up(t_q, 8))
-    tk = min(tk, _round_up(t_k, 128))
+    tq, tk = _fwd_tiles(t_q, t_k, tq, tk)
     tq_p = _round_up(t_q, tq)
     tk_p = _round_up(t_k, tk)
     d_p, dv_p = _round_up(d, _LANES), _round_up(dv, _LANES)
@@ -285,6 +329,15 @@ def _block_flash_fwd_impl(q, k, v, q_off, k_off, *, causal, scale,
     nq, nk = tq_p // tq, tk_p // tk
     offs = jnp.stack([q_off, k_off]).astype(jnp.int32)
 
+    def tile_of_kv(i, j, offs):
+        """Under ``causal`` a skipped tile asks for the k and v blocks of the
+        last live tile of its q tile (the first, where its queries see no
+        key), the pipeline then copying nothing for it."""
+        if not causal:
+            return j
+        last = lax.div(jnp.maximum(offs[0] + (i + 1) * tq - 1 - offs[1], 0), tk)
+        return jnp.minimum(j, last)
+
     grid = (bh, nq, nk)
     kern = pl.pallas_call(
         functools.partial(_kernel, tq=tq, tk=tk, nk=nk, causal=causal,
@@ -294,8 +347,10 @@ def _block_flash_fwd_impl(q, k, v, q_off, k_off, *, causal, scale,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, tq, d_p), lambda b, i, j, offs: (b, i, 0)),
-                pl.BlockSpec((1, tk, d_p), lambda b, i, j, offs: (b, j, 0)),
-                pl.BlockSpec((1, tk, dv_p), lambda b, i, j, offs: (b, j, 0)),
+                pl.BlockSpec((1, tk, d_p), lambda b, i, j, offs: (
+                    b, tile_of_kv(i, j, offs), 0)),
+                pl.BlockSpec((1, tk, dv_p), lambda b, i, j, offs: (
+                    b, tile_of_kv(i, j, offs), 0)),
             ],
             out_specs=[
                 pl.BlockSpec((1, tq, dv_p), lambda b, i, j, offs: (b, i, 0)),
@@ -443,12 +498,14 @@ def _block_flash_fwd(q, k, v, q_off, k_off, causal, scale, tq, tk, interpret):
 
 
 # Tiles, measured on a v5e at 32 heads of 64 over 8,192 tokens in bf16.  The
-# forward kernel alone (PERF.md) takes 15.9 ms at (256, 512), 13.0 at
-# (512, 512), 8.7 at (512, 1024) and 7.3 at (1024, 1024).  The backward
-# kernel alone, causal (PERF.md, section 6): 7.87 ms at (1024, 1024), 8.26 at
-# (512, 1024), 8.39 at (1024, 512), 9.13 at (512, 512), 12.44 at (256, 512),
-# 7.95 at (2048, 1024), 8.00 at (1024, 2048) and 7.83 at (2048, 2048), which
-# needs a VMEM limit named (below); the einsum tiles it replaced took 20.86.
+# forward alone, causal, its lane pads and slices included (PERF.md, section
+# 6; wall clock, median of 30 calls): 7.45 ms at (1024, 1024), 8.66 at
+# (512, 1024), 12.31 at (1024, 512); (2048, 1024) and (1024, 2048) need more
+# than the compiler's 16 MiB of VMEM.  The backward kernel alone, causal
+# (PERF.md, section 6): 7.87 ms at (1024, 1024), 8.26 at (512, 1024), 8.39 at
+# (1024, 512), 9.13 at (512, 512), 12.44 at (256, 512), 7.95 at (2048, 1024),
+# 8.00 at (1024, 2048) and 7.83 at (2048, 2048), which needs a VMEM limit
+# named (below); the einsum tiles it replaced took 20.86.
 _BWD_TQ, _BWD_TK = 1024, 1024  # the most query, key rows of a backward tile
 # The compiler's own VMEM budget for a kernel on a v5e.  The backward kernel
 # asks for more only where it needs more: a kernel that names any limit

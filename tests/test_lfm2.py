@@ -365,6 +365,32 @@ def test_one_chip_trains_through_build_train_and_run_supervised(tiny):
     assert summary["expert_paths"].get("ragged_dot", 0) >= 2
 
 
+@pytest.mark.parametrize("seq,pct", [(8192, "78"), (300, "0")])
+def test_the_attention_site_counts_the_forward_kernels_whole_tiles(
+        monkeypatch, seq, pct):
+    """``flash_whole_tile_pct``, at trace time on the Pallas path alone: the
+    share in percent of the forward kernel's live tiles that fold whole, 28
+    of 36 at 8,192 tokens in tiles of 1,024; at 300 the one tile is on the
+    diagonal.  The einsum path notes nothing."""
+    import mpi4dl_tpu.config as config
+    from mpi4dl_tpu.obs.spans import recorder
+
+    layer = lfm2.Attention(64, 4, 2, 16, 10000.0, 1e-5)
+    x = jax.ShapeDtypeStruct((1, seq, 64), jnp.bfloat16)
+    params = jax.eval_shape(lambda: layer.init(jax.random.key(0), x.shape)[0])
+    noted = lambda: {path for kind, i, path in recorder()._sites
+                     if kind == "flash_whole_tile_pct" and i == id(layer)}
+    apply = lambda: jax.eval_shape(
+        lambda p, x: layer.apply(p, x, ApplyCtx(train=True)), params, x)
+    apply()
+    assert noted() == set()
+    monkeypatch.setattr(config, "is_tpu_backend", lambda: True)
+    apply()
+    assert noted() == {pct}
+    assert recorder().site_paths("flash_whole_tile_pct")[pct] >= 1
+    assert recorder().summary()["flash_whole_tile_pct"][pct] >= 1
+
+
 def test_gpipe_over_two_stages_gives_the_one_chip_loss(tiny):
     assert len(jax.devices()) >= 2
     _, one, _, _ = _first_losses(ARGV, "lp", jax.devices()[:1])

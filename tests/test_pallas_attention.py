@@ -21,7 +21,7 @@ from jax.sharding import PartitionSpec as P
 from mpi4dl_tpu.mesh import MeshSpec, build_mesh
 from mpi4dl_tpu.ops.pallas_attention import (
     _NEG_INF, _reference_mlo, block_flash, block_flash_backward,
-    flash_attention_local, mlo_merge,
+    causal_tile_split, flash_attention_local, mlo_merge,
 )
 from mpi4dl_tpu.ops.pallas_latent_attention import _forward, latent_flash
 from mpi4dl_tpu.ops.ring import ring_attention
@@ -403,6 +403,207 @@ def test_block_flash_backward_rows_that_see_no_key_give_exactly_nothing():
                                        128, 128, True)
     np.testing.assert_array_equal(dk, np.asarray(dk0))
     np.testing.assert_array_equal(dv, np.asarray(dv0))
+
+
+# --- block_flash's forward kernel --------------------------------------------
+
+
+def _masked_everywhere_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
+                              l_ref, acc, m_scr, l_scr, *, tq, tk, nk, causal,
+                              t_k_real, scale):
+    """The forward kernel as it was before it sorted its tiles: every live
+    tile masked by position and padding and guarded, a tile past the
+    diagonal skipped but its k and v blocks still fetched."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+
+    ki = pl.program_id(2)
+    qi = pl.program_id(1)
+
+    @pl.when(ki == 0)
+    def _():
+        acc[:] = jnp.zeros_like(acc)
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+
+    def fold():
+        s = lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            precision=lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32,
+        ) * scale
+        col = ki * tk + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+        if t_k_real % tk:
+            s = jnp.where(col < t_k_real, s, _NEG_INF)
+        if causal:
+            q_pos = offs_ref[0] + qi * tq + lax.broadcasted_iota(
+                jnp.int32, (tq, tk), 0)
+            s = jnp.where(q_pos >= offs_ref[1] + col, s, _NEG_INF)
+        m_prev = m_scr[:, 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        c = jnp.exp(m_prev - m_new)
+        p = jnp.where(s > _NEG_INF * 0.5, jnp.exp(s - m_new[:, None]), 0.0)
+        l_new = l_scr[:, 0] * c + jnp.sum(p, axis=-1)
+        v = v_ref[0]
+        acc[:] = acc[:] * c[:, None] + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            precision=lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+
+    if causal:
+        pl.when(offs_ref[0] + (qi + 1) * tq - 1 >= offs_ref[1] + ki * tk)(fold)
+    else:
+        fold()
+
+    @pl.when(ki == nk - 1)
+    def _():
+        o_ref[0] = acc[:].astype(o_ref.dtype)
+        m_ref[0] = m_scr[...].astype(m_ref.dtype)
+        l_ref[0] = l_scr[...].astype(l_ref.dtype)
+
+
+def _masked_everywhere_forward(q, k, v, q_off, k_off, causal, scale, tq, tk,
+                               interpret=True):
+    """``(o_hat, m, l)`` of :func:`_masked_everywhere_kernel` (in interpret
+    mode unless told otherwise), on k and v blocks indexed by the k tile
+    alone."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    up = lambda n, m: -(-n // m) * m
+    bh, t_q, d = q.shape
+    t_k, dv = k.shape[1], v.shape[-1]
+    tq_p, tk_p, d_p, dv_p = up(t_q, tq), up(t_k, tk), up(d, 128), up(dv, 128)
+    qp = jnp.pad(q, ((0, 0), (0, tq_p - t_q), (0, d_p - d)))
+    kp = jnp.pad(k, ((0, 0), (0, tk_p - t_k), (0, d_p - d)))
+    vp = jnp.pad(v, ((0, 0), (0, tk_p - t_k), (0, dv_p - dv)))
+    nk = tk_p // tk
+    f32 = jnp.float32
+    rows = lambda w: pl.BlockSpec((1, tq, w), lambda b, i, j, offs: (b, i, 0))
+    keys = lambda w: pl.BlockSpec((1, tk, w), lambda b, i, j, offs: (b, j, 0))
+    o, m, l = pl.pallas_call(
+        functools.partial(_masked_everywhere_kernel, tq=tq, tk=tk, nk=nk,
+                          causal=causal, t_k_real=t_k, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(bh, tq_p // tq, nk),
+            in_specs=[rows(d_p), keys(d_p), keys(dv_p)],
+            out_specs=[rows(dv_p), rows(128), rows(128)],
+            scratch_shapes=[pltpu.VMEM((tq, dv_p), f32),
+                            pltpu.VMEM((tq, 128), f32),
+                            pltpu.VMEM((tq, 128), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((bh, tq_p, dv_p), f32),
+                   jax.ShapeDtypeStruct((bh, tq_p, 128), f32),
+                   jax.ShapeDtypeStruct((bh, tq_p, 128), f32)],
+        interpret=interpret,
+    )(jnp.stack([q_off, k_off]).astype(jnp.int32), qp, kp, vp)
+    return o[:, :t_q, :dv], m[:, :t_q, 0], l[:, :t_q, 0]
+
+
+# The backward's hops, and 300 tokens in tiles of 128 (every kind of tile,
+# a ragged tail) both ways, a hop in which queries 0-99 see no key, and hops
+# whose tiles' first query lies one short of a tile's last key (the tile is
+# not whole) or on it (whole).
+_FWD_CASES = {
+    **_BWD_CASES,
+    "ragged_square": (300, 300, 32, 32, True, 0, 0),
+    "ragged_square_not_causal": (300, 300, 32, 32, False, 0, 0),
+    "rows_see_no_key": (300, 256, 32, 32, True, 0, 100),
+    "one_short_of_whole": (256, 256, 32, 32, True, 126, 0),
+    "just_whole": (256, 256, 32, 32, True, 127, 0),
+}
+
+
+_FWD_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
+
+
+def _forward_both_ways(path):
+    """Every case of ``_FWD_CASES`` in each of ``_FWD_DTYPES``, through
+    ``block_flash`` and through :func:`_masked_everywhere_forward`, at tiles
+    of 128 with the offsets traced as a ring hop hands them over; the six
+    outputs of each saved to ``path`` (``.npz``) under ``<case>-<dtype>``."""
+    out = {}
+    for case, (t_q, t_k, d, dv, causal, q_off, k_off) in _FWD_CASES.items():
+        for name, dtype in _FWD_DTYPES.items():
+            rng = np.random.default_rng(4)
+            q, k, v = (jnp.asarray(rng.standard_normal((3, t, w), np.float32),
+                                   dtype)
+                       for t, w in ((t_q, d), (t_k, d), (t_k, dv)))
+            scale = d ** -0.5
+            offs = jnp.int32(q_off), jnp.int32(k_off)
+            got = jax.jit(lambda q_off, k_off: block_flash(
+                q, k, v, q_off, k_off, causal, scale, 128, 128, True))(*offs)
+            want = jax.jit(lambda q_off, k_off: _masked_everywhere_forward(
+                q, k, v, q_off, k_off, causal, scale, 128, 128))(*offs)
+            for tag, arrays in (("got", got), ("want", want)):
+                for x, a in zip("oml", arrays):
+                    out[f"{case}-{name}-{tag}-{x}"] = np.asarray(a)
+    np.savez(path, **out)
+
+
+@pytest.fixture(scope="module")
+def forward_both_ways(tmp_path_factory):
+    """:func:`_forward_both_ways` in a process of its own whose CPU target
+    has no fused multiply-add: where it has one, XLA rounds an ``exp`` that
+    shares its fusion with a ``select`` differently from one that does not
+    (by an ulp), which says nothing of the kernels; without it every
+    operation rounds once, as written."""
+    import os
+    import subprocess
+    import sys
+
+    path = tmp_path_factory.mktemp("forward") / "both.npz"
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=(
+        os.environ.get("XLA_FLAGS", "") + " --xla_cpu_max_isa=SSE4_2").strip(),
+        PYTHONPATH=os.pathsep.join([os.path.dirname(here), here]))
+    subprocess.run([sys.executable, "-c",
+                    "import sys, test_pallas_attention as t; "
+                    "t._forward_both_ways(sys.argv[1])", str(path)],
+                   env=env, check=True, timeout=600)
+    return np.load(path)
+
+
+@pytest.mark.parametrize("dtype", sorted(_FWD_DTYPES))
+@pytest.mark.parametrize("case", sorted(_FWD_CASES))
+def test_block_flash_forward_is_the_masked_everywhere_kernel_bit_for_bit(
+        forward_both_ways, case, dtype):
+    """The forward kernel, which folds a whole tile with no mask and no
+    guard and fetches no k or v for a skipped one, against the kernel that
+    masked every live tile: ``(o_hat, m, l)`` equal bit for bit, in
+    interpret mode, causal and not, aligned and ragged, on ring hops whose
+    queries see all of the keys, part of them or none, and on one whose
+    first 100 queries see no key (``l`` 0 there, positive elsewhere)."""
+    for x in "oml":
+        got = forward_both_ways[f"{case}-{dtype}-got-{x}"]
+        want = forward_both_ways[f"{case}-{dtype}-want-{x}"]
+        assert got.shape == want.shape and got.dtype == want.dtype == np.float32
+        np.testing.assert_array_equal(got, want)
+    if case == "rows_see_no_key":
+        l = forward_both_ways[f"{case}-{dtype}-got-l"]
+        assert not np.any(l[:, :100]) and np.all(l[:, 100:] > 0)
+
+
+@pytest.mark.parametrize("lengths,tiles,split", [
+    ((8192, 8192), (1024, 1024), (28, 8, 28)),
+    ((300, 300), (128, 128), (3, 3, 3)),
+    ((300, 200), (128, 128), (2, 3, 1)),
+    ((200, 300), (128, 128), (1, 2, 3)),
+    ((50, 50), (1024, 1024), (0, 1, 0)),
+], ids=["8192", "300", "300x200", "200x300", "50"])
+def test_causal_tile_split_counts_the_kernels_tiles_by_kind(lengths, tiles,
+                                                            split):
+    """Whole, diagonal and skipped tiles of a causal call at zero offsets:
+    at 8,192 tokens in tiles of 1,024, 28 of the 36 live tiles fold whole;
+    under ragged lengths the three kinds sum to the grid of the tiles the
+    kernel takes (at most the lengths in whole sublanes and lanes)."""
+    got = causal_tile_split(*lengths, *tiles)
+    assert got == split
+    tq, tk = min(tiles[0], -(-lengths[0] // 8) * 8), min(
+        tiles[1], -(-lengths[1] // 128) * 128)
+    assert sum(got) == -(-lengths[0] // tq) * -(-lengths[1] // tk)
 
 
 # --- latent attention on the projections' layout ------------------------------
