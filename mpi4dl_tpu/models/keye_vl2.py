@@ -252,9 +252,8 @@ def _sparse_fwd(q, k, v, iq, ik, w, topk, scale, flash):
         # the key-value heads as they are: a grid step of the kernel reads
         # one k and one v block for the query heads of their group
         with scope("attention_core"):
-            o_hat, m, l = pallas_attention.sparse_flash_forward(
+            o, m, l = pallas_attention.sparse_flash_forward(
                 _fold(q), _fold(k), _fold(v), words, heads=h, scale=scale)
-        o = o_hat / jnp.maximum(l, 1e-30)[..., None]
         out = o.reshape(b, h, t, -1).transpose(0, 2, 1, 3).astype(q.dtype)
         rows = (m, l, o)
     else:
@@ -272,25 +271,21 @@ def _sparse_bwd(topk, scale, flash, res, cts):
     do = cts[0]
     b, t, h, d = q.shape
     kv = k.shape[2]
-    rep = h // kv
     inv_n = 1.0 / (b * t)
     if flash:
         m, l, o = rows
-        l = jnp.maximum(l, 1e-30)
-        dof = _fold(do).astype(jnp.float32)
-        kf, vf = (_fold(jnp.repeat(x, rep, axis=2)) for x in (k, v))
+        # the key-value heads as they are, dô as it comes: the kernel divides
+        # by l, computes Δ = Σ dô·o and sums dk and dv over a group's heads
         with scope("attention_core"):
             dq, dk, dv = pallas_attention.sparse_flash_backward(
-                _fold(q), kf, vf, m, dof / l[..., None],
-                -jnp.sum(dof * o, axis=-1) / l, words_t, heads=h, scale=scale)
-        unfold = lambda x: x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
-        group = lambda x: unfold(x).reshape(b, t, kv, rep, d).astype(
-            jnp.float32).sum(axis=3).astype(k.dtype)
-        dq, dk, dv = unfold(dq), group(dk), group(dv)
+                _fold(q), _fold(k), _fold(v), o, m, l, _fold(do), words_t,
+                heads=h, scale=scale)
+        unfold = lambda x, n: x.reshape(b, n, t, d).transpose(0, 2, 1, 3)
+        dq, dk, dv = unfold(dq, h), unfold(dk, kv), unfold(dv, kv)
         with scope("sparse_indexer"):
             diq, dik, dw = sparse_indexer.indexer_backward(
-                q, k, (m + jnp.log(l)).reshape(b, h, t), iq, ik, w, words_t,
-                lse, scale=scale, inv_n=inv_n)
+                q, k, (m + jnp.log(jnp.maximum(l, 1e-30))).reshape(b, h, t),
+                iq, ik, w, words_t, lse, scale=scale, inv_n=inv_n)
     else:
         sel = sparse_indexer.unpack_selection(jnp.swapaxes(words_t, 1, 2), t)
         with scope("attention_core"):
@@ -337,9 +332,9 @@ class SparseAttention(Layer):
         rec.note_site("sparse_indexer", self.indexer,
                       "pallas" if flash else "xla")
         att = self.attention
-        if flash:  # the query heads that share a plane: the kernel's group
-            rec.note_site("sparse_plane_heads", self,
-                          str(att.heads // att.kv_heads))
+        if flash:  # the query heads that share a plane: the kernels' group
+            for kind in ("sparse_plane_heads", "sparse_bwd_plane_heads"):
+                rec.note_site(kind, self, str(att.heads // att.kv_heads))
         b, s, _ = x.shape
         q, k, v = att.project(params, x, ctx)
         with scope("sparse_indexer"):

@@ -97,7 +97,8 @@ CONV_PATHS = ("wfold", "hstripe", "phase", "xla", "dot")
 # a folded run (``layers.run_fold``), or ``[N, H, W, C]``.  A kind whose
 # paths are None takes any path: ``sparse_plane_heads``, how many query heads
 # of models/keye_vl2.py's Pallas sparse attention share one decoded plane of
-# the selection in a grid step of ``sparse_flash_fwd`` (the key-value group);
+# the selection in a grid step of ``sparse_flash_fwd`` (the key-value group),
+# and ``sparse_bwd_plane_heads``, the same of ``sparse_flash_bwd``;
 # ``ut_loop``, how many cells of models/ouro.py apply a held layer (its
 # passes, ``total_ut_steps``); ``flash_whole_tile_pct``, the share in percent
 # of the live tiles of ``block_flash_fwd`` that fold whole (no mask, no
@@ -113,6 +114,7 @@ SITE_PATHS = {
     "tied_head": ("table_transposed",),
     "sparse_indexer": ("pallas", "xla"),
     "sparse_plane_heads": None,
+    "sparse_bwd_plane_heads": None,
     "ut_loop": None,
     "flash_whole_tile_pct": None,
 }
@@ -389,7 +391,8 @@ class Recorder:
         and, where the model has such sites, ``attention_paths``,
         ``expert_paths``, ``shared_expert_paths``, ``ssm_scan_paths``,
         ``tied_head_paths``, ``sparse_indexer_paths``,
-        ``sparse_plane_heads``, ``ut_loop`` and ``flash_whole_tile_pct``."""
+        ``sparse_plane_heads``, ``sparse_bwd_plane_heads``, ``ut_loop`` and
+        ``flash_whole_tile_pct``."""
         spans = sorted((s for s in list(self._closed)
                         if s.name.startswith(SETUP_PREFIXES)),
                        key=lambda s: s.start_ns)
@@ -425,6 +428,7 @@ class Recorder:
                           ("tied_head_paths", "tied_head"),
                           ("sparse_indexer_paths", "sparse_indexer"),
                           ("sparse_plane_heads", "sparse_plane_heads"),
+                          ("sparse_bwd_plane_heads", "sparse_bwd_plane_heads"),
                           ("ut_loop", "ut_loop"),
                           ("flash_whole_tile_pct", "flash_whole_tile_pct")):
             paths = self.site_paths(kind)

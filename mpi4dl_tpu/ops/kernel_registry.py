@@ -151,21 +151,21 @@ def _sparse_flash_case(backward: bool):
         # Three heads a sequence share its selection's words (128 a query:
         # at 300 tokens a forward tile of 128 keys is one bit; the
         # backward's own three tiles of 768 over 2,300 tokens, a zero-padded
-        # tail in the last, are six).  The forward's grid (2, 3, 3) is one
-        # key-value group of the three a sequence, on k and v as they are;
-        # the backward's (2·3, 3, 3) a head, on k and v repeated.
+        # tail in the last, are six).  Both grids, (2, 3, 3), are one
+        # key-value group of the three a sequence, on k and v as they are.
         t = 2300 if backward else _SPARSE_TOKENS
         q = jnp.zeros((6, t, 64), jnp.bfloat16)
+        kv = jnp.zeros((2, t, 64), jnp.bfloat16)
         words = jnp.zeros((2, t, 128), jnp.int32)
         if not backward:
-            kv = jnp.zeros((2, t, 64), jnp.bfloat16)
             fn = lambda q, k, v, words: sparse_flash_forward(  # noqa: E731
                 q, k, v, words, heads=3, scale=0.125, tq=128, tk=128)
             return fn, (q, kv, kv, words)
+        o = jnp.zeros((6, t, 64), jnp.float32)
         stat = jnp.zeros((6, t), jnp.float32)
-        fn = lambda q, k, v, m, do, dl, w: sparse_flash_backward(  # noqa: E731
-            q, k, v, m, do, dl, w, heads=3, scale=0.125)
-        return fn, (q, q, q, stat, q, stat, jnp.swapaxes(words, 1, 2))
+        fn = lambda *a: sparse_flash_backward(  # noqa: E731
+            *a, heads=3, scale=0.125)
+        return fn, (q, kv, kv, o, stat, stat, q, jnp.swapaxes(words, 1, 2))
 
     name = "sparse_flash_backward" if backward else "sparse_flash_forward"
     return KernelCase(name=f"{name}:causal:bfloat16", build=build)

@@ -234,7 +234,8 @@ def _sparse_kernel(q_ref, k_ref, v_ref, words_ref, o_ref, m_ref, l_ref,
     products are :func:`_kernel`'s, in the same k-tile order.  The group's
     block of ``o_hat`` (``o_ref``, float32) stays in VMEM across the k tiles
     and is the accumulator; m and l of each head are kept in scratch and
-    written at the last k tile."""
+    written at the last k tile, where ``o_hat`` is divided by l (the sum of a
+    row that sees no key taken as 1e-30)."""
     ki = pl.program_id(2)
     qi = pl.program_id(1)
 
@@ -278,6 +279,8 @@ def _sparse_kernel(q_ref, k_ref, v_ref, words_ref, o_ref, m_ref, l_ref,
     def _():
         m_ref[...] = m_scr[...]
         l_ref[...] = l_scr[...]
+        for h in range(rep):
+            o_ref[h] = o_ref[h] / jnp.maximum(l_scr[h, :, :1], 1e-30)
 
 
 def _sparse_fwd_vmem_bytes(rep, tq, tk, d, dv, width, itemsize):
@@ -397,17 +400,20 @@ def _reference_mlo(q, k, v, q_off, k_off, causal, scale):
 def sparse_flash_forward(q, k, v, words, *, heads, scale,
                          tq=LOCAL_TILES_SPARSE[0], tk=LOCAL_TILES_SPARSE[1],
                          interpret=False):
-    """The block state ``(o_hat, m, l)`` of causal attention in which query
-    ``t`` of a sequence sees only the keys its row of ``words`` [B, Tq, W]
-    selects (:func:`selection_width`), all ``heads`` of the sequence alike:
+    """Causal attention in which query ``t`` of a sequence sees only the
+    keys its row of ``words`` [B, Tq, W] selects (:func:`selection_width`),
+    all ``heads`` of the sequence alike, as ``(o, m, l)``:
     ``q`` [B·heads, Tq, D] and the key-value heads ``k`` [B·KV, Tk, D], ``v``
     [B·KV, Tk, Dv], query head ``h`` reading key-value head ``h // (heads //
     KV)`` (``jnp.repeat``'s order).  Positions are the sequence's own.  The
     kernel is :func:`_sparse_kernel`, ``sparse_flash_fwd``, over the grid
     (B·KV, q tile, k tile): a step reads one k and one v block for the
     group's heads and decodes one plane of the selection for them all.
-    ``o_hat`` [B·heads, Tq, Dv], m and l [B·heads, Tq], in float32.  Its
-    backward is :func:`sparse_flash_backward`."""
+    The output ``o`` = ``o_hat / l`` [B·heads, Tq, Dv] and the row maxima
+    and sums m and l [B·heads, Tq], in float32 (the kernel divides: XLA's
+    division, a broadcast of l to every feature, cost the Keye step's heap
+    0.69 GiB; PERF.md, section 6).  Its backward is
+    :func:`sparse_flash_backward`."""
     bh, t_q, d = q.shape
     bkv, t_k, _ = k.shape
     dv = v.shape[-1]
@@ -544,7 +550,9 @@ def _bwd_vmem_bytes(tq, tk, tq_p, d, dv, itemsize):
     return resident + blocks + 10 * tq * tk
 
 
-def _bwd_kernel(*refs, tq, tk, nq, nk, causal, t_k_real, scale, width=None):
+def _bwd_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, m_ref, dl_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                tq, tk, nq, nk, causal, t_k_real, scale):
     """One (bh, k-tile, q-tile) step of the backward on FEATURE-MAJOR blocks
     (``q_ref`` [1, D, TQ], ``k_ref`` [1, D, TK], …: tokens along the lanes),
     the scores transposed (keys on the sublanes, queries along the lanes,
@@ -553,15 +561,7 @@ def _bwd_kernel(*refs, tq, tk, nq, nk, causal, t_k_real, scale, width=None):
     ``dq_acc`` holds the whole sequence of the bh index across both: a q
     tile's columns are zeroed at the first k tile and written after the last
     one its queries see.  Positions are GLOBAL (``offs_ref``: the q and k
-    offsets), as in the forward kernel.  ``width`` as in :func:`_kernel`,
-    the words transposed (``[1, W, TQ]``: a tile's selection comes out as
-    its scores do, keys on the sublanes)."""
-    if width is None:
-        (offs_ref, q_ref, k_ref, v_ref, do_ref, m_ref, dl_ref,
-         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
-    else:
-        (offs_ref, q_ref, k_ref, v_ref, do_ref, m_ref, dl_ref, words_ref,
-         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc) = refs
+    offsets), as in the forward kernel."""
     ki, qi = pl.program_id(1), pl.program_id(2)
     cols = pl.ds(pl.multiple_of(qi * tq, tq), tq)
     q0 = offs_ref[0] + qi * tq          # the tile's first query
@@ -588,8 +588,6 @@ def _bwd_kernel(*refs, tq, tk, nq, nk, causal, t_k_real, scale, width=None):
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         s = product(k, q, (0, 0)) * scale               # [TK, TQ]
         p = jnp.exp(s - m_ref[0])                       # m: [1, TQ]
-        if width is not None:
-            p = jnp.where(_plane(words_ref[0], ki, tk, width, 0), p, 0.0)
         if masked:
             key = ki * tk + lax.broadcasted_iota(jnp.int32, (tk, tq), 0)
             mask = key < t_k_real
@@ -742,81 +740,208 @@ def block_flash_backward(q, k, v, q_off, k_off, m, do, dl, causal, scale,
             jnp.swapaxes(dv_[..., :t_k], 1, 2))
 
 
-@functools.partial(_traced_once, static_argnums=(7, 8, 9, 10, 11))
-def _sparse_bwd(q, k, v, m, do, dl, words_t, heads, scale, tq, tk, interpret):
-    """:func:`block_flash_backward`'s kernel under a key selection
-    (:func:`sparse_flash_backward`), ``sparse_flash_bwd``."""
+def _sparse_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, m_ref, l_ref,
+                       words_ref, dq_ref, dk_ref, dv_ref,
+                       dq_acc, dk_acc, dv_acc, dos, dl, *,
+                       rep, tq, tk, nq, nk, t_k_real, scale, width):
+    """One (key-value group, q tile, k tile) step of
+    :func:`sparse_flash_backward`: the ``rep`` query heads of a group
+    (``q_ref``, ``do_ref`` [rep, D, TQ]) against the group's one k and one v
+    block (``[1, D, TK]``), FEATURE-MAJOR and the scores transposed as in
+    :func:`_bwd_kernel`, whose products, in the same order, each head makes.
+
+    At a q tile's first k tile (which every causal q tile sees) each head's
+    ``dô / l`` goes to ``dos`` in the operands' dtype and ``−Δ / l``, with
+    Δ = Σ dô·o over a query's features (``o_ref`` [rep, TQ, Dv], turned to
+    features on the sublanes), to ``dl``.  The selection's bits of the tile
+    (:func:`_plane` of the words of its queries), with the causal and the
+    padded keys' masks on a tile that needs them, make one ``[TK, TQ]``
+    plane that each head applies once to its ``p``.  ``dq_acc`` holds the
+    group's q tile across the k tiles and is written after the last its
+    queries see; ``dk_acc`` and ``dv_acc`` hold the group's whole sequence,
+    summed over its heads and the q tiles in float32, and leave a k tile at
+    a time at the last q tile, which sees every k tile."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    f32 = jnp.float32
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        for h in range(rep):
+            l = jnp.maximum(l_ref[h], 1e-30)                # [1, TQ]
+            do = do_ref[h].astype(f32)                      # [Dv, TQ]
+            dos[h] = (do / l).astype(dos.dtype)
+            dl[h] = -jnp.sum(do * o_ref[h].astype(f32).T, axis=0,
+                             keepdims=True) / l
+
+    def product(a, b, contract):
+        # DEFAULT, said outright: as in the forward kernel
+        return lax.dot_general(
+            a, b, ((contract[:1], contract[1:]), ((), ())),
+            precision=lax.Precision.DEFAULT,
+            preferred_element_type=f32)
+
+    def fold(masked: bool):
+        k, v = k_ref[0], v_ref[0]
+        plane = _plane(words_ref[0], j, tk, width, 0)       # [TK, TQ]
+        if masked:
+            key = j * tk + lax.broadcasted_iota(jnp.int32, (tk, tq), 0)
+            plane = plane & (
+                i * tq + lax.broadcasted_iota(jnp.int32, (tk, tq), 1) >= key)
+            if t_k_real % tk:
+                plane = plane & (key < t_k_real)
+        # a loop of two heads an iteration: on a v5e at the Keye cell's shape
+        # the call took 59.3 ms with the eight unrolled, 65.6 with four a
+        # loop iteration, 35.3 with two and 35.8 with one (PERF.md, section 6)
+        per = 2 if rep % 2 == 0 else 1
+
+        def heads(t, carry):
+            for u in range(per):
+                h = t * per + u
+                q = q_ref[h]
+                s = product(k, q, (0, 0)) * scale           # [TK, TQ]
+                # also a row without a visible key: m = _NEG_INF there, and
+                # exp(s − m) would count every masked key
+                p = jnp.where(plane, jnp.exp(s - m_ref[h]), 0.0)
+                ds = (p * (product(v, dos[h], (0, 0)) + dl[h])).astype(
+                    q.dtype)
+                dv_acc[j] += product(dos[h], p.astype(dos.dtype), (1, 1))
+                dk_acc[j] += product(q, ds, (1, 1))
+                dq_acc[h] += product(k, ds, (1, 0))
+            return carry
+
+        lax.fori_loop(0, rep // per, heads, None)
+
+    # as _bwd_kernel: a tile all of whose keys every one of its queries sees
+    # needs no mask; one whose first key lies after its last query is skipped
+    whole = (j + 1) * tk - 1 <= i * tq
+    if t_k_real % tk:
+        whole = jnp.logical_and(whole, (j + 1) * tk <= t_k_real)
+    pl.when(whole)(functools.partial(fold, False))
+    pl.when(jnp.logical_and(jnp.logical_not(whole),
+                            (i + 1) * tq - 1 >= j * tk))(
+        functools.partial(fold, True))
+
+    @pl.when(j == jnp.minimum(((i + 1) * tq - 1) // tk, nk - 1))
+    def _():
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+    @pl.when(i == nq - 1)
+    def _():
+        dk_ref[0] = (dk_acc[j] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[j].astype(dv_ref.dtype)
+
+
+def _sparse_bwd_vmem_bytes(rep, tq, tk, tk_p, d, dv, width, itemsize):
+    """An upper bound of the VMEM :func:`_sparse_bwd_kernel` takes: the
+    blocks of a grid step double buffered (the group's q, dô and dq, o in
+    float32, m and l a row a head padded to eight sublanes, one k and one v
+    block, dk's and dv's, the words of its queries), the group's dq, dk and
+    dv sums in float32 and ``dô / l`` and ``−Δ / l`` in scratch, and about
+    sixteen bytes an element of one head's [TK, TQ] tile for the plane, the
+    scores and their kin.  Fitted to the limit under which the kernel
+    compiled for a v5e at the Keye cell's shape (PERF.md, section 6): 62.85
+    MiB at tiles of (1024, 1024) (this gives 65.25)."""
+    blocks = (itemsize * (2 * rep * d * tq + rep * dv * tq + (d + dv) * 2 * tk)
+              + 4 * rep * tq * dv + 2 * 4 * rep * 8 * tq + 4 * width * tq)
+    scratch = (4 * (rep * d * tq + (d + dv) * tk_p + rep * 8 * tq)
+               + itemsize * rep * dv * tq)
+    return 2 * blocks + scratch + 16 * tq * tk
+
+
+@functools.partial(_traced_once, static_argnums=(8, 9, 10, 11, 12))
+def _sparse_bwd(q, k, v, o, m, l, do, words_t, heads, scale, tq, tk,
+                interpret):
+    """:func:`sparse_flash_backward` at tiles ``(tq, tk)``."""
     bh, t_q, d = q.shape
-    t_k, dv = k.shape[1], v.shape[-1]
+    bkv, t_k, _ = k.shape
+    dv = v.shape[-1]
     width = words_t.shape[1]
+    rep = bh // bkv
+    kv = heads // rep
+    assert bkv * rep == bh and kv * rep == heads, (bh, bkv, heads)
     assert tk % width == 0, (tk, width)
     f32 = jnp.float32
     tq_p, tk_p = _round_up(t_q, tq), _round_up(t_k, tk)
     assert tk_p <= 32 * width, (t_k, width)
     tokens_last = lambda x, t_p: jnp.pad(
         jnp.swapaxes(x, 1, 2), ((0, 0), (0, 0), (0, t_p - x.shape[1])))
-    q, do = tokens_last(q, tq_p), tokens_last(do.astype(q.dtype), tq_p)
+    q, do = tokens_last(q, tq_p), tokens_last(do, tq_p)
     k, v = tokens_last(k, tk_p), tokens_last(v, tk_p)
-    m, dl = (jnp.pad(x.astype(f32), ((0, 0), (0, tq_p - t_q)))[:, None, :]
-             for x in (m, dl))
+    o = jnp.pad(o, ((0, 0), (0, tq_p - t_q), (0, 0)))
+    m, l = (jnp.pad(x.astype(f32), ((0, 0), (0, tq_p - t_q)))[:, None, :]
+            for x in (m, l))
     words_t = jnp.pad(words_t, ((0, 0), (0, 0), (0, tq_p - words_t.shape[2])))
     nq, nk = tq_p // tq, tk_p // tk
-    offs = jnp.zeros((2,), jnp.int32)
-    vmem = (_bwd_vmem_bytes(tq, tk, tq_p, d, dv, q.dtype.itemsize)
-            + 2 * 4 * width * tq + 4 * tk * tq)
+    vmem = _sparse_bwd_vmem_bytes(rep, tq, tk, tk_p, d, dv, width,
+                                  q.dtype.itemsize)
 
-    def tile_of_q(j, i):
-        first = lax.div(j * tk, tq)  # causal: the first q tile that sees j
-        return jnp.maximum(i, jnp.minimum(first, nq - 1))
+    def tile_of_k(i, j):
+        """A tile above the diagonal (skipped) asks for the k tile of the
+        last that is not, the pipeline then copying nothing for it."""
+        return jnp.minimum(j, lax.div((i + 1) * tq - 1, tk))
 
-    of_q = lambda w: pl.BlockSpec(
-        (1, w, tq), lambda b, j, i, *_: (b, 0, tile_of_q(j, i)))
-    of_k = lambda w: pl.BlockSpec((1, w, tk), lambda b, j, i, *_: (b, 0, j))
+    of_q = lambda w: pl.BlockSpec((rep, w, tq), lambda g, i, j: (g, 0, i))
+    of_k = lambda w: pl.BlockSpec(
+        (1, w, tk), lambda g, i, j: (g, 0, tile_of_k(i, j)))
+    # dk and dv leave a k tile at a time at the last q tile, and stand on the
+    # first before it (the pipeline writes a block back when it moves on)
+    of_dk = lambda w: pl.BlockSpec((1, w, tk), lambda g, i, j: (
+        g, 0, jnp.where(i == nq - 1, j, 0)))
     dq, dk, dv_ = pl.pallas_call(
-        functools.partial(_bwd_kernel, tq=tq, tk=tk, nq=nq, nk=nk,
-                          causal=True, t_k_real=t_k, scale=scale,
-                          width=width),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, nk, nq),
-            in_specs=[of_q(d), of_k(d), of_k(dv), of_q(dv), of_q(1), of_q(1),
-                      pl.BlockSpec((1, width, tq), lambda b, j, i, *_: (
-                          b // heads, 0, tile_of_q(j, i)))],
-            out_specs=[
-                pl.BlockSpec((1, d, tq_p), lambda b, j, i, *_: (b, 0, 0),
-                             pipeline_mode=pl.Buffered(1)),
-                of_k(d), of_k(dv),
-            ],
-            scratch_shapes=[pltpu.VMEM((d, tq_p), f32),
-                            pltpu.VMEM((d, tk), f32),
-                            pltpu.VMEM((dv, tk), f32)],
-        ),
+        functools.partial(_sparse_bwd_kernel, rep=rep, tq=tq, tk=tk, nq=nq,
+                          nk=nk, t_k_real=t_k, scale=scale, width=width),
+        grid=(bkv, nq, nk),
+        in_specs=[of_q(d), of_k(d), of_k(dv),
+                  pl.BlockSpec((rep, tq, dv), lambda g, i, j: (g, i, 0)),
+                  of_q(dv), of_q(1), of_q(1),
+                  pl.BlockSpec((1, width, tq), lambda g, i, j: (g // kv, 0, i))],
+        out_specs=[of_q(d), of_dk(d), of_dk(dv)],
+        scratch_shapes=[pltpu.VMEM((rep, d, tq), f32),
+                        pltpu.VMEM((nk, d, tk), f32),
+                        pltpu.VMEM((nk, dv, tk), f32),
+                        pltpu.VMEM((rep, dv, tq), q.dtype),
+                        pltpu.VMEM((rep, 1, tq), f32)],
         out_shape=[jax.ShapeDtypeStruct((bh, d, tq_p), q.dtype),
-                   jax.ShapeDtypeStruct((bh, d, tk_p), k.dtype),
-                   jax.ShapeDtypeStruct((bh, dv, tk_p), v.dtype)],
+                   jax.ShapeDtypeStruct((bkv, d, tk_p), k.dtype),
+                   jax.ShapeDtypeStruct((bkv, dv, tk_p), v.dtype)],
         compiler_params=(
             None if vmem <= _DEFAULT_VMEM
             else pltpu.CompilerParams(vmem_limit_bytes=vmem + 2 ** 21)),
         interpret=interpret,
         name="sparse_flash_bwd",
-    )(offs, q, k, v, do, m, dl, words_t)
+    )(q, k, v, o, do, m, l, words_t)
     return (jnp.swapaxes(dq[..., :t_q], 1, 2), jnp.swapaxes(dk[..., :t_k], 1, 2),
             jnp.swapaxes(dv_[..., :t_k], 1, 2))
 
 
-def sparse_flash_backward(q, k, v, m, do, dl, words_t, *, heads, scale,
-                          interpret=False):
-    """:func:`sparse_flash_forward`'s backward, ``(dq, dk, dv)`` from the row
-    maxima ``m`` and the cotangents ``do`` of ``o_hat`` and ``dl`` of ``l``:
-    :func:`block_flash_backward`'s kernel (its docstring has the
-    mathematics), each tile's scores masked by the selection, on the words
+def sparse_flash_backward(q, k, v, o, m, l, do, words_t, *, heads, scale,
+                          tq=None, tk=None, interpret=False):
+    """:func:`sparse_flash_forward`'s backward, from its operands (``q``
+    [B·heads, Tq, D], the key-value heads ``k`` [B·KV, Tk, D] and ``v`` [B·KV,
+    Tk, Dv] as they are), the normalized output ``o`` = ``o_hat`` / l
+    [B·heads, Tq, Dv], the row maxima ``m`` and sums ``l`` [B·heads, Tq], the
+    cotangent ``do`` of ``o`` in the dtype the caller has it, and the words
     transposed (``words_t`` [B, W, Tq]: a tile's selection comes out keys on
-    the sublanes, as its scores do), at tiles of its own that are whole
-    multiples of the selection's width."""
-    return _sparse_bwd(q, k, v, m, do, dl, words_t, heads, scale,
-                       _bwd_tile(q.shape[1], _BWD_TQ),
-                       _round_up(_bwd_tile(k.shape[1], _BWD_TK),
-                                 words_t.shape[1]), interpret)
+    the sublanes, as its scores do): ``(dq, dk, dv)`` in the operands'
+    shapes and dtypes.  With ô = o·l, the cotangents of ô and l are dô / l
+    and −Δ / l, Δ = Σ dô·o a query, and the rest is the mathematics of
+    :func:`block_flash_backward` (its docstring), each tile's scores masked
+    by the selection.  The kernel is :func:`_sparse_bwd_kernel`,
+    ``sparse_flash_bwd``, over the grid (B·KV, q tile, k tile): a step reads
+    one k and one v block and decodes one plane of the selection for the
+    group's heads, and dk and dv are the group's sums.  Tiles of its own,
+    whole multiples of the selection's width, unless ``tq`` and ``tk`` say
+    (multiples of 128, and ``tk`` of the width)."""
+    return _sparse_bwd(q, k, v, o, m, l, do, words_t, heads, scale,
+                       tq or _bwd_tile(q.shape[1], _BWD_TQ),
+                       tk or _round_up(_bwd_tile(k.shape[1], _BWD_TK),
+                                       words_t.shape[1]), interpret)
 
 
 def _block_flash_bwd(causal, scale, tq, tk, interpret, res, cts):

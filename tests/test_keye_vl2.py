@@ -319,22 +319,23 @@ def test_the_attention_kernels_under_a_selection_match_the_dense_masked_einsum(
         t, tk, rep):
     """``sparse_flash_fwd`` and ``sparse_flash_bwd`` in interpret mode
     against einsums over the whole masked score matrix, three heads a
-    sequence sharing its selection: the block state and every cotangent,
-    with a tile that spans one bit and one that spans two.  The forward
-    takes the key-value heads, ``rep`` query heads a group (3: one group a
-    sequence); the backward and the einsums take them repeated."""
+    sequence sharing its selection: the output and the row maxima, and every
+    cotangent of the output, with a tile that spans one bit and one that
+    spans two.  Both kernels take the key-value heads as they are, ``rep`` query
+    heads a group (3: one group a sequence); the einsums take them repeated,
+    so their k and v cotangents are the group's sums."""
     b, heads, d = 2, 3, 32
     rng = np.random.default_rng(t)
     q = jnp.asarray(rng.standard_normal((b * heads, t, d)), jnp.float32)
     k_kv, v_kv = (jnp.asarray(rng.standard_normal((b * heads // rep, t, d)),
                               jnp.float32) for _ in range(2))
-    k, v = (jnp.repeat(x, rep, axis=0) for x in (k_kv, v_kv))
     sel = _selection(b, t, 0)
     words = sparse_indexer.pack_selection(jnp.asarray(sel))
     mask = jnp.repeat(jnp.asarray(sel), heads, axis=0)
     scale = d ** -0.5
 
-    def dense(q, k, v):
+    def dense(q, k_kv, v_kv):
+        k, v = (jnp.repeat(x, rep, axis=0) for x in (k_kv, v_kv))
         s = jnp.where(mask, jnp.einsum("bqd,bkd->bqk", q, k) * scale, -1e30)
         m = jax.lax.stop_gradient(jnp.max(s, -1))
         p = jnp.where(mask, jnp.exp(s - m[..., None]), 0.0)
@@ -344,24 +345,30 @@ def test_the_attention_kernels_under_a_selection_match_the_dense_masked_einsum(
         o, m, l = pallas_attention.sparse_flash_forward(
             q, k_kv, v_kv, words, heads=heads, scale=scale, tq=128, tk=tk,
             interpret=True)
-        o_d, m_d, l_d = dense(q, k, v)
+        o_d, m_d, l_d = dense(q, k_kv, v_kv)
         do = jnp.asarray(rng.standard_normal(o.shape), jnp.float32)
-        dl = jnp.asarray(rng.standard_normal(l.shape), jnp.float32)
-        want = jax.vjp(lambda *a: dense(*a)[::2], q, k, v)[1]((do, dl))
+        want = jax.vjp(lambda *a: (lambda o, _, l: o / l[..., None])(
+            *dense(*a)), q, k_kv, v_kv)[1](do)
         got = pallas_attention.sparse_flash_backward(
-            q, k, v, m, do, dl, jnp.swapaxes(words, 1, 2), heads=heads,
-            scale=scale, interpret=True)
-    _close(o / l[..., None], o_d / l_d[..., None], tol=1e-5)
+            q, k_kv, v_kv, o, m, l, do,
+            jnp.swapaxes(words, 1, 2), heads=heads, scale=scale,
+            interpret=True)
+    _close(o, o_d / l_d[..., None], tol=1e-5)
     np.testing.assert_allclose(np.asarray(m), np.asarray(m_d), rtol=1e-5, atol=1e-5)
     for a, w_ in zip(got, want):
+        assert a.shape == w_.shape
         _close(a, w_, tol=1e-5)
 
 
 def test_the_whole_causal_selection_is_block_flash():
-    """Every causal key selected: ``sparse_flash_fwd`` (one key-value group
-    of three heads a sequence) and ``sparse_flash_bwd`` give what
-    ``block_flash`` and its backward give under ``causal`` on the key-value
-    heads repeated, the dense kernels whose tile code they follow."""
+    """Every causal key selected: ``sparse_flash_fwd`` and
+    ``sparse_flash_bwd`` (one key-value group of three heads a sequence)
+    give what ``block_flash`` and its backward give under ``causal`` on the
+    key-value heads repeated, the dense kernels whose tile code they follow:
+    the backward handed ``o``'s cotangent gives what ``block_flash``'s gives
+    from the cotangents of ``o_hat`` and ``l`` that it makes (dô / l and
+    −Σ dô·o / l), its dk and dv the sums of the group's; the forward's
+    output is ``block_flash``'s ``o_hat / l``."""
     b, heads, t, d = 2, 3, 300, 32
     rng = np.random.default_rng(2)
     q, k_kv, v_kv = (jnp.asarray(rng.standard_normal((n, t, d)), jnp.float32)
@@ -371,19 +378,78 @@ def test_the_whole_causal_selection_is_block_flash():
         jnp.asarray(np.tril(np.ones((b, t, t), bool))))
     scale, zero = d ** -0.5, jnp.int32(0)
     do = jnp.asarray(rng.standard_normal((b * heads, t, d)), jnp.float32)
-    dl = jnp.asarray(rng.standard_normal((b * heads, t)), jnp.float32)
     with jax.default_matmul_precision("highest"):
         got = pallas_attention.sparse_flash_forward(
             q, k_kv, v_kv, words, heads=heads, scale=scale, tq=128, tk=128,
             interpret=True)
         want = pallas_attention.block_flash(q, k, v, zero, zero, True, scale,
                                             128, 128, True)
+        o, m, l = got
         g_got = pallas_attention.sparse_flash_backward(
-            q, k, v, got[1], do, dl, jnp.swapaxes(words, 1, 2), heads=heads,
-            scale=scale, interpret=True)
-        g_want = pallas_attention.block_flash_backward(
-            q, k, v, zero, zero, want[1], do, dl, True, scale, 128, 128, True)
-    for a, w_ in zip((*got, *g_got), (*want, *g_want)):
+            q, k_kv, v_kv, o, m, l, do, jnp.swapaxes(words, 1, 2),
+            heads=heads, scale=scale, tq=128, tk=128, interpret=True)
+        dq, dk, dv = pallas_attention.block_flash_backward(
+            q, k, v, zero, zero, want[1], do / l[..., None],
+            -jnp.sum(do * o, -1) / l, True, scale, 128, 128, True)
+    group = lambda x: x.reshape(b, heads, t, d).sum(1)
+    want = (want[0] / want[2][..., None], *want[1:])
+    for a, w_ in zip((*got, *g_got), (*want, dq, group(dk), group(dv))):
+        _close(a, w_, tol=1e-5)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["sparse", "causal"])
+@pytest.mark.parametrize("rep", [1, 2, 8])
+def test_the_grouped_backward_is_the_backward_on_repeated_heads_summed(
+        rep, causal):
+    """``sparse_flash_bwd`` over key-value groups of ``rep`` query heads
+    against two things: what it gives on k and v repeated to one head a
+    group (``rep`` 1: a head a grid step), its dk and dv then summed over
+    each group (dq to the bit, dk and dv, summed in float32 before their one
+    rounding, to float32's rounding), and the dense masked einsum's
+    cotangents of the normalized output.  At a length that is no multiple
+    of the tiles (300 tokens, q tiles of 128 and k tiles of 256), under a
+    selection with a q tile that sees nothing in k tile 0 and tiles no
+    query chose, and under ``causal``'s: every causal key."""
+    b, heads, t, d = 2, 8, 300, 32
+    kv = heads // rep
+    rng = np.random.default_rng(10 + rep)
+    q, k, v = (jnp.asarray(rng.standard_normal((n, t, d)), jnp.float32)
+               for n in (b * heads, b * kv, b * kv))
+    if causal:
+        sel = np.tril(np.ones((b, t, t), bool))
+    else:
+        sel = _selection(b, t, 1)
+        sel[:, 256:, :256] = False  # q tile 2 sees nothing in k tile 0
+    words = sparse_indexer.pack_selection(jnp.asarray(sel))
+    scale = d ** -0.5
+    o, m, l = pallas_attention.sparse_flash_forward(
+        q, k, v, words, heads=heads, scale=scale, tq=128, tk=256,
+        interpret=True)
+    do = jnp.asarray(rng.standard_normal(o.shape), jnp.float32)
+
+    def backward(k, v):
+        return pallas_attention.sparse_flash_backward(
+            q, k, v, o, m, l, do, jnp.swapaxes(words, 1, 2), heads=heads,
+            scale=scale, tq=128, tk=256, interpret=True)
+
+    dq, dk, dv = backward(k, v)
+    dq_1, dk_1, dv_1 = backward(*(jnp.repeat(x, rep, axis=0) for x in (k, v)))
+    np.testing.assert_array_equal(np.asarray(dq), np.asarray(dq_1))
+    group = lambda x: x.reshape(b * kv, rep, t, d).sum(1)
+    for a, w_ in ((dk, group(dk_1)), (dv, group(dv_1))):
+        assert a.shape == (b * kv, t, d)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w_),
+                                   rtol=1e-5, atol=1e-5)
+    mask = jnp.repeat(jnp.asarray(sel), heads, axis=0)
+
+    def dense(q, k, v):
+        k, v = (jnp.repeat(x, rep, axis=0) for x in (k, v))
+        s = jnp.where(mask, jnp.einsum("bqd,bkd->bqk", q, k) * scale, -1e30)
+        return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, -1), v)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.vjp(dense, q, k, v)[1](do)
+    for a, w_ in zip((dq, dk, dv), want):
         _close(a, w_, tol=1e-5)
 
 
@@ -470,15 +536,18 @@ def test_the_step_reports_the_sampled_indexer_loss(monkeypatch):
 
     from mpi4dl_tpu.obs.spans import recorder
 
-    noted = lambda: {(i, path) for kind, i, path in recorder()._sites
-                     if kind == "sparse_plane_heads" and i == id(layer)}
+    kinds = ("sparse_plane_heads", "sparse_bwd_plane_heads")
+    noted = lambda: {(kind, path) for kind, i, path in recorder()._sites
+                     if kind in kinds and i == id(layer)}
     xla = kl()
     assert noted() == set()  # no plane on the einsum path
     _pallas(monkeypatch)
     assert 0 < xla and kl() == pytest.approx(xla, rel=1e-4)
-    # TINY's 4 query heads over 2 key-value heads share a plane two by two
-    assert noted() == {(id(layer), "2")}
-    assert recorder().site_paths("sparse_plane_heads")["2"] >= 1
+    # TINY's 4 query heads over 2 key-value heads share a plane two by two,
+    # forward and backward
+    assert noted() == {(kind, "2") for kind in kinds}
+    for kind in kinds:
+        assert recorder().site_paths(kind)["2"] >= 1
 
 
 # --- the expert layer --------------------------------------------------------------

@@ -866,7 +866,7 @@ def test_rule12_tests_are_exempt(tmp_path):
 
 @pytest.mark.parametrize("name, grid", [
     ("sparse_flash_forward:causal:bfloat16", [2, 3, 3]),
-    ("sparse_flash_backward:causal:bfloat16", [6, 3, 3]),
+    ("sparse_flash_backward:causal:bfloat16", [2, 3, 3]),
     ("indexer_select:bfloat16", [1, 3]),
     ("indexer_backward:bfloat16", [1, 2, 32]),
 ])
@@ -874,10 +874,12 @@ def test_sparse_attention_contract_shape(registry_contract, name, grid):
     """The sparse attention's kernels (PR 40): the attention's forward and
     backward under a key selection take the selection's words as one
     more blocked operand (a q tile's rows of 128 words; transposed for the
-    backward) beside the forward's or backward's own blocks.  The forward's
-    grid step is a key-value group: the q block and o, m and l hold its three
-    heads, the k and v blocks one head as they are, and scratch holds m and l
-    of the three (o's block is the accumulator); the indexer's
+    backward) beside the forward's or backward's own blocks.  Both grid
+    steps are a key-value group: the q block (and o, m and l, forward and
+    backward; dô and dq, backward) holds its three heads, the k and v blocks
+    one head as they are; forward scratch holds m and l of the three (o's
+    block is the accumulator), backward scratch dq of the three and dk and
+    dv of the group's whole sequence, a k tile a row; the indexer's
     selection holds a block's keys as int32 images in scratch for the whole
     padded key range (32 bits of 128 words: 4,096), and its gradient's key
     accumulator is resident for the sequence.  No DMA of their own, and
@@ -892,7 +894,10 @@ def test_sparse_attention_contract_shape(registry_contract, name, grid):
         assert blocks["in1"] == blocks["in2"] == [1, 128, 128]
         assert "scratch2" not in blocks
     elif name.startswith("sparse_flash_backward"):
-        assert blocks["in6"] == [1, 128, 768] and blocks["out0"] == [1, 64, 2304]
+        assert blocks["in7"] == [1, 128, 768]
+        assert blocks["in0"] == blocks["out0"] == blocks["scratch0"] == [3, 64, 768]
+        assert blocks["in1"] == blocks["in2"] == blocks["out1"] == [1, 64, 768]
+        assert blocks["scratch1"] == blocks["scratch2"] == [3, 64, 768]  # 3 k tiles
     elif name.startswith("indexer_select"):
         assert blocks["scratch0"] == [4096, 128] and blocks["out0"] == [1, 128, 128]
     else:

@@ -865,13 +865,29 @@ def test_keye_vl2_layer_compiles_for_v5e_with_its_kernels_in_their_scopes(
     ``sparse_indexer``, and the routed layer's scopes as in LFM2's.
     ``sparse_flash_fwd`` takes the 4 key-value heads as they are (a grid
     step a group of 8 query heads): no broadcast or copy of k or v to the 32
-    query heads feeds it."""
+    query heads feeds it.  So does ``sparse_flash_bwd``, one custom call
+    under the VMEM limit it names: q, dô and dq feature-major for the 32
+    heads, k and v (and dk and dv) for the 4, o as the forward wrote it, and
+    no float32 ``[32, 16384, 128]`` array (a copy of dô) in the attention's
+    backward."""
+    from mpi4dl_tpu.ops.pallas_attention import _sparse_bwd_vmem_bytes
+
     said = _compiled_layer("keye_vl2", 0, 1, one_chip, seq=16384)
     forward = [r["operands"] for r in (r for r, _ in said)
                if r["name"].startswith("sparse_flash_fwd")]
     assert len(forward) == 2 and all(ops == [
         "bf16[32,16384,128]", "bf16[4,16384,128]", "bf16[4,16384,128]",
         "s32[1,16384,512]"] for ops in forward), forward
+    backward = [r for r, _ in said if r["name"].startswith("sparse_flash_bwd")]
+    assert [r["operands"] for r in backward] == [[
+        "bf16[32,128,16384]", "bf16[4,128,16384]", "bf16[4,128,16384]",
+        "f32[32,16384,128]", "bf16[32,128,16384]", "f32[32,1,16384]",
+        "f32[32,1,16384]", "s32[1,512,16384]"]], [r["operands"] for r in backward]
+    assert backward[0]["vmem_bytes"] == [
+        _sparse_bwd_vmem_bytes(8, 1024, 1024, 16384, 128, 128, 512, 2) + 2 ** 21]
+    assert not [d["key"] for r, d in said if d["pass"] == "backward"
+                and "attention_core" in d["scopes"]
+                and "f32[32,16384,128]" in r["types"]]
     kernels = [(r["name"].split(".")[0], d) for r, d in said
                if d["cls"] == "kernel" and not r["name"].startswith("ragged-dot")]
     assert sorted((n, d["pass"]) for n, d in kernels) == [
